@@ -148,9 +148,6 @@ def make_shard_map_agg_step(mesh, plan: AggConfig, nc: int,
     explicit-collective shape as the count/serve steps
     (parallel/mesh.py), with the aggregate state as the carried operand.
     Rows pad with ``valid=False`` so the pad never counts."""
-    from spark_bam_tpu.parallel.mesh import _shard_map_compat
-
-    shard_map = _shard_map_compat()
 
     def local_step(state: dict, planes: dict) -> dict:
         delta = _reduce_chunk(plan, nc, planes)
@@ -158,12 +155,12 @@ def make_shard_map_agg_step(mesh, plan: AggConfig, nc: int,
         return {k: state[k] + delta[k] for k in state}
 
     return jax.jit(
-        shard_map(
+        jax.shard_map(
             local_step,
             mesh=mesh,
             in_specs=(P(), P(axis)),
             out_specs=P(),
-            check_rep=False,
+            check_vma=False,
         )
     )
 

@@ -685,6 +685,18 @@ def _router_dashboard(router, listen: str):
     return DashboardServer(listen, provider).start()
 
 
+def _compiles_device_programs(args, config) -> bool:
+    """Whether this invocation will build jax programs (so the compile
+    cache is placed first). The host-only commands never import jax, and
+    this must not make them: it costs seconds of start-up."""
+    return (
+        args.command in ("serve", "aggregate", "export")
+        or any(getattr(args, flag, False)
+               for flag in ("sharded", "resident", "streaming"))
+        or config.backend in ("tpu", "pallas")
+    )
+
+
 def main(argv=None) -> int:
     args = build_parser().parse_args(argv)
     import logging
@@ -697,10 +709,6 @@ def main(argv=None) -> int:
     from spark_bam_tpu import obs
     from spark_bam_tpu.cli.output import Printer
 
-    # Known-benign backend banners (xla_bridge's "Platform ... is
-    # experimental") stay out of every subcommand's stderr; real
-    # warnings still pass (obs/noise.py).
-    obs.install_noise_filter()
     out = open(args.out, "w") if getattr(args, "out", None) else None
     p = Printer(out=out, limit=getattr(args, "print_limit", 10))
     config = Config.from_env()
@@ -809,6 +817,10 @@ def main(argv=None) -> int:
     from spark_bam_tpu.sbi.store import reset_cache_events
 
     reset_cache_events()
+    if _compiles_device_programs(args, config):
+        from spark_bam_tpu.core.platform import enable_compile_cache
+
+        enable_compile_cache()
 
     # --metrics-out (or the env var) turns the process-wide registry on
     # for this run; everything below the root ``cli.<command>`` span

@@ -106,6 +106,15 @@ class RecordGapError(IOError, Unrecoverable):
         self.reason = reason
 
 
+#: What a check of untrusted bytes raises when the INPUT is at fault — the
+#: only errors the device paths may answer by demoting a window to host
+#: zlib. ``IOError`` covers the tokenizer's rejects and footer
+#: disagreements, ``EOFError`` truncation, and ``MalformedInputError`` the
+#: taxonomy above. Compiler, lowering and device runtime errors are none of
+#: these and propagate.
+INPUT_ERRORS = (IOError, EOFError, MalformedInputError)
+
+
 class ResourceExhausted(OSError):
     """The environment ran out of a resource mid-operation — disk space
     (``ENOSPC``), quota (``EDQUOT``), a failing device (``EIO``) — while

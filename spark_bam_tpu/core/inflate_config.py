@@ -16,19 +16,16 @@ two-phase device inflate (tpu/inflate.py):
   bit-reader kernel (tpu/tokenize_device.py / ``tokenize_pallas``)
   decodes Huffman tables and emits token planes on-device; malformed
   members demote per window, never produce wrong bytes.
-* ``auto``   — ``device`` on the TPU backend, ``host`` elsewhere. The
-  honest default: the vmapped bit-reader is profitable where lanes are
-  wide and H2D is the bottleneck; on the CPU backend XLA serializes the
-  symbol loop per lane and the native tokenizer wins by orders of
-  magnitude (measured in docs/benchmarks.md).
+* ``auto``   — ``host`` on every backend measured so far (see
+  ``resolve_tokenize``): decided by measurement, not by capability.
 
-``kernel`` pins the device tokenizer's engine: ``pallas`` (grid lanes,
-VMEM rows), ``xla`` (the vmap form), or ``auto`` (pallas on TPU with
-permanent demote-to-XLA on Mosaic refusal — the ``lz77_resolve_pallas``
-policy). ``donate`` controls ``jax.jit`` buffer donation through the
-dispatch/materialize split so the inflate window ring reuses HBM
-instead of re-allocating per window; ``off`` is a debugging escape
-hatch only.
+``kernel`` pins the device tokenizer's engine: ``xla`` (the vmap form),
+``pallas`` (grid lanes, VMEM rows — Mosaic refuses it for the v5e, so it
+is explicit-only and raises what the compiler raised), or ``auto``
+(``xla`` on every backend). ``donate`` controls ``jax.jit`` buffer
+donation through the dispatch/materialize split so the inflate window
+ring reuses HBM instead of re-allocating per window; ``off`` is a
+debugging escape hatch only.
 """
 
 from __future__ import annotations
@@ -51,19 +48,15 @@ class InflateConfig:
     def donate_enabled(self) -> bool:
         return self.donate == "on"
 
-    def resolve_tokenize(self, backend: str | None = None) -> str:
-        """Collapse ``auto`` to a concrete mode for ``backend`` (the
-        current jax backend when None). Device tokenization pays off
-        where block lanes run in parallel — the TPU grid — and loses
-        badly on the CPU backend's serialized vmap, so auto is
-        backend-gated, not capability-gated."""
-        if self.tokenize != "auto":
-            return self.tokenize
-        if backend is None:
-            import jax
-
-            backend = jax.default_backend()
-        return "device" if backend == "tpu" else "host"
+    def resolve_tokenize(self) -> str:
+        """Collapse ``auto`` to a concrete mode. The device
+        bit-reader is a serial per-block symbol loop under vmap: the CPU
+        backend serializes it, and on a TPU v5e one 24 MiB window took
+        250 s against 12.4 s for the whole fused window with the native
+        tokenizer (one smoke run, CHANGES.md PR 22). So ``auto`` is
+        ``host`` on both; ``tokenize=device`` stays reachable by its
+        explicit setting."""
+        return "host" if self.tokenize == "auto" else self.tokenize
 
     @staticmethod
     @functools.lru_cache(maxsize=64)

@@ -74,9 +74,6 @@ def serve_worker(
     from spark_bam_tpu.serve.server import ServerThread
     from spark_bam_tpu.serve.service import SplitService
 
-    # Keep the platform-is-experimental banner (and nothing else) out of
-    # worker stderr — N workers each re-import jax.
-    obs.install_noise_filter()
     # A live registry regardless of --metrics-out: the stats op's
     # split_resolutions (the per-worker warm-tier proof) reads it.
     if not obs.enabled():

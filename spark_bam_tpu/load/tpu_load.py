@@ -311,7 +311,7 @@ def stream_read_batches(
 def count_reads_tpu(path, config: Config = Config()) -> int:
     """count-reads via the streaming checker: O(window) host memory, device
     windows double-buffered, per-window counts reduced on device. This is
-    the same code path bench.py measures."""
+    the same code path chip_smoke.py drives."""
     from spark_bam_tpu.tpu.stream_check import StreamChecker
 
     with obs.span("load.count", path=str(path)):

@@ -22,6 +22,7 @@ log = logging.getLogger(__name__)
 
 _SRC = Path(__file__).parent / "spark_bam_native.cpp"
 _LIB_CACHE: list = []  # [lib or None], filled once
+_LOAD_INFO: dict = {}  # path / built (this process ran g++) / error
 _LOAD_LOCK = threading.Lock()  # concurrent first-use (pipeline threads)
 
 
@@ -43,14 +44,16 @@ def _build(src: Path, out: Path) -> bool:
                 os.replace(tmp, out)
                 return True
             except FileNotFoundError as e:
+                _LOAD_INFO["error"] = f"g++ not found: {e}"
                 log.warning("native build failed (%s); using Python fallbacks", e)
                 return False
             except subprocess.CalledProcessError as e:
                 last_err = e
+        tail_err = (last_err.stderr or b"").decode(errors="replace")[-500:]
+        _LOAD_INFO["error"] = f"g++ rc={last_err.returncode}: {tail_err}"
         log.warning(
             "native build failed (rc=%s): %s; using Python fallbacks",
-            last_err.returncode,
-            (last_err.stderr or b"").decode(errors="replace")[-500:],
+            last_err.returncode, tail_err,
         )
         return False
     finally:
@@ -83,15 +86,37 @@ def load_native():
         return _load_native_locked()
 
 
+def native_info() -> dict:
+    """How this process got the library: ``path``, ``built`` (g++ ran here,
+    as opposed to a cached .so) and, when it is unavailable, ``error``."""
+    load_native()
+    return dict(_LOAD_INFO)
+
+
+def require_native(why: str):
+    """``load_native()`` for callers that cannot do without it: raises,
+    naming the build error, where the optional paths return None."""
+    lib = load_native()
+    if lib is None:
+        raise RuntimeError(
+            f"{why} needs the native library "
+            f"({_SRC.name}) and it is unavailable: "
+            f"{_LOAD_INFO.get('error', 'unknown build error')}"
+        )
+    return lib
+
+
 def _load_native_locked():
     digest = hashlib.sha256(_SRC.read_bytes()).hexdigest()[:16]
     out = _SRC.parent / f"_spark_bam_native_{digest}_{_host_token()}.so"
+    _LOAD_INFO.update(path=str(out), built=not out.exists())
     if not out.exists() and not _build(_SRC, out):
         _LIB_CACHE.append(None)
         return None
     try:
         lib = ctypes.CDLL(str(out))
     except OSError as e:
+        _LOAD_INFO["error"] = f"dlopen failed: {e}"
         log.warning("native load failed (%s); using Python fallbacks", e)
         _LIB_CACHE.append(None)
         return None

@@ -15,7 +15,6 @@ Everything is a shared no-op until ``obs.configure()`` runs (the CLI's
 """
 
 from spark_bam_tpu.obs import account, flight, sampler, slo, timeseries, trace
-from spark_bam_tpu.obs.noise import install_noise_filter
 from spark_bam_tpu.obs.registry import (
     NOOP,
     Counter,
@@ -54,7 +53,6 @@ __all__ = [
     "flight",
     "gauge",
     "histogram",
-    "install_noise_filter",
     "observe",
     "read_jsonl",
     "registry",

@@ -12,7 +12,7 @@ it needs the event buffer); this module renders *snapshots*:
   the CLI golden reports use, so per-stage timings read like the rest of
   the toolkit's output.
 - ``stage_totals``: compact ``{span_name: {count, total_ms}}`` dict —
-  the per-stage breakdown bench.py attaches to BENCH_*.json captures.
+  a per-stage breakdown small enough to attach to a run's record.
 """
 
 from __future__ import annotations

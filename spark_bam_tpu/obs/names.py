@@ -49,7 +49,8 @@ NAMES = frozenset({
     # check — record-boundary checker
     "check.accepted", "check.candidates", "check.count_escape_retries",
     "check.defer_resolved", "check.defer_retries", "check.deferred",
-    "check.escaped", "check.find_record_start", "check.positions",
+    "check.escaped", "check.find_record_start", "check.fused_demotions",
+    "check.positions",
     "check.window", "check.windows",
     # cli — root spans, one per subcommand (cli/main.py)
     "cli.aggregate", "cli.check-bam", "cli.check-blocks",
@@ -103,7 +104,8 @@ NAMES = frozenset({
     # inflate — device-resident BGZF inflate (docs/design.md)
     "inflate.block", "inflate.blocks", "inflate.bytes",
     "inflate.device_kernel", "inflate.device_ms", "inflate.device_windows",
-    "inflate.h2d", "inflate.h2d_bytes", "inflate.h2d_ms", "inflate.host_ms",
+    "inflate.h2d", "inflate.h2d_bytes", "inflate.h2d_ms",
+    "inflate.host_demotions", "inflate.host_ms",
     "inflate.pack", "inflate.rounds", "inflate.stall_ms", "inflate.stalls",
     "inflate.tokenize", "inflate.tokenize_blocks",
     "inflate.tokenize_demotions", "inflate.tokenize_device",

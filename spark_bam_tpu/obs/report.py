@@ -1,7 +1,6 @@
 """Render JSONL metrics traces as a human report (reference stats format).
 
-The ``spark-bam-tpu metrics-report`` subcommand and ``tools/tpu_watch.py``
-both consume this: parse the JSONL a ``--metrics-out`` run emitted,
+The ``spark-bam-tpu metrics-report`` subcommand consumes this: parse the JSONL a ``--metrics-out`` run emitted,
 regroup span events by name, and render per-stage duration statistics
 with the same ``core/stats.py`` formatting the golden CLI reports use.
 
@@ -148,17 +147,3 @@ def render_merged_report(paths, max_traces: int = 8) -> str:
             f"... {len(merged['traces']) - max_traces} more traces omitted"
         )
     return "\n\n".join(blocks) + "\n"
-
-
-def stage_summary_line(path, top: int = 5) -> str:
-    """One-line ``name=total_ms×count`` digest of the heaviest stages —
-    the tpu_watch per-capture log format."""
-    trace = load_trace(path)
-    totals = [
-        (name, sum(ms), len(ms))
-        for name, ms in trace["spans_by_name"].items()
-    ]
-    totals.sort(key=lambda t: -t[1])
-    return " ".join(
-        f"{name}={total:.0f}ms×{n}" for name, total, n in totals[:top]
-    )

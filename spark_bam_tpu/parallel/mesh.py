@@ -258,22 +258,14 @@ def shard_windows(
     return mesh_steps(mesh, axis).put(windows)
 
 
-def _shard_map_compat():
-    """jax.shard_map across the 0.6/0.7 API rename (check_rep → check_vma)."""
-    try:
-        from jax import shard_map as _shard_map
+def _mesh_pallas_interpret(mesh: Mesh, flags_impl: str) -> bool:
+    """Interpret mode by where THIS mesh's kernels run (not the process
+    default): Mosaic on a TPU, interpret on the CPU, an error elsewhere."""
+    from spark_bam_tpu.tpu.pallas_kernels import interpret_for_platform
 
-        def shard_map(f, *, mesh, in_specs, out_specs, check_rep):
-            return _shard_map(
-                f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                check_vma=check_rep,
-            )
-
-        return shard_map
-    except ImportError:  # jax < 0.7
-        from jax.experimental.shard_map import shard_map
-
-        return shard_map
+    return flags_impl == "pallas" and interpret_for_platform(
+        mesh.devices.flat[0].platform
+    )
 
 
 def make_shard_map_check_step(mesh: Mesh, reads_to_check: int = 10, axis: str = "data"):
@@ -285,7 +277,6 @@ def make_shard_map_check_step(mesh: Mesh, reads_to_check: int = 10, axis: str = 
     over the mesh axis — the XLA collective riding ICI. Semantically
     identical; kept as the explicit form the multi-host deployment uses.
     """
-    shard_map = _shard_map_compat()
 
     def local_step(windows, ns, at_eofs, truth, lengths, num_contigs):
         def one(window, n, at_eof, tr):
@@ -310,7 +301,7 @@ def make_shard_map_check_step(mesh: Mesh, reads_to_check: int = 10, axis: str = 
         return verdicts, totals
 
     return jax.jit(
-        shard_map(
+        jax.shard_map(
             local_step,
             mesh=mesh,
             in_specs=(P(axis), P(axis), P(axis), P(axis), P(), P()),
@@ -318,7 +309,7 @@ def make_shard_map_check_step(mesh: Mesh, reads_to_check: int = 10, axis: str = 
             # The kernel's scan carries start from unvarying constants; skip
             # the replication check rather than thread pvary through shared
             # kernel code.
-            check_rep=False,
+            check_vma=False,
         )
     )
 
@@ -340,14 +331,10 @@ def _make_sharded_stats_step(
     position-scale counter overflows past ~64 devices × 32 MB windows.
     Position totals are host-derivable (callers know their owned spans).
     """
-    shard_map = _shard_map_compat()
 
     # Interpret mode is decided by where THIS mesh's kernels actually run
     # (not the process-default backend): Mosaic compiles only on real TPUs.
-    pallas_interpret = (
-        flags_impl == "pallas"
-        and mesh.devices.flat[0].platform != "tpu"
-    )
+    pallas_interpret = _mesh_pallas_interpret(mesh, flags_impl)
 
     def one(window, n, at_eof, lo, own, tr, lengths, num_contigs):
         res = check_window(
@@ -379,12 +366,12 @@ def _make_sharded_stats_step(
 
         in_specs = (P(axis), P(axis), P(axis), P(axis), P(axis), P(), P())
     return jax.jit(
-        shard_map(
+        jax.shard_map(
             local_step,
             mesh=mesh,
             in_specs=in_specs,
             out_specs=P(),
-            check_rep=False,
+            check_vma=False,
         )
     )
 
@@ -473,13 +460,9 @@ def make_shard_map_full_step(
     """
     from spark_bam_tpu.check.flags import BIT, FLAG_NAMES
 
-    shard_map = _shard_map_compat()
     bit0 = int(BIT["tooFewFixedBlockBytes"])
     n_flags = len(FLAG_NAMES)
-    pallas_interpret = (
-        flags_impl == "pallas"
-        and mesh.devices.flat[0].platform != "tpu"
-    )
+    pallas_interpret = _mesh_pallas_interpret(mesh, flags_impl)
 
     def one(window, n, at_eof, lo, own, lengths, num_contigs):
         res = check_window(
@@ -531,12 +514,12 @@ def make_shard_map_full_step(
         return totals, ci, cm, ti, tm
 
     return jax.jit(
-        shard_map(
+        jax.shard_map(
             local_step,
             mesh=mesh,
             in_specs=(P(axis), P(axis), P(axis), P(axis), P(axis), P(), P()),
             out_specs=(P(), P(axis), P(axis), P(axis), P(axis)),
-            check_rep=False,
+            check_vma=False,
         )
     )
 
@@ -557,11 +540,7 @@ def make_shard_map_serve_step(
     The batch shape is fixed by the caller (pad to ``batch_rows``), so
     the jit traces exactly once per step config.
     """
-    shard_map = _shard_map_compat()
-    pallas_interpret = (
-        flags_impl == "pallas"
-        and mesh.devices.flat[0].platform != "tpu"
-    )
+    pallas_interpret = _mesh_pallas_interpret(mesh, flags_impl)
 
     def one(window, n, at_eof, lo, own, lengths, num_contigs):
         res = check_window(
@@ -581,12 +560,12 @@ def make_shard_map_serve_step(
         return jax.vmap(one)(windows, ns, at_eofs, los, owns, lengths, ncs)
 
     return jax.jit(
-        shard_map(
+        jax.shard_map(
             local_step,
             mesh=mesh,
             in_specs=(P(axis),) * 7,
             out_specs=P(axis),
-            check_rep=False,
+            check_vma=False,
         )
     )
 

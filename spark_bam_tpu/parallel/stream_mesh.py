@@ -55,6 +55,7 @@ from spark_bam_tpu.bgzf.block import MAX_BLOCK_SIZE
 from spark_bam_tpu.bgzf.flat import inflate_blocks
 from spark_bam_tpu.core.channel import open_channel
 from spark_bam_tpu.core.config import Config
+from spark_bam_tpu.core.guard import INPUT_ERRORS
 from spark_bam_tpu.parallel.mesh import make_mesh, mesh_steps
 from spark_bam_tpu.tpu.checker import PAD
 from spark_bam_tpu.tpu.inflate import (
@@ -103,6 +104,26 @@ def _halo_block_range(
         extra += metas[b1].uncompressed_size
         b1 += 1
     return b0, b1
+
+
+#: Device bytes one vmapped window row of the mesh steps may need, per
+#: window byte: the v5e compiler reports 2.7 GiB of temporaries for one
+#: 32 MiB row and 15.9 GiB for five (more than the chip holds), so the
+#: budget is 128x, which admits three.
+_ROW_DEVICE_BYTES_PER_WINDOW_BYTE = 128
+
+
+def _rows_fitting_device(device, kernel_window: int) -> int:
+    """Rows of ``kernel_window`` one device can check in a single step: by
+    the memory the device reports (a TPU does; the CPU backend reports
+    none, and is not bounded here)."""
+    stats = device.memory_stats()
+    limit = (stats or {}).get("bytes_limit")
+    if not limit:
+        return 1 << 30
+    return max(
+        1, limit // (_ROW_DEVICE_BYTES_PER_WINDOW_BYTE * (kernel_window + PAD))
+    )
 
 
 class _ShardedStream:
@@ -157,8 +178,9 @@ class _ShardedStream:
 
         n_local = self.n_global // num_processes
         kw = self.kernel_window
-        self.step_rows_local = n_local * max(
-            1, chunk_bytes // ((kw + PAD) * max(n_local, 1))
+        self.step_rows_local = n_local * min(
+            max(1, chunk_bytes // ((kw + PAD) * max(n_local, 1))),
+            _rows_fitting_device(self.mesh.devices.flat[0], kw),
         )
         if self.per_proc:
             self.step_rows_local = min(self.step_rows_local, self.per_proc)
@@ -180,8 +202,10 @@ class _ShardedStream:
         if self.device_inflate:
             try:
                 view = inflate_group_device(ch, run)
-            except Exception:
-                view = None  # host zlib is the permanent fallback
+            except INPUT_ERRORS:
+                view = None  # host zlib answers input the tokenizer rejects
+            if view is None:
+                obs.count("inflate.host_demotions")
         if view is None:
             view = inflate_blocks(ch, run, threads=8)
         at_eof = b1 == len(self.metas)

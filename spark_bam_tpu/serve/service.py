@@ -39,7 +39,7 @@ from spark_bam_tpu.obs.timeseries import RingStore
 from spark_bam_tpu.bgzf.flat import flatten_file
 from spark_bam_tpu.core.config import Config
 from spark_bam_tpu.core.faults import LatencyTracker
-from spark_bam_tpu.core.guard import ResourceExhausted
+from spark_bam_tpu.core.guard import INPUT_ERRORS, ResourceExhausted
 from spark_bam_tpu.parallel.mesh import make_mesh, mesh_steps
 from spark_bam_tpu.serve.admission import CLASS_OF, AdmissionGate
 from spark_bam_tpu.serve.batcher import Batcher, RowTask
@@ -938,11 +938,11 @@ class SplitService:
                     batch.columns, plan, fs.nc,
                     steps=self.steps, chunk=chunk,
                 )
-            except Exception:
-                # Device path down (no mesh step for this shape, XLA
-                # failure): the numpy oracle answers identically, just
-                # slower — availability over speed, counted so the
-                # dashboard surfaces the regression.
+            except INPUT_ERRORS:
+                # Planes the device reduction rejects as input: the numpy
+                # oracle answers identically, just slower, counted so the
+                # dashboard surfaces it. Compiler and device runtime
+                # errors are not input errors and fail the request.
                 obs.count("agg.host_fallbacks")
                 vectors = host_aggregate(batch.columns, plan, fs.nc)
         with obs.span("agg.encode", path=fs.path):

@@ -73,6 +73,7 @@ from spark_bam_tpu.bgzf.flat import (
     FlatView, inflate_blocks, read_run_payloads, stage_run_payloads,
 )
 from spark_bam_tpu.core.channel import open_channel
+from spark_bam_tpu.core.guard import INPUT_ERRORS
 
 # Fixed token-row width: one BGZF block inflates to ≤ MAX_BLOCK_SIZE
 # (reference Block.scala:49-51).
@@ -161,60 +162,37 @@ _resolve_planes = jax.jit(_resolve_body)
 _resolve_planes_donated = jax.jit(_resolve_body, donate_argnums=(0,))
 
 
-# Fused-Pallas LZ77 engine selection. "auto" uses the Pallas kernel on the
-# TPU backend (per-block VMEM rows, in-kernel early exit) and the XLA
-# while_loop elsewhere; a Mosaic lowering/compile failure demotes to XLA
-# permanently for the process (logged once). SPARK_BAM_LZ77=xla|pallas pins.
-_lz77_engine: str | None = None
-
-
+# LZ77 resolve engine. ``auto`` is the XLA while_loop on every backend:
+# Mosaic refuses ``lz77_resolve_pallas`` for the v5e (a (1, 64 Ki) row block
+# is not divisible by 8 sublanes, and past that the body gathers over a
+# whole 64 Ki row), so the kernel is reachable only by its explicit
+# setting, SPARK_BAM_LZ77=pallas, and then raises what the compiler raised.
 def _lz77_impl() -> str:
-    global _lz77_engine
-    if _lz77_engine is None:
-        env = os.environ.get("SPARK_BAM_LZ77", "").lower()
-        if env in ("xla", "pallas"):
-            _lz77_engine = env
-        else:
-            _lz77_engine = (
-                "pallas" if jax.default_backend() == "tpu" else "xla"
-            )
-    return _lz77_engine
+    env = os.environ.get("SPARK_BAM_LZ77", "").lower()
+    return env if env in ("xla", "pallas") else "xla"
 
 
 def _dispatch_resolve(packed: np.ndarray):
     """H2D + resolve dispatch (async; nothing is synced here). Returns
     ``(resolved_dev (B, STRIDE) u8, rounds_dev () i32)``."""
-    global _lz77_engine
     if _lz77_impl() == "pallas":
-        try:
-            from spark_bam_tpu.tpu.pallas_kernels import lz77_resolve_pallas
+        from spark_bam_tpu.tpu.pallas_kernels import (
+            interpret_for_platform, lz77_resolve_pallas,
+        )
 
-            dev = jnp.asarray(packed)
-            lit, dist = _unpack_tokens(dev)
-            return lz77_resolve_pallas(lit, dist)
-        except Exception:
-            _lz77_engine = "xla"
-            log.warning(
-                "Pallas LZ77 kernel unavailable; using the XLA resolve "
-                "(reported once per process)", exc_info=True,
-            )
+        lit, dist = _unpack_tokens(jnp.asarray(packed))
+        return lz77_resolve_pallas(
+            lit, dist, interpret=interpret_for_platform()
+        )
     return _resolve_packed(jnp.asarray(packed))
 
 
-# Device-tokenizer engine selection: same demote policy as the LZ77 engine
-# above — "auto" tries the Pallas bit-reader on the TPU backend and falls
-# back to the XLA vmap form permanently for the process on Mosaic refusal.
-# ``Config.inflate``'s kernel= knob pins either engine explicitly.
-_tok_engine: str | None = None
-
-
 def _tok_impl(kernel: str = "auto") -> str:
-    global _tok_engine
-    if kernel in ("xla", "pallas"):
-        return kernel
-    if _tok_engine is None:
-        _tok_engine = "pallas" if jax.default_backend() == "tpu" else "xla"
-    return _tok_engine
+    """Device-tokenizer engine for ``Config.inflate``'s kernel= knob.
+    ``auto`` is the XLA vmap bit-reader on every backend: Mosaic refuses
+    ``tokenize_pallas`` for the v5e the same way it refuses the LZ77
+    kernel, so only ``kernel=pallas`` reaches it, and a refusal raises."""
+    return "pallas" if kernel == "pallas" else "xla"
 
 
 def _dispatch_tokenize(staged_dev, clens_dev, kernel: str = "auto"):
@@ -223,18 +201,14 @@ def _dispatch_tokenize(staged_dev, clens_dev, kernel: str = "auto"):
     device; returns ``(lit, dist, out_lens_dev, ok_dev)`` token planes plus
     the per-row produced length and well-formedness flag the materialize
     sync validates against the block footers."""
-    global _tok_engine
     if _tok_impl(kernel) == "pallas":
-        try:
-            from spark_bam_tpu.tpu.pallas_kernels import tokenize_pallas
+        from spark_bam_tpu.tpu.pallas_kernels import (
+            interpret_for_platform, tokenize_pallas,
+        )
 
-            return tokenize_pallas(staged_dev, clens_dev)
-        except Exception:
-            _tok_engine = "xla"
-            log.warning(
-                "Pallas tokenize kernel unavailable; using the XLA "
-                "bit-reader (reported once per process)", exc_info=True,
-            )
+        return tokenize_pallas(
+            staged_dev, clens_dev, interpret=interpret_for_platform()
+        )
     from spark_bam_tpu.tpu.tokenize_device import tokenize_planes
 
     return tokenize_planes(staged_dev, clens_dev)
@@ -310,8 +284,8 @@ def attribute_ms(host_ms=None, h2d_ms=None, device_ms=None,
                  tokenize_host_ms=None, tokenize_device_ms=None) -> None:
     """Per-window host-vs-device attribution (ROADMAP item 1's missing
     evidence): each phase lands as BOTH a gauge (last window + peak, the
-    ``top``/Prometheus view) and an ms-unit histogram (the stage digest
-    bench attaches to BENCH_HISTORY rows). No-op without a live registry.
+    ``top``/Prometheus view) and an ms-unit histogram. No-op without a
+    live registry.
 
     ``host_ms`` is ONLY the residual host work every mode shares (bulk
     read + boundary scan + staging); the entropy phase reports under the
@@ -635,12 +609,11 @@ def inflate_file_device(path) -> FlatView | None:
 
 
 def resolve_device_inflate(config, use_device: bool = True) -> bool:
-    """Resolve ``Config.device_inflate``'s auto (``None``) state: True only
-    on the TPU backend with the native tokenizer built — the production
-    default per the measured A/B (bench.py's device_inflate probe); False
-    for host-only consumers (never initializes a JAX backend for them) and
-    wherever the tokenizer is missing (the pipeline would demote every
-    window to host zlib anyway, with a warning)."""
+    """Resolve ``Config.device_inflate``'s auto (``None``) state: True on
+    the TPU backend, False elsewhere and for host-only consumers (never
+    initializes a JAX backend for them). On a TPU a host entropy phase
+    without the native tokenizer is an error naming the build failure, not
+    a quiet return to host zlib."""
     if config.device_inflate is not None:
         return config.device_inflate
     if not use_device:
@@ -649,10 +622,11 @@ def resolve_device_inflate(config, use_device: bool = True) -> bool:
 
     if jax.default_backend() != "tpu":
         return False
-    from spark_bam_tpu.native.build import load_native
+    if config.inflate_config.resolve_tokenize() == "host":
+        from spark_bam_tpu.native.build import require_native
 
-    lib = load_native()
-    return lib is not None and hasattr(lib, "sbt_tokenize_deflate")
+        require_native("device inflate on a TPU (tokenize=host)")
+    return True
 
 
 def window_plan(metas: list[Metadata], window_uncompressed: int) -> list[list[Metadata]]:
@@ -714,11 +688,12 @@ class InflatePipeline:
         self._warned_device_demote = False
 
     def _demote_warn(self):
+        obs.count("inflate.host_demotions")
         if not self._warned_device_demote:
             self._warned_device_demote = True
             log.warning(
-                "device inflate failed; demoting window(s) to host zlib "
-                "(reported once per stream)", exc_info=True,
+                "device inflate rejected the input; demoting window(s) to "
+                "host zlib (reported once per stream)", exc_info=True,
             )
 
     def __iter__(self) -> Iterator[FlatView]:
@@ -735,19 +710,21 @@ class InflatePipeline:
 
         def produce(group):
             if self.device_copy:
-                # Host zlib is the permanent correctness fallback: a stream
-                # the tokenizer can't take (or a size disagreement) demotes
-                # the window, never kills the pipeline.
+                # Host zlib answers MALFORMED INPUT only: a stream the
+                # tokenizer can't take (or a size disagreement) demotes the
+                # window, never kills the pipeline. Compiler and device
+                # errors are not input errors and propagate.
                 try:
                     pending = dispatch_group_device(
                         ch, group, file_total=self.total,
                         inflate_spec=self.inflate_spec,
                     )
-                except Exception:
+                    if pending is not None:
+                        return pending
+                    # Host entropy phase without the native tokenizer.
+                    obs.count("inflate.host_demotions")
+                except INPUT_ERRORS:
                     self._demote_warn()
-                    pending = None
-                if pending is not None:
-                    return pending
             return inflate_blocks(
                 ch, group, file_total=self.total, threads=self.threads
             )
@@ -783,11 +760,11 @@ class InflatePipeline:
                         # Materialize on the consumer thread: workers are
                         # already tokenizing the NEXT groups while this D2H
                         # syncs (the double-buffering overlap point). An
-                        # async dispatch error surfaces here — demote just
-                        # this window to host zlib.
+                        # The footer validation runs here — a window whose
+                        # input it rejects demotes to host zlib.
                         try:
                             view = view.materialize()
-                        except Exception:
+                        except INPUT_ERRORS:
                             self._demote_warn()
                             view = inflate_blocks(
                                 ch, self.groups[i], file_total=self.total,

@@ -40,10 +40,11 @@ pointer-doubling with an **in-kernel early exit** the moment every chain
 has reached its root literal (``lax.while_loop``; worst case
 log2(64 Ki) = 16 rounds, typical BAM blocks converge in a handful).
 Unlike the flag kernels this one keeps the per-row ``take_along_axis`` —
-the indices stay inside the 64 Ki block row, but Mosaic may still refuse
-the gather on some TPU generations, so the inflate dispatcher treats any
-lowering failure as a demotion to the (identical-math, also early-exit)
-XLA resolve and logs once. Parity is pinned in interpret mode.
+the indices stay inside the 64 Ki block row. Mosaic refuses it for the
+v5e (tests/test_chip_compile.py records the refusal), so ``auto`` never
+selects it: it runs only under SPARK_BAM_LZ77=pallas, where a lowering
+failure raises. Parity with the (identical-math, also early-exit) XLA
+resolve is pinned in interpret mode.
 """
 
 from __future__ import annotations
@@ -57,6 +58,23 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from spark_bam_tpu.check.flags import BIT
+
+
+def interpret_for_platform(platform: str | None = None) -> bool:
+    """Whether Pallas kernels placed on ``platform`` (the process-default
+    backend when None) run in interpret mode: Mosaic compiles on a TPU, the
+    CPU (the tests' virtual mesh) interprets, and any other platform is an
+    error rather than a silent slow path."""
+    if platform is None:
+        platform = jax.default_backend()
+    if platform == "tpu":
+        return False
+    if platform == "cpu":
+        return True
+    raise RuntimeError(
+        f"Pallas kernels run on tpu (Mosaic) or cpu (interpret mode), "
+        f"not on platform {platform!r}"
+    )
 
 TILE = 32 * 1024
 
@@ -309,11 +327,11 @@ def tokenize_pallas(
 
     Returns ``(lit (B, S) u8, dist (B, S) u16, out_lens (B,) i32,
     ok (B,) bool)``. Bit-serial control flow leans hard on Mosaic
-    (nested ``while_loop``, dynamic 1-D slices); any lowering refusal is
-    a *demotion*, not an error — the inflate dispatcher falls back to
-    the identical-math XLA vmap (``tokenize_device.tokenize_planes``)
-    and logs once, mirroring ``lz77_resolve_pallas``. Parity is pinned
-    in interpret mode by tests/test_tokenize_device.py."""
+    (nested ``while_loop``, dynamic 1-D slices) and Mosaic refuses the
+    kernel for the v5e, so ``auto`` never selects it: it runs only under
+    ``inflate kernel=pallas``, where the refusal raises. The
+    identical-math XLA vmap is ``tokenize_device.tokenize_planes``; parity
+    is pinned in interpret mode by tests/test_tokenize_device.py."""
     from spark_bam_tpu.tpu.tokenize_device import STRIDE as _TOK_S
     from spark_bam_tpu.tpu.tokenize_device import TABLES
 

@@ -129,7 +129,7 @@ def test_fused_funnel_stats_populated(tmp_path):
 
 
 def test_resident_chunk_bytes_cap(tmp_path):
-    """The resident-chunk HBM cap (the r05 worker-crash fix) must bound the
+    """The resident-chunk HBM cap (the device-memory budget) must bound the
     chunk size without changing the count."""
     path = tmp_path / "cap.bam"
     random_bam(path, 17, contigs=(("chr1", 5_000_000),), dup_rate=0.05)

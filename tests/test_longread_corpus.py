@@ -127,8 +127,7 @@ def test_split_resolution_native_equals_python_and_wins(corpus):
     assert native == python
     # Long-read data is where the native scan matters: boundaries are far
     # apart, so the Python oracle walks tens of thousands of positions per
-    # split. Assert a conservative floor; the 1 GB benchmark in ROUND5.md
-    # records the real (~100x+) ratio.
+    # split. Assert a conservative floor.
     assert t_python > 3 * t_native, (t_python, t_native)
 
 

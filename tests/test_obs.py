@@ -3,7 +3,6 @@ round-trip, exporter formats, the disabled no-op fast path, trace
 propagation, the flight recorder, and the ``--metrics-out`` /
 ``metrics-report`` CLI surface."""
 
-import logging
 import threading
 
 import pytest
@@ -349,39 +348,6 @@ def test_histogram_reservoir_deterministic_per_series():
             h.observe(float(v))
     assert a.histogram("x", unit="ms").values == \
         b.histogram("x", unit="ms").values  # crc32-seeded RNG, not hash()
-
-
-# ------------------------------------------------------------ noise filter
-
-
-def _capture_logger(name):
-    records = []
-
-    class _Cap(logging.Handler):
-        def emit(self, record):
-            records.append(record.getMessage())
-
-    lg = logging.getLogger(name)
-    h = _Cap()
-    lg.addHandler(h)
-    return lg, h, records
-
-
-def test_noise_filter_drops_benign_keeps_real_warnings():
-    obs.install_noise_filter()
-    obs.install_noise_filter()  # idempotent: no duplicate filters
-    lg, h, records = _capture_logger("jax._src.xla_bridge")
-    try:
-        assert sum(
-            1 for f in lg.filters if type(f).__name__ == "BenignNoiseFilter"
-        ) == 1
-        lg.warning("Platform 'METAL' is experimental and not all JAX "
-                   "functionality may be correctly supported!")
-        assert records == []  # the known-benign banner is dropped
-        lg.warning("Unable to initialize backend 'tpu': %s", "boom")
-        assert records == ["Unable to initialize backend 'tpu': boom"]
-    finally:
-        lg.removeHandler(h)
 
 
 # ------------------------------------------------------- trace propagation

@@ -1,7 +1,7 @@
 """Big-BAM streaming equality: the product path == native CPU == manifest.
 
 The streaming device path (``count_reads_tpu`` → ``StreamChecker``) is the
-same code bench.py measures; this test pins its count against two
+same code chip_smoke.py drives; this test pins its count against two
 independent sources on a multi-window synthesized BAM: the native C++
 eager checker over the whole flat file, and the synthesis manifest's exact
 read count. Scale via ``SB_BIG_BAM_TEST_BYTES`` (driver/bench runs use

@@ -315,10 +315,9 @@ def test_inflate_config_parse():
     assert full.tokenize == "device" and full.kernel == "pallas"
     assert not full.donate_enabled
     assert InflateConfig.parse("") is InflateConfig.parse("")     # lru cache
-    # auto follows the backend: device iff TPU, host everywhere else.
-    assert InflateConfig.parse("").resolve_tokenize(backend="tpu") == "device"
-    assert InflateConfig.parse("").resolve_tokenize(backend="cpu") == "host"
-    assert full.resolve_tokenize(backend="cpu") == "device"       # pinned
+    # auto is host on every backend (measured); an explicit pin wins.
+    assert InflateConfig.parse("").resolve_tokenize() == "host"
+    assert full.resolve_tokenize() == "device"                    # pinned
     with pytest.raises(ValueError):
         InflateConfig.parse("tokenize=maybe")
     with pytest.raises(ValueError):
@@ -393,20 +392,19 @@ def test_demote_parity_on_kernel_reject(synth_path, reg, monkeypatch):
     assert obs.counter("inflate.tokenize_demotions").value > 0
 
 
-def test_demote_parity_on_kernel_raise(synth_path, monkeypatch):
-    """A kernel that throws (Mosaic refusal stand-in) demotes at dispatch;
-    the pipeline must still produce exact bytes."""
-    from spark_bam_tpu.bgzf.flat import flatten_file
+def test_kernel_raise_propagates(synth_path, monkeypatch):
+    """A kernel that throws (a compiler refusal stand-in) is NOT an input
+    error: the pipeline raises it instead of hiding the device behind
+    host zlib. Only rows the kernel disavows demote (the test above)."""
     from spark_bam_tpu.tpu import tokenize_device
 
     def boom(staged, clens):
         raise RuntimeError("mosaic said no")
 
     monkeypatch.setattr(tokenize_device, "tokenize_planes", boom)
-    host = flatten_file(synth_path)
-    got = _pipeline_bytes(synth_path,
-                          inflate_spec="tokenize=device,kernel=xla")
-    assert np.array_equal(got, host.data)
+    with pytest.raises(RuntimeError, match="mosaic said no"):
+        _pipeline_bytes(synth_path,
+                        inflate_spec="tokenize=device,kernel=xla")
 
 
 def test_donation_keeps_steady_state_allocations_flat(tmp_path):

@@ -122,43 +122,3 @@ def test_count_scan_matches_per_window_kernel(bam1):
     )
     assert int(out["esc_count"]) == 0  # full halos; no escapes expected
     assert int(out["count"]) == want
-
-
-def test_count_repeat_matches_iterated_count(bam1):
-    """count_repeat(iters=K) must equal K x the fused single-window count:
-    the slope-probe's loop body is the real kernel (carry-dependent but
-    value-neutral ``n``), so a collapse to one evaluation — or any drift
-    of the per-iteration result — would corrupt the chip-rate slope."""
-    import jax.numpy as jnp
-
-    from spark_bam_tpu.bam.header import contig_lengths
-    from spark_bam_tpu.tpu.checker import (
-        PAD,
-        make_count_repeat,
-        make_count_window,
-    )
-
-    flat = flatten_file(bam1)
-    lens_arr = np.array(contig_lengths(bam1).lengths_list(), dtype=np.int32)
-    lens = np.zeros(1024, dtype=np.int32)
-    lens[: len(lens_arr)] = lens_arr
-    nc = jnp.int32(len(lens_arr))
-
-    w = 1 << 18
-    padded = np.zeros(w + PAD, dtype=np.uint8)
-    padded[:w] = flat.data[:w]
-
-    ref = make_count_window(w, 10)
-    one = int(ref(
-        jnp.asarray(padded), jnp.asarray(lens), nc,
-        jnp.int32(w), jnp.bool_(False), jnp.int32(0), jnp.int32(w),
-    )["count"])
-    assert one > 0
-
-    kern = make_count_repeat(w, 10)
-    for iters in (1, 7):
-        got = int(kern(
-            jnp.asarray(padded), jnp.asarray(lens), nc,
-            jnp.int32(w), jnp.bool_(False), iters,
-        ))
-        assert got == iters * one
