@@ -46,7 +46,7 @@ ZERO_COUNTERS = (
     "agg.host_fallbacks", "mesh.escapes",
 )
 EVIDENCE_COUNTERS = (
-    "check.windows", "inflate.device_windows", "inflate.tokenize_blocks",
+    "check.windows", "inflate.tokenize_blocks",
     "mesh.steps", "serve.batches", "serve.batch_rows", "funnel.positions",
     "funnel.survivors",
 )

@@ -62,6 +62,7 @@ def state_zeros(plan: AggConfig, nc: int) -> "dict[str, np.ndarray]":
     }
 
 
+@jax.named_scope("agg_reduce")
 def _reduce_chunk(plan: AggConfig, nc: int, planes: dict) -> dict:
     """One window's partial vectors (int32) — the traced core shared by
     the plain jit and the shard_map step."""
@@ -133,11 +134,11 @@ def update_fn(plan: AggConfig, nc: int):
     Cached per (plan, nc) — the plan is frozen/hashable by design."""
 
     @jax.jit
-    def update(state: dict, planes: dict) -> dict:
+    def agg_update(state: dict, planes: dict) -> dict:
         delta = _reduce_chunk(plan, nc, planes)
         return {k: state[k] + delta[k] for k in state}
 
-    return update
+    return agg_update
 
 
 def make_shard_map_agg_step(mesh, plan: AggConfig, nc: int,
@@ -149,14 +150,15 @@ def make_shard_map_agg_step(mesh, plan: AggConfig, nc: int,
     (parallel/mesh.py), with the aggregate state as the carried operand.
     Rows pad with ``valid=False`` so the pad never counts."""
 
-    def local_step(state: dict, planes: dict) -> dict:
+    def agg_step(state: dict, planes: dict) -> dict:
         delta = _reduce_chunk(plan, nc, planes)
-        delta = {k: jax.lax.psum(v, axis) for k, v in delta.items()}  # ← ICI
+        with jax.named_scope("agg_reduce"):
+            delta = {k: jax.lax.psum(v, axis) for k, v in delta.items()}  # ← ICI
         return {k: state[k] + delta[k] for k in state}
 
     return jax.jit(
         jax.shard_map(
-            local_step,
+            agg_step,
             mesh=mesh,
             in_specs=(P(), P(axis)),
             out_specs=P(),

@@ -46,9 +46,11 @@ def _add_metrics(sub):
     )
     sub.add_argument(
         "--profile", default=None, metavar="DIR",
-        help="capture ONE inflate window with jax.profiler.trace into "
-             "this directory (TensorBoard format; SPARK_BAM_PROFILE env "
-             "var works too — fabric workers inherit it)",
+        help="capture ONE steady inflate/count window (the first whose "
+             "shape has run before, so it does not compile) with "
+             "jax.profiler.trace into this directory (TensorBoard format; "
+             "SPARK_BAM_PROFILE env var works too — fabric workers "
+             "inherit it)",
     )
 
 

@@ -49,8 +49,8 @@ NAMES = frozenset({
     # check — record-boundary checker
     "check.accepted", "check.candidates", "check.count_escape_retries",
     "check.defer_resolved", "check.defer_retries", "check.deferred",
-    "check.escaped", "check.find_record_start", "check.fused_demotions",
-    "check.positions",
+    "check.escaped", "check.find_record_start", "check.flush",
+    "check.fused_demotions", "check.pace",
     "check.window", "check.windows",
     # cli — root spans, one per subcommand (cli/main.py)
     "cli.aggregate", "cli.check-bam", "cli.check-blocks",
@@ -97,16 +97,15 @@ NAMES = frozenset({
     "faults.attempt_ms", "faults.hedges", "faults.quarantined",
     "faults.quarantined_blocks", "faults.retries",
     # funnel — two-stage checker candidate funnel (docs/design.md)
-    "funnel.positions", "funnel.reduction", "funnel.survivors",
-    "funnel.window_survivors",
+    "funnel.positions", "funnel.survivors",
     # guard — untrusted-byte decode boundary (core/guard.py)
     "guard.quarantined_blocks", "guard.quarantined_records",
     # inflate — device-resident BGZF inflate (docs/design.md)
     "inflate.block", "inflate.blocks", "inflate.bytes",
-    "inflate.device_kernel", "inflate.device_ms", "inflate.device_windows",
+    "inflate.device_kernel", "inflate.device_ms",
     "inflate.h2d", "inflate.h2d_bytes", "inflate.h2d_ms",
     "inflate.host_demotions", "inflate.host_ms",
-    "inflate.pack", "inflate.rounds", "inflate.stall_ms", "inflate.stalls",
+    "inflate.pack", "inflate.rounds", "inflate.stall_ms",
     "inflate.tokenize", "inflate.tokenize_blocks",
     "inflate.tokenize_demotions", "inflate.tokenize_device",
     "inflate.tokenize_device_ms", "inflate.tokenize_host_ms",
@@ -140,11 +139,15 @@ NAMES = frozenset({
     "scrub.artifacts", "scrub.findings", "scrub.quarantined",
     "scrub.records_checked",
     # serve — split-service daemon (docs/serving.md)
-    "serve.batch_encode", "serve.batch_rows", "serve.batches",
-    "serve.connections", "serve.device_dispatch", "serve.errors",
+    "serve.batch_encode", "serve.batch_pack", "serve.batch_rows",
+    "serve.batch_wait", "serve.batches",
+    "serve.connections", "serve.cycle", "serve.d2h",
+    "serve.device_dispatch",
+    "serve.errors", "serve.h2d",
     "serve.h2d_bytes", "serve.latency_ms", "serve.overloaded",
     "serve.parse", "serve.queue_depth", "serve.queue_ms", "serve.request",
-    "serve.requests", "serve.rewrite", "serve.shed", "serve.stream_aborts",
+    "serve.requests", "serve.rewrite", "serve.scatter", "serve.shed",
+    "serve.step", "serve.stream_aborts",
     "serve.tick", "serve.tuned",
     # serve shm — segment lifecycle + encoded-frame cache
     # (docs/serving.md "Transport")
@@ -161,6 +164,29 @@ NAMES = frozenset({
     "transport.shm_connections", "transport.shm_frames",
     # ts — time-series ring scraper (obs/timeseries.py)
     "ts.scrapes", "ts.series",
+})
+
+#: ``jax.named_scope`` names inside the jitted programs, as they appear in
+#: an operation's name path in a device trace
+#: (``jit(count_window_tokens)/.../check/flags/...``). A reduction finds a
+#: stage by these, so they are a contract like the span names above. The
+#: fused window program (tpu/checker.count_window_tokens) has ``unpack``,
+#: ``lz77_resolve``, ``assemble``, ``check`` with its children ``flags``,
+#: ``funnel`` and ``chain_walk``, ``reduce`` (the two count sums) and
+#: ``carry``; the steps of parallel/mesh.py have the ``check`` family and
+#: ``reduce``; agg/kernels.py has ``agg_reduce``.
+SCOPES = frozenset({
+    "agg_reduce", "assemble", "carry", "chain_walk", "check", "flags",
+    "funnel", "lz77_resolve", "reduce", "unpack",
+})
+
+#: Names of the jitted programs the scopes live in: ``jit_<name>`` is the
+#: XLA module's name, which a device trace shows for each execution.
+PROGRAMS = frozenset({
+    "agg_step", "agg_update", "check_step", "check_window",
+    "confusion_step", "count_scan", "count_step", "count_window",
+    "count_window_raw_program", "count_window_tokens", "full_step",
+    "serve_step", "sharded_check_step",
 })
 
 

@@ -63,6 +63,28 @@ _HIST_SAMPLE_CAP = 4096
 _TRACE_EVENT_CAP = 200_000
 
 
+_SCALARS = (int, float, str, bool)
+_TraceAnnotation = None
+
+
+def _annotation(name: str, attrs: dict):
+    """A ``jax.profiler.TraceAnnotation`` of the span: while a profiler
+    session runs, the span is an event on its thread's line of the
+    capture's host plane, on the clock the device's operations are on
+    (scalar attributes ride along as the event's stats). With no session
+    it is a TraceMe that records nothing, a few hundred nanoseconds.
+    jax is imported on the first span of a live registry, never by the
+    no-op path."""
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        from jax.profiler import TraceAnnotation
+
+        _TraceAnnotation = TraceAnnotation
+    return _TraceAnnotation(
+        name, **{k: v for k, v in attrs.items() if isinstance(v, _SCALARS)}
+    )
+
+
 def _label_key(labels: dict) -> tuple:
     return tuple(sorted(labels.items()))
 
@@ -174,11 +196,15 @@ class Span:
     its duration so nested work — including threads that capture the
     context at the seam — lands in the same tree. With no trace bound,
     spans behave exactly as before (local name-parenting only).
+
+    Every span is also a profiler annotation (``_annotation``), so a
+    ``jax.profiler`` capture shows the program's spans beside the device's
+    operations, on one clock.
     """
 
     __slots__ = ("registry", "name", "attrs", "parent", "depth", "_t0",
                  "t_wall", "trace_id", "span_id", "parent_span_id",
-                 "_ctx_token", "_stack_token")
+                 "_ctx_token", "_stack_token", "_annotation")
 
     def __init__(self, registry: "Registry", name: str, attrs: dict):
         self.registry = registry
@@ -193,6 +219,7 @@ class Span:
         self.parent_span_id = None
         self._ctx_token = None
         self._stack_token = None
+        self._annotation = None
 
     def set(self, **attrs) -> None:
         """Attach attributes mid-span (e.g. measured device time)."""
@@ -218,11 +245,14 @@ class Span:
                 _trace.TraceContext(self.trace_id, self.span_id)
             )
         self._stack_token = _SPAN_STACK.set(stack + (self,))
+        self._annotation = _annotation(self.name, self.attrs)
         self.t_wall = time.time()
         self._t0 = time.perf_counter()
+        self._annotation.__enter__()
         return self
 
     def __exit__(self, *exc) -> None:
+        self._annotation.__exit__(None, None, None)
         ms = (time.perf_counter() - self._t0) * 1e3
         # reset() restores the exact entry-time stack — exits from
         # interleaved asyncio tasks can't pop each other's spans.

@@ -220,19 +220,20 @@ def sharded_check_step(
             window, lengths, num_contigs, n, at_eof, reads_to_check=reads_to_check
         )
         w = window.shape[0] - PAD
-        in_range = jnp.arange(w, dtype=jnp.int32) < n
-        v = res["verdict"] & in_range
-        t = tr & in_range
-        stats = jnp.stack(
-            [
-                jnp.sum((v & t).astype(jnp.int32)),    # true positives
-                jnp.sum((v & ~t).astype(jnp.int32)),   # false positives
-                jnp.sum((~v & t).astype(jnp.int32)),   # false negatives
-                jnp.sum((~v & ~t).astype(jnp.int32)),  # true negatives
-                jnp.sum(in_range.astype(jnp.int32)),   # positions checked
-            ]
-        )
-        return v, res["escaped"] & in_range, stats
+        with jax.named_scope("reduce"):
+            in_range = jnp.arange(w, dtype=jnp.int32) < n
+            v = res["verdict"] & in_range
+            t = tr & in_range
+            stats = jnp.stack(
+                [
+                    jnp.sum((v & t).astype(jnp.int32)),    # true positives
+                    jnp.sum((v & ~t).astype(jnp.int32)),   # false positives
+                    jnp.sum((~v & t).astype(jnp.int32)),   # false negatives
+                    jnp.sum((~v & ~t).astype(jnp.int32)),  # true negatives
+                    jnp.sum(in_range.astype(jnp.int32)),   # positions checked
+                ]
+            )
+            return v, res["escaped"] & in_range, stats
 
     verdicts, escapes, stats = jax.vmap(one)(windows, ns, at_eofs, truth)
     totals = jnp.sum(stats, axis=0)
@@ -278,31 +279,33 @@ def make_shard_map_check_step(mesh: Mesh, reads_to_check: int = 10, axis: str = 
     identical; kept as the explicit form the multi-host deployment uses.
     """
 
-    def local_step(windows, ns, at_eofs, truth, lengths, num_contigs):
+    def check_step(windows, ns, at_eofs, truth, lengths, num_contigs):
         def one(window, n, at_eof, tr):
             res = check_window(
                 window, lengths, num_contigs, n, at_eof,
                 reads_to_check=reads_to_check,
             )
             w = window.shape[0] - PAD
-            in_range = jnp.arange(w, dtype=jnp.int32) < n
-            v = res["verdict"] & in_range
-            t = tr & in_range
-            return v, jnp.stack([
-                jnp.sum((v & t).astype(jnp.int32)),
-                jnp.sum((v & ~t).astype(jnp.int32)),
-                jnp.sum((~v & t).astype(jnp.int32)),
-                jnp.sum((~v & ~t).astype(jnp.int32)),
-                jnp.sum(in_range.astype(jnp.int32)),
-            ])
+            with jax.named_scope("reduce"):
+                in_range = jnp.arange(w, dtype=jnp.int32) < n
+                v = res["verdict"] & in_range
+                t = tr & in_range
+                return v, jnp.stack([
+                    jnp.sum((v & t).astype(jnp.int32)),
+                    jnp.sum((v & ~t).astype(jnp.int32)),
+                    jnp.sum((~v & t).astype(jnp.int32)),
+                    jnp.sum((~v & ~t).astype(jnp.int32)),
+                    jnp.sum(in_range.astype(jnp.int32)),
+                ])
 
         verdicts, stats = jax.vmap(one)(windows, ns, at_eofs, truth)
-        totals = jax.lax.psum(jnp.sum(stats, axis=0), axis)  # ← ICI all-reduce
+        with jax.named_scope("reduce"):
+            totals = jax.lax.psum(jnp.sum(stats, axis=0), axis)  # ← ICI
         return verdicts, totals
 
     return jax.jit(
         jax.shard_map(
-            local_step,
+            check_step,
             mesh=mesh,
             in_specs=(P(axis), P(axis), P(axis), P(axis), P(), P()),
             out_specs=(P(axis), P()),
@@ -315,13 +318,14 @@ def make_shard_map_check_step(mesh: Mesh, reads_to_check: int = 10, axis: str = 
 
 
 def _make_sharded_stats_step(
-    mesh: Mesh, reads_to_check: int, axis: str, row_stats, with_truth: bool,
-    flags_impl: str = "xla", funnel: bool = False,
+    name: str, mesh: Mesh, reads_to_check: int, axis: str, row_stats,
+    with_truth: bool, flags_impl: str = "xla", funnel: bool = False,
 ):
     """Shared scaffolding for the streaming-step makers below: per-row
     ``check_window`` + owned-span mask [lo, own), per-device ``vmap``, and
     the stat vector all-reduced with ``lax.psum`` over the mesh axis.
-    ``row_stats(res, m, tr)`` stacks the workload's counters.
+    ``row_stats(res, m, tr)`` stacks the workload's counters; ``name`` is
+    the compiled program's (``jit_<name>`` in a device trace).
     ``funnel=True`` runs the two-stage candidate funnel per row — verdict
     projections only (the full-check step stays single-pass: its product
     is the per-position flag mask, which the funnel does not preserve).
@@ -343,16 +347,21 @@ def _make_sharded_stats_step(
             pallas_interpret=pallas_interpret, funnel=funnel,
         )
         w = window.shape[0] - PAD
-        i = jnp.arange(w, dtype=jnp.int32)
-        m = (i >= lo) & (i < own)
-        return row_stats(res, m, tr)
+        with jax.named_scope("reduce"):
+            i = jnp.arange(w, dtype=jnp.int32)
+            m = (i >= lo) & (i < own)
+            return row_stats(res, m, tr)
+
+    @jax.named_scope("reduce")
+    def total(stats):
+        return jax.lax.psum(jnp.sum(stats, axis=0), axis)  # ← ICI
 
     if with_truth:
         def local_step(windows, ns, at_eofs, truth, los, owns, lengths, nc):
             stats = jax.vmap(
                 lambda wd, n, e, t, lo, ow: one(wd, n, e, lo, ow, t, lengths, nc)
             )(windows, ns, at_eofs, truth, los, owns)
-            return jax.lax.psum(jnp.sum(stats, axis=0), axis)  # ← ICI
+            return total(stats)
 
         in_specs = (
             P(axis), P(axis), P(axis), P(axis), P(axis), P(axis), P(), P(),
@@ -362,9 +371,10 @@ def _make_sharded_stats_step(
             stats = jax.vmap(
                 lambda wd, n, e, lo, ow: one(wd, n, e, lo, ow, None, lengths, nc)
             )(windows, ns, at_eofs, los, owns)
-            return jax.lax.psum(jnp.sum(stats, axis=0), axis)  # ← ICI
+            return total(stats)
 
         in_specs = (P(axis), P(axis), P(axis), P(axis), P(axis), P(), P())
+    local_step.__name__ = name
     return jax.jit(
         jax.shard_map(
             local_step,
@@ -395,7 +405,7 @@ def make_shard_map_count_step(
         ])
 
     return _make_sharded_stats_step(
-        mesh, reads_to_check, axis, row_stats, with_truth=False,
+        "count_step", mesh, reads_to_check, axis, row_stats, with_truth=False,
         flags_impl=flags_impl, funnel=funnel,
     )
 
@@ -424,7 +434,8 @@ def make_shard_map_confusion_step(
         ])
 
     return _make_sharded_stats_step(
-        mesh, reads_to_check, axis, row_stats, with_truth=True,
+        "confusion_step", mesh, reads_to_check, axis, row_stats,
+        with_truth=True,
         flags_impl=flags_impl, funnel=funnel,
     )
 
@@ -471,6 +482,10 @@ def make_shard_map_full_step(
             pallas_interpret=pallas_interpret,
         )
         w = window.shape[0] - PAD
+        return row_report(res, w, lo, own)
+
+    @jax.named_scope("reduce")
+    def row_report(res, w, lo, own):
         i = jnp.arange(w, dtype=jnp.int32)
         m = (i >= lo) & (i < own)
         fm = jnp.where(m, res["fail_mask"], 0)
@@ -506,16 +521,17 @@ def make_shard_map_full_step(
             two_idx.astype(jnp.int32), two_mask,
         )
 
-    def local_step(windows, ns, at_eofs, los, owns, lengths, nc):
+    def full_step(windows, ns, at_eofs, los, owns, lengths, nc):
         stats, ci, cm, ti, tm = jax.vmap(
             lambda wd, n, e, lo, ow: one(wd, n, e, lo, ow, lengths, nc)
         )(windows, ns, at_eofs, los, owns)
-        totals = jax.lax.psum(jnp.sum(stats, axis=0), axis)  # ← ICI
+        with jax.named_scope("reduce"):
+            totals = jax.lax.psum(jnp.sum(stats, axis=0), axis)  # ← ICI
         return totals, ci, cm, ti, tm
 
     return jax.jit(
         jax.shard_map(
-            local_step,
+            full_step,
             mesh=mesh,
             in_specs=(P(axis), P(axis), P(axis), P(axis), P(axis), P(), P()),
             out_specs=(P(), P(axis), P(axis), P(axis), P(axis)),
@@ -549,19 +565,20 @@ def make_shard_map_serve_step(
             pallas_interpret=pallas_interpret, funnel=funnel,
         )
         w = window.shape[0] - PAD
-        i = jnp.arange(w, dtype=jnp.int32)
-        m = (i >= lo) & (i < own)
-        return jnp.stack([
-            jnp.sum((res["verdict"] & m).astype(jnp.int32)),
-            jnp.sum((res["escaped"] & m).astype(jnp.int32)),
-        ])
+        with jax.named_scope("reduce"):
+            i = jnp.arange(w, dtype=jnp.int32)
+            m = (i >= lo) & (i < own)
+            return jnp.stack([
+                jnp.sum((res["verdict"] & m).astype(jnp.int32)),
+                jnp.sum((res["escaped"] & m).astype(jnp.int32)),
+            ])
 
-    def local_step(windows, ns, at_eofs, los, owns, lengths, ncs):
+    def serve_step(windows, ns, at_eofs, los, owns, lengths, ncs):
         return jax.vmap(one)(windows, ns, at_eofs, los, owns, lengths, ncs)
 
     return jax.jit(
         jax.shard_map(
-            local_step,
+            serve_step,
             mesh=mesh,
             in_specs=(P(axis),) * 7,
             out_specs=P(axis),

@@ -168,31 +168,40 @@ class Batcher:
 
     def _loop(self) -> None:
         while True:
+            # One span over the whole turn, so that what falls between its
+            # phases (the GIL handed to the threads a tick's results woke)
+            # is still the batcher's in a capture.
+            with obs.span("serve.cycle"):
+                if not self._cycle():
+                    return
+
+    def _cycle(self) -> bool:
+        """One turn: wait for a batch, shed, dispatch. False at close."""
+        # From the end of one tick's work to a batch in hand.
+        with obs.span("serve.batch_wait"):
             self._running.wait()
             batch = self._take_batch()
-            if not batch and self._closed:
-                return
-            if not batch:
-                continue
-            # Shed rows whose request deadline already passed.
-            now = time.monotonic()
-            live = []
-            for t in batch:
-                if t.deadline_ts is not None and now > t.deadline_ts:
-                    obs.count("serve.shed")
-                    t.future.set_exception(
-                        TimeoutError("deadline expired in serve queue")
-                    )
-                else:
-                    live.append(t)
-            if not live:
-                continue
+        if not batch:
+            return not self._closed
+        # Shed rows whose request deadline already passed.
+        now = time.monotonic()
+        live = []
+        for t in batch:
+            if t.deadline_ts is not None and now > t.deadline_ts:
+                obs.count("serve.shed")
+                t.future.set_exception(
+                    TimeoutError("deadline expired in serve queue")
+                )
+            else:
+                live.append(t)
+        if live:
             try:
                 self._dispatch(live)
             except BaseException as exc:  # scatter failure to every row
                 for t in live:
                     if not t.future.done():
                         t.future.set_exception(exc)
+        return True
 
     def _dispatch(self, batch: "list[RowTask]") -> None:
         # Pad to the CURRENT target, or up to the next mesh multiple of the
@@ -200,34 +209,44 @@ class Batcher:
         # was taken — the dispatch shape must always cover the batch.
         B = max(self.batch_rows, -(-len(batch) // self.ndev) * self.ndev)
         width = self.width
-        ws = np.zeros((B, width), dtype=np.uint8)
-        ns = np.zeros(B, dtype=np.int32)
-        eofs = np.zeros(B, dtype=bool)
-        los = np.zeros(B, dtype=np.int32)
-        owns = np.zeros(B, dtype=np.int32)
-        lens = np.zeros((B, MAX_CONTIGS), dtype=np.int32)
-        ncs = np.ones(B, dtype=np.int32)  # benign dict for padding rows
-        now = time.monotonic()
-        for i, t in enumerate(batch):
-            ws[i, : len(t.window)] = t.window
-            ns[i] = t.n
-            eofs[i] = t.at_eof
-            los[i] = t.lo
-            owns[i] = t.own
-            lens[i, : len(t.lengths)] = t.lengths
-            ncs[i] = t.nc
-            obs.observe("serve.queue_ms", (now - t.enqueued_ts) * 1000.0)
+        with obs.span("serve.batch_pack", rows=len(batch), shape=B):
+            ws = np.zeros((B, width), dtype=np.uint8)
+            ns = np.zeros(B, dtype=np.int32)
+            eofs = np.zeros(B, dtype=bool)
+            los = np.zeros(B, dtype=np.int32)
+            owns = np.zeros(B, dtype=np.int32)
+            lens = np.zeros((B, MAX_CONTIGS), dtype=np.int32)
+            ncs = np.ones(B, dtype=np.int32)  # benign dict for padding rows
+            now = time.monotonic()
+            for i, t in enumerate(batch):
+                ws[i, : len(t.window)] = t.window
+                ns[i] = t.n
+                eofs[i] = t.at_eof
+                los[i] = t.lo
+                owns[i] = t.own
+                lens[i, : len(t.lengths)] = t.lengths
+                ncs[i] = t.nc
+                obs.observe("serve.queue_ms", (now - t.enqueued_ts) * 1000.0)
         # Padding rows keep lo == own == 0: empty owned span, zero counts.
         put = self.steps.put
         t_wall = time.time()
         t0 = time.perf_counter()
         with obs.span("serve.tick", rows=len(batch), shape=B):
-            out = self._step(
-                put(ws), put(ns), put(eofs), put(los), put(owns),
-                put(lens), put(ncs),
-            )
-            res = np.asarray(out)
+            with obs.span("serve.h2d"):
+                operands = [put(a) for a in
+                            (ws, ns, eofs, los, owns, lens, ncs)]
+            with obs.span("serve.step"):
+                out = self._step(*operands)
+            with obs.span("serve.d2h"):
+                res = np.asarray(out)
         tick_ms = (time.perf_counter() - t0) * 1000.0
+        with obs.span("serve.scatter", rows=len(batch)):
+            self._scatter(batch, res, now, tick_ms, t_wall)
+
+    def _scatter(self, batch: "list[RowTask]", res, now: float,
+                 tick_ms: float, t_wall: float) -> None:
+        """After the tick: counters, each row's cost share and per-trace
+        event, and the rows' futures."""
         self.batch_sizes[len(batch)] += 1
         obs.count("serve.batches")
         obs.observe("serve.batch_rows", len(batch))

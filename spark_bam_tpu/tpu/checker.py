@@ -438,6 +438,7 @@ def _compact_mask(mask, capacity: int):
 # Sentinel bounds for the logical cursor: anything outside [0, n] behaves
 # identically (it can never equal the physical cursor at EOF), so clamping is
 # exact unless the cursor needs to *re-enter* range — tracked per lane.
+@jax.named_scope("check")
 def _check_lanes(
     padded, lengths, num_contigs, n, at_eof,
     reads_to_check: int = 10, flags_impl: str = "xla",
@@ -449,32 +450,36 @@ def _check_lanes(
     directly — for two scalars the scatters are pure overhead that XLA
     cannot eliminate through the sums)."""
     w = padded.shape[0] - PAD
-    if funnel:
-        if flags_impl == "pallas":
-            from spark_bam_tpu.tpu.pallas_kernels import prefilter_check_flags
+    with jax.named_scope("flags"):
+        if funnel:
+            if flags_impl == "pallas":
+                from spark_bam_tpu.tpu.pallas_kernels import (
+                    prefilter_check_flags,
+                )
 
-            F = prefilter_check_flags(
+                F = prefilter_check_flags(
+                    padded, lengths, num_contigs.reshape(1), n.reshape(1),
+                    interpret=pallas_interpret,
+                )
+            else:
+                F = _prefilter_flags(padded, lengths, num_contigs, n)
+        elif flags_impl == "pallas":
+            from spark_bam_tpu.tpu.pallas_kernels import full_check_flags
+
+            F = full_check_flags(
                 padded, lengths, num_contigs.reshape(1), n.reshape(1),
                 interpret=pallas_interpret,
             )
         else:
-            F = _prefilter_flags(padded, lengths, num_contigs, n)
-    elif flags_impl == "pallas":
-        from spark_bam_tpu.tpu.pallas_kernels import full_check_flags
-
-        F = full_check_flags(
-            padded, lengths, num_contigs.reshape(1), n.reshape(1),
-            interpret=pallas_interpret,
-        )
-    else:
-        F = _compute_flags(padded, lengths, num_contigs, n)
+            F = _compute_flags(padded, lengths, num_contigs, n)
     if funnel:
         # Lane-width misc: the walk only ever reads remaining/body_end at
         # (capacity,) positions — full-width materialization is the single
         # biggest non-prefilter cost on the funnel path.
         misc_at = functools.partial(_misc_at, padded, n)
     else:
-        remaining, body_end = _compute_misc(padded, n)
+        with jax.named_scope("flags"):
+            remaining, body_end = _compute_misc(padded, n)
 
         def misc_at(pi):
             return (
@@ -502,7 +507,9 @@ def _check_lanes(
     # --- survivor compaction ---------------------------------------------
     capacity = max(w // 32, 4096)
     if funnel:
-        cand, n_survivors = _compact_mask(survivor, capacity)
+        with jax.named_scope("funnel"):
+            cand, n_survivors = _compact_mask(survivor, capacity)
+            tables = _funnel_tables(padded, n)
         overflow = n_survivors > capacity
         live = cand >= 0
         # Stage 1: full 19-bit flags once at candidate positions, scattered
@@ -511,25 +518,28 @@ def _check_lanes(
         # deep mask is here — deep-failing candidates resolve inside the
         # walk's step logic exactly like fail0/esc0/inexact0 above) or
         # fails it (then the prefilter bits alone are verdict-equivalent).
-        tables = _funnel_tables(padded, n)
-        F_cand = _deep_flags_at(
-            padded, lengths, num_contigs, n, tables,
-            jnp.where(live, cand, _I32(0)),
-        )
-        F_cand = jnp.where(live, F_cand, _I32(0))
-        tgt0 = jnp.where(live, cand, _I32(w))
-        F_deep = jnp.zeros(w + 1, dtype=_I32).at[tgt0].set(
-            F_cand, mode="drop"
-        )[:w]
+        with jax.named_scope("flags"):
+            F_cand = _deep_flags_at(
+                padded, lengths, num_contigs, n, tables,
+                jnp.where(live, cand, _I32(0)),
+            )
+        with jax.named_scope("funnel"):
+            F_cand = jnp.where(live, F_cand, _I32(0))
+            tgt0 = jnp.where(live, cand, _I32(w))
+            F_deep = jnp.zeros(w + 1, dtype=_I32).at[tgt0].set(
+                F_cand, mode="drop"
+            )[:w]
 
         def flags_lookup(pi):
             pre = jnp.take(F, pi, mode="clip")
             return jnp.where(pre == 0, jnp.take(F_deep, pi, mode="clip"), pre)
     else:
-        n_survivors = jnp.sum(survivor.astype(_I32))
+        # No funnel: the survivors' compaction is the walk's own prologue.
+        with jax.named_scope("chain_walk"):
+            n_survivors = jnp.sum(survivor.astype(_I32))
+            (cand,) = jnp.nonzero(survivor, size=capacity, fill_value=-1)
+            cand = cand.astype(_I32)
         overflow = n_survivors > capacity
-        (cand,) = jnp.nonzero(survivor, size=capacity, fill_value=-1)
-        cand = cand.astype(_I32)
         live = cand >= 0
 
         def flags_lookup(pi):
@@ -604,17 +614,14 @@ def _check_lanes(
         ), None
 
     state = (logical, physical, l_overflowed, res, fail_mask, reads_before, reads_parsed, exact)
-    if funnel:
-        # Unrolled walk: the loop-carried scan blocks XLA from fusing the
-        # lane gathers with their producers (~25% of the funnel path); ten
-        # lane-width steps unroll cheaply. The funnel=False scan is kept
-        # verbatim so the funnel A/B baseline measures the original kernel.
+    # Unrolled under the funnel: the loop-carried scan blocks XLA from
+    # fusing the lane gathers with their producers (~25% of the funnel
+    # path); ten lane-width steps unroll cheaply. The funnel=False scan is
+    # kept rolled so the funnel A/B baseline measures the original kernel.
+    with jax.named_scope("chain_walk"):
         state, _ = lax.scan(
-            step, state, jnp.arange(reads_to_check, dtype=_I32), unroll=True
-        )
-    else:
-        state, _ = lax.scan(
-            step, state, jnp.arange(reads_to_check, dtype=_I32)
+            step, state, jnp.arange(reads_to_check, dtype=_I32),
+            unroll=True if funnel else 1,
         )
     logical, physical, l_overflowed, res, fail_mask, reads_before, reads_parsed, exact = state
 
@@ -677,14 +684,19 @@ def check_window(
         reads_to_check=reads_to_check, flags_impl=flags_impl,
         pallas_interpret=pallas_interpret, funnel=funnel,
     )
+    return _scatter_lanes(L, w)
+
+
+@jax.named_scope("check")
+def _scatter_lanes(L: dict, w: int) -> dict:
+    """``_check_lanes``' verdicts scattered back over the F-derived base:
+    the (W,) arrays ``check_window`` returns."""
     survivor, res0 = L["survivor"], L["res0"]
     fail_mask0, inexact0 = L["fail_mask0"], L["inexact0"]
     cand, live, res = L["cand"], L["live"], L["res"]
     fail_mask, reads_before = L["fail_mask"], L["reads_before"]
     reads_parsed, exact = L["reads_parsed"], L["exact"]
     overflow, n_survivors = L["overflow"], L["n_survivors"]
-
-    # --- scatter survivors back over the F-derived base -------------------
     tgt = jnp.where(live, cand, _I32(w))  # dead lanes scatter into the pad row
     res_full = jnp.zeros(w + 1, dtype=jnp.int8).at[tgt].set(
         jnp.where(live, res, jnp.int8(0)), mode="drop"
@@ -748,13 +760,14 @@ def count_window(
             reads_to_check=reads_to_check, flags_impl=flags_impl,
             pallas_interpret=pallas_interpret, funnel=True,
         )
-        own_lane = L["live"] & (L["cand"] >= lo) & (L["cand"] < own)
-        count = jnp.sum(own_lane & (L["res"] == 1))
-        esc = jnp.sum(m & (L["res0"] == 2)) + jnp.sum(
-            own_lane & (L["res"] == 2)
-        )
-        count = jnp.where(L["overflow"], 0, count)
-        esc = jnp.where(L["overflow"], jnp.sum(m), esc)
+        with jax.named_scope("reduce"):
+            own_lane = L["live"] & (L["cand"] >= lo) & (L["cand"] < own)
+            count = jnp.sum(own_lane & (L["res"] == 1))
+            esc = jnp.sum(m & (L["res0"] == 2)) + jnp.sum(
+                own_lane & (L["res"] == 2)
+            )
+            count = jnp.where(L["overflow"], 0, count)
+            esc = jnp.where(L["overflow"], jnp.sum(m), esc)
         return {
             "count": count, "esc_count": esc, "survivors": L["n_survivors"],
         }
@@ -764,11 +777,12 @@ def count_window(
         flags_impl=flags_impl, pallas_interpret=pallas_interpret,
         funnel=funnel,
     )
-    return {
-        "count": jnp.sum(m & res["verdict"]),
-        "esc_count": jnp.sum(m & res["escaped"]),
-        "survivors": res["survivors"],
-    }
+    with jax.named_scope("reduce"):
+        return {
+            "count": jnp.sum(m & res["verdict"]),
+            "esc_count": jnp.sum(m & res["escaped"]),
+            "survivors": res["survivors"],
+        }
 
 
 def _pallas_interpret_for(impl: str) -> bool:
@@ -951,28 +965,30 @@ def _count_from_planes(
     from spark_bam_tpu.tpu.inflate import STRIDE
 
     b = resolved.shape[0]
-    cum = jnp.concatenate(
-        [jnp.zeros(1, _I32), jnp.cumsum(out_lens.astype(_I32))]
-    )
-    i = jnp.arange(window, dtype=_I32)
-    j = i - carry_len
-    blk = jnp.clip(jnp.searchsorted(cum, j, side="right") - 1, 0, b - 1)
-    off = jnp.clip(j - cum[blk], 0, STRIDE - 1)
-    from_blocks = resolved.reshape(-1)[blk * STRIDE + off]
-    carry_v = carry[jnp.clip(i, 0, halo - 1)]
-    val = jnp.where(
-        i < carry_len, carry_v,
-        jnp.where(i < n, from_blocks, jnp.uint8(0)),
-    )
-    padded = jnp.concatenate([val, jnp.zeros(PAD, jnp.uint8)])
+    with jax.named_scope("assemble"):
+        cum = jnp.concatenate(
+            [jnp.zeros(1, _I32), jnp.cumsum(out_lens.astype(_I32))]
+        )
+        i = jnp.arange(window, dtype=_I32)
+        j = i - carry_len
+        blk = jnp.clip(jnp.searchsorted(cum, j, side="right") - 1, 0, b - 1)
+        off = jnp.clip(j - cum[blk], 0, STRIDE - 1)
+        from_blocks = resolved.reshape(-1)[blk * STRIDE + off]
+        carry_v = carry[jnp.clip(i, 0, halo - 1)]
+        val = jnp.where(
+            i < carry_len, carry_v,
+            jnp.where(i < n, from_blocks, jnp.uint8(0)),
+        )
+        padded = jnp.concatenate([val, jnp.zeros(PAD, jnp.uint8)])
     r = count_window(
         padded, lengths, num_contigs, n, at_eof, lo, own,
         reads_to_check=reads_to_check, window=window,
         flags_impl=flags_impl, pallas_interpret=pallas_interpret,
         funnel=funnel,
     )
-    ext = jnp.concatenate([val, jnp.zeros(halo, jnp.uint8)])
-    new_carry = lax.dynamic_slice(ext, (own,), (halo,))
+    with jax.named_scope("carry"):
+        ext = jnp.concatenate([val, jnp.zeros(halo, jnp.uint8)])
+        new_carry = lax.dynamic_slice(ext, (own,), (halo,))
     return {**r, "carry": new_carry, "rounds": rounds}
 
 
@@ -1052,8 +1068,8 @@ def make_count_window_raw(
     pallas_interpret = _pallas_interpret_for(flags_impl)
     tok_interpret = _pallas_interpret_for(tok_impl)
 
-    def run(staged, clens, exp_lens, carry, lengths, num_contigs,
-            carry_len, n, at_eof, lo, own):
+    def count_window_raw_program(staged, clens, exp_lens, carry, lengths,
+                                 num_contigs, carry_len, n, at_eof, lo, own):
         return count_window_raw(
             staged, clens, exp_lens, carry, lengths, num_contigs,
             carry_len, n, at_eof, lo, own,
@@ -1062,7 +1078,8 @@ def make_count_window_raw(
             funnel=funnel, tok_impl=tok_impl, tok_interpret=tok_interpret,
         )
 
-    return jax.jit(run, donate_argnums=(3,)) if donate else jax.jit(run)
+    return jax.jit(count_window_raw_program,
+                   donate_argnums=(3,) if donate else ())
 
 
 def make_count_window_tokens(

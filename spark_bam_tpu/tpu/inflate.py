@@ -54,6 +54,8 @@ import contextlib
 import functools
 import logging
 import os
+import queue
+import threading
 import time
 from concurrent.futures import ThreadPoolExecutor
 from typing import Iterator
@@ -96,10 +98,11 @@ def _unpack_tokens(packed: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Device-side inverse of ``pack_tokens`` (shape-derived batch dim)."""
     plane = packed.shape[0] // 3
     b = plane // STRIDE
-    lit = packed[:plane].reshape(b, STRIDE)
-    dist = lax.bitcast_convert_type(
-        packed[plane:].reshape(b, STRIDE, 2), jnp.uint16
-    )
+    with jax.named_scope("unpack"):
+        lit = packed[:plane].reshape(b, STRIDE)
+        dist = lax.bitcast_convert_type(
+            packed[plane:].reshape(b, STRIDE, 2), jnp.uint16
+        )
     return lit, dist
 
 
@@ -111,9 +114,6 @@ def _resolve_body(lit: jnp.ndarray, dist: jnp.ndarray):
     (roots are the only fixed points — dist=0 ⇒ parent=i), after which
     further doubling is the identity. Worst case ``_DOUBLING_ROUNDS``; a
     literal-only batch costs exactly one gather (the test itself)."""
-    iota = jnp.arange(lit.shape[1], dtype=jnp.int32)[None, :]
-    parent = iota - dist.astype(jnp.int32)
-
     def cond(state):
         _, r, done = state
         return jnp.logical_and(~done, r < _DOUBLING_ROUNDS)
@@ -123,10 +123,13 @@ def _resolve_body(lit: jnp.ndarray, dist: jnp.ndarray):
         nxt = jnp.take_along_axis(p, p, axis=1)
         return nxt, r + jnp.int32(1), jnp.all(nxt == p)
 
-    roots, rounds, _ = lax.while_loop(
-        cond, body, (parent, jnp.int32(0), jnp.bool_(False))
-    )
-    return jnp.take_along_axis(lit, roots, axis=1), rounds
+    with jax.named_scope("lz77_resolve"):
+        iota = jnp.arange(lit.shape[1], dtype=jnp.int32)[None, :]
+        parent = iota - dist.astype(jnp.int32)
+        roots, rounds, _ = lax.while_loop(
+            cond, body, (parent, jnp.int32(0), jnp.bool_(False))
+        )
+        return jnp.take_along_axis(lit, roots, axis=1), rounds
 
 
 @jax.jit
@@ -282,10 +285,9 @@ def _record_rounds(rounds_dev) -> None:
 
 def attribute_ms(host_ms=None, h2d_ms=None, device_ms=None,
                  tokenize_host_ms=None, tokenize_device_ms=None) -> None:
-    """Per-window host-vs-device attribution (ROADMAP item 1's missing
-    evidence): each phase lands as BOTH a gauge (last window + peak, the
-    ``top``/Prometheus view) and an ms-unit histogram. No-op without a
-    live registry.
+    """Per-window host-vs-device attribution: each phase lands in an
+    ms-unit histogram, and the three ``top`` shows (host / h2d / device)
+    also as a gauge (last window + peak). No-op without a live registry.
 
     ``host_ms`` is ONLY the residual host work every mode shares (bulk
     read + boundary scan + staging); the entropy phase reports under the
@@ -295,25 +297,102 @@ def attribute_ms(host_ms=None, h2d_ms=None, device_ms=None,
     r = obs.registry()
     if r is None:
         return
-    for name, v in (("inflate.host_ms", host_ms),
-                    ("inflate.h2d_ms", h2d_ms),
-                    ("inflate.device_ms", device_ms),
-                    ("inflate.tokenize_host_ms", tokenize_host_ms),
-                    ("inflate.tokenize_device_ms", tokenize_device_ms)):
+    for name, v, shown in (
+            ("inflate.host_ms", host_ms, True),
+            ("inflate.h2d_ms", h2d_ms, True),
+            ("inflate.device_ms", device_ms, True),
+            ("inflate.tokenize_host_ms", tokenize_host_ms, False),
+            ("inflate.tokenize_device_ms", tokenize_device_ms, False)):
         if v is not None:
-            r.gauge(name).set(round(v, 3))
+            if shown:
+                r.gauge(name).set(round(v, 3))
             r.histogram(name, unit="ms").observe(v)
+
+
+class DeviceObserver:
+    """Waits on a window's device arrays OFF the thread that feeds the
+    chip, so a live registry changes neither that thread's dispatches nor
+    its waits. Built only under a live registry (``maybe``).
+
+    The feeding thread hands over, per window, the H2D operand and one
+    output of the dispatch with the host times it issued them at. Two
+    daemon threads block on them in order: one observes
+    ``inflate.h2d_ms`` (issue to arrival of the operand, as a rule hidden
+    behind the previous window's program), the other
+    ``inflate.device_ms = t_ready(k) - max(t_dispatch(k), t_ready(k-1))``
+    and ``inflate.rounds``. ``device_ms`` is therefore the program's time
+    plus whatever of its operand's H2D the previous program did not hide
+    (all of it on the first window of a pass)."""
+
+    def __init__(self):
+        self._threads: list = []
+        self._h2d = self._start("obs-h2d", self._on_h2d)
+        self._dev = self._start("obs-device", self._on_device)
+        self._t_ready = 0.0
+
+    @classmethod
+    def maybe(cls) -> "DeviceObserver | None":
+        return cls() if obs.enabled() else None
+
+    def _start(self, name: str, handle) -> queue.SimpleQueue:
+        q: queue.SimpleQueue = queue.SimpleQueue()
+
+        def run():
+            while (item := q.get()) is not None:
+                try:
+                    handle(*item)
+                except Exception:
+                    # A failed program surfaces on the feeding thread, at
+                    # its own next wait; there is nothing to time here.
+                    log.debug("%s: wait failed", name, exc_info=True)
+
+        self._threads.append(
+            threading.Thread(target=run, name=name, daemon=True))
+        self._threads[-1].start()
+        return q
+
+    def window(self, operand, t_put: float, out, t_dispatch: float) -> None:
+        """``operand``: the H2D array (None when the transfer happened on
+        a producer thread); ``out``: the dispatch's ``rounds`` scalar."""
+        if operand is not None:
+            self._h2d.put((operand, t_put))
+        self._dev.put((out, t_dispatch))
+
+    def close(self) -> None:
+        """Drains both threads: every window handed over is observed."""
+        for q in (self._h2d, self._dev):
+            q.put(None)
+        for thread in self._threads:
+            thread.join()
+
+    @staticmethod
+    def _on_h2d(operand, t_put: float) -> None:
+        operand.block_until_ready()
+        attribute_ms(h2d_ms=(time.perf_counter() - t_put) * 1e3)
+
+    def _on_device(self, rounds_dev, t_dispatch: float) -> None:
+        rounds_dev.block_until_ready()
+        t_ready = time.perf_counter()
+        attribute_ms(
+            device_ms=(t_ready - max(t_dispatch, self._t_ready)) * 1e3)
+        self._t_ready = t_ready
+        obs.observe("inflate.rounds", int(rounds_dev), unit="rounds")
 
 
 PROFILE_ENV = "SPARK_BAM_PROFILE"
 _profiled = False
+_profile_seen: set = set()
 
 
 @contextlib.contextmanager
-def maybe_profile_window(label: str = "inflate_window"):
-    """One-shot ``jax.profiler.trace`` around the FIRST window of the
+def maybe_profile_window(label: str = "inflate_window", shape=None):
+    """One-shot ``jax.profiler.trace`` around ONE steady window of the
     process when ``SPARK_BAM_PROFILE`` names a dump directory (the CLI's
-    ``--profile`` flag sets it). Exactly one window is captured — the
+    ``--profile`` flag sets it): the first window whose ``shape`` (what
+    its program's jit key depends on) has executed before, so the capture
+    holds a window that does not compile, with the programs' scopes and
+    the obs spans in it. A pass whose windows all differ in shape (a file
+    of one window) captures nothing. Exactly one window is captured — the
     profiler's own overhead would poison every later window's host/device
     attribution. The dump path lands in the flight ring (and the log) so
     ``top``/postmortems can point an operator at the TensorBoard trace.
@@ -321,6 +400,11 @@ def maybe_profile_window(label: str = "inflate_window"):
     global _profiled
     out = os.environ.get(PROFILE_ENV)
     if not out or _profiled:
+        yield None
+        return
+    key = (label, shape)
+    if key not in _profile_seen:
+        _profile_seen.add(key)
         yield None
         return
     _profiled = True
@@ -378,7 +462,6 @@ def inflate_blocks_device(
         attribute_ms(h2d_ms=(t1 - t0) * 1e3,
                      device_ms=(time.perf_counter() - t1) * 1e3)
         _record_rounds(rounds_dev)
-        obs.count("inflate.device_windows")
     else:
         resolved_dev, rounds_dev = _dispatch_resolve(packed)
         resolved = np.asarray(resolved_dev)[:b]
@@ -480,7 +563,6 @@ class _PendingDeviceView:
                     f"produced={int(lens[bad])}, footer={int(expected[bad])})"
                 )
         _record_rounds(self.rounds_dev)
-        obs.count("inflate.device_windows")
         data = np.concatenate(
             [resolved[i, :n] for i, n in enumerate(self.out_lens.tolist())]
         ) if len(self.out_lens) else np.empty(0, dtype=np.uint8)
@@ -735,22 +817,17 @@ class InflatePipeline:
             ]
             for i in range(len(self.groups)):
                 fut = pending.pop(0)
-                with contextlib.ExitStack() as stack:
-                    if i == 0:
-                        # --profile: the trace spans the first window's
-                        # produce overlap AND its materialize sync, and is
-                        # closed before the window is yielded so consumer
-                        # work stays out of the capture.
-                        stack.enter_context(maybe_profile_window())
+                # --profile: the trace spans one steady window's produce
+                # overlap AND its materialize sync (the first whose padded
+                # block count has run before), and is closed before the
+                # window is yielded so consumer work stays out of it.
+                with maybe_profile_window(shape=max(
+                        len(self.groups[i]) - 1, 0).bit_length()):
                     # Double-buffer health: time spent blocked on the host
                     # producer is exactly the stall the ``depth`` knob
-                    # exists to hide. >1ms of wait counts as a stall.
-                    t0 = time.perf_counter()
-                    view = fut.result()
-                    wait_ms = (time.perf_counter() - t0) * 1e3
-                    obs.observe("inflate.stall_ms", wait_ms, unit="ms")
-                    if wait_ms > 1.0:
-                        obs.count("inflate.stalls")
+                    # exists to hide.
+                    with obs.span("inflate.stall_ms"):
+                        view = fut.result()
                     nxt = i + self.depth
                     if nxt < len(self.groups):
                         pending.append(

@@ -54,7 +54,6 @@ overlaps the device's current one via the same prefetch pool.
 
 from __future__ import annotations
 
-import contextlib
 import time
 from typing import Callable, Iterator
 
@@ -221,8 +220,6 @@ class StreamChecker:
         if obs.enabled():
             obs.count("funnel.positions", screened)
             obs.count("funnel.survivors", survivors)
-            obs.observe("funnel.window_survivors", survivors)
-            obs.observe("funnel.reduction", screened / max(survivors, 1))
 
     def _launcher(self, full_masks: bool = False):
         """Full-output launch (the spans path)."""
@@ -448,7 +445,6 @@ class StreamChecker:
                     deferred.add(base + bad_idx, buf, base)
             if obs.enabled():
                 obs.count("check.windows")
-                obs.count("check.positions", own_end)
                 obs.count("check.deferred", len(bad_idx))
                 # The escaped sum is an O(own_end) pass — only pay it
                 # under a live registry.
@@ -521,7 +517,6 @@ class StreamChecker:
             windows += 1
             chunk += 1
             obs.count("check.windows")
-            obs.count("check.positions", own_end)
             if self.progress is not None:
                 self.progress(windows, base + own_end, self.total)
             # One early escape checkpoint (window 4): escape-prone inputs
@@ -596,7 +591,7 @@ class StreamChecker:
             make_count_window_raw, make_count_window_tokens,
         )
         from spark_bam_tpu.tpu.inflate import (
-            _tok_impl, attribute_ms, maybe_profile_window,
+            DeviceObserver, _tok_impl, maybe_profile_window,
             stage_group_device, tokenize_group,
         )
 
@@ -658,161 +653,150 @@ class StreamChecker:
 
         ch = open_channel(self.path)
         pool = ThreadPoolExecutor(max_workers=self.pipeline.depth)
+        # Under a live registry the per-window device times are taken by
+        # observer threads: this thread dispatches and waits exactly as it
+        # does with the registry off.
+        observer = DeviceObserver.maybe()
         try:
             pending = [
                 pool.submit(produce, ch, g)
                 for g in groups[: self.pipeline.depth]
             ]
-            for gi in range(len(groups)):
-                fut = pending.pop(0)
-                t0 = time.perf_counter()
-                try:
-                    tp = fut.result()
-                except INPUT_ERRORS:
-                    # A stream the tokenizer rejects (or a footer
-                    # disagreement): demote the whole count to the host-
-                    # inflate loop — correctness never depends on phase 1.
-                    demoted = True
-                    break
-                wait_ms = (time.perf_counter() - t0) * 1e3
-                obs.observe("inflate.stall_ms", wait_ms, unit="ms")
-                if wait_ms > 1.0:
-                    obs.count("inflate.stalls")
-                if tp is None:
-                    demoted = True
-                    break
-                nxt = gi + self.pipeline.depth
-                if nxt < len(groups):
-                    pending.append(
-                        pool.submit(produce, ch, groups[nxt])
-                    )
-                if device_tok:
-                    staged_dev, clens_dev, usizes = tp
-                    n = carry_len + int(usizes.sum())
-                else:
-                    packed, out_lens, _b = tp
-                    n = carry_len + int(out_lens.sum())
+            for gi, group in enumerate(groups):
+                n = carry_len + sum(m.uncompressed_size for m in group)
                 at_eof = gi == len(groups) - 1
                 own_end = n if at_eof else max(n - halo, 0)
                 lo = min(max(self.header_end_abs - base, 0), own_end)
-                with contextlib.ExitStack() as stack:
-                    if gi == 0:
-                        # --profile: one-shot capture of the first fused
-                        # window (H2D + count kernel + the rounds sync).
-                        stack.enter_context(maybe_profile_window(
-                            label="count_window"))
+                with obs.span("check.window", window=gi,
+                              members=len(group), n=n):
+                    fut = pending.pop(0)
+                    try:
+                        # The wait for the entropy phase: what the prefetch
+                        # pool exists to hide.
+                        with obs.span("inflate.stall_ms"):
+                            tp = fut.result()
+                    except INPUT_ERRORS:
+                        # A stream the tokenizer rejects (or a footer
+                        # disagreement): demote the whole count to the
+                        # host-inflate loop — correctness never depends on
+                        # phase 1.
+                        demoted = True
+                        break
+                    if tp is None:
+                        demoted = True
+                        break
+                    nxt = gi + self.pipeline.depth
+                    if nxt < len(groups):
+                        pending.append(
+                            pool.submit(produce, ch, groups[nxt])
+                        )
                     if device_tok:
                         # H2D happened on the producer thread
                         # (stage_group_device) — off this critical path.
+                        staged_dev, clens_dev, usizes = tp
                         exp = np.zeros(staged_dev.shape[0], dtype=np.int32)
                         exp[: len(usizes)] = usizes
-                        out = kernel(
-                            staged_dev, clens_dev, jnp.asarray(exp),
-                            carry_dev, lens_dev, nc,
-                            jnp.int32(carry_len), jnp.int32(n),
-                            jnp.bool_(at_eof), jnp.int32(lo),
-                            jnp.int32(own_end),
-                        )
-                        ok_ring.append(out["tok_ok"])
+                        operands = (staged_dev, clens_dev, jnp.asarray(exp))
+                        shape = staged_dev.shape
                         obs.count("inflate.tokenize_blocks", len(usizes))
                     else:
+                        packed, out_lens, _b = tp
+                        shape = (packed.shape, out_lens.shape)
                         obs.count("inflate.h2d_bytes", int(packed.nbytes))
-                        if obs.enabled():
-                            # H2D split: sync the packed transfer alone
-                            # before the kernel dispatch. Only under a live
-                            # registry — the production path stays fully
-                            # async.
-                            t_h2d = time.perf_counter()
-                            packed_dev = jnp.asarray(packed)
-                            packed_dev.block_until_ready()
-                            attribute_ms(
-                                h2d_ms=(time.perf_counter() - t_h2d) * 1e3
+                    # --profile: one steady window (H2D + the program).
+                    with maybe_profile_window("count_window", shape):
+                        t_put = time.perf_counter()
+                        if not device_tok:
+                            with obs.span("inflate.h2d",
+                                          bytes=packed.nbytes):
+                                operands = (
+                                    jnp.asarray(packed),
+                                    jnp.asarray(out_lens.astype(np.int32)),
+                                )
+                        t_dispatch = time.perf_counter()
+                        with obs.span("inflate.device_kernel"):
+                            out = kernel(
+                                *operands, carry_dev, lens_dev, nc,
+                                jnp.int32(carry_len), jnp.int32(n),
+                                jnp.bool_(at_eof), jnp.int32(lo),
+                                jnp.int32(own_end),
                             )
-                        else:
-                            packed_dev = jnp.asarray(packed)
-                        out = kernel(
-                            packed_dev,
-                            jnp.asarray(out_lens.astype(np.int32)),
-                            carry_dev, lens_dev, nc,
-                            jnp.int32(carry_len), jnp.int32(n),
-                            jnp.bool_(at_eof), jnp.int32(lo),
-                            jnp.int32(own_end),
+                    if observer is not None:
+                        observer.window(
+                            None if device_tok else operands[0], t_put,
+                            out["rounds"], t_dispatch,
                         )
+                    if device_tok:
+                        ok_ring.append(out["tok_ok"])
                     carry_dev = out["carry"]
                     carry_len = n - own_end
                     base += own_end
-                    if obs.enabled():
-                        # The rounds sync below is the first wait on the
-                        # dispatch — its wall time IS the window's device
-                        # phase (kernel + scalar D2H).
-                        t_dev = time.perf_counter()
-                        rounds = int(out["rounds"])
-                        attribute_ms(
-                            device_ms=(time.perf_counter() - t_dev) * 1e3
-                        )
-                        obs.observe("inflate.rounds", rounds, unit="rounds")
-                        obs.count("inflate.device_windows")
-                dev_total = (
-                    out["count"] if dev_total is None
-                    else dev_total + out["count"]
-                )
-                dev_esc = (
-                    out["esc_count"] if dev_esc is None
-                    else dev_esc + out["esc_count"]
-                )
-                dev_surv = (
-                    out["survivors"] if dev_surv is None
-                    else dev_surv + out["survivors"]
-                )
-                screened += n
-                ring.append(out["count"])
-                if len(ring) > self.ring_depth:
-                    ring.pop(0).block_until_ready()
-                    # Validate the bit-reader verdicts lazily, at the same
-                    # pacing sync: a rejected row anywhere demotes the
-                    # whole count (the classic loop restarts from scratch;
-                    # nothing was consumed from self.pipeline).
-                    if ok_ring and not bool(ok_ring.pop(0)):
-                        obs.count("inflate.tokenize_demotions")
-                        demoted = True
-                        break
-                windows += 1
-                chunk += 1
-                obs.count("check.windows")
-                obs.count("check.positions", own_end)
-                if self.progress is not None:
-                    self.progress(windows, base, self.total)
-                # Same escape-checkpoint policy as count_reads: one early
-                # sync at window 4, then flush-aligned.
-                if windows == 4 and int(dev_esc):
-                    escaped = True
-                    break
-                if chunk >= flush_every:
+                    dev_total = (
+                        out["count"] if dev_total is None
+                        else dev_total + out["count"]
+                    )
+                    dev_esc = (
+                        out["esc_count"] if dev_esc is None
+                        else dev_esc + out["esc_count"]
+                    )
+                    dev_surv = (
+                        out["survivors"] if dev_surv is None
+                        else dev_surv + out["survivors"]
+                    )
+                    screened += n
+                    ring.append(out["count"])
+                    if len(ring) > self.ring_depth:
+                        with obs.span("check.pace"):
+                            ring.pop(0).block_until_ready()
+                        # Validate the bit-reader verdicts lazily, at the
+                        # same pacing sync: a rejected row anywhere demotes
+                        # the whole count (the classic loop restarts from
+                        # scratch; nothing was consumed from
+                        # self.pipeline).
+                        if ok_ring and not bool(ok_ring.pop(0)):
+                            obs.count("inflate.tokenize_demotions")
+                            demoted = True
+                            break
+                    windows += 1
+                    chunk += 1
+                    obs.count("check.windows")
+                    if self.progress is not None:
+                        self.progress(windows, base, self.total)
+                    # Same escape-checkpoint policy as count_reads: one
+                    # early sync at window 4, then flush-aligned.
+                    if windows == 4 or chunk >= flush_every:
+                        with obs.span("check.flush"):
+                            if int(dev_esc):
+                                escaped = True
+                                break
+                            if chunk >= flush_every:
+                                total += int(dev_total)
+                                if funnel:
+                                    self._funnel_add(
+                                        screened, int(dev_surv))
+                                dev_total = dev_esc = dev_surv = None
+                                chunk = 0
+                                screened = 0
+            if not demoted and ok_ring and not all(
+                    bool(ok) for ok in ok_ring):
+                obs.count("inflate.tokenize_demotions")
+                demoted = True
+            if not (demoted or escaped) and dev_total is not None:
+                with obs.span("check.flush"):
                     if int(dev_esc):
                         escaped = True
-                        break
-                    total += int(dev_total)
-                    if funnel:
-                        self._funnel_add(screened, int(dev_surv))
-                    dev_total = dev_esc = dev_surv = None
-                    chunk = 0
-                    screened = 0
+                    else:
+                        total += int(dev_total)
+                        if funnel:
+                            self._funnel_add(screened, int(dev_surv))
         finally:
             pool.shutdown(wait=True, cancel_futures=True)
             ch.close()
-        if not demoted and ok_ring and not all(bool(ok) for ok in ok_ring):
-            obs.count("inflate.tokenize_demotions")
-            demoted = True
+            if observer is not None:
+                observer.close()
         if demoted:
             obs.count("check.fused_demotions")
             return None
-        if not escaped and dev_total is not None:
-            if int(dev_esc):
-                escaped = True
-            else:
-                total += int(dev_total)
-                if funnel:
-                    self._funnel_add(screened, int(dev_surv))
         if escaped:
             obs.count("check.count_escape_retries")
             saved, self.progress = self.progress, None
@@ -926,7 +910,6 @@ class StreamChecker:
                 windows_done += 1
                 pos_flushed = base + own_end
                 obs.count("check.windows")
-                obs.count("check.positions", own_end)
                 if len(rows) >= cap:
                     out = flush(rows)
                     scr = sum(len(r[0]) for r in rows)
