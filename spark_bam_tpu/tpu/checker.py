@@ -932,14 +932,14 @@ def count_window_tokens(
     ``inflate_blocks_device`` → host concatenate → ``count_window``, which
     bounces every inflated byte through host twice.
 
-    Window assembly is gather-based: byte ``i`` of the logical window is
-    either ``carry[i]`` (the previous window's halo tail) or byte
-    ``j = i - carry_len`` of the concatenated block outputs, located by a
-    ``searchsorted`` over the cumulative ``out_lens`` — zero-length rows
-    (batch padding, empty final BGZF blocks) occupy no output range and
-    are skipped naturally. The new carry is the owned-end tail
-    ``val[own : own+halo]`` (zeros beyond ``n``), exactly the
-    ``halo_windows`` carry discipline.
+    Window assembly is a concatenation done by placing rows
+    (``_assemble_window``): the carry goes to the front of a buffer and
+    each resolved block row is copied whole to ``carry_len + Σ`` of the
+    ``out_lens`` before it, in ascending order, so the bytes a row holds
+    past its length are overwritten by the row that follows — zero-length
+    rows (batch padding, empty final BGZF blocks) leave nothing behind.
+    The new carry is the owned-end tail ``val[own : own+halo]`` (zeros
+    beyond ``n``), exactly the ``halo_windows`` carry discipline.
     """
     from spark_bam_tpu.tpu.inflate import _resolve_body, _unpack_tokens
 
@@ -953,31 +953,49 @@ def count_window_tokens(
     )
 
 
+def _assemble_window(resolved, out_lens, carry, carry_len, n, *, window, halo):
+    """The logical window ``carry[:carry_len] ‖ row_0[:len_0] ‖ row_1[:len_1]
+    ‖ … ‖ zeros`` as (window,) u8, by placing rows: work proportional to the
+    bytes moved, no per-byte lookup.
+
+    Every row of ``out_lens`` is copied WHOLE (all ``stride`` bytes) to its
+    start ``carry_len + Σ len_<b``, in ascending order. The order is what
+    makes the overlap exact: row b's bytes past ``len_b`` land where row
+    b+1 starts, and row b+1 is written after it; a zero-length row (empty
+    BGZF member, batch padding) writes only bytes that the next row or the
+    ``i < n`` mask replaces, and so does the carry's tail past
+    ``carry_len``. The buffer has one row of slack past ``window``: every
+    start is ≤ n ≤ window (the callers' geometry check), so no update is
+    ever clamped — a clamped ``dynamic_update_slice`` would shift the row.
+    """
+    stride = resolved.shape[1]
+    lens = out_lens.astype(_I32)
+    starts = carry_len + jnp.cumsum(lens) - lens
+    buf = jnp.concatenate(
+        [carry, jnp.zeros(window + stride - halo, jnp.uint8)]
+    )
+
+    def place(b, buf):
+        row = lax.dynamic_index_in_dim(resolved, b, keepdims=False)
+        return lax.dynamic_update_slice(buf, row, (starts[b],))
+
+    buf = lax.fori_loop(0, lens.shape[0], place, buf)
+    i = jnp.arange(window, dtype=_I32)
+    return jnp.where(i < n, buf[:window], jnp.uint8(0))
+
+
 def _count_from_planes(
     resolved, rounds, out_lens, carry, lengths, num_contigs, carry_len, n,
     at_eof, lo, own, *, window, halo, reads_to_check, flags_impl,
     pallas_interpret, funnel,
 ):
-    """Shared back half of the fused count kernels: gather-assemble the
-    logical window from resolved block rows + the halo carry, run the
-    count, slice the next carry. Traced inside both the packed-token and
-    raw-payload entry points."""
-    from spark_bam_tpu.tpu.inflate import STRIDE
-
-    b = resolved.shape[0]
+    """Shared back half of the fused count kernels: assemble the logical
+    window from resolved block rows + the halo carry, run the count, slice
+    the next carry. Traced inside both the packed-token and raw-payload
+    entry points."""
     with jax.named_scope("assemble"):
-        cum = jnp.concatenate(
-            [jnp.zeros(1, _I32), jnp.cumsum(out_lens.astype(_I32))]
-        )
-        i = jnp.arange(window, dtype=_I32)
-        j = i - carry_len
-        blk = jnp.clip(jnp.searchsorted(cum, j, side="right") - 1, 0, b - 1)
-        off = jnp.clip(j - cum[blk], 0, STRIDE - 1)
-        from_blocks = resolved.reshape(-1)[blk * STRIDE + off]
-        carry_v = carry[jnp.clip(i, 0, halo - 1)]
-        val = jnp.where(
-            i < carry_len, carry_v,
-            jnp.where(i < n, from_blocks, jnp.uint8(0)),
+        val = _assemble_window(
+            resolved, out_lens, carry, carry_len, n, window=window, halo=halo
         )
         padded = jnp.concatenate([val, jnp.zeros(PAD, jnp.uint8)])
     r = count_window(
