@@ -1,0 +1,125 @@
+"""The four-chip cell ``wgs-short-host4.count``, as far as the CPU can show
+it: the cell rehearses through ``run.py`` (on one CPU device, so through the
+one-device stream: the mesh engine is selected by TPU chips alone), its
+metrics are the declared ones, and its byte count makes exactly eight rows."""
+
+import json
+
+import numpy as np
+import pytest
+
+from bench.tests.conftest import config_of, generate
+from bench.tests.test_run import last_line, run_py
+
+CELL = "wgs-short-host4.count"
+CONFIG = "wgs-short-host4"
+MESH_METRICS = {
+    "mesh_step_device_ms", "resolve_device_ms.mesh", "check_device_ms.mesh",
+    "mesh_assemble_host_ms", "mesh_h2d_ms", "mesh_stall_ms",
+    "device_idle_share.mesh", "idle_attributed_share.mesh",
+    "hbm_peak_gib.mesh", "count_tokens_step_roofline", "chip_balance",
+    "lz77_rounds.mesh", "assemble_device_ms.mesh", "tokenize_host_ms.mesh",
+}
+
+
+def test_the_entry_is_the_issues(benchmark_json):
+    bm = benchmark_json
+    cell = next(w for w in bm["workloads"] if w["name"] == CELL)
+    assert (cell["config"], cell["traffic"], cell["chips"]) == (
+        CONFIG, "count", 4)
+    assert sum(w["chips"] == 4 for w in bm["workloads"]) <= max(
+        1, len(bm["workloads"]) // 2)
+    mine = {m["name"] for m in bm["per_layer"]
+            if CELL in m.get("workloads", [])}
+    assert mine == MESH_METRICS
+    for m in bm["per_layer"]:
+        if m["name"] in mine:
+            assert m["workloads"] == [CELL] and m["moves"] == "scan_rate"
+    assert CELL in next(m for m in bm["end_to_end"]
+                        if m["name"] == "scan_rate")["workloads"]
+    config, short = config_of(CONFIG), config_of("wgs-short")
+    assert config["params"] == short["params"]  # the source's shapes
+    assert config["guarantees"] == short["guarantees"]
+    assert config["reduced"] == ["uncompressed_bytes"]
+
+
+@pytest.mark.parametrize("trace", (0, 1))
+def test_the_cell_rehearses(trace, benchmark_json):
+    proc = run_py(["--workload", CELL, "--seed", str(2 ** 31 + 27),
+                   "--seconds", "1", "--trace", str(trace), "--rehearse"])
+    line = last_line(proc)
+    assert line["correct"] is False and line["failed"] == 0
+    assert line["attempted"] >= 1 and line["device"]["platform"] == "cpu"
+    group = "per_layer" if trace else "end_to_end"
+    declared = {m["name"]: m["unit"] for m in benchmark_json[group]
+                if CELL in m.get("workloads", [CELL])}
+    for name, row in line["metrics"].items():
+        assert row["unit"] == declared[name]
+    if not trace:
+        assert set(line["metrics"]) == {"scan_rate", "setup_s"}
+    checks = [json.loads(s) for s in proc.stdout.splitlines()
+              if s.startswith('{"check"')]
+    assert checks and all(c["ok"] for c in checks)
+
+
+@pytest.mark.parametrize("seed", (3, 2 ** 31 + 27, 987654401))
+def test_the_byte_count_gives_exactly_eight_rows(seed, tmp_path):
+    """The generator cuts the file at the first record past the target, so
+    a file is ``header + target + (0 .. one record)`` bytes. Over that whole
+    range the engine's own planner must give 8 rows: two steps of one row a
+    chip, no padding row, every row within the 512 token rows and the
+    32 MiB kernel window of the one compiled shape."""
+    from spark_bam_tpu.bgzf.block import Metadata
+    from spark_bam_tpu.core.config import Config
+    from spark_bam_tpu.parallel.stream_mesh import (
+        _halo_block_range, _plan_rows,
+    )
+
+    index, config = generate(CONFIG, seed, tmp_path / "small.bam")
+    target = config["scale"]["uncompressed_bytes"]
+    payload = config["shapes"]["bgzf_payload_bytes"]
+    longest = int(np.diff(index["record_starts"]).max())
+    cfg = Config()
+    for total in (target + index["header_end"],
+                  target + index["header_end"] + 2 * longest):
+        sizes = [payload] * (total // payload) + [total % payload, 0]
+        metas = [Metadata(30_000 * i, 30_000, n) for i, n in enumerate(sizes)]
+        groups, owned, _flat, first_block, per_proc = _plan_rows(
+            metas, cfg.window_size, 4, 1)
+        assert len(groups) == per_proc == config["shapes"]["rows_per_pass"]
+        assert int(owned.max()) == 385 * payload
+        for g in range(len(groups)):
+            b0, b1 = _halo_block_range(
+                metas, groups, first_block, g, g + 1, cfg.halo_size)
+            assert b1 - b0 <= config["shapes"]["token_rows"]
+            assert sum(sizes[b0:b1]) <= config["shapes"]["kernel_window_bytes"]
+
+
+def test_chip_balance_on_a_trace_worked_out_by_hand(monkeypatch):
+    """Chip 0 busy 60 ms (overlapping operations merge), chip 1 busy 45 ms,
+    a host plane ignored: 75%. One chip, or a chip that ran nothing, has no
+    balance to report and a 0% balance."""
+    from bench.readers import chip_balance
+    from bench.readers.xplane import Event
+
+    ms = 1e6
+
+    def plane(n, *ops):
+        return (f"/device:TPU:{n}", [
+            ("XLA Modules", [Event("jit_prog(1)", 0.0, 100 * ms, {})]),
+            ("XLA Ops", [Event(f"%op.{i}", s * ms, d * ms, {})
+                         for i, (s, d) in enumerate(ops)]),
+        ])
+
+    host = ("/host:CPU", [("python3", [Event("span", 0.0, 500 * ms, {})])])
+    both = [host, plane(0, (0, 40), (30, 20), (70, 10)), plane(1, (5, 45))]
+    assert chip_balance.busy_by_plane(both) == [60 * ms, 45 * ms]
+    sources = {"profile": {"file": "capture"}}
+    loaded = {"capture": both}
+    monkeypatch.setattr(chip_balance.xplane, "load", loaded.get)
+    assert chip_balance.read({}, sources) == pytest.approx(75.0)
+    loaded["capture"] = both[:2]
+    assert chip_balance.read({}, sources) is None
+    loaded["capture"] = [both[1], plane(1)]
+    assert chip_balance.read({}, sources) == 0.0
+    assert chip_balance.read({}, {"profile": None}) is None

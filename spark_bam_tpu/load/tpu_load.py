@@ -308,14 +308,33 @@ def stream_read_batches(
         )
 
 
-def count_reads_tpu(path, config: Config = Config()) -> int:
-    """count-reads via the streaming checker: O(window) host memory, device
-    windows double-buffered, per-window counts reduced on device. This is
-    the same code path chip_smoke.py drives."""
-    from spark_bam_tpu.tpu.stream_check import StreamChecker
+def counts_across_chips() -> bool:
+    """Whether a whole-file count runs on the mesh engine: the backend is a
+    TPU and this process sees more than one of its chips. Observed, never
+    asked for (the same kind of rule as ``resolve_device_inflate``); the
+    CPU backend's virtual devices do not select it."""
+    import jax
 
+    return jax.default_backend() == "tpu" and jax.local_device_count() > 1
+
+
+def count_reads_tpu(path, config: Config = Config()) -> int:
+    """count-reads on the device: O(window) host memory, per-window counts
+    reduced on device. On a host with several TPU chips the file is counted
+    across all of them (``parallel/stream_mesh.count_reads_sharded``: every
+    chip inflates and checks its own rows); on one device the streaming
+    checker's windows are double-buffered through it. This is the same code
+    path chip_smoke.py drives."""
     with obs.span("load.count", path=str(path)):
-        n = StreamChecker(path, config).count_reads()
+        if counts_across_chips():
+            from spark_bam_tpu.parallel.mesh import local_mesh
+            from spark_bam_tpu.parallel.stream_mesh import count_reads_sharded
+
+            n = count_reads_sharded(path, config, mesh=local_mesh())
+        else:
+            from spark_bam_tpu.tpu.stream_check import StreamChecker
+
+            n = StreamChecker(path, config).count_reads()
     obs.count("load.records", n)
     return n
 

@@ -123,9 +123,11 @@ NAMES = frozenset({
     "load.partitions", "load.record_starts", "load.records",
     "load.split_resolutions",
     # mesh — compiled-step registry + shard_map dispatch
-    "mesh.dirty_steps", "mesh.dispatch", "mesh.escapes",
+    "mesh.assemble", "mesh.dirty_steps", "mesh.dispatch", "mesh.escapes",
+    "mesh.h2d", "mesh.h2d_bytes",
     "mesh.patch_chunk_positions", "mesh.patch_chunks", "mesh.patch_rows",
-    "mesh.step", "mesh.steps",
+    "mesh.rounds", "mesh.rows", "mesh.stall", "mesh.step",
+    "mesh.step_device_ms", "mesh.steps",
     # progress — long-run heartbeats
     "progress.beats",
     # remote — plan-driven data plane (docs/remote.md)
@@ -174,7 +176,8 @@ NAMES = frozenset({
 #: ``lz77_resolve``, ``assemble``, ``check`` with its children ``flags``,
 #: ``funnel`` and ``chain_walk``, ``reduce`` (the two count sums) and
 #: ``carry``; the steps of parallel/mesh.py have the ``check`` family and
-#: ``reduce``; agg/kernels.py has ``agg_reduce``.
+#: ``reduce`` (``count_tokens_step`` runs the fused window program on every
+#: chip, so it has all of that program's); agg/kernels.py has ``agg_reduce``.
 SCOPES = frozenset({
     "agg_reduce", "assemble", "carry", "chain_walk", "check", "flags",
     "funnel", "lz77_resolve", "reduce", "unpack",
@@ -185,7 +188,8 @@ SCOPES = frozenset({
 PROGRAMS = frozenset({
     "agg_step", "agg_update", "check_step", "check_window",
     "confusion_step", "count_scan", "count_step", "count_window",
-    "count_window_raw_program", "count_window_tokens", "full_step",
+    "count_tokens_step", "count_window_raw_program", "count_window_tokens",
+    "full_step",
     "serve_step", "sharded_check_step",
 })
 

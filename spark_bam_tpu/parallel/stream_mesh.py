@@ -27,6 +27,14 @@ Row discipline (the property multi-host needs — any row computable from
   ``StreamChecker.count_reads``. On real data with the default halo this
   never triggers.
 
+Where the device inflates (``resolve_device_inflate``: a TPU),
+``count_reads_sharded`` runs the FUSED step: the host tokenizes each row's
+members, puts the packed tokens on the chip that owns the row, and every
+chip runs the one-chip stream's window program on its own row
+(``mesh.make_shard_map_count_tokens_step``). No inflated byte returns to
+the host. The other workloads need the inflated bytes on the host (truth
+masks, site lists) and keep the host-assembled rows.
+
 Workloads (SURVEY.md §2.8 maps file/block data-parallelism onto per-core
 batch pipelines; §2.9 replaces Spark accumulators with ``psum``):
 
@@ -40,6 +48,7 @@ batch pipelines; §2.9 replaces Spark accumulators with ``psum``):
 
 from __future__ import annotations
 
+import time
 from concurrent.futures import ThreadPoolExecutor
 from typing import Callable
 
@@ -59,8 +68,11 @@ from spark_bam_tpu.core.guard import INPUT_ERRORS
 from spark_bam_tpu.parallel.mesh import make_mesh, mesh_steps
 from spark_bam_tpu.tpu.checker import PAD
 from spark_bam_tpu.tpu.inflate import (
+    STRIDE,
+    DeviceObserver,
     inflate_group_device,
     resolve_device_inflate,
+    tokenize_group,
     window_plan,
 )
 from spark_bam_tpu.tpu.stream_check import (
@@ -142,6 +154,7 @@ class _ShardedStream:
         num_processes: int = 1,
         process_id: int = 0,
         chunk_bytes: int = 192 << 20,
+        fused: bool = False,
     ):
         from spark_bam_tpu.bgzf.index_blocks import blocks_metadata
 
@@ -180,8 +193,41 @@ class _ShardedStream:
         kw = self.kernel_window
         self.step_rows_local = n_local * min(
             max(1, chunk_bytes // ((kw + PAD) * max(n_local, 1))),
-            _rows_fitting_device(self.mesh.devices.flat[0], kw),
+            # One of THIS process's devices: another process's reports no
+            # memory to this one.
+            _rows_fitting_device(
+                next(d for d in self.mesh.devices.flat
+                     if d.process_index == jax.process_index()), kw),
         )
+        # The fused step (count only): the host's entropy phase feeds the
+        # one-chip window program on every chip. Multi-host keeps the
+        # host-assembled rows: a tokenizer that rejects one process's row
+        # must not leave the others inside a collective.
+        self.fused = (
+            fused and self.device_inflate and num_processes == 1
+            and config.inflate_config.resolve_tokenize() == "host"
+        )
+        if self.fused:
+            # ONE row a device a step: the device's block of the sharded
+            # operands is its row, and the step's body is the window
+            # program itself. That program reserves 6.0 GiB of a v5e's
+            # 15.75 for a 32 MiB row (190 bytes a window byte, against the
+            # 128 of the check alone); a second row would buy nothing, the
+            # chip is busy with one.
+            self.step_rows_local = n_local
+            self.row_blocks = [
+                _halo_block_range(
+                    self.metas, self.groups, self.first_block, g, g + 1,
+                    self.halo,
+                )
+                for g in range(len(self.groups))
+            ]
+            # Token rows of the step's ONE compiled shape: every row's
+            # members (own group + halo) padded to the widest row's pow2.
+            self.token_rows = _next_pow2(
+                max((b1 - b0 for b0, b1 in self.row_blocks), default=1)
+            )
+            self._zero_tokens: dict = {}
         if self.per_proc:
             self.step_rows_local = min(self.step_rows_local, self.per_proc)
         self.with_truth = with_truth
@@ -193,7 +239,7 @@ class _ShardedStream:
 
     # ------------------------------------------------------------- assembly
     def _row(self, ch, g: int):
-        """Inflate global row ``g``: returns (buf, n, at_eof, own, base)."""
+        """Inflate global row ``g`` to the host: returns (buf, n, at_eof)."""
         b0, b1 = _halo_block_range(
             self.metas, self.groups, self.first_block, g, g + 1, self.halo
         )
@@ -208,13 +254,7 @@ class _ShardedStream:
                 obs.count("inflate.host_demotions")
         if view is None:
             view = inflate_blocks(ch, run, threads=8)
-        at_eof = b1 == len(self.metas)
-        own = (
-            view.size
-            if at_eof and g == len(self.groups) - 1
-            else int(self.sizes[g])
-        )
-        return view.data, view.size, at_eof, own, int(self.flat_starts[g])
+        return view.data, view.size, b1 == len(self.metas)
 
     def _assemble(self, ch, c0: int, header_clamp: bool, fill_row):
         """One step's process-local arrays (fixed shapes; padding rows are
@@ -227,40 +267,45 @@ class _ShardedStream:
         los = np.zeros(k, dtype=np.int32)
         owns = np.zeros(k, dtype=np.int32)
         truth = np.zeros((k, kw), dtype=bool) if self.with_truth else None
-        he = self.header_end if header_clamp else 0
         for j in range(k):
             g = self.process_id * self.per_proc + c0 + j
             if c0 + j >= self.per_proc or g >= len(self.groups):
                 continue
-            buf, n, at_eof, own, base = self._row(ch, g)
+            buf, n, at_eof = self._row(ch, g)
             ws[j, :n] = buf
             ns[j] = n
             eofs[j] = at_eof
-            owns[j] = own
-            los[j] = min(max(he - base, 0), own)
+            owns[j], los[j] = self._row_span(g, n, at_eof, header_clamp)
             if fill_row is not None:
-                fill_row(truth[j], buf, base, n)
+                fill_row(truth[j], buf, int(self.flat_starts[g]), n)
         return ws, ns, eofs, los, owns, truth
 
-    def batches(self, header_clamp: bool, fill_row=None):
-        """Yield ``(sharded_args, positions_done, c0)`` per step (``c0`` =
+    def _row_span(self, g: int, n: int, at_eof: bool, header_clamp: bool):
+        """Row ``g``'s owned span ``[lo, own)`` in row-local offsets, given
+        the ``n`` bytes its buffer holds: ``(own, lo)``."""
+        own = n if at_eof and g == len(self.groups) - 1 else int(self.sizes[g])
+        he = self.header_end if header_clamp else 0
+        return own, min(max(he - int(self.flat_starts[g]), 0), own)
+
+    def _steps(self, assemble, finish=None):
+        """Yield ``(step operands, positions_done, c0)`` per step (``c0`` =
         the step's first process-local row index — row ``j`` of the step is
         global group ``process_id * per_proc + c0 + j``), assembling the
-        next step's rows while the caller's device work runs (one step of
-        lookahead — the double-buffering the single-host pipeline had)."""
+        next step's rows on one worker thread while the caller's device
+        work runs (one step of lookahead). ``assemble(ch, c0)`` runs on
+        that thread; ``finish`` (if any) on the caller's, at hand-over."""
         if not self.per_proc:
             return
         steps = list(range(0, self.per_proc, self.step_rows_local))
         with open_channel(self.path) as ch, ThreadPoolExecutor(1) as pool:
-            pending = pool.submit(
-                self._assemble, ch, steps[0], header_clamp, fill_row
-            )
+            pending = pool.submit(assemble, ch, steps[0])
             for i, c0 in enumerate(steps):
-                arrays = pending.result()
+                # The wait for a step's operands: what the lookahead
+                # exists to hide (all of the first step's assembly).
+                with obs.span("mesh.stall", c0=c0):
+                    arrays = pending.result()
                 if i + 1 < len(steps):
-                    pending = pool.submit(
-                        self._assemble, ch, steps[i + 1], header_clamp, fill_row
-                    )
+                    pending = pool.submit(assemble, ch, steps[i + 1])
                 # Highest global row completed this step (process-major row
                 # order: the last process owns the file's final groups).
                 g_hi = min(
@@ -269,7 +314,106 @@ class _ShardedStream:
                     len(self.groups),
                 ) - 1
                 done = int(self.flat_starts[g_hi] + self.sizes[g_hi])
-                yield self._sharded_args(arrays), done, c0
+                yield (finish(arrays) if finish else arrays), done, c0
+
+    def batches(self, header_clamp: bool, fill_row=None):
+        """The host-assembled steps: rows inflated to host arrays
+        (``_assemble``), placed sharded at hand-over (``_sharded_args``)."""
+        return self._steps(
+            lambda ch, c0: self._assemble(ch, c0, header_clamp, fill_row),
+            self._sharded_args,
+        )
+
+    # ------------------------------------------------------- fused assembly
+    def _tokenize_row(self, ch, g: int):
+        """Global row ``g``'s members (own group + halo) through the host
+        entropy phase, packed at the step's token rows: ``(packed
+        (3·B·STRIDE,) u8, out_lens (B,) i32, n, at_eof)``, or None without
+        the native tokenizer. Raises ``INPUT_ERRORS`` on a stream the
+        tokenizer rejects."""
+        b0, b1 = self.row_blocks[g]
+        tp = tokenize_group(ch, self.metas[b0:b1])
+        if tp is None:
+            return None
+        packed, out_lens, b = tp
+        rows, plane = self.token_rows, self.token_rows * STRIDE
+        had = packed.shape[0] // 3
+        if had != plane:
+            # A narrower row (the file's last, as a rule) re-laid at the
+            # step's width: lit plane, then the dist plane's bytes.
+            wide = np.zeros(3 * plane, dtype=np.uint8)
+            wide[:had] = packed[:had]
+            wide[plane: plane + 2 * had] = packed[had:]
+            packed = wide
+        lens = np.zeros(rows, dtype=np.int32)
+        lens[:b] = out_lens
+        return packed, lens, int(out_lens.sum()), b1 == len(self.metas)
+
+    def _assemble_tokens(self, ch, c0: int, rows_pool):
+        """One step's operands for the fused step, ON the devices: each
+        row's packed tokens go straight to the chip that owns the row.
+        Returns None when the entropy phase cannot serve a row (no native
+        tokenizer, or input it rejects): the caller demotes the count."""
+        k = self.step_rows_local
+        g0 = self.process_id * self.per_proc + c0
+        live = [  # (row of the step, global row); the rest are padding
+            (j, g0 + j) for j in range(k)
+            if c0 + j < self.per_proc and g0 + j < len(self.groups)
+        ]
+        with obs.span("mesh.assemble", c0=c0, rows=len(live)):
+            try:
+                toks = list(rows_pool.map(
+                    lambda jg: self._tokenize_row(ch, jg[1]), live
+                ))
+            except INPUT_ERRORS:
+                return None
+        if any(t is None for t in toks):
+            return None
+        lens = np.zeros((k, self.token_rows), dtype=np.int32)
+        ns = np.zeros(k, dtype=np.int32)
+        eofs = np.zeros(k, dtype=bool)
+        los = np.zeros(k, dtype=np.int32)
+        owns = np.zeros(k, dtype=np.int32)
+        width = 3 * self.token_rows * STRIDE
+        devices = list(self.mesh.devices.flat)
+        shards = [None] * k
+        sent = 0
+        with obs.span("mesh.h2d", c0=c0, rows=len(live)):
+            for (j, g), (packed, row_lens, n, at_eof) in zip(live, toks):
+                shards[j] = jax.device_put(packed, devices[j])
+                sent += packed.nbytes
+                lens[j], ns[j], eofs[j] = row_lens, n, at_eof
+                owns[j], los[j] = self._row_span(g, n, at_eof, True)
+            for j in range(k):
+                if shards[j] is None:  # a padding row: resident zeros
+                    if j not in self._zero_tokens:
+                        self._zero_tokens[j] = jax.device_put(
+                            np.zeros(width, dtype=np.uint8), devices[j]
+                        )
+                    shards[j] = self._zero_tokens[j]
+            # Flat operands: a device's block of each is its row's, in the
+            # one-chip program's own shapes (mesh.count_tokens_step).
+            tokens = jax.make_array_from_single_device_arrays(
+                (k * width,), self.row_sharding, shards
+            )
+            args = [tokens] + [
+                jax.make_array_from_process_local_data(self.row_sharding, a)
+                for a in (lens.reshape(-1), ns, eofs, los, owns)
+            ]
+            # Waited for HERE, registry or none: the span is the transfer,
+            # and the feeding thread is handed operands that have arrived.
+            jax.block_until_ready(args)
+        obs.count("mesh.rows", len(live))
+        obs.count("mesh.h2d_bytes", sent)
+        return args + [self.lengths_d, self.nc]
+
+    def token_batches(self):
+        """The fused steps: ``(operands on the devices | None, done, c0)``;
+        None is a step the entropy phase could not serve."""
+        with ThreadPoolExecutor(self.step_rows_local) as rows_pool:
+            yield from self._steps(
+                lambda ch, c0: self._assemble_tokens(ch, c0, rows_pool)
+            )
 
     def _sharded_args(self, arrays):
         ws, ns, eofs, los, owns, truth = arrays
@@ -442,6 +586,108 @@ def _step_global_rows(st: "_ShardedStream", c0: int) -> list[int]:
     return rows
 
 
+class _StepObserver(DeviceObserver):
+    """The fused steps' device times, taken OFF the thread that feeds the
+    chips (as ``DeviceObserver`` does for the one-chip stream, and only
+    under a live registry), under the mesh's names: ``mesh.step_device_ms =
+    t_ready(k) − max(t_dispatch(k), t_ready(k−1))`` and ``mesh.rounds``,
+    the most LZ77 rounds any chip's row took."""
+
+    @staticmethod
+    def _observe(device_ms: float, rounds: int) -> None:
+        obs.observe("mesh.step_device_ms", device_ms, unit="ms")
+        obs.observe("mesh.rounds", rounds, unit="rounds")
+
+
+def _count_steps(st: "_ShardedStream", config: Config, progress):
+    """The count pass over ``st``'s steps: ``(count, escapes, steps, dirty,
+    whole_file)``, or None when a fused step could not be served (the
+    caller demotes to the host-assembled rows).
+
+    Step k+1's operands are put and its program dispatched BEFORE step k's
+    totals are read, so the devices never wait between steps for a
+    transfer or for the host; totals, and with them the escape guard, are
+    one step late."""
+    # Cached per (mesh, params): repeat invocations — and the serve/
+    # daemon's ticks — reuse one traced executable instead of re-jitting.
+    steps_of = mesh_steps(st.mesh, st.axis)
+    params = dict(
+        reads_to_check=config.reads_to_check, flags_impl=config.flags_impl,
+        funnel=config.funnel_enabled(),
+    )
+    if st.fused:
+        step = steps_of.count_tokens_step(st.kernel_window, st.halo, **params)
+        batches = st.token_batches()
+    else:
+        step = steps_of.count_step(**params)
+        batches = st.batches(header_clamp=True)
+    observer = _StepObserver.maybe() if st.fused else None
+    count = escapes = steps = 0
+    dirty: list[int] = []  # local row offsets (c0) of escaped steps
+
+    def settle(out, done, c0) -> bool:
+        """Read one step's totals; True when the pass should stop."""
+        nonlocal count, escapes, steps
+        totals = np.asarray(out[0] if st.fused else out)
+        esc = int(totals[1])
+        steps += 1
+        obs.count("mesh.steps")
+        if esc:
+            obs.count("mesh.dirty_steps")
+            obs.count("mesh.escapes", esc)
+            # Escape-localized handling: the dirty STEP's device totals
+            # are untrusted (an escaped chain's verdict can be wrong in
+            # either direction), but every other step stands. Record the
+            # step for a host-side exact patch instead of discarding the
+            # whole device pass.
+            escapes += esc
+            dirty.append(c0)
+        else:
+            count += int(totals[0])
+        if progress is not None:
+            progress(steps, done, st.total)
+        # Pathological guard (mirrors count_reads' window-4 escape
+        # checkpoint): if nearly every step escapes, the halo is
+        # undersized for this input — stop burning device work and take
+        # the whole-file exact path.
+        return _mostly_dirty(dirty, steps)
+
+    served = True      # False: a fused step the entropy phase refused
+    whole_file = False  # True: the guard stopped the pass
+    unread = None       # the dispatched step whose totals are not read yet
+    # Closing the batch generator on early exit (escape break, error)
+    # shuts down the assembly pool and channel before any fallback
+    # reopens the file.
+    try:
+        for args, done, c0 in batches:
+            if args is None:
+                served = False
+                break
+            with obs.span("mesh.step", workload="count", c0=c0):
+                t_dispatch = time.perf_counter()
+                out = step(*args)
+                if observer is not None:
+                    observer.window(None, 0.0, out[1], t_dispatch)
+                whole_file = unread is not None and settle(*unread)
+            unread = (out, done, c0)
+            if whole_file:
+                break
+        if served and not whole_file and unread is not None:
+            with obs.span("mesh.step", workload="count", c0=unread[2]):
+                whole_file = settle(*unread)
+    finally:
+        batches.close()
+        if observer is not None:
+            observer.close()
+    if (not served or whole_file) and unread is not None:
+        # The step in flight is dropped, and waited for: what takes over
+        # (the host-assembled rows, the whole-file path) finds an idle mesh.
+        jax.block_until_ready(unread[0])
+    if not served:
+        return None
+    return count, escapes, steps, dirty, whole_file
+
+
 def count_reads_sharded(
     path,
     config: Config = Config(),
@@ -458,61 +704,34 @@ def count_reads_sharded(
     """Record count of ``path`` computed across ``mesh`` (default: all
     devices; multi-host callers pass their process coordinates and get the
     globally reduced count on every process). ``progress(steps_done,
-    positions_done, total_positions)`` fires after each sharded step.
-    ``stats_out``, when given, receives ``{"steps", "escapes", "fallback",
-    "patched_steps"}`` — escaped steps are normally re-derived exactly on
-    host (``patched_steps`` counts them; the other steps' device totals
-    stand); ``fallback`` is True only when the whole-file exact path ran
-    instead (no native library, adversarial lookahead growth, or an
-    escape-everywhere input)."""
-    st = _ShardedStream(
-        path, config, mesh, window_uncompressed, halo, metas,
+    positions_done, total_positions)`` fires as each sharded step's totals
+    are read; a count that leaves the fused step for the host-assembled
+    rows starts over at the first row, and ``progress`` with it. ``stats_out``, when given, receives ``{"steps", "escapes",
+    "fallback", "patched_steps", "rows", "fused"}`` — escaped steps are
+    normally re-derived exactly on host (``patched_steps`` counts them, and
+    ``check.count_escape_retries``; the other steps' device totals stand);
+    ``fallback`` is True only when the whole-file exact path ran instead
+    (no native library, adversarial lookahead growth, or an
+    escape-everywhere input; ``check.fused_demotions``). ``fused`` says the
+    device inflated its own rows (the fused step)."""
+    kw = dict(
         num_processes=num_processes, process_id=process_id,
         chunk_bytes=chunk_bytes,
     )
-    # Cached per (mesh, params): repeat invocations — and the serve/
-    # daemon's ticks — reuse one traced executable instead of re-jitting.
-    step = mesh_steps(st.mesh, st.axis).count_step(
-        reads_to_check=config.reads_to_check,
-        flags_impl=config.flags_impl, funnel=config.funnel_enabled(),
+    st = _ShardedStream(
+        path, config, mesh, window_uncompressed, halo, metas, fused=True, **kw
     )
-    count = escapes = steps = 0
-    dirty: list[int] = []  # local row offsets (c0) of escaped steps
-    whole_file = False
-    # Closing the batch generator on early exit (escape break, error)
-    # shuts down the assembly pool and channel before any fallback
-    # reopens the file.
-    batches = st.batches(header_clamp=True)
-    try:
-        for args, done, c0 in batches:
-            with obs.span("mesh.step", workload="count", c0=c0):
-                totals = np.asarray(step(*args))
-            esc = int(totals[1])
-            steps += 1
-            obs.count("mesh.steps")
-            if esc:
-                obs.count("mesh.dirty_steps")
-                obs.count("mesh.escapes", esc)
-                # Escape-localized handling: the dirty STEP's device
-                # totals are untrusted (an escaped chain's verdict can be
-                # wrong in either direction), but every other step stands.
-                # Record the step for a host-side exact patch instead of
-                # discarding the whole device pass.
-                escapes += esc
-                dirty.append(c0)
-            else:
-                count += int(totals[0])
-            if progress is not None:
-                progress(steps, done, st.total)
-            # Pathological guard (mirrors count_reads' window-4 escape
-            # checkpoint): if nearly every step escapes, the halo is
-            # undersized for this input — stop burning device work and
-            # take the whole-file exact path.
-            if _mostly_dirty(dirty, steps):
-                whole_file = True
-                break
-    finally:
-        batches.close()
+    result = _count_steps(st, config, progress)
+    if result is None:
+        # The entropy phase could not serve a row (no native tokenizer, or
+        # input it rejects): the count leaves the fused step for the
+        # host-assembled rows, where host zlib answers such input.
+        obs.count("check.fused_demotions")
+        st = _ShardedStream(
+            path, config, st.mesh, window_uncompressed, halo, st.metas, **kw
+        )
+        result = _count_steps(st, config, progress)
+    count, escapes, steps, dirty, whole_file = result
 
     patched = None
     if dirty and not whole_file:
@@ -525,13 +744,15 @@ def count_reads_sharded(
                     patched = None  # no native lib / adversarial growth
                     break
                 patched += len(pos)
+        if patched is not None:
+            obs.count("check.count_escape_retries", len(dirty))
 
     if stats_out is not None:
         stats_out.update(
             steps=steps, escapes=escapes,
             fallback=bool(escapes) and patched is None,
             patched_steps=0 if patched is None else len(dirty),
-            rows=len(st.groups),
+            rows=len(st.groups), fused=st.fused,
         )
     if escapes and patched is None:
         # Whole-file exact fallback (no native library, adversarial
@@ -539,6 +760,7 @@ def count_reads_sharded(
         # through the single-device deferral path (reusing this pass's
         # block-metadata scan). Multi-host: every process computes the
         # same exact count — redundant but correct.
+        obs.count("check.fused_demotions")
         return StreamChecker(
             path, config, window_uncompressed=st.fresh, halo=st.halo,
             metas=st.metas,

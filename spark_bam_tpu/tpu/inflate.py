@@ -316,7 +316,8 @@ class DeviceObserver:
 
     The feeding thread hands over, per window, the H2D operand and one
     output of the dispatch with the host times it issued them at. Two
-    daemon threads block on them in order: one observes
+    daemon threads block on them in order: one (started by the first
+    operand: the mesh steps hand over none) observes
     ``inflate.h2d_ms`` (issue to arrival of the operand, as a rule hidden
     behind the previous window's program), the other
     ``inflate.device_ms = t_ready(k) - max(t_dispatch(k), t_ready(k-1))``
@@ -326,7 +327,7 @@ class DeviceObserver:
 
     def __init__(self):
         self._threads: list = []
-        self._h2d = self._start("obs-h2d", self._on_h2d)
+        self._h2d = None  # started by the first operand handed over
         self._dev = self._start("obs-device", self._on_device)
         self._t_ready = 0.0
 
@@ -353,15 +354,18 @@ class DeviceObserver:
 
     def window(self, operand, t_put: float, out, t_dispatch: float) -> None:
         """``operand``: the H2D array (None when the transfer happened on
-        a producer thread); ``out``: the dispatch's ``rounds`` scalar."""
+        a producer thread); ``out``: the dispatch's ``rounds`` output."""
         if operand is not None:
+            if self._h2d is None:
+                self._h2d = self._start("obs-h2d", self._on_h2d)
             self._h2d.put((operand, t_put))
         self._dev.put((out, t_dispatch))
 
     def close(self) -> None:
-        """Drains both threads: every window handed over is observed."""
+        """Drains the threads: every window handed over is observed."""
         for q in (self._h2d, self._dev):
-            q.put(None)
+            if q is not None:
+                q.put(None)
         for thread in self._threads:
             thread.join()
 
@@ -373,10 +377,15 @@ class DeviceObserver:
     def _on_device(self, rounds_dev, t_dispatch: float) -> None:
         rounds_dev.block_until_ready()
         t_ready = time.perf_counter()
-        attribute_ms(
-            device_ms=(t_ready - max(t_dispatch, self._t_ready)) * 1e3)
+        device_ms = (t_ready - max(t_dispatch, self._t_ready)) * 1e3
         self._t_ready = t_ready
-        obs.observe("inflate.rounds", int(rounds_dev), unit="rounds")
+        # A mesh step hands over one round count a chip: the most of them.
+        self._observe(device_ms, int(np.asarray(rounds_dev).max()))
+
+    @staticmethod
+    def _observe(device_ms: float, rounds: int) -> None:
+        attribute_ms(device_ms=device_ms)
+        obs.observe("inflate.rounds", rounds, unit="rounds")
 
 
 PROFILE_ENV = "SPARK_BAM_PROFILE"

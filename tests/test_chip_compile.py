@@ -156,6 +156,39 @@ def test_sharded_count_step_fits_one_chip_at_its_row_cap(topo, chip):
     assert _device_bytes(compiled) < HBM
 
 
+@pytest.mark.parametrize("window,blocks,least", [
+    (WINDOW, BLOCKS, 4 << 30),
+    (1 << 20, 32, 0),  # a 1 MiB file: a window smaller than the 4 MiB halo
+])
+def test_fused_count_tokens_step_compiles_for_four_chips(
+        window, blocks, least, topo, chip):
+    """``jit_count_tokens_step``: the fused window program on every chip of
+    a v5e host, one row a chip (512 token rows, 32 MiB window, 4 MiB
+    halo), the count pair ``psum``'d. Each device must hold its row's
+    program: the one-chip program's 6.4 GiB, not four of them. A multi-chip
+    host sends its small files through the same step, at the window their
+    size gives."""
+    from spark_bam_tpu.parallel.mesh import make_shard_map_count_tokens_step
+    from spark_bam_tpu.tpu.inflate import STRIDE
+
+    n = 4
+    mesh, shape, repl = _mesh_shapes(topo, n)
+    step = make_shard_map_count_tokens_step(
+        mesh, window, HALO, 10, "data", "xla", funnel=True
+    )
+    compiled = step.lower(
+        shape((n * 3 * blocks * STRIDE,), jnp.uint8),
+        shape((n * blocks,), jnp.int32), shape((n,), jnp.int32),
+        shape((n,), jnp.bool_), shape((n,), jnp.int32),
+        shape((n,), jnp.int32), shape((CMAX,), jnp.int32, repl),
+        shape((), jnp.int32, repl),
+    ).compile()
+    assert least < _device_bytes(compiled) < HBM
+    text = compiled.as_text()
+    assert "all-reduce" in text  # the psum, and nothing gathers the rows
+    assert "all-gather" not in text and "all-to-all" not in text
+
+
 # ----------------------------------------------------------------- small
 def test_serve_step_compiles_at_serve_config_defaults(topo, chip):
     from spark_bam_tpu.parallel.mesh import make_shard_map_serve_step
