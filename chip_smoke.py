@@ -306,27 +306,17 @@ def phase_serve(compiles: Compiles, path: Path, manifest: dict) -> bool:
 
 
 def windows_per_device(path: Path, mesh) -> dict:
-    """Device id → windows (non-empty rows) the sharded stream places
-    there. Rows are inflated on the host here: placement does not depend on
-    where a row was inflated, and this pass is outside the comparison."""
-    import numpy as np
-
+    """Device id → windows (rows) the sharded count places there: the
+    stream's own plan (``row_slots``), nothing inflated."""
     from spark_bam_tpu.core.config import Config
     from spark_bam_tpu.parallel.stream_mesh import _ShardedStream
 
-    st = _ShardedStream(
-        path, Config(device_inflate=False), mesh, None, None, None
-    )
-    placed = {int(d.id): 0 for d in mesh.devices.flat}
-    batches = st.batches(header_clamp=True)
-    try:
-        for args, _done, _c0 in batches:
-            for shard in args[1].addressable_shards:  # ns: bytes per row
-                placed[int(shard.device.id)] += int(
-                    np.count_nonzero(np.asarray(shard.data))
-                )
-    finally:
-        batches.close()
+    st = _ShardedStream(path, Config(), mesh, None, None, None)
+    devices = list(mesh.devices.flat)
+    placed = {int(d.id): 0 for d in devices}
+    for c0 in range(0, st.per_proc, st.step_rows_local):
+        for _g, d, _slot in st.row_slots(c0):
+            placed[int(devices[d].id)] += 1
     return placed
 
 

@@ -311,20 +311,23 @@ def stream_read_batches(
 def counts_across_chips() -> bool:
     """Whether a whole-file count runs on the mesh engine: the backend is a
     TPU and this process sees more than one of its chips. Observed, never
-    asked for (the same kind of rule as ``resolve_device_inflate``); the
-    CPU backend's virtual devices do not select it."""
+    asked for; the CPU backend's virtual devices do not select it. (Where
+    the windows are inflated is no such rule any more: on the host, on
+    every backend, ``resolve_device_inflate``.)"""
     import jax
 
     return jax.default_backend() == "tpu" and jax.local_device_count() > 1
 
 
 def count_reads_tpu(path, config: Config = Config()) -> int:
-    """count-reads on the device: O(window) host memory, per-window counts
-    reduced on device. On a host with several TPU chips the file is counted
-    across all of them (``parallel/stream_mesh.count_reads_sharded``: every
-    chip inflates and checks its own rows); on one device the streaming
-    checker's windows are double-buffered through it. This is the same code
-    path chip_smoke.py drives."""
+    """count-reads on the device: O(window) host memory, the windows
+    inflated on the host's worker pool, every position checked and the
+    per-window counts reduced on the device. On a host with several TPU
+    chips the file is counted across all of them
+    (``parallel/stream_mesh.count_reads_sharded``: the rows of a step are
+    inflated side by side and every chip checks its own); on one device the
+    streaming checker's windows are put and dispatched up to ``ring_depth``
+    ahead of it. This is the same code path chip_smoke.py drives."""
     with obs.span("load.count", path=str(path)):
         if counts_across_chips():
             from spark_bam_tpu.parallel.mesh import local_mesh

@@ -28,7 +28,9 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from spark_bam_tpu import obs
-from spark_bam_tpu.tpu.checker import PAD, check_window, count_window_tokens
+from spark_bam_tpu.tpu.checker import (
+    PAD, check_window, count_window, count_window_tokens,
+)
 
 
 def make_mesh(devices=None, axis: str = "data") -> Mesh:
@@ -328,96 +330,60 @@ def make_shard_map_check_step(mesh: Mesh, reads_to_check: int = 10, axis: str = 
     )
 
 
-def _make_sharded_stats_step(
-    name: str, mesh: Mesh, reads_to_check: int, axis: str, row_stats,
-    with_truth: bool, flags_impl: str = "xla", funnel: bool = False,
-):
-    """Shared scaffolding for the streaming-step makers below: per-row
-    ``check_window`` + owned-span mask [lo, own), per-device ``vmap``, and
-    the stat vector all-reduced with ``lax.psum`` over the mesh axis.
-    ``row_stats(res, m, tr)`` stacks the workload's counters; ``name`` is
-    the compiled program's (``jit_<name>`` in a device trace).
-    ``funnel=True`` runs the two-stage candidate funnel per row — verdict
-    projections only (the full-check step stays single-pass: its product
-    is the per-position flag mask, which the funnel does not preserve).
-
-    Every counter psum'd here must be record-scale (≤ positions/40 per
-    step), never position-scale: the reduction is int32 and a
-    position-scale counter overflows past ~64 devices × 32 MB windows.
-    Position totals are host-derivable (callers know their owned spans).
-    """
-
-    # Interpret mode is decided by where THIS mesh's kernels actually run
-    # (not the process-default backend): Mosaic compiles only on real TPUs.
-    pallas_interpret = _mesh_pallas_interpret(mesh, flags_impl)
-
-    def one(window, n, at_eof, lo, own, tr, lengths, num_contigs):
-        res = check_window(
-            window, lengths, num_contigs, n, at_eof,
-            reads_to_check=reads_to_check, flags_impl=flags_impl,
-            pallas_interpret=pallas_interpret, funnel=funnel,
-        )
-        w = window.shape[0] - PAD
-        with jax.named_scope("reduce"):
-            i = jnp.arange(w, dtype=jnp.int32)
-            m = (i >= lo) & (i < own)
-            return row_stats(res, m, tr)
-
-    @jax.named_scope("reduce")
-    def total(stats):
-        return jax.lax.psum(jnp.sum(stats, axis=0), axis)  # ← ICI
-
-    if with_truth:
-        def local_step(windows, ns, at_eofs, truth, los, owns, lengths, nc):
-            stats = jax.vmap(
-                lambda wd, n, e, t, lo, ow: one(wd, n, e, lo, ow, t, lengths, nc)
-            )(windows, ns, at_eofs, truth, los, owns)
-            return total(stats)
-
-        in_specs = (
-            P(axis), P(axis), P(axis), P(axis), P(axis), P(axis), P(), P(),
-        )
-    else:
-        def local_step(windows, ns, at_eofs, los, owns, lengths, nc):
-            stats = jax.vmap(
-                lambda wd, n, e, lo, ow: one(wd, n, e, lo, ow, None, lengths, nc)
-            )(windows, ns, at_eofs, los, owns)
-            return total(stats)
-
-        in_specs = (P(axis), P(axis), P(axis), P(axis), P(axis), P(), P())
-    local_step.__name__ = name
-    return jax.jit(
-        jax.shard_map(
-            local_step,
-            mesh=mesh,
-            in_specs=in_specs,
-            out_specs=P(),
-            check_vma=False,
-        )
-    )
-
-
 def make_shard_map_count_step(
     mesh: Mesh, reads_to_check: int = 10, axis: str = "data",
     flags_impl: str = "xla", funnel: bool = False,
 ):
-    """Sharded count-reads step: each device checks its window rows and the
-    (boundary count, owned escapes) pair all-reduces with ``lax.psum`` —
-    the count-reads workload (reference docs/benchmarks.md:53-59) as one
-    mesh-partitioned unit. Rows carry per-row owned spans [lo, own) so
-    halo bytes and the BAM header are counted exactly once globally.
-    ``flags_impl="pallas"`` swaps the flag pass for the Pallas kernel
-    (``spark.bam.backend=pallas`` reaches the mesh tier too)."""
+    """Sharded count-reads step, the whole-file count's step on every
+    backend: each device runs the one-chip stream's window program
+    (``checker.count_window``: the check and its owned-span count
+    reduction, nothing scattered back over the window) on its rows of
+    host-inflated bytes, and the (boundary count, owned escapes) pair
+    all-reduces with ``lax.psum`` — the count-reads workload (reference
+    docs/benchmarks.md:53-59) as one mesh-partitioned unit. Rows carry
+    per-row owned spans [lo, own) so halo bytes and the BAM header are
+    counted exactly once globally.
 
-    def row_stats(res, m, _tr):
-        return jnp.stack([
-            jnp.sum((res["verdict"] & m).astype(jnp.int32)),
-            jnp.sum((res["escaped"] & m).astype(jnp.int32)),
-        ])
+    ``windows`` is the rows' concatenation, FLAT and sharded over the mesh
+    axis (``(rows · (W+PAD),)`` u8), so a device's block is its own rows'
+    bytes as the one-chip program takes them: a leading row dimension
+    would cost a device holding one u8 row four times its bytes on a TPU
+    (``(1, N)`` u8 is tiled four rows high) and a relayout in the program.
+    A device with one row runs the window program on its block as it is;
+    with more it splits the block and ``vmap``s. The per-row scalars are
+    ``(rows,)`` in the same device-major order. ``flags_impl="pallas"``
+    swaps the flag pass for the Pallas kernel (``spark.bam.backend=pallas``
+    reaches the mesh tier too). The compiled program is ``jit_count_step``."""
+    pallas_interpret = _mesh_pallas_interpret(mesh, flags_impl)
 
-    return _make_sharded_stats_step(
-        "count_step", mesh, reads_to_check, axis, row_stats, with_truth=False,
-        flags_impl=flags_impl, funnel=funnel,
+    def one(window, n, at_eof, lo, own, lengths, nc):
+        r = count_window(
+            window, lengths, nc, n, at_eof, lo, own,
+            reads_to_check=reads_to_check, flags_impl=flags_impl,
+            pallas_interpret=pallas_interpret, funnel=funnel,
+        )
+        return jnp.stack([r["count"], r["esc_count"]]).astype(jnp.int32)
+
+    def count_step(windows, ns, at_eofs, los, owns, lengths, nc):
+        rows = ns.shape[0]  # this device's
+        if rows == 1:
+            stats = one(windows, ns[0], at_eofs[0], los[0], owns[0],
+                        lengths, nc)
+        else:
+            stats = jnp.sum(jax.vmap(
+                lambda wd, n, e, lo, ow: one(wd, n, e, lo, ow, lengths, nc)
+            )(windows.reshape(rows, -1), ns, at_eofs, los, owns), axis=0)
+        with jax.named_scope("reduce"):
+            return jax.lax.psum(stats, axis)  # ← ICI
+
+    return jax.jit(
+        jax.shard_map(
+            count_step,
+            mesh=mesh,
+            in_specs=(P(axis),) * 5 + (P(), P()),
+            out_specs=P(),
+            check_vma=False,
+        )
     )
 
 
@@ -480,26 +446,55 @@ def make_shard_map_confusion_step(
     """Sharded check-bam step: verdicts vs indexed truth at every owned
     position, the (tp, fp, fn, escapes) counters ``psum``'d over the mesh
     axis — the check-bam validation workload (reference
-    CheckerApp.scala:59-70's accumulators) as one mesh-partitioned unit.
-    Position totals and true negatives are deliberately NOT reduced on
-    device: they are position-scale (int32-overflow risk at mesh scale)
-    and the caller derives them exactly from its owned spans
-    (tn = positions - tp - fp - fn)."""
+    CheckerApp.scala:59-70's accumulators) as one mesh-partitioned unit:
+    per-row ``check_window`` + owned-span mask [lo, own), per-device
+    ``vmap``. ``funnel=True`` runs the two-stage candidate funnel per row
+    (verdicts are what this step projects; the funnel preserves them).
 
-    def row_stats(res, m, tr):
-        v = res["verdict"] & m
-        t = tr & m
-        return jnp.stack([
-            jnp.sum((v & t).astype(jnp.int32)),    # true positives
-            jnp.sum((v & ~t).astype(jnp.int32)),   # false positives
-            jnp.sum((~v & t).astype(jnp.int32)),   # false negatives
-            jnp.sum((res["escaped"] & m).astype(jnp.int32)),
-        ])
+    Every counter psum'd here is record-scale (≤ positions/40 per step),
+    never position-scale: the reduction is int32 and a position-scale
+    counter overflows past ~64 devices × 32 MB windows. Position totals
+    and true negatives are host-derived: the caller knows its owned spans
+    (tn = positions - tp - fp - fn). The compiled program is
+    ``jit_confusion_step``."""
+    # Interpret mode is decided by where THIS mesh's kernels actually run
+    # (not the process-default backend): Mosaic compiles only on real TPUs.
+    pallas_interpret = _mesh_pallas_interpret(mesh, flags_impl)
 
-    return _make_sharded_stats_step(
-        "confusion_step", mesh, reads_to_check, axis, row_stats,
-        with_truth=True,
-        flags_impl=flags_impl, funnel=funnel,
+    def one(window, n, at_eof, tr, lo, own, lengths, num_contigs):
+        res = check_window(
+            window, lengths, num_contigs, n, at_eof,
+            reads_to_check=reads_to_check, flags_impl=flags_impl,
+            pallas_interpret=pallas_interpret, funnel=funnel,
+        )
+        w = window.shape[0] - PAD
+        with jax.named_scope("reduce"):
+            i = jnp.arange(w, dtype=jnp.int32)
+            m = (i >= lo) & (i < own)
+            v = res["verdict"] & m
+            t = tr & m
+            return jnp.stack([
+                jnp.sum((v & t).astype(jnp.int32)),    # true positives
+                jnp.sum((v & ~t).astype(jnp.int32)),   # false positives
+                jnp.sum((~v & t).astype(jnp.int32)),   # false negatives
+                jnp.sum((res["escaped"] & m).astype(jnp.int32)),
+            ])
+
+    def confusion_step(windows, ns, at_eofs, truth, los, owns, lengths, nc):
+        stats = jax.vmap(
+            lambda wd, n, e, t, lo, ow: one(wd, n, e, t, lo, ow, lengths, nc)
+        )(windows, ns, at_eofs, truth, los, owns)
+        with jax.named_scope("reduce"):
+            return jax.lax.psum(jnp.sum(stats, axis=0), axis)  # ← ICI
+
+    return jax.jit(
+        jax.shard_map(
+            confusion_step,
+            mesh=mesh,
+            in_specs=(P(axis),) * 6 + (P(), P()),
+            out_specs=P(),
+            check_vma=False,
+        )
     )
 
 

@@ -27,13 +27,18 @@ Row discipline (the property multi-host needs — any row computable from
   ``StreamChecker.count_reads``. On real data with the default halo this
   never triggers.
 
-Where the device inflates (``resolve_device_inflate``: a TPU),
-``count_reads_sharded`` runs the FUSED step: the host tokenizes each row's
-members, puts the packed tokens on the chip that owns the row, and every
-chip runs the one-chip stream's window program on its own row
-(``mesh.make_shard_map_count_tokens_step``). No inflated byte returns to
-the host. The other workloads need the inflated bytes on the host (truth
-masks, site lists) and keep the host-assembled rows.
+Every row is inflated on the host, on every backend: ``count_reads_sharded``
+inflates a step's rows side by side on a pool (the native inflater, the
+producer the one-chip stream has), puts each row's bytes on the chip that
+owns the row, and every chip runs the one-chip stream's check-and-count
+program on its rows (``mesh.make_shard_map_count_step``). The next step is
+inflated and put while the chips run the current one. An explicit
+``Config.device_inflate=True`` reaches the FUSED step instead (the host
+tokenizes, every chip resolves and assembles its own row:
+``mesh.make_shard_map_count_tokens_step``), a hundred times slower over the
+copy than the host is (``tpu/inflate.py``); it ships until the
+``simplicity`` PR that deletes it. The other workloads need the inflated
+bytes on the host anyway (truth masks, site lists).
 
 Workloads (SURVEY.md §2.8 maps file/block data-parallelism onto per-core
 batch pipelines; §2.9 replaces Spark accumulators with ``psum``):
@@ -189,20 +194,23 @@ class _ShardedStream:
         )
         self.device_inflate = resolve_device_inflate(config)
 
-        n_local = self.n_global // num_processes
+        n_local = self.n_local = self.n_global // num_processes
+        # THIS process's devices: another process's reports no memory to
+        # this one, and takes no operand from it.
+        self.local_devices = [
+            d for d in self.mesh.devices.flat
+            if d.process_index == jax.process_index()
+        ]
         kw = self.kernel_window
         self.step_rows_local = n_local * min(
             max(1, chunk_bytes // ((kw + PAD) * max(n_local, 1))),
-            # One of THIS process's devices: another process's reports no
-            # memory to this one.
-            _rows_fitting_device(
-                next(d for d in self.mesh.devices.flat
-                     if d.process_index == jax.process_index()), kw),
+            _rows_fitting_device(self.local_devices[0], kw),
         )
-        # The fused step (count only): the host's entropy phase feeds the
-        # one-chip window program on every chip. Multi-host keeps the
-        # host-assembled rows: a tokenizer that rejects one process's row
-        # must not leave the others inside a collective.
+        # The fused step (count only, and only where ``device_inflate`` is
+        # asked for): the host's entropy phase feeds the token window
+        # program on every chip. Multi-host keeps the host-inflated rows:
+        # a tokenizer that rejects one process's row must not leave the
+        # others inside a collective.
         self.fused = (
             fused and self.device_inflate and num_processes == 1
             and config.inflate_config.resolve_tokenize() == "host"
@@ -230,6 +238,7 @@ class _ShardedStream:
             self._zero_tokens: dict = {}
         if self.per_proc:
             self.step_rows_local = min(self.step_rows_local, self.per_proc)
+        self._zero_rows: dict = {}
         self.with_truth = with_truth
 
         self.row_sharding = NamedSharding(self.mesh, P(self.axis))
@@ -256,9 +265,10 @@ class _ShardedStream:
             view = inflate_blocks(ch, run, threads=8)
         return view.data, view.size, b1 == len(self.metas)
 
-    def _assemble(self, ch, c0: int, header_clamp: bool, fill_row):
-        """One step's process-local arrays (fixed shapes; padding rows are
-        all-zero and own nothing)."""
+    def _assemble(self, ch, c0: int, fill_row):
+        """One step's process-local arrays for the workloads that report
+        on every position, header bytes included (fixed shapes; padding
+        rows are all-zero and own nothing)."""
         kw = self.kernel_window
         k = self.step_rows_local
         ws = np.zeros((k, kw + PAD), dtype=np.uint8)
@@ -275,7 +285,7 @@ class _ShardedStream:
             ws[j, :n] = buf
             ns[j] = n
             eofs[j] = at_eof
-            owns[j], los[j] = self._row_span(g, n, at_eof, header_clamp)
+            owns[j], los[j] = self._row_span(g, n, at_eof, False)
             if fill_row is not None:
                 fill_row(truth[j], buf, int(self.flat_starts[g]), n)
         return ws, ns, eofs, los, owns, truth
@@ -316,13 +326,91 @@ class _ShardedStream:
                 done = int(self.flat_starts[g_hi] + self.sizes[g_hi])
                 yield (finish(arrays) if finish else arrays), done, c0
 
-    def batches(self, header_clamp: bool, fill_row=None):
-        """The host-assembled steps: rows inflated to host arrays
-        (``_assemble``), placed sharded at hand-over (``_sharded_args``)."""
+    def batches(self, fill_row=None):
+        """The check-bam / full-check steps: rows inflated to host arrays
+        one after another (``_assemble``), placed sharded at hand-over
+        (``_sharded_args``)."""
         return self._steps(
-            lambda ch, c0: self._assemble(ch, c0, header_clamp, fill_row),
+            lambda ch, c0: self._assemble(ch, c0, fill_row),
             self._sharded_args,
         )
+
+    # ------------------------------------------------------- count assembly
+    def row_slots(self, c0: int) -> list[tuple[int, int, int]]:
+        """Where the count step at local row offset ``c0`` puts its rows:
+        ``(global row, local device, slot on that device)`` for every live
+        row, dealt round-robin over this process's devices so the chips of
+        a step are loaded within one row of each other whatever the step's
+        width (a last step of 8 rows in a step 12 wide lands 2 a chip, not
+        3/3/2/0). A device's block of the step's flat operands is its
+        slots in order."""
+        g0 = self.process_id * self.per_proc + c0
+        return [
+            (g0 + j, j % self.n_local, j // self.n_local)
+            for j in range(self.step_rows_local)
+            if c0 + j < self.per_proc and g0 + j < len(self.groups)
+        ]
+
+    def _assemble_rows(self, ch, c0: int, rows_pool):
+        """One count step's operands, ON the devices: the step's rows are
+        inflated side by side (``rows_pool``), each into its slot of its
+        device's flat buffer, and every buffer goes straight to its chip
+        (``mesh.make_shard_map_count_step`` has the layout). Padding slots
+        are zeros and own nothing; a device without a live row keeps one
+        resident buffer of them."""
+        width = self.kernel_window + PAD
+        per_dev = self.step_rows_local // self.n_local
+        slots = self.row_slots(c0)
+        bufs = {d: np.zeros(per_dev * width, dtype=np.uint8)
+                for d in {d for _g, d, _s in slots}}
+        k = self.step_rows_local
+        ns = np.zeros(k, dtype=np.int32)
+        eofs = np.zeros(k, dtype=bool)
+        los = np.zeros(k, dtype=np.int32)
+        owns = np.zeros(k, dtype=np.int32)
+
+        def fill(slot):
+            g, d, s = slot
+            buf, n, at_eof = self._row(ch, g)
+            bufs[d][s * width: s * width + n] = buf
+            i = d * per_dev + s  # device-major, as the flat operand is
+            ns[i], eofs[i] = n, at_eof
+            owns[i], los[i] = self._row_span(g, n, at_eof, True)
+
+        with obs.span("mesh.assemble", c0=c0, rows=len(slots)):
+            list(rows_pool.map(fill, slots))
+        with obs.span("mesh.h2d", c0=c0, rows=len(slots)):
+            shards = []
+            for d, device in enumerate(self.local_devices):
+                if d in bufs:
+                    shards.append(jax.device_put(bufs[d], device))
+                else:
+                    if d not in self._zero_rows:
+                        self._zero_rows[d] = jax.device_put(
+                            np.zeros(per_dev * width, dtype=np.uint8), device
+                        )
+                    shards.append(self._zero_rows[d])
+            windows = jax.make_array_from_single_device_arrays(
+                (self.n_global * per_dev * width,), self.row_sharding, shards
+            )
+            args = [windows] + [
+                jax.make_array_from_process_local_data(self.row_sharding, a)
+                for a in (ns, eofs, los, owns)
+            ]
+            # Waited for HERE, registry or none: the span is the transfer,
+            # and the feeding thread is handed operands that have arrived.
+            jax.block_until_ready(args)
+        obs.count("mesh.rows", len(slots))
+        obs.count("mesh.h2d_bytes", len(bufs) * per_dev * width)
+        return args + [self.lengths_d, self.nc]
+
+    def row_batches(self):
+        """The count's steps: ``(operands on the devices, done, c0)``."""
+        # Rows side by side, each on the inflater's own eight threads.
+        with ThreadPoolExecutor(min(self.step_rows_local, 8)) as rows_pool:
+            yield from self._steps(
+                lambda ch, c0: self._assemble_rows(ch, c0, rows_pool)
+            )
 
     # ------------------------------------------------------- fused assembly
     def _tokenize_row(self, ch, g: int):
@@ -587,22 +675,23 @@ def _step_global_rows(st: "_ShardedStream", c0: int) -> list[int]:
 
 
 class _StepObserver(DeviceObserver):
-    """The fused steps' device times, taken OFF the thread that feeds the
+    """The count steps' device times, taken OFF the thread that feeds the
     chips (as ``DeviceObserver`` does for the one-chip stream, and only
     under a live registry), under the mesh's names: ``mesh.step_device_ms =
-    t_ready(k) − max(t_dispatch(k), t_ready(k−1))`` and ``mesh.rounds``,
-    the most LZ77 rounds any chip's row took."""
+    t_ready(k) − max(t_dispatch(k), t_ready(k−1))`` and, from the fused
+    step alone, ``mesh.rounds``, the most LZ77 rounds any chip's row took."""
 
     @staticmethod
-    def _observe(device_ms: float, rounds: int) -> None:
+    def _observe(device_ms: float, rounds: int | None) -> None:
         obs.observe("mesh.step_device_ms", device_ms, unit="ms")
-        obs.observe("mesh.rounds", rounds, unit="rounds")
+        if rounds is not None:
+            obs.observe("mesh.rounds", rounds, unit="rounds")
 
 
 def _count_steps(st: "_ShardedStream", config: Config, progress):
     """The count pass over ``st``'s steps: ``(count, escapes, steps, dirty,
     whole_file)``, or None when a fused step could not be served (the
-    caller demotes to the host-assembled rows).
+    caller demotes to the host-inflated rows).
 
     Step k+1's operands are put and its program dispatched BEFORE step k's
     totals are read, so the devices never wait between steps for a
@@ -620,15 +709,15 @@ def _count_steps(st: "_ShardedStream", config: Config, progress):
         batches = st.token_batches()
     else:
         step = steps_of.count_step(**params)
-        batches = st.batches(header_clamp=True)
-    observer = _StepObserver.maybe() if st.fused else None
+        batches = st.row_batches()
+    observer = _StepObserver.maybe()
     count = escapes = steps = 0
     dirty: list[int] = []  # local row offsets (c0) of escaped steps
 
     def settle(out, done, c0) -> bool:
         """Read one step's totals; True when the pass should stop."""
         nonlocal count, escapes, steps
-        totals = np.asarray(out[0] if st.fused else out)
+        totals = np.asarray(out)
         esc = int(totals[1])
         steps += 1
         obs.count("mesh.steps")
@@ -665,9 +754,11 @@ def _count_steps(st: "_ShardedStream", config: Config, progress):
                 break
             with obs.span("mesh.step", workload="count", c0=c0):
                 t_dispatch = time.perf_counter()
-                out = step(*args)
+                # ``out``: the step's totals; the fused step also hands
+                # back each chip's LZ77 round count.
+                out, rounds = step(*args) if st.fused else (step(*args), None)
                 if observer is not None:
-                    observer.window(None, 0.0, out[1], t_dispatch)
+                    observer.window(None, 0.0, out, t_dispatch, rounds=rounds)
                 whole_file = unread is not None and settle(*unread)
             unread = (out, done, c0)
             if whole_file:
@@ -681,7 +772,7 @@ def _count_steps(st: "_ShardedStream", config: Config, progress):
             observer.close()
     if (not served or whole_file) and unread is not None:
         # The step in flight is dropped, and waited for: what takes over
-        # (the host-assembled rows, the whole-file path) finds an idle mesh.
+        # (the host-inflated rows, the whole-file path) finds an idle mesh.
         jax.block_until_ready(unread[0])
     if not served:
         return None
@@ -705,15 +796,19 @@ def count_reads_sharded(
     devices; multi-host callers pass their process coordinates and get the
     globally reduced count on every process). ``progress(steps_done,
     positions_done, total_positions)`` fires as each sharded step's totals
-    are read; a count that leaves the fused step for the host-assembled
-    rows starts over at the first row, and ``progress`` with it. ``stats_out``, when given, receives ``{"steps", "escapes",
+    are read. Every row is inflated on the host and checked on the chip
+    that owns it (``jit_count_step``); only ``Config.device_inflate=True``
+    reaches the fused token step, and a count that leaves THAT step for the
+    host-inflated rows starts over at the first row, and ``progress`` with
+    it. ``stats_out``, when given, receives ``{"steps", "escapes",
     "fallback", "patched_steps", "rows", "fused"}`` — escaped steps are
     normally re-derived exactly on host (``patched_steps`` counts them, and
     ``check.count_escape_retries``; the other steps' device totals stand);
     ``fallback`` is True only when the whole-file exact path ran instead
     (no native library, adversarial lookahead growth, or an
     escape-everywhere input; ``check.fused_demotions``). ``fused`` says the
-    device inflated its own rows (the fused step)."""
+    devices resolved their own rows' tokens (the fused step: asked for by
+    ``device_inflate=True``, never selected)."""
     kw = dict(
         num_processes=num_processes, process_id=process_id,
         chunk_bytes=chunk_bytes,
@@ -725,7 +820,7 @@ def count_reads_sharded(
     if result is None:
         # The entropy phase could not serve a row (no native tokenizer, or
         # input it rejects): the count leaves the fused step for the
-        # host-assembled rows, where host zlib answers such input.
+        # host-inflated rows, where host zlib answers such input.
         obs.count("check.fused_demotions")
         st = _ShardedStream(
             path, config, st.mesh, window_uncompressed, halo, st.metas, **kw
@@ -825,7 +920,7 @@ def full_check_summary_sharded(
     defers = 0
     dirty: list[int] = []  # local row offsets (c0) of deferred steps
     steps = 0
-    batches = st.batches(header_clamp=False)
+    batches = st.batches()
     try:
         for args, done, c0 in batches:
             with obs.span("mesh.step", workload="full_check", c0=c0):
@@ -1085,7 +1180,7 @@ def check_bam_sharded(
     steps = 0
     dirty: list[int] = []  # local row offsets (c0) of escaped steps
     whole_file = False
-    batches = st.batches(header_clamp=False, fill_row=fill_row)
+    batches = st.batches(fill_row=fill_row)
     try:
         for args, done, c0 in batches:
             with obs.span("mesh.step", workload="check_bam", c0=c0):

@@ -1,12 +1,19 @@
-"""BGZF inflate feeding the device: host-parallel path + two-phase device path.
+"""BGZF inflate feeding the device: the host path, and the two-phase device path.
 
-Production path A inflates on host (zlib releases the GIL; a thread pool
-saturates cores — bgzf/flat.py) and ships flat windows to HBM. That is
-already off the critical path for the checker speedup: SURVEY.md §7 "the
-checker/parser speedup does not depend on it [device DEFLATE]".
+**The default on every backend is host inflate** (path A): the native
+table-driven inflater (``bgzf/flat.inflate_blocks``; zlib where the native
+library is missing) decodes AND copies on a thread pool, and flat windows of
+inflated bytes go to HBM, where the device does nothing but check them
+(``checker.count_window``; on the mesh ``count_step``). A host that has just
+decoded a token copies its bytes for nothing: one 24 MiB window inflates in
+14-50 ms on eight threads (sandbox CPU), against 320-600 ms to tokenize it
+for the device and 5.2-5.9 s for the device to resolve the tokens on a v5e
+(``PERF.md`` §6, PR 28). ``Config.device_inflate=None`` resolves to this
+path everywhere (``resolve_device_inflate``).
 
-Path B is the **batched two-phase device inflate** (SURVEY §7 hard-part
-#1). Bit-serial Huffman decoding resists lane-parallelism, so the split is:
+Path B, reached only by an explicit ``Config.device_inflate=True``, is the
+**batched two-phase device inflate** (SURVEY §7 hard-part #1). Bit-serial
+Huffman decoding resists lane-parallelism, so the split is:
 
 1. *Host entropy phase* (`sbt_tokenize_deflate`, native/): decode the
    DEFLATE bitstream into per-output-byte tokens — ``lit[i]`` (the byte, if
@@ -33,19 +40,19 @@ Batching: ALL blocks of a window group go through one tokenize call, one
 packed H2D transfer, and one resolve dispatch — (blocks, 64 Ki) lanes per
 launch, batch dim padded to a power of two so jit shape churn is bounded.
 
-``InflatePipeline`` overlaps the stages: worker threads run read +
-tokenize + pack + **async device dispatch** for up to ``depth`` window
-groups while the consumer materializes the previous window's resolved
-bytes — real double-buffering, so the device never idles on the host
-entropy phase and the host never idles on the device copy phase.
+``InflatePipeline`` overlaps the stages on either path: worker threads
+inflate (path A), or run read + tokenize + pack + **async device dispatch**
+(path B), for up to ``depth`` window groups while the consumer feeds the
+previous window to the device.
 
-The fully device-resident consumer (``checker.count_window_tokens``) goes
-one step further: it takes the packed tokens directly, resolves + windows
-+ counts inside ONE program, and only scalars (and the halo carry) ever
-leave HBM — see stream_check.StreamChecker.count_reads.
+Path B's fully device-resident consumer (``checker.count_window_tokens``)
+takes the packed tokens directly, resolves + windows + counts inside ONE
+program, and only scalars (and the halo carry) ever leave HBM — see
+stream_check.StreamChecker._count_reads_fused. It ships until the
+``simplicity`` PR that deletes it (ROADMAP D1/D2).
 
-Keeping host zlib as the correctness fallback is permanent policy: the
-checker consumes identical flat windows from either producer.
+The checker consumes identical flat windows from either producer, and a
+member the native inflater rejects still goes to zlib.
 """
 
 from __future__ import annotations
@@ -321,7 +328,8 @@ class DeviceObserver:
     ``inflate.h2d_ms`` (issue to arrival of the operand, as a rule hidden
     behind the previous window's program), the other
     ``inflate.device_ms = t_ready(k) - max(t_dispatch(k), t_ready(k-1))``
-    and ``inflate.rounds``. ``device_ms`` is therefore the program's time
+    and, where the program resolves tokens and hands its round count over,
+    ``inflate.rounds``. ``device_ms`` is therefore the program's time
     plus whatever of its operand's H2D the previous program did not hide
     (all of it on the first window of a pass)."""
 
@@ -352,14 +360,18 @@ class DeviceObserver:
         self._threads[-1].start()
         return q
 
-    def window(self, operand, t_put: float, out, t_dispatch: float) -> None:
+    def window(self, operand, t_put: float, out, t_dispatch: float,
+               rounds=None) -> None:
         """``operand``: the H2D array (None when the transfer happened on
-        a producer thread); ``out``: the dispatch's ``rounds`` output."""
+        a producer thread); ``out``: an output of the dispatch to wait on
+        (the count scalar, a step's totals); ``rounds``: the LZ77 round
+        count of a program that resolves tokens, None of one that only
+        checks."""
         if operand is not None:
             if self._h2d is None:
                 self._h2d = self._start("obs-h2d", self._on_h2d)
             self._h2d.put((operand, t_put))
-        self._dev.put((out, t_dispatch))
+        self._dev.put((out, t_dispatch, rounds))
 
     def close(self) -> None:
         """Drains the threads: every window handed over is observed."""
@@ -374,18 +386,22 @@ class DeviceObserver:
         operand.block_until_ready()
         attribute_ms(h2d_ms=(time.perf_counter() - t_put) * 1e3)
 
-    def _on_device(self, rounds_dev, t_dispatch: float) -> None:
-        rounds_dev.block_until_ready()
+    def _on_device(self, out, t_dispatch: float, rounds) -> None:
+        out.block_until_ready()
         t_ready = time.perf_counter()
         device_ms = (t_ready - max(t_dispatch, self._t_ready)) * 1e3
         self._t_ready = t_ready
         # A mesh step hands over one round count a chip: the most of them.
-        self._observe(device_ms, int(np.asarray(rounds_dev).max()))
+        self._observe(
+            device_ms,
+            None if rounds is None else int(np.asarray(rounds).max()),
+        )
 
     @staticmethod
-    def _observe(device_ms: float, rounds: int) -> None:
+    def _observe(device_ms: float, rounds: int | None) -> None:
         attribute_ms(device_ms=device_ms)
-        obs.observe("inflate.rounds", rounds, unit="rounds")
+        if rounds is not None:
+            obs.observe("inflate.rounds", rounds, unit="rounds")
 
 
 PROFILE_ENV = "SPARK_BAM_PROFILE"
@@ -699,25 +715,18 @@ def inflate_file_device(path) -> FlatView | None:
     return view
 
 
-def resolve_device_inflate(config, use_device: bool = True) -> bool:
-    """Resolve ``Config.device_inflate``'s auto (``None``) state: True on
-    the TPU backend, False elsewhere and for host-only consumers (never
-    initializes a JAX backend for them). On a TPU a host entropy phase
-    without the native tokenizer is an error naming the build failure, not
-    a quiet return to host zlib."""
-    if config.device_inflate is not None:
-        return config.device_inflate
-    if not use_device:
-        return False
-    import jax
-
-    if jax.default_backend() != "tpu":
-        return False
-    if config.inflate_config.resolve_tokenize() == "host":
-        from spark_bam_tpu.native.build import require_native
-
-        require_native("device inflate on a TPU (tokenize=host)")
-    return True
+def resolve_device_inflate(config) -> bool:
+    """Resolve ``Config.device_inflate``'s auto (``None``) state: host
+    inflate, on every backend and for every consumer. The host that decodes
+    a member's tokens copies its bytes for a fraction of what handing the
+    tokens to the device costs (the module text has the numbers), and no
+    observable property of a BAM makes the device copy the better half, so
+    there is nothing to select on: host inflate is the designed path and
+    counts as no demotion. Only an explicit ``device_inflate=True`` reaches
+    the two-phase device inflate and the fused count, where a host entropy
+    phase without the native tokenizer raises on a TPU
+    (``StreamChecker._count_reads_fused``). Never touches a JAX backend."""
+    return bool(config.device_inflate)
 
 
 def window_plan(metas: list[Metadata], window_uncompressed: int) -> list[list[Metadata]]:

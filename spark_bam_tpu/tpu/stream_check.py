@@ -40,16 +40,18 @@ spill decode — the load workload).
 window runs one fused kernel whose owned-span count reduces on-chip, the
 scalars accumulate on device, and a handful of integers cross the wire
 per ~2^30 positions (reference workload: count-reads,
-docs/benchmarks.md:53-59).
+docs/benchmarks.md:53-59). On every backend its windows reach the device
+as inflated bytes: the pipeline's workers run the native inflate ahead of
+the feeding thread, one H2D a window carries the window, and the device
+program is the check and its count reduction (``checker.count_window``).
 
-When the device inflate is live (``Config.device_inflate`` /
-``fused_count``), ``count_reads`` goes one step further and runs the
-**fully device-resident** loop: the host ships only the packed LZ77
+Only an explicit ``Config.device_inflate=True`` (or ``fused_count``)
+reaches the **fully device-resident** loop: the host ships the packed LZ77
 token planes per window, and ``checker.count_window_tokens`` resolves +
-assembles + funnels + walks inside one XLA program — the inflated bytes
-never exist on host, the halo carry stays in HBM between windows, and
-only the count scalars cross back. Host tokenize of the next windows
-overlaps the device's current one via the same prefetch pool.
+assembles + funnels + walks inside one XLA program — the halo carry stays
+in HBM between windows, and only the count scalars cross back. The device
+takes a hundred times longer over that copy than the host does
+(``tpu/inflate.py``), so nothing selects it by itself.
 """
 
 from __future__ import annotations
@@ -161,7 +163,7 @@ class StreamChecker:
         # header walk over every BGZF block — seconds on multi-GB files).
         self.pipeline = InflatePipeline(
             path, window_uncompressed=fresh,
-            device_copy=resolve_device_inflate(config, use_device),
+            device_copy=resolve_device_inflate(config),
             metas=metas, inflate_spec=config.inflate, **pipe_kw,
         )
         self.total = self.pipeline.total
@@ -244,28 +246,6 @@ class StreamChecker:
             return kernel(
                 jnp.asarray(padded), lens_dev, nc, jnp.int32(n),
                 jnp.bool_(at_eof),
-            )
-
-        return launch
-
-    def _count_launcher(self):
-        """Fused count launch: one dispatch per window, scatters DCE'd."""
-        from spark_bam_tpu.tpu.checker import PAD, make_count_window
-
-        kernel = make_count_window(
-            self.kernel_window, self.config.reads_to_check,
-            flags_impl=self._flags_impl(),
-            funnel=self.config.funnel_enabled(),
-        )
-        lens_dev, nc = self._device_inputs()
-        w = self.kernel_window
-
-        def launch(buf, n, at_eof, lo, own_end):
-            padded = np.zeros(w + PAD, dtype=np.uint8)
-            padded[:n] = buf
-            return kernel(
-                jnp.asarray(padded), lens_dev, nc, jnp.int32(n),
-                jnp.bool_(at_eof), jnp.int32(lo), jnp.int32(own_end),
             )
 
         return launch
@@ -466,14 +446,24 @@ class StreamChecker:
     def count_reads(self) -> int:
         """Record count (the count-reads workload).
 
-        On device, each window runs ONE fused kernel whose owned-span count
-        reduces on-chip, and the per-window scalars accumulate *on device* —
-        nothing crosses the wire until EOF. A pacing
-        sync on a two-windows-old scalar bounds in-flight windows (and HBM)
-        without a transfer. If any owned candidate escaped (chains beyond
-        the halo — ultra-long reads), the exact spans() path re-runs the
-        file with full deferral; on real data with the default halo this
-        never triggers.
+        On device, each window runs ONE fused kernel (``count_window``: the
+        check and its owned-span count reduction), and the per-window
+        scalars accumulate *on device* — nothing crosses the wire until
+        EOF. The windows arrive as inflated bytes: the pipeline's workers
+        inflate ``depth`` groups ahead (``inflate.stall_ms`` is this
+        thread's wait for them), this thread lays the carry and the window
+        into a zero-padded buffer and puts it (``inflate.h2d``), and
+        dispatches (``inflate.device_kernel``). A pacing sync on a
+        two-windows-old scalar (``check.pace``) bounds in-flight windows
+        (and HBM) without a transfer, so this thread runs up to
+        ``ring_depth`` windows ahead of the device and the device waits for
+        it at the head of a pass only. Under a live registry a
+        ``DeviceObserver`` takes ``inflate.device_ms`` off this thread,
+        which dispatches and waits exactly as it does with the registry
+        off. If any owned candidate escaped (chains beyond the halo —
+        ultra-long reads), the exact spans() path re-runs the file with
+        full deferral; on real data with the default halo this never
+        triggers.
         """
         if not self.use_device:
             return self._count_via_spans()
@@ -484,69 +474,112 @@ class StreamChecker:
             res = self._count_reads_fused()
             if res is not None:
                 return res
+        from spark_bam_tpu.tpu.checker import PAD, make_count_window
+        from spark_bam_tpu.tpu.inflate import DeviceObserver
+
+        funnel = self.config.funnel_enabled()
+        kernel = make_count_window(
+            self.kernel_window, self.config.reads_to_check,
+            flags_impl=self._flags_impl(), funnel=funnel,
+        )
+        lens_dev, nc = self._device_inputs()
+        w = self.kernel_window
+
         total = 0
-        dev_total = None
-        dev_esc = None
-        dev_surv = None
+        dev_total = dev_esc = dev_surv = None
         windows = 0
         chunk = 0
         screened = 0
         flush_every = self.flush_every
-        funnel = self.config.funnel_enabled()
         escaped = False
         # pacing: keep ≤ ring_depth windows' scalars un-synced
         ring: list = []
-        for buf, base, own_end, at_eof, out in self._windows(
-            self._count_launcher()
-        ):
-            dev_total = (
-                out["count"] if dev_total is None else dev_total + out["count"]
-            )
-            dev_esc = (
-                out["esc_count"] if dev_esc is None
-                else dev_esc + out["esc_count"]
-            )
-            dev_surv = (
-                out["survivors"] if dev_surv is None
-                else dev_surv + out["survivors"]
-            )
-            screened += len(buf)
-            ring.append(out["count"])
-            if len(ring) > self.ring_depth:
-                ring.pop(0).block_until_ready()
-            windows += 1
-            chunk += 1
-            obs.count("check.windows")
-            if self.progress is not None:
-                self.progress(windows, base + own_end, self.total)
-            # One early escape checkpoint (window 4): escape-prone inputs
-            # (ultra-long reads vs this halo) abort to the exact path after
-            # ~4 windows instead of after a whole flush interval (up to
-            # 2^30 positions of doomed device work). Costs a single extra
-            # device sync per file; the steady-state policy stays
-            # flush-aligned so the device is not synced per window.
-            if windows == 4 and int(dev_esc):
-                escaped = True
-                break
-            if chunk >= flush_every:
-                # Escape checkpoint rides the flush: abort to the exact
-                # path early instead of finishing a doomed device pass.
-                if int(dev_esc):
-                    escaped = True
-                    break
-                total += int(dev_total)
-                if funnel:
-                    self._funnel_add(screened, int(dev_surv))
-                dev_total = dev_esc = dev_surv = None
-                chunk = 0
-                screened = 0
-        if not escaped and dev_total is not None:
-            if int(dev_esc):
-                escaped = True
-            else:
-                total += int(dev_total)
-                if funnel:
-                    self._funnel_add(screened, int(dev_surv))
+        observer = DeviceObserver.maybe()
+        rows = halo_windows(self.pipeline, self.halo, self.header_end_abs)
+        try:
+            while not escaped:
+                with obs.span("check.window", window=windows):
+                    # The pipeline's wait for the host inflate
+                    # (``inflate.stall_ms``) is inside this ``next``.
+                    row = next(rows, None)
+                    if row is None:
+                        break
+                    buf, base, own_end, lo, at_eof = row
+                    n = len(buf)
+                    t_put = time.perf_counter()
+                    with obs.span("inflate.h2d", bytes=w + PAD):
+                        # Fresh buffer per window (never mutated after
+                        # dispatch): safe under async dispatch even when
+                        # jnp.asarray aliases zero-copy on the CPU backend.
+                        padded = np.zeros(w + PAD, dtype=np.uint8)
+                        padded[:n] = buf
+                        operand = jnp.asarray(padded)
+                    obs.count("inflate.h2d_bytes", w + PAD)
+                    t_dispatch = time.perf_counter()
+                    with obs.span("inflate.device_kernel"):
+                        out = kernel(
+                            operand, lens_dev, nc, jnp.int32(n),
+                            jnp.bool_(at_eof), jnp.int32(lo),
+                            jnp.int32(own_end),
+                        )
+                    if observer is not None:
+                        observer.window(
+                            operand, t_put, out["count"], t_dispatch)
+                    dev_total = (
+                        out["count"] if dev_total is None
+                        else dev_total + out["count"]
+                    )
+                    dev_esc = (
+                        out["esc_count"] if dev_esc is None
+                        else dev_esc + out["esc_count"]
+                    )
+                    dev_surv = (
+                        out["survivors"] if dev_surv is None
+                        else dev_surv + out["survivors"]
+                    )
+                    screened += n
+                    ring.append(out["count"])
+                    if len(ring) > self.ring_depth:
+                        with obs.span("check.pace"):
+                            ring.pop(0).block_until_ready()
+                    windows += 1
+                    chunk += 1
+                    obs.count("check.windows")
+                    if self.progress is not None:
+                        self.progress(windows, base + own_end, self.total)
+                    # One early escape checkpoint (window 4): escape-prone
+                    # inputs (ultra-long reads vs this halo) abort to the
+                    # exact path after ~4 windows instead of after a whole
+                    # flush interval (up to 2^30 positions of doomed device
+                    # work). Costs a single extra device sync per file; the
+                    # steady-state policy stays flush-aligned so the device
+                    # is not synced per window.
+                    if windows == 4 or chunk >= flush_every:
+                        with obs.span("check.flush"):
+                            if int(dev_esc):
+                                escaped = True
+                            elif chunk >= flush_every:
+                                total += int(dev_total)
+                                if funnel:
+                                    self._funnel_add(
+                                        screened, int(dev_surv))
+                                dev_total = dev_esc = dev_surv = None
+                                chunk = 0
+                                screened = 0
+            if not escaped and dev_total is not None:
+                with obs.span("check.flush"):
+                    if int(dev_esc):
+                        escaped = True
+                    else:
+                        total += int(dev_total)
+                        if funnel:
+                            self._funnel_add(screened, int(dev_surv))
+        finally:
+            # Closing the generator shuts the pipeline's pool and channel
+            # before the exact path (if any) reopens the file.
+            rows.close()
+            if observer is not None:
+                observer.close()
         if escaped:
             # Rare exact path (chains outran the halo — ultra-long reads):
             # the spans path resolves every deferral bit-exactly. Suppress
@@ -724,7 +757,7 @@ class StreamChecker:
                     if observer is not None:
                         observer.window(
                             None if device_tok else operands[0], t_put,
-                            out["rounds"], t_dispatch,
+                            out["rounds"], t_dispatch, rounds=out["rounds"],
                         )
                     if device_tok:
                         ok_ring.append(out["tok_ok"])

@@ -4,8 +4,10 @@ The TPU compiler is installed without a chip: ``jax.experimental.topologies``
 describes a ``v5e:2x2`` and ``.lower(...).compile()`` against its devices
 raises what the attached chip would raise — Mosaic lowering refusals, HBM
 exhaustion — at no chip time. These cases pin the main path's programs at the
-default width (32 MiB kernel, 4 MiB halo, 512 block rows × 64 KiB payloads)
-plus the small serve / aggregate / Pallas programs.
+default width (the count of host-inflated 32 MiB windows on one chip and on
+four; the token path's 4 MiB halo, 512 block rows × 64 KiB payloads, which
+an explicit ``device_inflate=True`` still reaches) plus the small serve /
+aggregate / Pallas programs.
 
 Only one process may hold libtpu, so the topology is described inside a
 module-scoped fixture (never at import or collection) and every compile runs
@@ -88,9 +90,9 @@ def _mesh_shapes(topo, n_devices: int):
 
 # ------------------------------------------------------------ full width
 def test_fused_count_program_auto_selects_compiles_at_default_width(chip):
-    """The whole fused count program ``auto`` ends in on a TPU — entropy
-    phase, LZ77 resolve, window assembly, funnel, chain walk — built the
-    way ``StreamChecker._count_reads_fused`` builds it."""
+    """The whole fused count program ``device_inflate=True`` ends in on a
+    TPU — entropy phase, LZ77 resolve, window assembly, funnel, chain walk —
+    built the way ``StreamChecker._count_reads_fused`` builds it."""
     from spark_bam_tpu.core.inflate_config import InflateConfig
     from spark_bam_tpu.tpu import checker
     from spark_bam_tpu.tpu.inflate import STRIDE, _tok_impl
@@ -117,6 +119,10 @@ def test_fused_count_program_auto_selects_compiles_at_default_width(chip):
 
 
 def test_count_window_xla_funnel_compiles_at_32mib(chip):
+    """``jit_count_window``: the whole device program of the one-chip count
+    on every backend (the windows arrive inflated). Its temporaries are the
+    check's alone: under 5 GiB with its one 32 MiB operand, where the token
+    program reserved 6.0."""
     from spark_bam_tpu.tpu.checker import make_count_window
 
     kernel = jax.jit(make_count_window(WINDOW, 10, "xla", funnel=True))
@@ -125,13 +131,25 @@ def test_count_window_xla_funnel_compiles_at_32mib(chip):
         *_scalars(chip, jnp.int32, jnp.int32, jnp.bool_, jnp.int32,
                   jnp.int32),
     ).compile()
-    assert _device_bytes(compiled) < HBM
+    assert _device_bytes(compiled) < 5 << 30
+    assert "gather" in compiled.as_text()  # the lane walk: a real program
+
+
+def _count_step_shapes(shape, repl, devices: int, rows: int):
+    """The count step's operands for ``rows`` rows a device, flat."""
+    k = devices * rows
+    return (
+        shape((k * (WINDOW + PAD),), jnp.uint8), shape((k,), jnp.int32),
+        shape((k,), jnp.bool_), shape((k,), jnp.int32),
+        shape((k,), jnp.int32), shape((CMAX,), jnp.int32, repl),
+        shape((), jnp.int32, repl),
+    )
 
 
 def test_sharded_count_step_fits_one_chip_at_its_row_cap(topo, chip):
-    """``count-reads --sharded`` vmaps window rows per device; five 32 MiB
-    rows need 15.9 GiB and are refused, so the stream caps rows by the
-    device's reported memory. The capped step must fit."""
+    """The mesh count vmaps a device's rows; five 32 MiB rows need 15.9 GiB
+    and are refused, so the stream caps rows by the device's reported
+    memory. The capped step must fit."""
     from types import SimpleNamespace
 
     from spark_bam_tpu.parallel.mesh import make_shard_map_count_step
@@ -147,13 +165,29 @@ def test_sharded_count_step_fits_one_chip_at_its_row_cap(topo, chip):
 
     mesh, shape, repl = _mesh_shapes(topo, 1)
     step = make_shard_map_count_step(mesh, 10, "data", "xla", funnel=True)
-    compiled = step.lower(
-        shape((rows, WINDOW + PAD), jnp.uint8), shape((rows,), jnp.int32),
-        shape((rows,), jnp.bool_), shape((rows,), jnp.int32),
-        shape((rows,), jnp.int32), shape((CMAX,), jnp.int32, repl),
-        shape((), jnp.int32, repl),
-    ).compile()
+    compiled = step.lower(*_count_step_shapes(shape, repl, 1, rows)).compile()
     assert _device_bytes(compiled) < HBM
+
+
+def test_count_step_compiles_for_four_chips_at_one_row_a_chip(topo, chip):
+    """``jit_count_step`` as the whole-file count runs it on a v5e host: one
+    host-inflated 32 MiB row a chip, flat, the count pair ``psum``'d. A
+    chip holds its own row's bytes once (a ``(1, N)`` u8 block of a
+    row-major operand would be tiled four rows high). The compiler sets
+    5.9 GiB aside for the one row of a several-chip mesh, against 2.7 GiB
+    for the same program on a mesh of one chip (``PERF.md`` §7)."""
+    from spark_bam_tpu.parallel.mesh import make_shard_map_count_step
+
+    n = 4
+    mesh, shape, repl = _mesh_shapes(topo, n)
+    step = make_shard_map_count_step(mesh, 10, "data", "xla", funnel=True)
+    compiled = step.lower(*_count_step_shapes(shape, repl, n, 1)).compile()
+    ma = compiled.memory_analysis()
+    assert WINDOW < ma.argument_size_in_bytes < WINDOW + (1 << 20)
+    assert 1 << 30 < _device_bytes(compiled) < HBM // 2
+    text = compiled.as_text()
+    assert "all-reduce" in text  # the psum, and nothing gathers the rows
+    assert "all-gather" not in text and "all-to-all" not in text
 
 
 @pytest.mark.parametrize("window,blocks,least", [

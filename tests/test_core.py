@@ -1,3 +1,5 @@
+import pytest
+
 from spark_bam_tpu.core.config import Config, format_bytes, parse_bytes
 from spark_bam_tpu.core.pos import Pos, parse_pos
 from spark_bam_tpu.core.ranges import ByteRange, RangeSet, parse_range, parse_ranges
@@ -110,25 +112,50 @@ def test_compile_cache_default_is_fixed_inside_the_checkout():
     assert CHECKOUT_JAX_CACHE == repo / ".jax_cache"
 
 
-def test_tpu_backend_without_native_library_raises(monkeypatch):
-    """On a TPU backend a missing native tokenizer is an error naming the
-    build failure — not a quiet return to host zlib."""
+@pytest.fixture
+def tpu_without_native(monkeypatch):
+    """What a TPU process sees when the native library did not build."""
     import jax
-    import pytest
 
     from spark_bam_tpu.native import build
-    from spark_bam_tpu.tpu.inflate import resolve_device_inflate
 
     monkeypatch.setattr(build, "_LIB_CACHE", [None])
     monkeypatch.setattr(build, "_LOAD_INFO", {"error": "g++ rc=1: boom"})
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    host_tok = Config(inflate="tokenize=host")
+
+
+@pytest.mark.parametrize("config,device", [
+    (Config(), False),
+    (Config(inflate="tokenize=host"), False),
+    (Config(inflate="tokenize=device"), False),
+    (Config(device_inflate=False), False),
+    (Config(device_inflate=True), True),
+    (Config(device_inflate=True, inflate="tokenize=device"), True),
+])
+def test_tpu_backend_resolves_inflate_as_every_backend_does(
+        config, device, tpu_without_native):
+    """``device_inflate=None`` is host inflate on a TPU too, with or without
+    the native library (zlib inflates where it is missing); an explicit
+    setting is honoured as it is."""
+    from spark_bam_tpu.tpu.inflate import resolve_device_inflate
+
+    assert resolve_device_inflate(config) is device
+
+
+def test_tpu_backend_token_path_without_native_library_raises(
+        tpu_without_native, tmp_path):
+    """Asked for by name, the fused count's host entropy phase without the
+    native tokenizer is still an error naming the build failure on a TPU —
+    not a quiet return to host zlib."""
+    from bam_factories import random_bam
+    from spark_bam_tpu.tpu.stream_check import StreamChecker
+
+    path = tmp_path / "small.bam"
+    random_bam(path, seed=7)
+    checker = StreamChecker(
+        path, Config(device_inflate=True, inflate="tokenize=host"))
     with pytest.raises(RuntimeError, match="g\\+\\+ rc=1: boom"):
-        resolve_device_inflate(host_tok)
-    # The device tokenizer needs no native library; an explicit pin wins.
-    assert resolve_device_inflate(Config(inflate="tokenize=device")) is True
-    assert resolve_device_inflate(host_tok.replace(device_inflate=False)) \
-        is False
+        checker.count_reads()
 
 
 def test_cpu_backend_without_native_library_stays_on_host(monkeypatch):

@@ -365,20 +365,15 @@ def test_count_reads_with_device_inflate_config(bam1):
 
 
 def test_device_inflate_auto_resolution():
-    """Default is auto (None): True only on a TPU backend with the native
-    tokenizer built; False on this CPU-mesh backend and for host-only
-    consumers; explicit settings always win."""
+    """Default is auto (None), which is host inflate on every backend; an
+    explicit setting is what it says, for host-only consumers too."""
     from spark_bam_tpu.core.config import Config
     from spark_bam_tpu.tpu.inflate import resolve_device_inflate
 
     cfg = Config()
     assert cfg.device_inflate is None
-    assert resolve_device_inflate(cfg) is False  # CPU test backend
-    assert resolve_device_inflate(cfg, use_device=False) is False
+    assert resolve_device_inflate(cfg) is False
     assert resolve_device_inflate(Config(device_inflate=True)) is True
-    assert resolve_device_inflate(
-        Config(device_inflate=True), use_device=False
-    ) is True  # explicit beats auto everywhere
     assert resolve_device_inflate(Config(device_inflate=False)) is False
     assert Config.from_dict({"spark.bam.device.inflate": "auto"}).device_inflate is None
     assert Config.from_dict({"spark.bam.device.inflate": "false"}).device_inflate is False
