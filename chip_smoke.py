@@ -41,12 +41,11 @@ DATA = ROOT / ".smoke_data"
 #: Counters that must stay at zero: each one is a path that quietly left
 #: the device, or (escape retries) a kernel that misjudged a short read.
 ZERO_COUNTERS = (
-    "inflate.tokenize_demotions", "inflate.host_demotions",
     "check.fused_demotions", "check.count_escape_retries",
     "agg.host_fallbacks", "mesh.escapes",
 )
 EVIDENCE_COUNTERS = (
-    "check.windows", "inflate.tokenize_blocks",
+    "check.windows", "inflate.windows",
     "mesh.steps", "serve.batches", "serve.batch_rows", "funnel.positions",
     "funnel.survivors",
 )
@@ -152,15 +151,7 @@ class Phase:
 
 def engines(config) -> dict:
     """Which engines ``auto`` resolves to in this process."""
-    from spark_bam_tpu.tpu import inflate
-
-    icfg = config.inflate_config
     return {
-        "inflate": "device" if inflate.resolve_device_inflate(config)
-        else "host",
-        "tokenize": icfg.resolve_tokenize(),
-        "tokenize_kernel": inflate._tok_impl(icfg.kernel),
-        "resolve": inflate._lz77_impl(),
         "flags": config.flags_impl,
         "funnel": config.funnel_enabled(),
     }

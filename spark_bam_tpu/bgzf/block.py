@@ -9,11 +9,6 @@ from spark_bam_tpu.core.pos import Pos
 
 MAX_BLOCK_SIZE = 64 * 1024  # uncompressed payload never exceeds 64 KiB
 FOOTER_SIZE = 8             # CRC32 + uncompressed-size, both u32
-# A member's raw-DEFLATE payload can't exceed BSIZE's u16 ceiling minus
-# the minimal wrapper (18-byte header + 8-byte footer): the bound the
-# device tokenizer's staged-row width is sized against (bgzf/flat.py
-# stage_run_payloads).
-MAX_COMPRESSED_PAYLOAD = (1 << 16) - 18 - FOOTER_SIZE
 
 
 def check_isize(uncompressed_size: int, start: int) -> int:

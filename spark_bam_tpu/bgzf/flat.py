@@ -6,9 +6,9 @@ uncompressed payloads of a run of blocks, plus the block table needed to map
 ``UncompressedBytes`` iterators for all bulk work (SURVEY.md §7 step 4a:
 "inflate on host, ship uncompressed blocks to HBM").
 
-Inflation fans out across threads: zlib releases the GIL, so a thread pool
-saturates host cores (the Pallas in-device inflate is the planned upgrade,
-tpu/inflate.py).
+Inflation fans out across threads: the native inflater and zlib release
+the GIL, so a thread pool saturates host cores. This is the one way the
+device paths inflate (tpu/inflate.py).
 """
 
 from __future__ import annotations
@@ -136,42 +136,6 @@ def read_run_payloads(
         off += len(part)
     comp = np.concatenate(parts) if parts else np.empty(0, dtype=np.uint8)
     return comp, offsets, lengths
-
-
-def stage_run_payloads(
-    ch: ByteChannel, metas: list[Metadata], threads: int = 8
-) -> tuple[np.ndarray, np.ndarray]:
-    """Stage a run of blocks' raw-DEFLATE payloads for the device
-    tokenizer: ``(staged (B_pad, C_pad) u8, clens (B_pad,) i32)``.
-
-    The bit-reader kernel (tpu/tokenize_device.py) wants one row per
-    block, zero-padded so its 4-byte bit reads never leave the row
-    (≥ 8 bytes of tail slack). Both dims are padded to powers of two —
-    rows because jit shape churn must stay log-bounded (the
-    ``tokenize_pack`` batch policy), columns because a window's blocks
-    share one compiled kernel; ``MAX_COMPRESSED_PAYLOAD`` bounds C_pad
-    at 64 KiB. Pad rows have ``clen == 0`` (callers treat them as
-    vacuously valid). This is the ONLY buffer that crosses H2D in
-    device-tokenize mode — compressed bytes, not token planes."""
-    from spark_bam_tpu.bgzf.block import MAX_COMPRESSED_PAYLOAD
-
-    comp, offsets, lengths = read_run_payloads(ch, metas, threads=threads)
-    b = len(metas)
-    b_pad = max(1 << max(b - 1, 0).bit_length(), 1)
-    longest = int(lengths.max()) if b else 0
-    if longest > MAX_COMPRESSED_PAYLOAD:
-        raise EOFError(
-            f"raw payload of {longest} bytes exceeds the BGZF "
-            f"{MAX_COMPRESSED_PAYLOAD}-byte ceiling"
-        )
-    c_pad = max(1 << max(longest + 8 - 1, 0).bit_length(), 1024)
-    staged = np.zeros((b_pad, c_pad), dtype=np.uint8)
-    for i in range(b):
-        o, n = int(offsets[i]), int(lengths[i])
-        staged[i, :n] = comp[o: o + n]
-    clens = np.zeros(b_pad, dtype=np.int32)
-    clens[:b] = lengths
-    return staged, clens
 
 
 def _inflate_one(ch: ByteChannel, meta: Metadata, out: np.ndarray, flat_off: int):

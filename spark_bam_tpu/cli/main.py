@@ -180,16 +180,6 @@ def _add_deflate(sub):
     )
 
 
-def _add_inflate(sub):
-    sub.add_argument(
-        "--inflate", default=None, metavar="SPEC",
-        help="read-path inflate knobs, e.g. 'tokenize=device,kernel=auto,"
-             "donate=on' (bare 'device'/'host' ok) — where the DEFLATE "
-             "entropy phase runs for the two-phase device inflate "
-             "(SPARK_BAM_INFLATE env var works too; docs/design.md)",
-    )
-
-
 def _add_common(sub, split_default=None):
     _add_metrics(sub)
     _add_faults(sub)
@@ -197,7 +187,6 @@ def _add_common(sub, split_default=None):
     _add_limits(sub)
     _add_remote(sub)
     _add_funnel(sub)
-    _add_inflate(sub)
     sub.add_argument("-m", "--max-split-size", default=split_default,
                      help="split size (byte shorthand like 2MB ok)")
     sub.add_argument("-l", "--print-limit", type=int, default=10)
@@ -768,11 +757,6 @@ def main(argv=None) -> int:
 
             DeflateConfig.parse(args.deflate)  # fail before any work starts
             config = config.replace(deflate=args.deflate)
-        if getattr(args, "inflate", None) is not None:
-            from spark_bam_tpu.core.inflate_config import InflateConfig
-
-            InflateConfig.parse(args.inflate)  # fail before any work starts
-            config = config.replace(inflate=args.inflate)
         if getattr(args, "serve", None) is not None:
             from spark_bam_tpu.serve import ServeConfig
 

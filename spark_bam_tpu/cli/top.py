@@ -25,17 +25,14 @@ def _ms(v) -> str:
 
 
 def _hd_split(snapshot) -> str:
-    """``host/h2d/dev`` last-window ms from the attribution gauges."""
+    """``h2d/dev`` last-window ms from the attribution gauges."""
     vals = {}
     for g in (snapshot or {}).get("gauges", []):
-        if g.get("name") in ("inflate.host_ms", "inflate.h2d_ms",
-                             "inflate.device_ms"):
+        if g.get("name") in ("inflate.h2d_ms", "inflate.device_ms"):
             vals[g["name"].rsplit(".", 1)[1]] = g.get("value")
     if not vals:
         return "-"
-    return "/".join(
-        _ms(vals.get(k)) for k in ("host_ms", "h2d_ms", "device_ms")
-    )
+    return "/".join(_ms(vals.get(k)) for k in ("h2d_ms", "device_ms"))
 
 
 def _slo_lines(p: Printer, slo: "dict | None", indent: str = "") -> None:
@@ -91,7 +88,7 @@ def _worker_lines(p: Printer, label: str, tel: dict, indent: str = "") -> None:
         f"queue={stats.get('queue_depth', 0)} "
         f"p50={_ms(stats.get('latency_p50_ms'))}ms "
         f"p99={_ms(stats.get('latency_p99_ms'))}ms "
-        f"host/h2d/dev={_hd_split(snap)}ms"
+        f"h2d/dev={_hd_split(snap)}ms"
         + ("" if tel.get("telemetry_enabled") else " (metrics disabled)")
     )
     ops = stats.get("ops") or {}

@@ -1,11 +1,8 @@
 """Device kernels for the write path: batched CRC32 + fixed-Huffman pack.
 
-Mirror image of tpu/inflate.py's geometry: many ≤64 KiB payload lanes
-per dispatch, ``(B, STRIDE)`` u8 with the batch dim padded to a power of
-two so jit shape churn stays bounded. Both kernels are XLA programs
-(jnp + lax) — the same tier the LZ77 resolve kernel runs at; a Pallas
-variant would slot in behind the same entry points the way
-``lz77_resolve_pallas`` does for inflate.
+Many ≤64 KiB payload lanes per dispatch, ``(B, STRIDE)`` u8 with the batch
+dim padded to a power of two so jit shape churn stays bounded. Both kernels
+are XLA programs (jnp + lax).
 
 **CRC32** is the sequential half: slice-by-4 table lookups
 (four 256-entry u32 tables as baked constants), one ``fori_loop``
@@ -149,7 +146,7 @@ def deflate_fixed_lanes(data: jnp.ndarray, lengths: jnp.ndarray):
 def pack_lanes(payloads: "list[bytes]"):
     """Host staging: payload list → ``(data (B', STRIDE) u8,
     lengths (B',) i32, b)`` with ``B'`` the power-of-two pad of ``b``
-    (bounded jit shape churn, the tokenize_pack idiom)."""
+    (bounded jit shape churn)."""
     b = len(payloads)
     b_pad = max(1 << max(b - 1, 0).bit_length(), 1)
     data = np.zeros((b_pad, STRIDE), dtype=np.uint8)

@@ -76,15 +76,6 @@ class Config:
     # fits a 16 GB-HBM chip (64 MB windows OOM at compile time).
     window_size: int = 24 << 20
     halo_size: int = 4 << 20            # extra trailing bytes so chains can complete
-    # Two-phase device inflate (host entropy decode + on-device LZ77
-    # resolution, tpu/inflate.py). ``None`` = auto: on the TPU backend with
-    # the native tokenizer built it resolves True (the production default —
-    # the LZ77 copy phase, inflate's memory-bandwidth half, belongs on HBM);
-    # anywhere else False. Tokens cost ~3x the uncompressed bytes on the
-    # wire, so hosts whose device link is the constraint should pin False.
-    # A window whose INPUT the tokenizer rejects demotes to host zlib;
-    # compiler and device errors raise.
-    device_inflate: bool | None = None
     # Resident-scan counting (tpu/stream_check.count_reads_resident):
     # windows packed into HBM-resident chunks, ONE dispatch per chunk via
     # checker.count_scan. Amortizes per-dispatch latency where a dispatch
@@ -98,13 +89,6 @@ class Config:
     # 16 GiB part at 32 MiB windows; 256 MiB keeps the dispatch
     # amortization with headroom.
     resident_chunk_bytes: int = 256 << 20
-    # Fully device-resident count path (stream_check._count_reads_fused):
-    # ship packed LZ77 tokens, resolve + assemble + funnel + walk in one
-    # XLA program per window, carry chained in HBM. ``None`` = auto:
-    # follows the resolved ``device_inflate`` state (the two share the
-    # tokenizer prerequisite); demotes to the classic streaming loop
-    # whenever the tokenizer or kernel geometry can't serve a file.
-    fused_count: bool | None = None
     # --- fault tolerance (core/faults.py; docs/robustness.md) ---
     # Compact FaultPolicy spec ("retries=3,deadline=60,mode=tolerant"; "" =
     # defaults). Kept as the string form so the frozen dataclass stays
@@ -138,15 +122,6 @@ class Config:
     # counts, native-container compression, and the default projection
     # for the export sinks and the serve ``batch`` op.
     columnar: str = ""
-    # --- read-path device inflate (tpu/inflate.py; docs/design.md) ---
-    # Compact InflateConfig spec ("tokenize=device,kernel=auto,
-    # donate=on"; "" = defaults: tokenize=auto). Same string-spec
-    # pattern; ``inflate_config`` parses it (cached). Governs where the
-    # DEFLATE entropy phase runs (host native tokenizer vs the device
-    # bit-reader kernel), the device kernel engine (pallas/xla), and
-    # window-ring buffer donation. Orthogonal to ``device_inflate``
-    # (whether the two-phase device path runs at all).
-    inflate: str = ""
     # --- write-path compression (compress/; docs/design.md) ---
     # Compact DeflateConfig spec ("mode=fixed,level=6,lanes=16,
     # device=auto"; "" = defaults: host zlib). Same string-spec pattern;
@@ -203,13 +178,13 @@ class Config:
     # take the exact path); "off" disables it everywhere.
     funnel: str = "auto"                # on | off | auto
     # --- device pacing (tpu/stream_check.py) ---
-    # Device→host flush interval for the fused count path, in windows.
-    # None → auto: ≤ 2^30 positions between flushes so the on-device
-    # int32 accumulators cannot overflow (the auto cap still bounds
-    # explicit values).
+    # Device→host flush interval of the count's windows
+    # (StreamChecker.count_reads), in windows. None → auto: ≤ 2^30
+    # positions between flushes so the on-device int32 accumulators cannot
+    # overflow (the auto cap still bounds explicit values).
     flush_every: int | None = None
-    # Windows whose device scalars may remain un-synced in the fused
-    # count ring (the two-in-flight pipeline's pacing depth).
+    # Windows whose device scalars may remain un-synced in the count's
+    # ring: how far the feeding thread runs ahead of the device.
     ring_depth: int = 2
     # --- misc ---
     warn: bool = False                  # root log-level toggle (args/LogArgs.scala:30-33)
@@ -272,13 +247,6 @@ class Config:
         from spark_bam_tpu.columnar.config import ColumnarConfig
 
         return ColumnarConfig.parse(self.columnar)
-
-    @property
-    def inflate_config(self):
-        """The parsed ``InflateConfig`` for this config's ``inflate`` spec."""
-        from spark_bam_tpu.core.inflate_config import InflateConfig
-
-        return InflateConfig.parse(self.inflate)
 
     @property
     def deflate_config(self):
@@ -379,13 +347,9 @@ class Config:
                     value = parse_bytes(value)
             elif f.type in ("float", float):
                 value = float(value)
-            elif f.type in ("bool", bool, "bool | None"):
+            elif f.type in ("bool", bool):
                 if not isinstance(value, bool):
-                    s = str(value).lower()
-                    if "None" in str(f.type) and s in ("auto", "none", ""):
-                        value = None
-                    else:
-                        value = s in ("1", "true", "yes")
+                    value = str(value).lower() in ("1", "true", "yes")
             kw[name] = value
         return base.replace(**kw)
 

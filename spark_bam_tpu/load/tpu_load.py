@@ -311,9 +311,7 @@ def stream_read_batches(
 def counts_across_chips() -> bool:
     """Whether a whole-file count runs on the mesh engine: the backend is a
     TPU and this process sees more than one of its chips. Observed, never
-    asked for; the CPU backend's virtual devices do not select it. (Where
-    the windows are inflated is no such rule any more: on the host, on
-    every backend, ``resolve_device_inflate``.)"""
+    asked for; the CPU backend's virtual devices do not select it."""
     import jax
 
     return jax.default_backend() == "tpu" and jax.local_device_count() > 1

@@ -124,7 +124,6 @@ def _load_native_locked():
     c_u8p = ctypes.POINTER(ctypes.c_uint8)
     c_i64p = ctypes.POINTER(ctypes.c_int64)
     c_i32p = ctypes.POINTER(ctypes.c_int32)
-    c_u16p = ctypes.POINTER(ctypes.c_uint16)
 
     lib.sbt_inflate_blocks.restype = ctypes.c_long
     lib.sbt_inflate_blocks.argtypes = [
@@ -150,11 +149,6 @@ def _load_native_locked():
     lib.sbt_eager_check_window.argtypes = [
         c_u8p, ctypes.c_int64, c_i64p, ctypes.c_int64,
         c_i32p, ctypes.c_int32, ctypes.c_int32, ctypes.c_int32, c_u8p,
-    ]
-    lib.sbt_tokenize_deflate.restype = ctypes.c_long
-    lib.sbt_tokenize_deflate.argtypes = [
-        c_u8p, c_i64p, c_i64p, ctypes.c_int64,
-        c_u8p, c_u16p, ctypes.c_int64, c_i64p,
     ]
     lib.sbt_rans_decompress.restype = ctypes.c_int64
     lib.sbt_rans_decompress.argtypes = [
@@ -280,43 +274,6 @@ def find_record_start_window_native(
     return found, int(uncertain.value)
 
 
-def tokenize_deflate_native(
-    comp: np.ndarray,
-    offsets: np.ndarray,
-    lengths: np.ndarray,
-    stride: int,
-) -> tuple[np.ndarray, np.ndarray, np.ndarray] | None:
-    """Phase 1 of the two-phase device inflate: entropy-decode raw-DEFLATE
-    payloads into fixed-shape (lit, dist, out_lens) token rows for the
-    device LZ77 resolver (tpu/inflate.py) — u8 lit + u16 dist, 3 wire
-    bytes per output byte (dist=0 marks a literal; a back-reference's
-    parent is i - dist). Returns None if the native library is
-    unavailable."""
-    lib = load_native()
-    if lib is None:
-        return None
-    comp = np.ascontiguousarray(comp, dtype=np.uint8)
-    offsets = np.ascontiguousarray(offsets, dtype=np.int64)
-    lengths = np.ascontiguousarray(lengths, dtype=np.int64)
-    count = len(offsets)
-    lit = np.empty((count, stride), dtype=np.uint8)
-    dist = np.empty((count, stride), dtype=np.uint16)
-    out_lens = np.zeros(count, dtype=np.int64)
-    rc = lib.sbt_tokenize_deflate(
-        _ptr(comp, ctypes.c_uint8),
-        _ptr(offsets, ctypes.c_int64),
-        _ptr(lengths, ctypes.c_int64),
-        count,
-        _ptr(lit, ctypes.c_uint8),
-        _ptr(dist, ctypes.c_uint16),
-        stride,
-        _ptr(out_lens, ctypes.c_int64),
-    )
-    if rc != 0:
-        raise IOError(f"deflate tokenize failed at block {rc - 1}")
-    return lit, dist, out_lens
-
-
 def rans_decompress_native(blob: bytes, out_size: int) -> bytes | None:
     """Native rANS 4x8 decode (cram/rans.py is the fallback + encoder).
     Returns None when the library is unavailable; raises on bad input."""
@@ -348,8 +305,9 @@ def inflate_blocks_fast_into(
     of ``out`` (they degrade to byte copies near it), so callers may pass
     exact-size buffers; +8 slack past the last block's end recovers full
     speed on the tail. Blocks the fast decoder rejects are re-run through
-    zlib, so a True return always means exact output; returns False only
-    when the native library is unavailable (caller falls back entirely).
+    zlib, so a True return always means exact output (a block zlib rejects
+    too raises ``BlockCorruptionError``); returns False only when the
+    native library is unavailable (caller falls back entirely).
     """
     lib = load_native()
     if lib is None:
@@ -372,16 +330,14 @@ def inflate_blocks_fast_into(
         if rc == 0:
             return True
         # Block (start + rc - 1) was rejected: decode it with zlib (the
-        # permanent correctness fallback) and resume after it.
-        import zlib
+        # permanent correctness fallback, which raises BlockCorruptionError
+        # on a corrupt stream or a footer that lies) and resume after it.
+        from spark_bam_tpu.bgzf.stream import inflate_block_payload
 
         i = start + int(rc) - 1
         o, l = int(offsets[i]), int(lengths[i])
-        data = zlib.decompress(comp[o: o + l].tobytes(), -15)
-        if len(data) != int(out_lengths[i]):
-            raise IOError(
-                f"inflate produced {len(data)} bytes, footer says {int(out_lengths[i])}"
-            )
+        data = inflate_block_payload(
+            comp[o: o + l].tobytes(), int(out_lengths[i]))
         oo = int(out_offsets[i])
         out[oo: oo + len(data)] = np.frombuffer(data, dtype=np.uint8)
         start = i + 1

@@ -100,16 +100,11 @@ NAMES = frozenset({
     "funnel.positions", "funnel.survivors",
     # guard — untrusted-byte decode boundary (core/guard.py)
     "guard.quarantined_blocks", "guard.quarantined_records",
-    # inflate — device-resident BGZF inflate (docs/design.md)
+    # inflate — host BGZF inflate feeding the device (docs/design.md)
     "inflate.block", "inflate.blocks", "inflate.bytes",
     "inflate.device_kernel", "inflate.device_ms",
     "inflate.h2d", "inflate.h2d_bytes", "inflate.h2d_ms",
-    "inflate.host_demotions", "inflate.host_ms",
-    "inflate.pack", "inflate.rounds", "inflate.stall_ms",
-    "inflate.tokenize", "inflate.tokenize_blocks",
-    "inflate.tokenize_demotions", "inflate.tokenize_device",
-    "inflate.tokenize_device_ms", "inflate.tokenize_host_ms",
-    "inflate.window", "inflate.windows",
+    "inflate.stall_ms", "inflate.window", "inflate.windows",
     # jobs — durable job plane: WAL + crash-resumable runners
     # (docs/robustness.md "Durable jobs & scrubbing")
     "jobs.cancelled", "jobs.checkpoint_bytes", "jobs.checkpoints",
@@ -126,7 +121,7 @@ NAMES = frozenset({
     "mesh.assemble", "mesh.dirty_steps", "mesh.dispatch", "mesh.escapes",
     "mesh.h2d", "mesh.h2d_bytes",
     "mesh.patch_chunk_positions", "mesh.patch_chunks", "mesh.patch_rows",
-    "mesh.rounds", "mesh.rows", "mesh.stall", "mesh.step",
+    "mesh.rows", "mesh.stall", "mesh.step",
     "mesh.step_device_ms", "mesh.steps",
     # progress — long-run heartbeats
     "progress.beats",
@@ -170,17 +165,14 @@ NAMES = frozenset({
 
 #: ``jax.named_scope`` names inside the jitted programs, as they appear in
 #: an operation's name path in a device trace
-#: (``jit(count_window_tokens)/.../check/flags/...``). A reduction finds a
-#: stage by these, so they are a contract like the span names above. The
-#: fused window program (tpu/checker.count_window_tokens) has ``unpack``,
-#: ``lz77_resolve``, ``assemble``, ``check`` with its children ``flags``,
-#: ``funnel`` and ``chain_walk``, ``reduce`` (the two count sums) and
-#: ``carry``; the steps of parallel/mesh.py have the ``check`` family and
-#: ``reduce`` (``count_tokens_step`` runs the fused window program on every
-#: chip, so it has all of that program's); agg/kernels.py has ``agg_reduce``.
+#: (``jit(count_window)/.../check/flags/...``). A reduction finds a stage
+#: by these, so they are a contract like the span names above. The window
+#: program (tpu/checker.count_window) and the steps of parallel/mesh.py
+#: have ``check`` with its children ``flags``, ``funnel`` and
+#: ``chain_walk``, and ``reduce`` (the count sums, a step's psum);
+#: agg/kernels.py has ``agg_reduce``.
 SCOPES = frozenset({
-    "agg_reduce", "assemble", "carry", "chain_walk", "check", "flags",
-    "funnel", "lz77_resolve", "reduce", "unpack",
+    "agg_reduce", "chain_walk", "check", "flags", "funnel", "reduce",
 })
 
 #: Names of the jitted programs the scopes live in: ``jit_<name>`` is the
@@ -188,7 +180,6 @@ SCOPES = frozenset({
 PROGRAMS = frozenset({
     "agg_step", "agg_update", "check_step", "check_window",
     "confusion_step", "count_scan", "count_step", "count_window",
-    "count_tokens_step", "count_window_raw_program", "count_window_tokens",
     "full_step",
     "serve_step", "sharded_check_step",
 })

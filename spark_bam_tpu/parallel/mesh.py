@@ -28,9 +28,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from spark_bam_tpu import obs
-from spark_bam_tpu.tpu.checker import (
-    PAD, check_window, count_window, count_window_tokens,
-)
+from spark_bam_tpu.tpu.checker import PAD, check_window, count_window
 
 
 def make_mesh(devices=None, axis: str = "data") -> Mesh:
@@ -109,17 +107,6 @@ class MeshSteps:
             lambda: make_shard_map_count_step(
                 self.mesh, reads_to_check=reads_to_check, axis=self.axis,
                 flags_impl=flags_impl, funnel=funnel,
-            ),
-        )
-
-    def count_tokens_step(self, window: int, halo: int,
-                          reads_to_check: int = 10, flags_impl: str = "xla",
-                          funnel: bool = False):
-        return self._get(
-            ("count_tokens", window, halo, reads_to_check, flags_impl, funnel),
-            lambda: make_shard_map_count_tokens_step(
-                self.mesh, window, halo, reads_to_check=reads_to_check,
-                axis=self.axis, flags_impl=flags_impl, funnel=funnel,
             ),
         )
 
@@ -382,58 +369,6 @@ def make_shard_map_count_step(
             mesh=mesh,
             in_specs=(P(axis),) * 5 + (P(), P()),
             out_specs=P(),
-            check_vma=False,
-        )
-    )
-
-
-def make_shard_map_count_tokens_step(
-    mesh: Mesh, window: int, halo: int, reads_to_check: int = 10,
-    axis: str = "data", flags_impl: str = "xla", funnel: bool = False,
-):
-    """Sharded FUSED count step: every device runs the one-chip stream's
-    window program (``checker.count_window_tokens``: unpack → LZ77 resolve →
-    assemble → check → reduce) on its own row's packed tokens, and the
-    (boundary count, owned escapes) pair all-reduces with ``lax.psum``.
-    One row a device: every operand is the rows' concatenation, FLAT and
-    sharded over the mesh axis, so a device's block is its single row just
-    as the one-chip program takes it (``packed (3·B·STRIDE,)``, ``out_lens
-    (B,)``, the scalars ``(1,)``). A leading row dimension would cost a
-    u8 row four times its bytes on a TPU (``(1, N)`` u8 is tiled four rows
-    high) and a relayout in the program. A mesh row is a window with an
-    empty carry (``carry_len = 0``): its halo is re-inflated from the
-    blocks that follow, not carried from the row before. To the window
-    program ``halo`` is only the size of that empty carry, so it is held to
-    the window: a file smaller than the halo gets a window smaller than it.
-
-    Returns ``(totals (2,) replicated, rounds (devices,) row-sharded)``;
-    ``rounds`` is each device's LZ77 round count (``mesh.rounds``). No
-    inflated byte exists outside the device that checks it. The compiled
-    program is ``jit_count_tokens_step``."""
-    pallas_interpret = _mesh_pallas_interpret(mesh, flags_impl)
-    halo = min(halo, window)
-
-    def count_tokens_step(packed, out_lens, ns, at_eofs, los, owns, lengths,
-                          nc):
-        r = count_window_tokens(
-            packed, out_lens, jnp.zeros(halo, jnp.uint8), lengths, nc,
-            jnp.int32(0), ns[0], at_eofs[0], los[0], owns[0],
-            window=window, halo=halo, reads_to_check=reads_to_check,
-            flags_impl=flags_impl, pallas_interpret=pallas_interpret,
-            funnel=funnel,
-        )
-        with jax.named_scope("reduce"):
-            totals = jax.lax.psum(
-                jnp.stack([r["count"], r["esc_count"]]), axis
-            )  # ← ICI
-        return totals, r["rounds"][None]
-
-    return jax.jit(
-        jax.shard_map(
-            count_tokens_step,
-            mesh=mesh,
-            in_specs=(P(axis),) * 6 + (P(), P()),
-            out_specs=(P(), P(axis)),
             check_vma=False,
         )
     )

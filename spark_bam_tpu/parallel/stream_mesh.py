@@ -32,13 +32,8 @@ inflates a step's rows side by side on a pool (the native inflater, the
 producer the one-chip stream has), puts each row's bytes on the chip that
 owns the row, and every chip runs the one-chip stream's check-and-count
 program on its rows (``mesh.make_shard_map_count_step``). The next step is
-inflated and put while the chips run the current one. An explicit
-``Config.device_inflate=True`` reaches the FUSED step instead (the host
-tokenizes, every chip resolves and assembles its own row:
-``mesh.make_shard_map_count_tokens_step``), a hundred times slower over the
-copy than the host is (``tpu/inflate.py``); it ships until the
-``simplicity`` PR that deletes it. The other workloads need the inflated
-bytes on the host anyway (truth masks, site lists).
+inflated and put while the chips run the current one. The other workloads
+need the inflated bytes on the host anyway (truth masks, site lists).
 
 Workloads (SURVEY.md §2.8 maps file/block data-parallelism onto per-core
 batch pipelines; §2.9 replaces Spark accumulators with ``psum``):
@@ -69,17 +64,9 @@ from spark_bam_tpu.bgzf.block import MAX_BLOCK_SIZE
 from spark_bam_tpu.bgzf.flat import inflate_blocks
 from spark_bam_tpu.core.channel import open_channel
 from spark_bam_tpu.core.config import Config
-from spark_bam_tpu.core.guard import INPUT_ERRORS
 from spark_bam_tpu.parallel.mesh import make_mesh, mesh_steps
 from spark_bam_tpu.tpu.checker import PAD
-from spark_bam_tpu.tpu.inflate import (
-    STRIDE,
-    DeviceObserver,
-    inflate_group_device,
-    resolve_device_inflate,
-    tokenize_group,
-    window_plan,
-)
+from spark_bam_tpu.tpu.inflate import DeviceObserver, window_plan
 from spark_bam_tpu.tpu.stream_check import (
     StreamChecker,
     _next_pow2,
@@ -159,7 +146,6 @@ class _ShardedStream:
         num_processes: int = 1,
         process_id: int = 0,
         chunk_bytes: int = 192 << 20,
-        fused: bool = False,
     ):
         from spark_bam_tpu.bgzf.index_blocks import blocks_metadata
 
@@ -192,7 +178,6 @@ class _ShardedStream:
         self.kernel_window = _next_pow2(
             min(row_bound, max(self.total, 1 << 16))
         )
-        self.device_inflate = resolve_device_inflate(config)
 
         n_local = self.n_local = self.n_global // num_processes
         # THIS process's devices: another process's reports no memory to
@@ -206,36 +191,6 @@ class _ShardedStream:
             max(1, chunk_bytes // ((kw + PAD) * max(n_local, 1))),
             _rows_fitting_device(self.local_devices[0], kw),
         )
-        # The fused step (count only, and only where ``device_inflate`` is
-        # asked for): the host's entropy phase feeds the token window
-        # program on every chip. Multi-host keeps the host-inflated rows:
-        # a tokenizer that rejects one process's row must not leave the
-        # others inside a collective.
-        self.fused = (
-            fused and self.device_inflate and num_processes == 1
-            and config.inflate_config.resolve_tokenize() == "host"
-        )
-        if self.fused:
-            # ONE row a device a step: the device's block of the sharded
-            # operands is its row, and the step's body is the window
-            # program itself. That program reserves 6.0 GiB of a v5e's
-            # 15.75 for a 32 MiB row (190 bytes a window byte, against the
-            # 128 of the check alone); a second row would buy nothing, the
-            # chip is busy with one.
-            self.step_rows_local = n_local
-            self.row_blocks = [
-                _halo_block_range(
-                    self.metas, self.groups, self.first_block, g, g + 1,
-                    self.halo,
-                )
-                for g in range(len(self.groups))
-            ]
-            # Token rows of the step's ONE compiled shape: every row's
-            # members (own group + halo) padded to the widest row's pow2.
-            self.token_rows = _next_pow2(
-                max((b1 - b0 for b0, b1 in self.row_blocks), default=1)
-            )
-            self._zero_tokens: dict = {}
         if self.per_proc:
             self.step_rows_local = min(self.step_rows_local, self.per_proc)
         self._zero_rows: dict = {}
@@ -252,17 +207,7 @@ class _ShardedStream:
         b0, b1 = _halo_block_range(
             self.metas, self.groups, self.first_block, g, g + 1, self.halo
         )
-        run = self.metas[b0:b1]
-        view = None
-        if self.device_inflate:
-            try:
-                view = inflate_group_device(ch, run)
-            except INPUT_ERRORS:
-                view = None  # host zlib answers input the tokenizer rejects
-            if view is None:
-                obs.count("inflate.host_demotions")
-        if view is None:
-            view = inflate_blocks(ch, run, threads=8)
+        view = inflate_blocks(ch, self.metas[b0:b1], threads=8)
         return view.data, view.size, b1 == len(self.metas)
 
     def _assemble(self, ch, c0: int, fill_row):
@@ -410,97 +355,6 @@ class _ShardedStream:
         with ThreadPoolExecutor(min(self.step_rows_local, 8)) as rows_pool:
             yield from self._steps(
                 lambda ch, c0: self._assemble_rows(ch, c0, rows_pool)
-            )
-
-    # ------------------------------------------------------- fused assembly
-    def _tokenize_row(self, ch, g: int):
-        """Global row ``g``'s members (own group + halo) through the host
-        entropy phase, packed at the step's token rows: ``(packed
-        (3·B·STRIDE,) u8, out_lens (B,) i32, n, at_eof)``, or None without
-        the native tokenizer. Raises ``INPUT_ERRORS`` on a stream the
-        tokenizer rejects."""
-        b0, b1 = self.row_blocks[g]
-        tp = tokenize_group(ch, self.metas[b0:b1])
-        if tp is None:
-            return None
-        packed, out_lens, b = tp
-        rows, plane = self.token_rows, self.token_rows * STRIDE
-        had = packed.shape[0] // 3
-        if had != plane:
-            # A narrower row (the file's last, as a rule) re-laid at the
-            # step's width: lit plane, then the dist plane's bytes.
-            wide = np.zeros(3 * plane, dtype=np.uint8)
-            wide[:had] = packed[:had]
-            wide[plane: plane + 2 * had] = packed[had:]
-            packed = wide
-        lens = np.zeros(rows, dtype=np.int32)
-        lens[:b] = out_lens
-        return packed, lens, int(out_lens.sum()), b1 == len(self.metas)
-
-    def _assemble_tokens(self, ch, c0: int, rows_pool):
-        """One step's operands for the fused step, ON the devices: each
-        row's packed tokens go straight to the chip that owns the row.
-        Returns None when the entropy phase cannot serve a row (no native
-        tokenizer, or input it rejects): the caller demotes the count."""
-        k = self.step_rows_local
-        g0 = self.process_id * self.per_proc + c0
-        live = [  # (row of the step, global row); the rest are padding
-            (j, g0 + j) for j in range(k)
-            if c0 + j < self.per_proc and g0 + j < len(self.groups)
-        ]
-        with obs.span("mesh.assemble", c0=c0, rows=len(live)):
-            try:
-                toks = list(rows_pool.map(
-                    lambda jg: self._tokenize_row(ch, jg[1]), live
-                ))
-            except INPUT_ERRORS:
-                return None
-        if any(t is None for t in toks):
-            return None
-        lens = np.zeros((k, self.token_rows), dtype=np.int32)
-        ns = np.zeros(k, dtype=np.int32)
-        eofs = np.zeros(k, dtype=bool)
-        los = np.zeros(k, dtype=np.int32)
-        owns = np.zeros(k, dtype=np.int32)
-        width = 3 * self.token_rows * STRIDE
-        devices = list(self.mesh.devices.flat)
-        shards = [None] * k
-        sent = 0
-        with obs.span("mesh.h2d", c0=c0, rows=len(live)):
-            for (j, g), (packed, row_lens, n, at_eof) in zip(live, toks):
-                shards[j] = jax.device_put(packed, devices[j])
-                sent += packed.nbytes
-                lens[j], ns[j], eofs[j] = row_lens, n, at_eof
-                owns[j], los[j] = self._row_span(g, n, at_eof, True)
-            for j in range(k):
-                if shards[j] is None:  # a padding row: resident zeros
-                    if j not in self._zero_tokens:
-                        self._zero_tokens[j] = jax.device_put(
-                            np.zeros(width, dtype=np.uint8), devices[j]
-                        )
-                    shards[j] = self._zero_tokens[j]
-            # Flat operands: a device's block of each is its row's, in the
-            # one-chip program's own shapes (mesh.count_tokens_step).
-            tokens = jax.make_array_from_single_device_arrays(
-                (k * width,), self.row_sharding, shards
-            )
-            args = [tokens] + [
-                jax.make_array_from_process_local_data(self.row_sharding, a)
-                for a in (lens.reshape(-1), ns, eofs, los, owns)
-            ]
-            # Waited for HERE, registry or none: the span is the transfer,
-            # and the feeding thread is handed operands that have arrived.
-            jax.block_until_ready(args)
-        obs.count("mesh.rows", len(live))
-        obs.count("mesh.h2d_bytes", sent)
-        return args + [self.lengths_d, self.nc]
-
-    def token_batches(self):
-        """The fused steps: ``(operands on the devices | None, done, c0)``;
-        None is a step the entropy phase could not serve."""
-        with ThreadPoolExecutor(self.step_rows_local) as rows_pool:
-            yield from self._steps(
-                lambda ch, c0: self._assemble_tokens(ch, c0, rows_pool)
             )
 
     def _sharded_args(self, arrays):
@@ -678,20 +532,16 @@ class _StepObserver(DeviceObserver):
     """The count steps' device times, taken OFF the thread that feeds the
     chips (as ``DeviceObserver`` does for the one-chip stream, and only
     under a live registry), under the mesh's names: ``mesh.step_device_ms =
-    t_ready(k) − max(t_dispatch(k), t_ready(k−1))`` and, from the fused
-    step alone, ``mesh.rounds``, the most LZ77 rounds any chip's row took."""
+    t_ready(k) − max(t_dispatch(k), t_ready(k−1))``."""
 
     @staticmethod
-    def _observe(device_ms: float, rounds: int | None) -> None:
+    def _observe(device_ms: float) -> None:
         obs.observe("mesh.step_device_ms", device_ms, unit="ms")
-        if rounds is not None:
-            obs.observe("mesh.rounds", rounds, unit="rounds")
 
 
 def _count_steps(st: "_ShardedStream", config: Config, progress):
     """The count pass over ``st``'s steps: ``(count, escapes, steps, dirty,
-    whole_file)``, or None when a fused step could not be served (the
-    caller demotes to the host-inflated rows).
+    whole_file)``.
 
     Step k+1's operands are put and its program dispatched BEFORE step k's
     totals are read, so the devices never wait between steps for a
@@ -699,17 +549,11 @@ def _count_steps(st: "_ShardedStream", config: Config, progress):
     one step late."""
     # Cached per (mesh, params): repeat invocations — and the serve/
     # daemon's ticks — reuse one traced executable instead of re-jitting.
-    steps_of = mesh_steps(st.mesh, st.axis)
-    params = dict(
+    step = mesh_steps(st.mesh, st.axis).count_step(
         reads_to_check=config.reads_to_check, flags_impl=config.flags_impl,
         funnel=config.funnel_enabled(),
     )
-    if st.fused:
-        step = steps_of.count_tokens_step(st.kernel_window, st.halo, **params)
-        batches = st.token_batches()
-    else:
-        step = steps_of.count_step(**params)
-        batches = st.row_batches()
+    batches = st.row_batches()
     observer = _StepObserver.maybe()
     count = escapes = steps = 0
     dirty: list[int] = []  # local row offsets (c0) of escaped steps
@@ -741,7 +585,6 @@ def _count_steps(st: "_ShardedStream", config: Config, progress):
         # the whole-file exact path.
         return _mostly_dirty(dirty, steps)
 
-    served = True      # False: a fused step the entropy phase refused
     whole_file = False  # True: the guard stopped the pass
     unread = None       # the dispatched step whose totals are not read yet
     # Closing the batch generator on early exit (escape break, error)
@@ -749,33 +592,26 @@ def _count_steps(st: "_ShardedStream", config: Config, progress):
     # reopens the file.
     try:
         for args, done, c0 in batches:
-            if args is None:
-                served = False
-                break
             with obs.span("mesh.step", workload="count", c0=c0):
                 t_dispatch = time.perf_counter()
-                # ``out``: the step's totals; the fused step also hands
-                # back each chip's LZ77 round count.
-                out, rounds = step(*args) if st.fused else (step(*args), None)
+                out = step(*args)  # the step's totals
                 if observer is not None:
-                    observer.window(None, 0.0, out, t_dispatch, rounds=rounds)
+                    observer.window(None, 0.0, out, t_dispatch)
                 whole_file = unread is not None and settle(*unread)
             unread = (out, done, c0)
             if whole_file:
                 break
-        if served and not whole_file and unread is not None:
+        if not whole_file and unread is not None:
             with obs.span("mesh.step", workload="count", c0=unread[2]):
                 whole_file = settle(*unread)
     finally:
         batches.close()
         if observer is not None:
             observer.close()
-    if (not served or whole_file) and unread is not None:
-        # The step in flight is dropped, and waited for: what takes over
-        # (the host-inflated rows, the whole-file path) finds an idle mesh.
+    if whole_file and unread is not None:
+        # The step in flight is dropped, and waited for: the whole-file
+        # path that takes over finds an idle mesh.
         jax.block_until_ready(unread[0])
-    if not served:
-        return None
     return count, escapes, steps, dirty, whole_file
 
 
@@ -797,36 +633,20 @@ def count_reads_sharded(
     globally reduced count on every process). ``progress(steps_done,
     positions_done, total_positions)`` fires as each sharded step's totals
     are read. Every row is inflated on the host and checked on the chip
-    that owns it (``jit_count_step``); only ``Config.device_inflate=True``
-    reaches the fused token step, and a count that leaves THAT step for the
-    host-inflated rows starts over at the first row, and ``progress`` with
-    it. ``stats_out``, when given, receives ``{"steps", "escapes",
-    "fallback", "patched_steps", "rows", "fused"}`` — escaped steps are
-    normally re-derived exactly on host (``patched_steps`` counts them, and
-    ``check.count_escape_retries``; the other steps' device totals stand);
-    ``fallback`` is True only when the whole-file exact path ran instead
-    (no native library, adversarial lookahead growth, or an
-    escape-everywhere input; ``check.fused_demotions``). ``fused`` says the
-    devices resolved their own rows' tokens (the fused step: asked for by
-    ``device_inflate=True``, never selected)."""
-    kw = dict(
+    that owns it (``jit_count_step``). ``stats_out``, when given, receives
+    ``{"steps", "escapes", "fallback", "patched_steps", "rows"}`` — escaped
+    steps are normally re-derived exactly on host (``patched_steps`` counts
+    them, and ``check.count_escape_retries``; the other steps' device totals
+    stand); ``fallback`` is True only when the whole-file exact path ran
+    instead (no native library, adversarial lookahead growth, or an
+    escape-everywhere input; ``check.fused_demotions``)."""
+    st = _ShardedStream(
+        path, config, mesh, window_uncompressed, halo, metas,
         num_processes=num_processes, process_id=process_id,
         chunk_bytes=chunk_bytes,
     )
-    st = _ShardedStream(
-        path, config, mesh, window_uncompressed, halo, metas, fused=True, **kw
-    )
-    result = _count_steps(st, config, progress)
-    if result is None:
-        # The entropy phase could not serve a row (no native tokenizer, or
-        # input it rejects): the count leaves the fused step for the
-        # host-inflated rows, where host zlib answers such input.
-        obs.count("check.fused_demotions")
-        st = _ShardedStream(
-            path, config, st.mesh, window_uncompressed, halo, st.metas, **kw
-        )
-        result = _count_steps(st, config, progress)
-    count, escapes, steps, dirty, whole_file = result
+    count, escapes, steps, dirty, whole_file = _count_steps(
+        st, config, progress)
 
     patched = None
     if dirty and not whole_file:
@@ -847,7 +667,7 @@ def count_reads_sharded(
             steps=steps, escapes=escapes,
             fallback=bool(escapes) and patched is None,
             patched_steps=0 if patched is None else len(dirty),
-            rows=len(st.groups), fused=st.fused,
+            rows=len(st.groups),
         )
     if escapes and patched is None:
         # Whole-file exact fallback (no native library, adversarial

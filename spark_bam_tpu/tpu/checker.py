@@ -895,233 +895,6 @@ def make_count_scan(
     return run
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=(
-        "window", "halo", "reads_to_check", "flags_impl", "pallas_interpret",
-        "funnel",
-    ),
-)
-def count_window_tokens(
-    packed,       # (3*B*STRIDE,) uint8 packed lit/dist token planes
-    out_lens,     # (B,) int32 inflated size per block row (0 ⇒ pad row)
-    carry,        # (halo,) uint8 previous window's tail (valid ≤ carry_len)
-    lengths,      # (Cmax,) int32
-    num_contigs,  # () int32
-    carry_len,    # () int32 valid carry bytes (≤ halo)
-    n,            # () int32 = carry_len + Σ out_lens (total window bytes)
-    at_eof,       # () bool
-    lo,           # () int32 owned-span start
-    own,          # () int32 owned-span end
-    *,
-    window: int,
-    halo: int,
-    reads_to_check: int = 10,
-    flags_impl: str = "xla",
-    pallas_interpret: bool = False,
-    funnel: bool = False,
-):
-    """The fully device-resident hot path: LZ77 resolve + window assembly
-    + funnel/deep check + chain walk in ONE XLA program.
-
-    The only H2D operands are the packed token planes from the host
-    entropy phase plus a handful of scalars; the only D2H results are the
-    two count scalars (+ survivors/rounds) and the (halo,) carry — which
-    itself stays on device between windows, so in steady state nothing but
-    scalars crosses the host/device boundary. Compare
-    ``inflate_blocks_device`` → host concatenate → ``count_window``, which
-    bounces every inflated byte through host twice.
-
-    Window assembly is a concatenation done by placing rows
-    (``_assemble_window``): the carry goes to the front of a buffer and
-    each resolved block row is copied whole to ``carry_len + Σ`` of the
-    ``out_lens`` before it, in ascending order, so the bytes a row holds
-    past its length are overwritten by the row that follows — zero-length
-    rows (batch padding, empty final BGZF blocks) leave nothing behind.
-    The new carry is the owned-end tail ``val[own : own+halo]`` (zeros
-    beyond ``n``), exactly the ``halo_windows`` carry discipline.
-    """
-    from spark_bam_tpu.tpu.inflate import _resolve_body, _unpack_tokens
-
-    lit, dist = _unpack_tokens(packed)
-    resolved, rounds = _resolve_body(lit, dist)
-    return _count_from_planes(
-        resolved, rounds, out_lens, carry, lengths, num_contigs, carry_len,
-        n, at_eof, lo, own, window=window, halo=halo,
-        reads_to_check=reads_to_check, flags_impl=flags_impl,
-        pallas_interpret=pallas_interpret, funnel=funnel,
-    )
-
-
-def _assemble_window(resolved, out_lens, carry, carry_len, n, *, window, halo):
-    """The logical window ``carry[:carry_len] ‖ row_0[:len_0] ‖ row_1[:len_1]
-    ‖ … ‖ zeros`` as (window,) u8, by placing rows: work proportional to the
-    bytes moved, no per-byte lookup.
-
-    Every row of ``out_lens`` is copied WHOLE (all ``stride`` bytes) to its
-    start ``carry_len + Σ len_<b``, in ascending order. The order is what
-    makes the overlap exact: row b's bytes past ``len_b`` land where row
-    b+1 starts, and row b+1 is written after it; a zero-length row (empty
-    BGZF member, batch padding) writes only bytes that the next row or the
-    ``i < n`` mask replaces, and so does the carry's tail past
-    ``carry_len``. The buffer has one row of slack past ``window``: every
-    start is ≤ n ≤ window (the callers' geometry check), so no update is
-    ever clamped — a clamped ``dynamic_update_slice`` would shift the row.
-    """
-    stride = resolved.shape[1]
-    lens = out_lens.astype(_I32)
-    starts = carry_len + jnp.cumsum(lens) - lens
-    buf = jnp.concatenate(
-        [carry, jnp.zeros(window + stride - halo, jnp.uint8)]
-    )
-
-    def place(b, buf):
-        row = lax.dynamic_index_in_dim(resolved, b, keepdims=False)
-        return lax.dynamic_update_slice(buf, row, (starts[b],))
-
-    buf = lax.fori_loop(0, lens.shape[0], place, buf)
-    i = jnp.arange(window, dtype=_I32)
-    return jnp.where(i < n, buf[:window], jnp.uint8(0))
-
-
-def _count_from_planes(
-    resolved, rounds, out_lens, carry, lengths, num_contigs, carry_len, n,
-    at_eof, lo, own, *, window, halo, reads_to_check, flags_impl,
-    pallas_interpret, funnel,
-):
-    """Shared back half of the fused count kernels: assemble the logical
-    window from resolved block rows + the halo carry, run the count, slice
-    the next carry. Traced inside both the packed-token and raw-payload
-    entry points."""
-    with jax.named_scope("assemble"):
-        val = _assemble_window(
-            resolved, out_lens, carry, carry_len, n, window=window, halo=halo
-        )
-        padded = jnp.concatenate([val, jnp.zeros(PAD, jnp.uint8)])
-    r = count_window(
-        padded, lengths, num_contigs, n, at_eof, lo, own,
-        reads_to_check=reads_to_check, window=window,
-        flags_impl=flags_impl, pallas_interpret=pallas_interpret,
-        funnel=funnel,
-    )
-    with jax.named_scope("carry"):
-        ext = jnp.concatenate([val, jnp.zeros(halo, jnp.uint8)])
-        new_carry = lax.dynamic_slice(ext, (own,), (halo,))
-    return {**r, "carry": new_carry, "rounds": rounds}
-
-
-def count_window_raw(
-    staged,       # (B_pad, C_pad) uint8 staged raw-DEFLATE payload rows
-    clens,        # (B_pad,) int32 compressed length per row (0 ⇒ pad row)
-    exp_lens,     # (B_pad,) int32 footer ISIZE per row (0 ⇒ pad row)
-    carry,        # (halo,) uint8 previous window's tail (valid ≤ carry_len)
-    lengths,      # (Cmax,) int32
-    num_contigs,  # () int32
-    carry_len,    # () int32
-    n,            # () int32 = carry_len + Σ exp_lens
-    at_eof,       # () bool
-    lo,           # () int32 owned-span start
-    own,          # () int32 owned-span end
-    *,
-    window: int,
-    halo: int,
-    reads_to_check: int = 10,
-    flags_impl: str = "xla",
-    pallas_interpret: bool = False,
-    funnel: bool = False,
-    tok_impl: str = "xla",
-    tok_interpret: bool = False,
-):
-    """``count_window_tokens`` one step deeper: the H2D operand is the RAW
-    compressed payload matrix — the device bit-reader runs the entropy
-    phase in the same program as resolve + assemble + count, so the host
-    never touches DEFLATE bits at all and the wire carries compressed
-    bytes (≈3× less than packed token planes, ≈window-size less than
-    inflated bytes).
-
-    Returns the ``count_window_tokens`` dict plus ``tok_ok``: a scalar
-    bool, True iff every real row decoded cleanly AND produced exactly its
-    footer's ISIZE. The stream driver checks it at each sync and demotes
-    the whole count run to the host-tokenize path on the first False —
-    window counts from a failed decode are never trusted (the assembly
-    below uses the footer lengths, so a lying row cannot shift its
-    neighbors' bytes even transiently).
-    """
-    if tok_impl == "pallas":
-        from spark_bam_tpu.tpu.pallas_kernels import tokenize_pallas
-
-        lit, dist, olens, ok = tokenize_pallas(
-            staged, clens, interpret=tok_interpret
-        )
-    else:
-        from spark_bam_tpu.tpu.tokenize_device import tokenize_planes
-
-        lit, dist, olens, ok = tokenize_planes(staged, clens)
-    from spark_bam_tpu.tpu.inflate import _resolve_body
-
-    pad = clens == 0
-    tok_ok = jnp.all((ok | pad) & ((olens == exp_lens) | pad))
-    resolved, rounds = _resolve_body(lit, dist)
-    out = _count_from_planes(
-        resolved, rounds, exp_lens, carry, lengths, num_contigs, carry_len,
-        n, at_eof, lo, own, window=window, halo=halo,
-        reads_to_check=reads_to_check, flags_impl=flags_impl,
-        pallas_interpret=pallas_interpret, funnel=funnel,
-    )
-    return {**out, "tok_ok": tok_ok}
-
-
-@functools.lru_cache(maxsize=None)
-def make_count_window_raw(
-    window: int, halo: int, reads_to_check: int = 10,
-    flags_impl: str = "xla", funnel: bool = False, tok_impl: str = "xla",
-    donate: bool = True,
-):
-    """A jit-compiled fused tokenize→resolve→assemble→count kernel for
-    fixed window/halo geometry (the ``tokenize=device`` count path of
-    stream_check.StreamChecker.count_reads). With ``donate`` the (halo,)
-    carry operand aliases the returned carry — the inter-window state ring
-    reuses its HBM instead of allocating per window. Memoised: a second
-    ``StreamChecker`` of the same geometry reuses the traced executable."""
-    pallas_interpret = _pallas_interpret_for(flags_impl)
-    tok_interpret = _pallas_interpret_for(tok_impl)
-
-    def count_window_raw_program(staged, clens, exp_lens, carry, lengths,
-                                 num_contigs, carry_len, n, at_eof, lo, own):
-        return count_window_raw(
-            staged, clens, exp_lens, carry, lengths, num_contigs,
-            carry_len, n, at_eof, lo, own,
-            window=window, halo=halo, reads_to_check=reads_to_check,
-            flags_impl=flags_impl, pallas_interpret=pallas_interpret,
-            funnel=funnel, tok_impl=tok_impl, tok_interpret=tok_interpret,
-        )
-
-    return jax.jit(count_window_raw_program,
-                   donate_argnums=(3,) if donate else ())
-
-
-def make_count_window_tokens(
-    window: int, halo: int, reads_to_check: int = 10,
-    flags_impl: str = "xla", funnel: bool = False,
-):
-    """A jit-compiled fused inflate→assemble→count kernel for fixed
-    window/halo geometry (the device-resident count path of
-    stream_check.StreamChecker.count_reads)."""
-    pallas_interpret = _pallas_interpret_for(flags_impl)
-
-    def run(packed, out_lens, carry, lengths, num_contigs, carry_len, n,
-            at_eof, lo, own):
-        return count_window_tokens(
-            packed, out_lens, carry, lengths, num_contigs, carry_len, n,
-            at_eof, lo, own,
-            window=window, halo=halo, reads_to_check=reads_to_check,
-            flags_impl=flags_impl, pallas_interpret=pallas_interpret,
-            funnel=funnel,
-        )
-
-    return run
-
-
 def make_check_window(
     window: int, reads_to_check: int = 10, flags_impl: str = "xla",
     funnel: bool = False,

@@ -33,18 +33,6 @@ a worst-case cigar array resolves in-slab. Wired into the product behind
 kernel; the chain walk is unchanged). On non-TPU backends it runs in
 interpret mode — the parity artifact (tests/test_pallas.py) pins it
 against both the XLA flag pass and the NumPy engine.
-
-``lz77_resolve_pallas`` — the fused device half of the two-phase inflate
-(tpu/inflate.py): one grid row per BGZF block, token rows in VMEM,
-pointer-doubling with an **in-kernel early exit** the moment every chain
-has reached its root literal (``lax.while_loop``; worst case
-log2(64 Ki) = 16 rounds, typical BAM blocks converge in a handful).
-Unlike the flag kernels this one keeps the per-row ``take_along_axis`` —
-the indices stay inside the 64 Ki block row. Mosaic refuses it for the
-v5e (tests/test_chip_compile.py records the refusal), so ``auto`` never
-selects it: it runs only under SPARK_BAM_LZ77=pallas, where a lowering
-failure raises. Parity with the (identical-math, also early-exit) XLA
-resolve is pinned in interpret mode.
 """
 
 from __future__ import annotations
@@ -230,137 +218,6 @@ def _full_flags_kernel(p_hbm, lengths_ref, nc_ref, n_ref, out_ref, slab, sem):
     F = jnp.where(few_fixed, _I32(BIT["tooFewFixedBlockBytes"]), F)
 
     out_ref[...] = F
-
-
-# ----------------------------------------------------- fused LZ77 kernel
-
-# Token-row width: one BGZF block inflates to ≤ 64 KiB (bgzf/block.py
-# MAX_BLOCK_SIZE); keep the constant local to avoid a tpu/inflate.py cycle.
-from spark_bam_tpu.bgzf.block import MAX_BLOCK_SIZE as _LZ_STRIDE  # noqa: E402
-
-_LZ_ROUNDS = (_LZ_STRIDE - 1).bit_length()
-
-
-def _lz77_kernel(lit_ref, dist_ref, out_ref, rounds_ref):
-    dist = dist_ref[...].astype(_I32)                       # (1, S)
-    iota = lax.broadcasted_iota(_I32, dist.shape, 1)
-    parent = iota - dist                                    # dist=0 ⇒ self
-
-    def cond(state):
-        _, r, done = state
-        return jnp.logical_and(~done, r < _LZ_ROUNDS)
-
-    def body(state):
-        p, r, _ = state
-        nxt = jnp.take_along_axis(p, p, axis=1)
-        # Fixed point ⇔ every pointer already names a root (the only
-        # self-parents); one extra gather is the convergence test itself.
-        return nxt, r + _I32(1), jnp.all(nxt == p)
-
-    roots, r, _ = lax.while_loop(
-        cond, body, (parent, _I32(0), jnp.bool_(False))
-    )
-    out_ref[...] = jnp.take_along_axis(lit_ref[...], roots, axis=1)
-    rounds_ref[0, 0] = r
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def lz77_resolve_pallas(
-    lit: jnp.ndarray,   # (B, 64 Ki) uint8 literal plane
-    dist: jnp.ndarray,  # (B, 64 Ki) uint16 back-reference distances (0 = literal)
-    interpret: bool = False,
-):
-    """Resolve LZ77 chains for a batch of tokenized BGZF blocks in one
-    launch, early-exiting per block row. Returns ``(resolved (B, S) u8,
-    rounds () i32)`` — rounds is the batch max, comparable to the XLA
-    resolve's global round count."""
-    b, s = lit.shape
-    out, rounds = pl.pallas_call(
-        _lz77_kernel,
-        grid=(b,),
-        in_specs=[
-            pl.BlockSpec((1, s), lambda i: (i, 0)),
-            pl.BlockSpec((1, s), lambda i: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, s), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, s), jnp.uint8),
-            jax.ShapeDtypeStruct((b, 1), jnp.int32),
-        ],
-        interpret=interpret,
-    )(lit, dist)
-    return out, jnp.max(rounds)
-
-
-# --------------------------------------------------- tokenize bit-reader
-
-
-def _tokenize_kernel(comp_ref, clen_ref, *refs):
-    # refs = 9 table refs (tokenize_device.TABLES order) + 4 output refs.
-    # pallas_call refuses captured array constants, so the RFC tables
-    # arrive as operands and thread back in through ``tabs``.
-    from spark_bam_tpu.tpu.tokenize_device import _tokenize_row
-
-    tabs = tuple(r[...] for r in refs[:9])
-    lit_ref, dist_ref, olen_ref, ok_ref = refs[9:]
-    lit, dist, o, ok = _tokenize_row(comp_ref[0, :], clen_ref[0, 0], tabs)
-    lit_ref[0, :] = lit
-    dist_ref[0, :] = dist
-    olen_ref[0, 0] = o
-    ok_ref[0, 0] = ok.astype(_I32)
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def tokenize_pallas(
-    staged: jnp.ndarray,  # (B, C_pad) uint8 zero-padded raw-DEFLATE payloads
-    clens: jnp.ndarray,   # (B,) int32 real payload byte lengths
-    interpret: bool = False,
-):
-    """The device entropy phase as a Pallas grid: one lane per BGZF
-    block walking its raw-DEFLATE bitstream in VMEM — Huffman table
-    decode, run expansion, symbol emission — producing the same packed
-    lit/dist token planes the host tokenizer does (see
-    tpu/tokenize_device.py for the row math and its error model).
-
-    Returns ``(lit (B, S) u8, dist (B, S) u16, out_lens (B,) i32,
-    ok (B,) bool)``. Bit-serial control flow leans hard on Mosaic
-    (nested ``while_loop``, dynamic 1-D slices) and Mosaic refuses the
-    kernel for the v5e, so ``auto`` never selects it: it runs only under
-    ``inflate kernel=pallas``, where the refusal raises. The
-    identical-math XLA vmap is ``tokenize_device.tokenize_planes``; parity
-    is pinned in interpret mode by tests/test_tokenize_device.py."""
-    from spark_bam_tpu.tpu.tokenize_device import STRIDE as _TOK_S
-    from spark_bam_tpu.tpu.tokenize_device import TABLES
-
-    b, c_pad = staged.shape
-    lit, dist, olens, ok = pl.pallas_call(
-        _tokenize_kernel,
-        grid=(b,),
-        in_specs=[
-            pl.BlockSpec((1, c_pad), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
-        ] + [
-            # Broadcast tables: every grid lane reads block 0 whole.
-            pl.BlockSpec(t.shape, lambda i: (0,)) for t in TABLES
-        ],
-        out_specs=[
-            pl.BlockSpec((1, _TOK_S), lambda i: (i, 0)),
-            pl.BlockSpec((1, _TOK_S), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, _TOK_S), jnp.uint8),
-            jax.ShapeDtypeStruct((b, _TOK_S), jnp.uint16),
-            jax.ShapeDtypeStruct((b, 1), jnp.int32),
-            jax.ShapeDtypeStruct((b, 1), jnp.int32),
-        ],
-        interpret=interpret,
-    )(staged, clens.reshape(b, 1), *TABLES)
-    return lit, dist, olens[:, 0], ok[:, 0] != 0
 
 
 # --------------------------------------------------- funnel stage-0 kernel

@@ -44,14 +44,6 @@ docs/benchmarks.md:53-59). On every backend its windows reach the device
 as inflated bytes: the pipeline's workers run the native inflate ahead of
 the feeding thread, one H2D a window carries the window, and the device
 program is the check and its count reduction (``checker.count_window``).
-
-Only an explicit ``Config.device_inflate=True`` (or ``fused_count``)
-reaches the **fully device-resident** loop: the host ships the packed LZ77
-token planes per window, and ``checker.count_window_tokens`` resolves +
-assembles + funnels + walks inside one XLA program — the halo carry stays
-in HBM between windows, and only the count scalars cross back. The device
-takes a hundred times longer over that copy than the host does
-(``tpu/inflate.py``), so nothing selects it by itself.
 """
 
 from __future__ import annotations
@@ -68,7 +60,7 @@ from spark_bam_tpu import obs
 from spark_bam_tpu.bam.header import read_header
 from spark_bam_tpu.check.vectorized import check_flat
 from spark_bam_tpu.core.config import Config
-from spark_bam_tpu.tpu.inflate import InflatePipeline, resolve_device_inflate
+from spark_bam_tpu.tpu.inflate import InflatePipeline
 
 
 def _next_pow2(n: int) -> int:
@@ -162,9 +154,7 @@ class StreamChecker:
         # ``metas``: reuse a caller's whole-file block-metadata scan (a
         # header walk over every BGZF block — seconds on multi-GB files).
         self.pipeline = InflatePipeline(
-            path, window_uncompressed=fresh,
-            device_copy=resolve_device_inflate(config),
-            metas=metas, inflate_spec=config.inflate, **pipe_kw,
+            path, window_uncompressed=fresh, metas=metas, **pipe_kw,
         )
         self.total = self.pipeline.total
         # Kernel shape: one power of two covering carry + window, clamped to
@@ -180,7 +170,7 @@ class StreamChecker:
         # that the int32 sums cannot overflow: ≤ 2^30 positions per chunk
         # (Config.flush_every overrides within that cap).
         self.flush_every = config.flush_every_for(self.kernel_window)
-        # Pacing depth of the fused count ring (Config.ring_depth).
+        # Pacing depth of the count's window ring (Config.ring_depth).
         self.ring_depth = max(1, config.ring_depth)
         # Funnel totals across the consuming projection (positions
         # screened by stage 0 / stage-0 survivors); None until a funnelled
@@ -467,13 +457,6 @@ class StreamChecker:
         """
         if not self.use_device:
             return self._count_via_spans()
-        fused = self.config.fused_count
-        if fused is None:
-            fused = self.pipeline.device_copy
-        if fused:
-            res = self._count_reads_fused()
-            if res is not None:
-                return res
         from spark_bam_tpu.tpu.checker import PAD, make_count_window
         from spark_bam_tpu.tpu.inflate import DeviceObserver
 
@@ -584,253 +567,6 @@ class StreamChecker:
             # Rare exact path (chains outran the halo — ultra-long reads):
             # the spans path resolves every deferral bit-exactly. Suppress
             # progress so consumers don't see the counters restart.
-            obs.count("check.count_escape_retries")
-            saved, self.progress = self.progress, None
-            try:
-                return self._count_via_spans()
-            finally:
-                self.progress = saved
-        return total
-
-    def _count_reads_fused(self) -> int | None:
-        """The fully device-resident count loop: packed tokens in, scalars
-        out, carry chained in HBM.
-
-        Per window group, the host runs only the entropy phase
-        (read + tokenize + pack, prefetched ``pipeline.depth`` groups
-        ahead on worker threads) and ships ONE packed u8 buffer;
-        ``checker.count_window_tokens`` does LZ77 resolve → window
-        assembly → funnel/deep check → chain walk in one XLA program, with
-        the (halo,) carry fed device-to-device between windows — the
-        serial carry dependency chains the kernels in the device stream
-        while the host tokenizes ahead, so neither side idles. Pacing,
-        flush, and escape checkpoints mirror ``count_reads``.
-
-        Returns None to demote to the classic (host-inflate) streaming
-        loop: tokenizer unavailable (off the TPU), a stream it rejects, or
-        a window group that cannot fit the kernel geometry — each counted
-        under ``check.fused_demotions``. Compiler and device errors are
-        not demotions and propagate. Nothing is consumed from
-        ``self.pipeline`` before demotion — the classic path restarts
-        cleanly. Escapes (chains beyond the halo) go to the exact spans
-        path, as everywhere.
-        """
-        from concurrent.futures import ThreadPoolExecutor
-
-        from spark_bam_tpu.core.channel import open_channel
-        from spark_bam_tpu.core.guard import INPUT_ERRORS
-        from spark_bam_tpu.native.build import load_native, require_native
-        from spark_bam_tpu.tpu.checker import (
-            make_count_window_raw, make_count_window_tokens,
-        )
-        from spark_bam_tpu.tpu.inflate import (
-            DeviceObserver, _tok_impl, maybe_profile_window,
-            stage_group_device, tokenize_group,
-        )
-
-        icfg = self.config.inflate_config
-        device_tok = icfg.resolve_tokenize() == "device"
-        if not device_tok:
-            if jax.default_backend() == "tpu":
-                require_native("the fused count on a TPU (tokenize=host)")
-            elif load_native() is None:
-                obs.count("check.fused_demotions")
-                return None
-        groups = self.pipeline.groups
-        if not groups:
-            return None
-        w = self.kernel_window
-        halo = self.halo
-        # Every window must fit the kernel: carry (≤ halo) + group bytes.
-        if max(
-            sum(m.uncompressed_size for m in g) for g in groups
-        ) + halo > w:
-            obs.count("check.fused_demotions")
-            return None
-
-        funnel = self.config.funnel_enabled()
-        if device_tok:
-            # tokenize=device: workers stage + H2D the RAW payload matrix
-            # (overlapping the kernel), and the entropy phase runs inside
-            # the fused program. Any row the bit-reader rejects — or whose
-            # produced length disagrees with its footer — flips the
-            # kernel's tok_ok scalar and demotes the whole count to the
-            # host-tokenize path; bad decodes never reach the total.
-            kernel = make_count_window_raw(
-                w, halo, self.config.reads_to_check,
-                flags_impl=self._flags_impl(), funnel=funnel,
-                tok_impl=_tok_impl(icfg.kernel),
-                donate=icfg.donate_enabled,
-            )
-        else:
-            kernel = make_count_window_tokens(
-                w, halo, self.config.reads_to_check,
-                flags_impl=self._flags_impl(), funnel=funnel,
-            )
-        lens_dev, nc = self._device_inputs()
-
-        total = 0
-        dev_total = dev_esc = dev_surv = None
-        windows = 0
-        chunk = 0
-        screened = 0
-        flush_every = self.flush_every
-        escaped = False
-        demoted = False
-        ring: list = []
-        ok_ring: list = []
-        carry_dev = jnp.zeros(halo, dtype=jnp.uint8)
-        carry_len = 0
-        base = 0
-        produce = stage_group_device if device_tok else tokenize_group
-
-        ch = open_channel(self.path)
-        pool = ThreadPoolExecutor(max_workers=self.pipeline.depth)
-        # Under a live registry the per-window device times are taken by
-        # observer threads: this thread dispatches and waits exactly as it
-        # does with the registry off.
-        observer = DeviceObserver.maybe()
-        try:
-            pending = [
-                pool.submit(produce, ch, g)
-                for g in groups[: self.pipeline.depth]
-            ]
-            for gi, group in enumerate(groups):
-                n = carry_len + sum(m.uncompressed_size for m in group)
-                at_eof = gi == len(groups) - 1
-                own_end = n if at_eof else max(n - halo, 0)
-                lo = min(max(self.header_end_abs - base, 0), own_end)
-                with obs.span("check.window", window=gi,
-                              members=len(group), n=n):
-                    fut = pending.pop(0)
-                    try:
-                        # The wait for the entropy phase: what the prefetch
-                        # pool exists to hide.
-                        with obs.span("inflate.stall_ms"):
-                            tp = fut.result()
-                    except INPUT_ERRORS:
-                        # A stream the tokenizer rejects (or a footer
-                        # disagreement): demote the whole count to the
-                        # host-inflate loop — correctness never depends on
-                        # phase 1.
-                        demoted = True
-                        break
-                    if tp is None:
-                        demoted = True
-                        break
-                    nxt = gi + self.pipeline.depth
-                    if nxt < len(groups):
-                        pending.append(
-                            pool.submit(produce, ch, groups[nxt])
-                        )
-                    if device_tok:
-                        # H2D happened on the producer thread
-                        # (stage_group_device) — off this critical path.
-                        staged_dev, clens_dev, usizes = tp
-                        exp = np.zeros(staged_dev.shape[0], dtype=np.int32)
-                        exp[: len(usizes)] = usizes
-                        operands = (staged_dev, clens_dev, jnp.asarray(exp))
-                        shape = staged_dev.shape
-                        obs.count("inflate.tokenize_blocks", len(usizes))
-                    else:
-                        packed, out_lens, _b = tp
-                        shape = (packed.shape, out_lens.shape)
-                        obs.count("inflate.h2d_bytes", int(packed.nbytes))
-                    # --profile: one steady window (H2D + the program).
-                    with maybe_profile_window("count_window", shape):
-                        t_put = time.perf_counter()
-                        if not device_tok:
-                            with obs.span("inflate.h2d",
-                                          bytes=packed.nbytes):
-                                operands = (
-                                    jnp.asarray(packed),
-                                    jnp.asarray(out_lens.astype(np.int32)),
-                                )
-                        t_dispatch = time.perf_counter()
-                        with obs.span("inflate.device_kernel"):
-                            out = kernel(
-                                *operands, carry_dev, lens_dev, nc,
-                                jnp.int32(carry_len), jnp.int32(n),
-                                jnp.bool_(at_eof), jnp.int32(lo),
-                                jnp.int32(own_end),
-                            )
-                    if observer is not None:
-                        observer.window(
-                            None if device_tok else operands[0], t_put,
-                            out["rounds"], t_dispatch, rounds=out["rounds"],
-                        )
-                    if device_tok:
-                        ok_ring.append(out["tok_ok"])
-                    carry_dev = out["carry"]
-                    carry_len = n - own_end
-                    base += own_end
-                    dev_total = (
-                        out["count"] if dev_total is None
-                        else dev_total + out["count"]
-                    )
-                    dev_esc = (
-                        out["esc_count"] if dev_esc is None
-                        else dev_esc + out["esc_count"]
-                    )
-                    dev_surv = (
-                        out["survivors"] if dev_surv is None
-                        else dev_surv + out["survivors"]
-                    )
-                    screened += n
-                    ring.append(out["count"])
-                    if len(ring) > self.ring_depth:
-                        with obs.span("check.pace"):
-                            ring.pop(0).block_until_ready()
-                        # Validate the bit-reader verdicts lazily, at the
-                        # same pacing sync: a rejected row anywhere demotes
-                        # the whole count (the classic loop restarts from
-                        # scratch; nothing was consumed from
-                        # self.pipeline).
-                        if ok_ring and not bool(ok_ring.pop(0)):
-                            obs.count("inflate.tokenize_demotions")
-                            demoted = True
-                            break
-                    windows += 1
-                    chunk += 1
-                    obs.count("check.windows")
-                    if self.progress is not None:
-                        self.progress(windows, base, self.total)
-                    # Same escape-checkpoint policy as count_reads: one
-                    # early sync at window 4, then flush-aligned.
-                    if windows == 4 or chunk >= flush_every:
-                        with obs.span("check.flush"):
-                            if int(dev_esc):
-                                escaped = True
-                                break
-                            if chunk >= flush_every:
-                                total += int(dev_total)
-                                if funnel:
-                                    self._funnel_add(
-                                        screened, int(dev_surv))
-                                dev_total = dev_esc = dev_surv = None
-                                chunk = 0
-                                screened = 0
-            if not demoted and ok_ring and not all(
-                    bool(ok) for ok in ok_ring):
-                obs.count("inflate.tokenize_demotions")
-                demoted = True
-            if not (demoted or escaped) and dev_total is not None:
-                with obs.span("check.flush"):
-                    if int(dev_esc):
-                        escaped = True
-                    else:
-                        total += int(dev_total)
-                        if funnel:
-                            self._funnel_add(screened, int(dev_surv))
-        finally:
-            pool.shutdown(wait=True, cancel_futures=True)
-            ch.close()
-            if observer is not None:
-                observer.close()
-        if demoted:
-            obs.count("check.fused_demotions")
-            return None
-        if escaped:
             obs.count("check.count_escape_retries")
             saved, self.progress = self.progress, None
             try:

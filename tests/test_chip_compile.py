@@ -5,9 +5,7 @@ describes a ``v5e:2x2`` and ``.lower(...).compile()`` against its devices
 raises what the attached chip would raise — Mosaic lowering refusals, HBM
 exhaustion — at no chip time. These cases pin the main path's programs at the
 default width (the count of host-inflated 32 MiB windows on one chip and on
-four; the token path's 4 MiB halo, 512 block rows × 64 KiB payloads, which
-an explicit ``device_inflate=True`` still reaches) plus the small serve /
-aggregate / Pallas programs.
+four) plus the small serve / aggregate programs.
 
 Only one process may hold libtpu, so the topology is described inside a
 module-scoped fixture (never at import or collection) and every compile runs
@@ -27,9 +25,6 @@ from spark_bam_tpu.tpu.checker import PAD
 
 HBM = 16 << 30
 WINDOW = 32 << 20       # Config(): next_pow2(24 MiB window + 4 MiB halo)
-HALO = 4 << 20
-BLOCKS = 512            # b_pad of a 24 MiB group of ~386 BGZF blocks
-C_PAD = 65536           # staged payload row: ≈43 KB payloads pad to 64 KiB
 CMAX = 1024
 
 
@@ -89,40 +84,10 @@ def _mesh_shapes(topo, n_devices: int):
 
 
 # ------------------------------------------------------------ full width
-def test_fused_count_program_auto_selects_compiles_at_default_width(chip):
-    """The whole fused count program ``device_inflate=True`` ends in on a
-    TPU — entropy phase, LZ77 resolve, window assembly, funnel, chain walk —
-    built the way ``StreamChecker._count_reads_fused`` builds it."""
-    from spark_bam_tpu.core.inflate_config import InflateConfig
-    from spark_bam_tpu.tpu import checker
-    from spark_bam_tpu.tpu.inflate import STRIDE, _tok_impl
-
-    icfg = InflateConfig()
-    tail = [chip((HALO,), jnp.uint8), chip((CMAX,), jnp.int32),
-            *_scalars(chip, jnp.int32, jnp.int32, jnp.int32, jnp.bool_,
-                      jnp.int32, jnp.int32)]
-    if icfg.resolve_tokenize() == "device":
-        kernel = checker.make_count_window_raw(
-            WINDOW, HALO, 10, flags_impl="xla", funnel=True,
-            tok_impl=_tok_impl(icfg.kernel), donate=icfg.donate_enabled,
-        )
-        args = [chip((BLOCKS, C_PAD), jnp.uint8), chip((BLOCKS,), jnp.int32),
-                chip((BLOCKS,), jnp.int32), *tail]
-    else:
-        kernel = jax.jit(checker.make_count_window_tokens(
-            WINDOW, HALO, 10, flags_impl="xla", funnel=True,
-        ))
-        args = [chip((3 * BLOCKS * STRIDE,), jnp.uint8),
-                chip((BLOCKS,), jnp.int32), *tail]
-    compiled = kernel.lower(*args).compile()
-    assert _device_bytes(compiled) < HBM
-
-
 def test_count_window_xla_funnel_compiles_at_32mib(chip):
     """``jit_count_window``: the whole device program of the one-chip count
     on every backend (the windows arrive inflated). Its temporaries are the
-    check's alone: under 5 GiB with its one 32 MiB operand, where the token
-    program reserved 6.0."""
+    check's alone: under 5 GiB with its one 32 MiB operand."""
     from spark_bam_tpu.tpu.checker import make_count_window
 
     kernel = jax.jit(make_count_window(WINDOW, 10, "xla", funnel=True))
@@ -190,39 +155,6 @@ def test_count_step_compiles_for_four_chips_at_one_row_a_chip(topo, chip):
     assert "all-gather" not in text and "all-to-all" not in text
 
 
-@pytest.mark.parametrize("window,blocks,least", [
-    (WINDOW, BLOCKS, 4 << 30),
-    (1 << 20, 32, 0),  # a 1 MiB file: a window smaller than the 4 MiB halo
-])
-def test_fused_count_tokens_step_compiles_for_four_chips(
-        window, blocks, least, topo, chip):
-    """``jit_count_tokens_step``: the fused window program on every chip of
-    a v5e host, one row a chip (512 token rows, 32 MiB window, 4 MiB
-    halo), the count pair ``psum``'d. Each device must hold its row's
-    program: the one-chip program's 6.4 GiB, not four of them. A multi-chip
-    host sends its small files through the same step, at the window their
-    size gives."""
-    from spark_bam_tpu.parallel.mesh import make_shard_map_count_tokens_step
-    from spark_bam_tpu.tpu.inflate import STRIDE
-
-    n = 4
-    mesh, shape, repl = _mesh_shapes(topo, n)
-    step = make_shard_map_count_tokens_step(
-        mesh, window, HALO, 10, "data", "xla", funnel=True
-    )
-    compiled = step.lower(
-        shape((n * 3 * blocks * STRIDE,), jnp.uint8),
-        shape((n * blocks,), jnp.int32), shape((n,), jnp.int32),
-        shape((n,), jnp.bool_), shape((n,), jnp.int32),
-        shape((n,), jnp.int32), shape((CMAX,), jnp.int32, repl),
-        shape((), jnp.int32, repl),
-    ).compile()
-    assert least < _device_bytes(compiled) < HBM
-    text = compiled.as_text()
-    assert "all-reduce" in text  # the psum, and nothing gathers the rows
-    assert "all-gather" not in text and "all-to-all" not in text
-
-
 # ----------------------------------------------------------------- small
 def test_serve_step_compiles_at_serve_config_defaults(topo, chip):
     from spark_bam_tpu.parallel.mesh import make_shard_map_serve_step
@@ -255,30 +187,3 @@ def test_aggregate_reduction_compiles(chip):
     }
     compiled = update_fn(plan, nc).lower(state, planes).compile()
     assert _device_bytes(compiled) < HBM
-
-
-@pytest.mark.parametrize("kernel", ["tokenize_pallas", "lz77_resolve_pallas"])
-def test_refused_pallas_kernels_stay_out_of_auto(chip, kernel, monkeypatch):
-    """Mosaic refuses both inflate kernels for the v5e, so ``auto`` must
-    never select them on any backend. When one of them is repaired this
-    test fails at ``pytest.raises``: that is the moment to let ``auto``
-    choose it again. (The ``backend=pallas`` flag kernels are explicit-only
-    too and have no case here: ``prefilter_check_flags`` on a four-tile grid
-    took 580 s to be refused for 26 MB of scoped VMEM against a 16 MB
-    limit.)"""
-    from spark_bam_tpu.tpu import inflate
-    from spark_bam_tpu.tpu import pallas_kernels as pk
-
-    monkeypatch.delenv("SPARK_BAM_LZ77", raising=False)
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    assert inflate._tok_impl("auto") == "xla"
-    assert inflate._lz77_impl() == "xla"
-    rows = 8
-    args = {
-        "tokenize_pallas": [chip((rows, C_PAD), jnp.uint8),
-                            chip((rows,), jnp.int32)],
-        "lz77_resolve_pallas": [chip((rows, 65536), jnp.uint8),
-                                chip((rows, 65536), jnp.uint16)],
-    }[kernel]
-    with pytest.raises(Exception, match="block shape"):
-        getattr(pk, kernel).lower(*args).compile()

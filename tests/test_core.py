@@ -112,58 +112,34 @@ def test_compile_cache_default_is_fixed_inside_the_checkout():
     assert CHECKOUT_JAX_CACHE == repo / ".jax_cache"
 
 
-@pytest.fixture
-def tpu_without_native(monkeypatch):
-    """What a TPU process sees when the native library did not build."""
+@pytest.mark.parametrize("backend", ["cpu", "tpu"])
+def test_count_without_native_library_inflates_with_zlib(
+        backend, monkeypatch, tmp_path):
+    """What a process sees when the native library did not build: the
+    count's windows are inflated by zlib, on a TPU as on the CPU, and the
+    count is the NumPy engine's."""
     import jax
 
-    from spark_bam_tpu.native import build
-
-    monkeypatch.setattr(build, "_LIB_CACHE", [None])
-    monkeypatch.setattr(build, "_LOAD_INFO", {"error": "g++ rc=1: boom"})
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-
-
-@pytest.mark.parametrize("config,device", [
-    (Config(), False),
-    (Config(inflate="tokenize=host"), False),
-    (Config(inflate="tokenize=device"), False),
-    (Config(device_inflate=False), False),
-    (Config(device_inflate=True), True),
-    (Config(device_inflate=True, inflate="tokenize=device"), True),
-])
-def test_tpu_backend_resolves_inflate_as_every_backend_does(
-        config, device, tpu_without_native):
-    """``device_inflate=None`` is host inflate on a TPU too, with or without
-    the native library (zlib inflates where it is missing); an explicit
-    setting is honoured as it is."""
-    from spark_bam_tpu.tpu.inflate import resolve_device_inflate
-
-    assert resolve_device_inflate(config) is device
-
-
-def test_tpu_backend_token_path_without_native_library_raises(
-        tpu_without_native, tmp_path):
-    """Asked for by name, the fused count's host entropy phase without the
-    native tokenizer is still an error naming the build failure on a TPU —
-    not a quiet return to host zlib."""
     from bam_factories import random_bam
+    from spark_bam_tpu import obs
+    from spark_bam_tpu.native import build
     from spark_bam_tpu.tpu.stream_check import StreamChecker
 
     path = tmp_path / "small.bam"
     random_bam(path, seed=7)
-    checker = StreamChecker(
-        path, Config(device_inflate=True, inflate="tokenize=host"))
-    with pytest.raises(RuntimeError, match="g\\+\\+ rc=1: boom"):
-        checker.count_reads()
-
-
-def test_cpu_backend_without_native_library_stays_on_host(monkeypatch):
-    from spark_bam_tpu.native import build
-    from spark_bam_tpu.tpu.inflate import resolve_device_inflate
-
+    want = StreamChecker(path, Config(), use_device=False).count_reads()
     monkeypatch.setattr(build, "_LIB_CACHE", [None])
-    assert resolve_device_inflate(Config()) is False
+    monkeypatch.setattr(build, "_LOAD_INFO", {"error": "g++ rc=1: boom"})
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    obs.shutdown()
+    reg = obs.configure()
+    try:
+        got = StreamChecker(path, Config()).count_reads()
+        engines = {e["attrs"]["engine"] for e in reg.events()
+                   if e["name"] == "inflate.window"}
+    finally:
+        obs.shutdown()
+    assert got == want > 0 and engines == {"zlib"}
 
 
 def test_pallas_interpret_only_on_cpu():

@@ -1,33 +1,40 @@
 """Fuzz: native decoders against zlib ground truth and corrupted input.
 
-The native DEFLATE tokenizer and rANS decoder parse untrusted bytes in
+The native DEFLATE inflater and rANS decoder parse untrusted bytes in
 process; these tests hammer them with (a) every zlib strategy/level
-combination — the tokenizer must agree with zlib byte-for-byte after
-device resolution — and (b) random truncations/corruptions, which must
-produce a Python exception, never a crash or hang.
+combination — the inflater every window goes through
+(``bgzf/flat.inflate_blocks``) must agree with zlib byte-for-byte — and
+(b) random truncations/corruptions, which must produce a Python exception,
+never a crash, a hang or wrong bytes.
 """
 
+import io
 import zlib
 
 import numpy as np
 import pytest
 
+from spark_bam_tpu.bgzf.block import Metadata
+from spark_bam_tpu.bgzf.flat import inflate_blocks
+from spark_bam_tpu.compress.huffman import bgzf_member
+from spark_bam_tpu.core.channel import FileStreamChannel
+from spark_bam_tpu.core.guard import INPUT_ERRORS
 from spark_bam_tpu.cram import rans
 from spark_bam_tpu.native.build import load_native, rans_decompress_native
-from spark_bam_tpu.tpu.inflate import inflate_blocks_device
 
 pytestmark = pytest.mark.skipif(
     load_native() is None, reason="native runtime unavailable"
 )
 
 
-def _device_inflate_one(comp: bytes, out_len: int):
-    return inflate_blocks_device(
-        np.frombuffer(comp, dtype=np.uint8),
-        np.array([0], dtype=np.int64),
-        np.array([len(comp)], dtype=np.int64),
-        np.array([out_len], dtype=np.int64),
-    )
+def _inflate_one(comp: bytes, out_len: int) -> bytes:
+    """One raw-DEFLATE stream framed as a BGZF member of ``out_len`` bytes,
+    through the native inflater (an in-memory channel: the bulk-read
+    branch, where ``tests/test_fast_inflate.py`` takes the mmap one)."""
+    member = bgzf_member(comp, 0, out_len)
+    with FileStreamChannel(io.BytesIO(member), len(member)) as ch:
+        view = inflate_blocks(ch, [Metadata(0, len(member), out_len)])
+    return view.data.tobytes()
 
 
 def _corpus():
@@ -46,7 +53,7 @@ def _corpus():
     ]
 
 
-def test_tokenizer_agrees_with_zlib_across_strategies():
+def test_inflater_agrees_with_zlib_across_strategies():
     strategies = [
         zlib.Z_DEFAULT_STRATEGY, zlib.Z_FILTERED, zlib.Z_HUFFMAN_ONLY,
         zlib.Z_RLE, zlib.Z_FIXED,
@@ -56,13 +63,12 @@ def test_tokenizer_agrees_with_zlib_across_strategies():
             for strategy in strategies:
                 co = zlib.compressobj(level, zlib.DEFLATED, -15, 8, strategy)
                 comp = co.compress(data) + co.flush()
-                out = _device_inflate_one(comp, len(data))
-                assert out is not None and out.tobytes() == data, (
+                assert _inflate_one(comp, len(data)) == data, (
                     level, strategy, len(data),
                 )
 
 
-def test_tokenizer_multi_deflate_block_streams():
+def test_inflater_multi_deflate_block_streams():
     # Z_FULL_FLUSH forces mid-stream block boundaries (and window resets),
     # exercising the multi-block loop and stored/dynamic interleavings.
     rng = np.random.default_rng(5)
@@ -76,13 +82,14 @@ def test_tokenizer_multi_deflate_block_streams():
         comp += co.compress(part) + co.flush(zlib.Z_FULL_FLUSH)
     comp += co.flush()
     data = b"".join(parts)
-    out = _device_inflate_one(comp, len(data))
-    assert out.tobytes() == data
+    assert _inflate_one(comp, len(data)) == data
 
 
-def test_tokenizer_never_crashes_on_corrupt_streams():
+def test_inflater_never_crashes_or_lies_on_corrupt_streams():
     rng = np.random.default_rng(17)
-    base = zlib.compress(b"corpus " * 3000)[2:-4]  # raw-ish deflate body
+    base = zlib.compress(b"corpus " * 3000)[2:-4]  # the raw deflate body
+    assert _inflate_one(base, 21_000) == b"corpus " * 3000
+    refused = 0
     for trial in range(200):
         blob = bytearray(base)
         kind = trial % 3
@@ -94,9 +101,13 @@ def test_tokenizer_never_crashes_on_corrupt_streams():
         else:
             blob = bytearray(rng.integers(0, 256, 300, dtype=np.uint8).tobytes())
         try:
-            _device_inflate_one(bytes(blob), 21_000)
-        except (IOError, ValueError):
-            pass  # rejection is the expected outcome
+            got = _inflate_one(bytes(blob), 21_000)
+        except INPUT_ERRORS:
+            refused += 1  # rejection is the expected outcome
+            continue
+        # Accepted: then zlib reads the same 21,000 bytes out of it.
+        assert got == zlib.decompressobj(-15).decompress(bytes(blob))
+    assert refused > 150
 
 
 def test_rans_never_crashes_on_corrupt_streams():

@@ -1,19 +1,23 @@
-"""The whole-file count's default path on every backend: windows and rows
-inflated on the host, the device only checking them (``jit_count_window``,
-``jit_count_step``), measured as the fused token path was.
+"""The whole-file count on every backend: windows and rows inflated on the
+host, the device only checking them (``jit_count_window``,
+``jit_count_step``), exact against the files' own index and the NumPy
+engine, and measured under the names the benchmark reads.
 
-Files come from ``bench/generators`` with their own index; ``Config()`` is
-what a TPU process passes too, so the TPU cases only patch what the process
-observes (``jax.default_backend``) and must take the same path.
+Files come from ``bench/generators`` with their own index and from
+``tests/bam_factories``; ``Config()`` is what a TPU process passes too, so
+the TPU cases only patch what the process observes
+(``jax.default_backend``) and must take the same path.
 """
 
+import numpy as np
 import pytest
 
 import jax
 
 from spark_bam_tpu import obs
 from spark_bam_tpu.core.config import Config
-from spark_bam_tpu.parallel.mesh import make_mesh
+from spark_bam_tpu.obs.names import NAMES
+from spark_bam_tpu.parallel.mesh import make_mesh, mesh_steps
 from spark_bam_tpu.parallel.stream_mesh import (
     _ShardedStream, count_reads_sharded,
 )
@@ -22,16 +26,10 @@ from spark_bam_tpu.tpu.stream_check import StreamChecker
 
 MEMBER = 0xFF00  # htslib's payload: what the generators fill every member to
 
+#: Each a path that left the device, or a kernel that misjudged a read.
 DEMOTIONS = (
-    "inflate.tokenize_demotions", "inflate.host_demotions",
     "check.fused_demotions", "agg.host_fallbacks",
     "check.count_escape_retries",
-)
-#: What only the token path emits: nothing resolves and nothing tokenizes
-#: here, so a value under these names would be a false reading.
-TOKEN_PATH_ONLY = (
-    "inflate.rounds", "mesh.rounds", "inflate.tokenize_host_ms",
-    "inflate.tokenize", "inflate.pack",
 )
 
 
@@ -75,8 +73,8 @@ def _observed(run):
 def _assert_host_fed(counters: dict, hists: dict) -> None:
     for name in DEMOTIONS:
         assert not counters.get(name), name
-    for name in TOKEN_PATH_ONLY:
-        assert name not in counters and not hists.get(name), name
+    # Nothing is emitted under a name the catalogue no longer has.
+    assert set(counters) | set(hists) <= NAMES
 
 
 @pytest.mark.parametrize("backend", ["cpu", "tpu"])
@@ -85,7 +83,6 @@ def test_one_device_count_is_host_fed_and_measured(
     path, index = generated
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
     checker = StreamChecker(path, Config())
-    assert checker.pipeline.device_copy is False
     got, counters, hists = _observed(checker.count_reads)
     assert got == len(index["record_starts"])
     _assert_host_fed(counters, hists)
@@ -125,17 +122,6 @@ def test_one_device_count_carries_the_halo_and_paces(short48):
     assert StreamChecker(path, config).count_reads() == got
 
 
-def test_explicit_device_inflate_still_reaches_the_token_path(generated):
-    path, index = generated
-    checker = StreamChecker(path, Config(device_inflate=True))
-    assert checker.pipeline.device_copy is True
-    got, counters, hists = _observed(checker.count_reads)
-    assert got == len(index["record_starts"])
-    assert hists["inflate.rounds"] == counters["check.windows"] == 1
-    assert hists["inflate.tokenize_host_ms"] == 1
-    assert "inflate.bytes" not in counters  # nothing inflated on the host
-
-
 def _mesh(n: int = 4):
     return make_mesh(jax.devices("cpu")[:n])
 
@@ -162,7 +148,6 @@ def test_mesh_count_of_eight_rows_lands_two_a_device(
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
     config = Config(window_size=6 * MEMBER, halo_size=64 << 10)
     probe = _ShardedStream(path, config, _mesh(), None, None, None)
-    assert not probe.fused and not probe.device_inflate
     assert len(probe.groups) == 8
     chunk = rows_a_device * 4 * (probe.kernel_window + PAD)
     st = _ShardedStream(
@@ -177,7 +162,7 @@ def test_mesh_count_of_eight_rows_lands_two_a_device(
         path, config, mesh=_mesh(), stats_out=stats, chunk_bytes=chunk))
     assert got == len(index["record_starts"])
     assert stats["rows"] == 8 and stats["steps"] == steps
-    assert not stats["fused"] and not stats["escapes"]
+    assert not stats["escapes"] and not stats["fallback"]
     _assert_host_fed(counters, hists)
     assert counters["mesh.steps"] == steps
     assert counters["mesh.rows"] == counters["inflate.windows"] == 8
@@ -219,7 +204,216 @@ def test_mesh_count_of_long_reads_is_host_fed(generated):
     got, counters, hists = _observed(lambda: count_reads_sharded(
         path, config, mesh=_mesh(), stats_out=stats))
     assert got == len(index["record_starts"])
-    assert not stats["fused"] and not stats["escapes"]
+    assert not stats["escapes"] and not stats["fallback"]
     _assert_host_fed(counters, hists)
     assert counters["mesh.rows"] == stats["rows"] >= 3
     assert hists["mesh.step_device_ms"] == stats["steps"]
+
+
+# ------------------------------------------- the one-device count is exact
+
+CFG = dict(window_uncompressed=128 << 10, halo=32 << 10)
+
+
+def _numpy_count(path, **cfg) -> int:
+    """The NumPy engine through the same windows: the differential oracle."""
+    return StreamChecker(path, Config(), use_device=False, **cfg).count_reads()
+
+
+def _random(tmp_path, seed, **kw):
+    from tests.bam_factories import random_bam
+
+    path = tmp_path / f"f{seed}.bam"
+    kw.setdefault("contigs", (("chr1", 5_000_000),))
+    random_bam(path, seed, dup_rate=kw.pop("dup_rate", 0.05), **kw)
+    return path
+
+
+def _longread(tmp_path):
+    from spark_bam_tpu.benchmarks.synth import synth_longread_bam
+
+    path = tmp_path / "lr.bam"
+    synth_longread_bam(
+        path, target_bytes=2 << 20, seed=0,
+        read_lens=(60_000, 140_000), ultra_seq_len=200_000,
+    )
+    return path
+
+
+#: name → (file, Config, window/halo): what the count must get right.
+ONE_DEVICE = {
+    "seed-0": (lambda t: _random(t, 0), Config(), CFG),
+    "seed-1": (lambda t: _random(t, 1), Config(), CFG),
+    "seed-2": (lambda t: _random(t, 2), Config(), CFG),
+    "funnel-off": (lambda t: _random(t, 13), Config(funnel="off"), CFG),
+    # Small windows force many carry seams; two contigs exercise the
+    # contig-length table.
+    "multi-contig-and-carry": (
+        lambda t: _random(
+            t, 14, contigs=(("chr1", 5_000_000), ("chr2", 3_000_000)),
+            dup_rate=0.1),
+        Config(), dict(window_uncompressed=64 << 10, halo=16 << 10)),
+    # Chains beyond the halo (long reads, a tiny halo) escape to the exact
+    # spans path: never a wrong count.
+    "escape-falls-back-exact": (
+        _longread, Config(),
+        dict(window_uncompressed=256 << 10, halo=16 << 10)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(ONE_DEVICE))
+def test_one_device_count_matches_the_numpy_engine(case, tmp_path):
+    make, config, cfg = ONE_DEVICE[case]
+    path = make(tmp_path)
+    run = StreamChecker(path, config, **cfg).count_reads
+    got, counters, _hists = _observed(run)
+    assert got == _numpy_count(path, **cfg) > 0
+    if case == "escape-falls-back-exact":
+        assert counters["check.count_escape_retries"] == 1
+
+
+def test_one_device_count_populates_the_funnel_stats(tmp_path):
+    checker = StreamChecker(_random(tmp_path, 16), Config(), **CFG)
+    checker.count_reads()
+    stats = checker.funnel_stats
+    assert stats is not None and stats["screened"] > 0
+    assert 0 < stats["survivors"] <= stats["screened"]
+
+
+# ------------------------------------------------ the mesh count is exact
+
+#: (configuration, bytes, row window, halo): 9 rows of short reads (a last
+#: step with three padding rows) and 6-7 rows of long reads whose 15-38 KB
+#: records span members and row seams; the halo covers the checker's ten
+#: reads of lookahead in both.
+MESH_FILES = {
+    "wgs-short": (2 << 20, 256 << 10, 64 << 10),
+    "longread-hifi": (6 << 20, 1 << 20, 512 << 10),
+}
+
+
+@pytest.fixture(scope="module", params=sorted(MESH_FILES))
+def mesh_file(request, tmp_path_factory):
+    size, window, halo = MESH_FILES[request.param]
+    path = tmp_path_factory.mktemp(request.param) / "file.bam"
+    index = _generate(request.param, 2 ** 31 + 27, size, path)
+    return path, index, Config(window_size=window, halo_size=halo)
+
+
+def test_mesh_count_is_the_index_and_the_one_device_count(mesh_file):
+    from spark_bam_tpu.load.tpu_load import count_reads_tpu
+
+    path, index, config = mesh_file
+    stats: dict = {}
+    got = count_reads_sharded(path, config, mesh=_mesh(), stats_out=stats)
+    assert got == len(index["record_starts"])
+    assert got == count_reads_tpu(path, config)  # one device, carried halo
+    assert not stats["escapes"] and not stats["fallback"]
+    rows = stats["rows"]
+    # Every row in one step at the default step width: up to three a device.
+    assert 5 <= rows <= 9 and stats["steps"] == 1
+
+
+def test_the_shares_add_up(mesh_file):
+    """Each row's device count is the index's count of record starts in the
+    span the row owns, and the rows' sum is the whole file's: a row counted
+    alone (a mesh of one device: its totals are the row's) needs nothing of
+    its neighbours but the bytes of its halo."""
+    path, index, config = mesh_file
+    starts = np.asarray(index["record_starts"])
+    st = _ShardedStream(
+        path, config, _mesh(1), None, None, None,
+        chunk_bytes=1)  # one row a step
+    assert st.step_rows_local == 1
+    step = mesh_steps(st.mesh, st.axis).count_step(
+        reads_to_check=config.reads_to_check, flags_impl=config.flags_impl,
+        funnel=config.funnel_enabled(),
+    )
+    per_row = []
+    batches = st.row_batches()
+    try:
+        for args, _done, c0 in batches:
+            count, escapes = np.asarray(step(*args)).tolist()
+            assert escapes == 0
+            lo = int(st.flat_starts[c0])
+            hi = lo + int(st.sizes[c0])
+            want = int(np.searchsorted(starts, hi) - np.searchsorted(starts, lo))
+            assert count == want, f"row {c0} owns [{lo}, {hi})"
+            per_row.append(count)
+    finally:
+        batches.close()
+    assert len(per_row) == len(st.groups)
+    assert sum(per_row) == len(starts)
+    # The owned spans tile the file.
+    assert int(st.flat_starts[-1] + st.sizes[-1]) == index["uncompressed_bytes"]
+
+
+def test_a_forced_escape_is_patched_exactly(tmp_path):
+    """Long reads behind a halo shorter than the checker's lookahead: owned
+    positions near the seams escape, the dirty steps' rows are re-derived on
+    the host, the count is exact, and the engine says so under the counter
+    the benchmark's ``correct`` reads."""
+    path = tmp_path / "long.bam"
+    index = _generate("longread-hifi", 2 ** 31 + 28, 3 << 20, path)
+    stats: dict = {}
+    config = Config(window_size=256 << 10, halo_size=64 << 10)
+    got, counters, _hists = _observed(lambda: count_reads_sharded(
+        path, config, mesh=_mesh(), stats_out=stats))
+    assert got == len(index["record_starts"])
+    assert stats["escapes"] > 0
+    assert stats["patched_steps"] > 0 and not stats["fallback"]
+    assert counters["check.count_escape_retries"] == stats["patched_steps"]
+    assert counters["mesh.escapes"] == stats["escapes"]
+    assert "check.fused_demotions" not in counters
+
+
+@pytest.mark.parametrize("size", [300 << 10, 1 << 20])
+def test_a_file_smaller_than_the_halo_at_the_defaults(size, tmp_path):
+    """``count_reads_tpu`` sends every file of a multi-chip host here, the
+    small ones too: the kernel window shrinks with the file while the
+    default halo stays 4 MiB, and the step still compiles and is exact (one
+    row, three padding rows)."""
+    path = tmp_path / "small.bam"
+    index = _generate("wgs-short", 2 ** 31 + 29, size, path)
+    config = Config()
+    assert index["uncompressed_bytes"] < config.halo_size
+    stats: dict = {}
+    got = count_reads_sharded(path, config, mesh=_mesh(), stats_out=stats)
+    assert got == len(index["record_starts"])
+    assert stats["rows"] == 1 and stats["steps"] == 1
+    assert not stats["escapes"] and not stats["fallback"]
+
+
+@pytest.mark.parametrize("backend,devices,sharded", [
+    ("tpu", 4, True), ("tpu", 1, False), ("cpu", 8, False),
+])
+def test_count_reads_tpu_counts_across_the_chips_it_sees(
+        backend, devices, sharded, monkeypatch):
+    """The mesh engine is chosen by what the process observes (a TPU backend
+    with more than one local chip), never by an option; the CPU's virtual
+    devices do not choose it."""
+    from spark_bam_tpu.load import tpu_load
+    from spark_bam_tpu.parallel import stream_mesh
+    from spark_bam_tpu.tpu import stream_check
+
+    assert not tpu_load.counts_across_chips()  # as the tests run: 8 x cpu
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    monkeypatch.setattr(jax, "local_device_count", lambda: devices)
+    called = []
+
+    def mesh_engine(path, config, mesh=None):
+        called.append(("mesh", mesh.devices.size))
+        return 7
+
+    class OneDevice:
+        def __init__(self, path, config):
+            called.append(("stream", 1))
+
+        def count_reads(self):
+            return 7
+
+    monkeypatch.setattr(stream_mesh, "count_reads_sharded", mesh_engine)
+    monkeypatch.setattr(stream_check, "StreamChecker", OneDevice)
+    assert tpu_load.count_reads_tpu("any.bam", Config()) == 7
+    assert called == [("mesh", len(jax.local_devices()))
+                      if sharded else ("stream", 1)]

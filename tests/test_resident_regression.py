@@ -18,9 +18,7 @@ CFG = dict(window_uncompressed=128 << 10, halo=32 << 10)
 
 
 def _streaming_count(path, **cfg):
-    return StreamChecker(
-        path, Config(device_inflate=False, fused_count=False), **cfg
-    ).count_reads()
+    return StreamChecker(path, Config(), **cfg).count_reads()
 
 
 def test_resident_matches_streaming_in_process(tmp_path):
@@ -43,3 +41,18 @@ def test_resident_tiny_chunk_cap_still_exact(tmp_path):
         path, Config(resident_chunk_bytes=1), **CFG
     ).count_reads_resident(chunk_windows=256)
     assert got == want
+
+
+def test_resident_chunk_bytes_cap(tmp_path):
+    """The resident-chunk HBM cap (the device-memory budget) must bound the
+    chunk size without changing the count."""
+    path = tmp_path / "cap.bam"
+    random_bam(path, 17, contigs=(("chr1", 5_000_000),), dup_rate=0.05)
+    want = _streaming_count(path, **CFG)
+    got = StreamChecker(
+        path, Config(resident_chunk_bytes=1 << 20), **CFG
+    ).count_reads_resident(chunk_windows=64, first_chunk_windows=2)
+    assert got == want
+    # And the knob flows through the generic config surface.
+    cfg = Config.from_dict({"spark.bam.resident.chunk.bytes": "64MB"})
+    assert cfg.resident_chunk_bytes == 64 << 20

@@ -31,16 +31,27 @@ def registry():
 W, HALO = 128 << 10, 32 << 10
 
 
-def _lower_fused_window():
-    from spark_bam_tpu.tpu.checker import count_window_tokens
-    from spark_bam_tpu.tpu.inflate import STRIDE
+def _lower_count_window():
+    from spark_bam_tpu.tpu.checker import PAD, count_window
 
     i32 = jnp.int32
-    return count_window_tokens.lower(
-        jnp.zeros(3 * 2 * STRIDE, jnp.uint8), jnp.zeros(2, i32),
-        jnp.zeros(HALO, jnp.uint8), jnp.zeros(8, i32), i32(1), i32(0),
-        i32(1000), jnp.bool_(True), i32(0), i32(1000),
-        window=W, halo=HALO, funnel=True,
+    return count_window.lower(
+        jnp.zeros(W + PAD, jnp.uint8), jnp.zeros(8, i32), i32(1), i32(1000),
+        jnp.bool_(True), i32(0), i32(1000), window=W, funnel=True,
+    )
+
+
+def _lower_count_step():
+    from spark_bam_tpu.parallel.mesh import local_mesh, make_shard_map_count_step
+    from spark_bam_tpu.tpu.checker import PAD
+
+    mesh = local_mesh()
+    b = mesh.devices.size
+    i32 = jnp.int32
+    return make_shard_map_count_step(mesh, funnel=True).lower(
+        jnp.zeros(b * (W + PAD), jnp.uint8), jnp.zeros(b, i32),
+        jnp.zeros(b, bool), jnp.zeros(b, i32), jnp.zeros(b, i32),
+        jnp.zeros(8, i32), i32(1),
     )
 
 
@@ -71,8 +82,8 @@ def _lower_agg_update():
 
 CHECK = {"check", "flags", "funnel", "chain_walk"}
 PROGRAM_SCOPES = [
-    ("count_window_tokens", _lower_fused_window,
-     CHECK | {"unpack", "lz77_resolve", "assemble", "carry", "reduce"}),
+    ("count_window", _lower_count_window, CHECK | {"reduce"}),
+    ("count_step", _lower_count_step, CHECK | {"reduce"}),
     ("serve_step", _lower_serve_step, CHECK | {"reduce"}),
     ("agg_update", _lower_agg_update, {"agg_reduce"}),
 ]
@@ -91,7 +102,7 @@ def test_scopes_are_in_the_lowered_program(program, lower, scopes):
         # ``check/flags/...`` or, directly under a vmap, ``vmap(reduce)/...``.
         assert any(f"{before}{scope}{after}/" in text for before, after in
                    (("/", ""), ('"', ""), ("(", ")"))), scope
-    if program == "count_window_tokens":
+    if program == "count_window":
         assert "check/flags/" in text and "check/chain_walk/" in text
 
 
@@ -176,12 +187,12 @@ class _Scalar:
 
 
 class _Operand(_Scalar):
-    """The packed H2D operand."""
+    """The window's H2D operand."""
 
 
-def _fused_schedule(monkeypatch, path):
-    """Runs ``_count_reads_fused`` over ``path`` with a kernel and an H2D
-    that compute nothing and record every dispatch and every wait with the
+def _count_schedule(monkeypatch, path):
+    """Runs ``count_reads`` over ``path`` with a kernel and an H2D that
+    compute nothing and record every dispatch and every wait with the
     thread that made it."""
     from spark_bam_tpu.core.config import Config
     from spark_bam_tpu.tpu import checker, stream_check
@@ -192,30 +203,25 @@ def _fused_schedule(monkeypatch, path):
     def asarray(x, *a, **kw):
         if isinstance(x, np.ndarray) and x.dtype == np.uint8 and x.ndim == 1:
             log.append(("h2d", x.size, threading.current_thread().name))
-            return _Operand(log, f"packed{x.size}")
+            return _Operand(log, f"window{x.size}")
         return real_asarray(x, *a, **kw)
 
     def make_kernel(*_a, **_kw):
-        def kernel(packed, out_lens, carry, lengths, nc, carry_len, n,
-                   at_eof, lo, own):
+        def kernel(window, lengths, nc, n, at_eof, lo, own):
             k = sum(1 for e in log if e[0] == "dispatch")
-            assert isinstance(packed, _Operand)
+            assert isinstance(window, _Operand)
             log.append(("dispatch", (int(n), bool(at_eof), int(lo), int(own)),
                         threading.current_thread().name))
             return {"count": _Scalar(log, f"count{k}", 1),
                     "esc_count": _Scalar(log, f"esc{k}"),
-                    "survivors": _Scalar(log, f"surv{k}", 2),
-                    "rounds": _Scalar(log, f"rounds{k}", 5),
-                    "carry": carry}
+                    "survivors": _Scalar(log, f"surv{k}", 2)}
         return kernel
 
-    monkeypatch.setattr(checker, "make_count_window_tokens", make_kernel)
+    monkeypatch.setattr(checker, "make_count_window", make_kernel)
     monkeypatch.setattr(stream_check.jnp, "asarray", asarray)
     ck = stream_check.StreamChecker(
-        path, Config(device_inflate=True),
-        window_uncompressed=128 << 10, halo=32 << 10)
-    total = ck._count_reads_fused()
-    return total, log
+        path, Config(), window_uncompressed=128 << 10, halo=32 << 10)
+    return ck.count_reads(), log
 
 
 @pytest.mark.skipif(load_native() is None, reason="native runtime unavailable")
@@ -226,10 +232,10 @@ def test_a_live_registry_leaves_the_count_loops_schedule_alone(
     path = tmp_path / "s.bam"
     random_bam(path, 5, contigs=(("chr1", 5_000_000),), n_records=(900, 1000))
     obs.shutdown()
-    total_off, log_off = _fused_schedule(monkeypatch, path)
+    total_off, log_off = _count_schedule(monkeypatch, path)
     reg = obs.configure()
     try:
-        total_on, log_on = _fused_schedule(monkeypatch, path)
+        total_on, log_on = _count_schedule(monkeypatch, path)
         snap = reg.snapshot()
         events = reg.events()
     finally:
@@ -244,7 +250,7 @@ def test_a_live_registry_leaves_the_count_loops_schedule_alone(
     # Same H2Ds, dispatches and waits, in the same order, on the feeding
     # thread; with the registry off nothing else waits at all.
     assert on_main(log_on) == on_main(log_off) == log_off
-    assert not any(e[0] == "block" and e[1].startswith(("packed", "rounds"))
+    assert not any(e[0] == "block" and e[1].startswith("window")
                    for e in on_main(log_on))
     # The observers waited instead, once a window each.
     others = [e for e in log_on if e[2] != main]
@@ -254,10 +260,11 @@ def test_a_live_registry_leaves_the_count_loops_schedule_alone(
     def hist(name):
         return sum(h["count"] for h in snap["hists"] if h["name"] == name)
 
-    for name in ("inflate.rounds", "inflate.device_ms", "inflate.h2d_ms",
-                 "inflate.stall_ms", "check.window", "inflate.h2d",
-                 "inflate.device_kernel"):
+    for name in ("inflate.device_ms", "inflate.h2d_ms", "inflate.stall_ms",
+                 "inflate.h2d", "inflate.device_kernel"):
         assert hist(name) == windows, name
+    # One span a window, and the one that finds the stream's end.
+    assert hist("check.window") == windows + 1
     assert [c["value"] for c in snap["counters"]
             if c["name"] == "check.windows"] == [windows]
     assert hist("check.pace") >= 1 and hist("check.flush") >= 2
