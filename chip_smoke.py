@@ -47,7 +47,7 @@ ZERO_COUNTERS = (
 EVIDENCE_COUNTERS = (
     "check.windows", "inflate.windows",
     "mesh.steps", "serve.batches", "serve.batch_rows", "funnel.positions",
-    "funnel.survivors",
+    "funnel.survivors", "funnel.lanes",
 )
 BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
 CACHE_HIT = "/jax/compilation_cache/cache_hits"

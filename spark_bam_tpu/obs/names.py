@@ -97,7 +97,7 @@ NAMES = frozenset({
     "faults.attempt_ms", "faults.hedges", "faults.quarantined",
     "faults.quarantined_blocks", "faults.retries",
     # funnel — two-stage checker candidate funnel (docs/design.md)
-    "funnel.positions", "funnel.survivors",
+    "funnel.lanes", "funnel.positions", "funnel.survivors",
     # guard — untrusted-byte decode boundary (core/guard.py)
     "guard.quarantined_blocks", "guard.quarantined_records",
     # inflate — host BGZF inflate feeding the device (docs/design.md)

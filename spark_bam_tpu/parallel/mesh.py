@@ -325,11 +325,14 @@ def make_shard_map_count_step(
     backend: each device runs the one-chip stream's window program
     (``checker.count_window``: the check and its owned-span count
     reduction, nothing scattered back over the window) on its rows of
-    host-inflated bytes, and the (boundary count, owned escapes) pair
-    all-reduces with ``lax.psum`` — the count-reads workload (reference
-    docs/benchmarks.md:53-59) as one mesh-partitioned unit. Rows carry
-    per-row owned spans [lo, own) so halo bytes and the BAM header are
-    counted exactly once globally.
+    host-inflated bytes, and ``(boundary count, owned escapes, stage-0
+    survivors, lanes run)`` all-reduces with ``lax.psum`` — the count-reads
+    workload (reference docs/benchmarks.md:53-59) as one mesh-partitioned
+    unit. Rows carry per-row owned spans [lo, own) so halo bytes and the
+    BAM header are counted exactly once globally. The last two are the
+    funnel's evidence (``funnel.survivors``, ``funnel.lanes``): record-scale
+    in every step whose escapes read 0 (a row over its lane capacity
+    escapes whole), which are the steps the caller reads them from.
 
     ``windows`` is the rows' concatenation, FLAT and sharded over the mesh
     axis (``(rows · (W+PAD),)`` u8), so a device's block is its own rows'
@@ -349,7 +352,9 @@ def make_shard_map_count_step(
             reads_to_check=reads_to_check, flags_impl=flags_impl,
             pallas_interpret=pallas_interpret, funnel=funnel,
         )
-        return jnp.stack([r["count"], r["esc_count"]]).astype(jnp.int32)
+        return jnp.stack([
+            r["count"], r["esc_count"], r["survivors"], r["lanes"],
+        ]).astype(jnp.int32)
 
     def count_step(windows, ns, at_eofs, los, owns, lengths, nc):
         rows = ns.shape[0]  # this device's

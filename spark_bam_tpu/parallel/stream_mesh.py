@@ -577,6 +577,10 @@ def _count_steps(st: "_ShardedStream", config: Config, progress):
             dirty.append(c0)
         else:
             count += int(totals[0])
+            # The funnel's evidence, as the one-device stream names it:
+            # stage-0 survivors and the lanes run for them, over the rows.
+            obs.count("funnel.survivors", int(totals[2]))
+            obs.count("funnel.lanes", int(totals[3]))
         if progress is not None:
             progress(steps, done, st.total)
         # Pathological guard (mirrors count_reads' window-4 escape

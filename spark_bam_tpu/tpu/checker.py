@@ -23,6 +23,7 @@ from __future__ import annotations
 
 import functools
 from dataclasses import dataclass
+from typing import NamedTuple
 
 import numpy as np
 
@@ -411,17 +412,29 @@ def _deep_flags_at(p, lengths, num_contigs, n, tables, pos):
     return F
 
 
-def _compact_mask(mask, capacity: int):
-    """Compact set positions of ``mask`` into a (capacity,) index buffer
-    (-1 beyond the population) without any full-width cumsum/sort/scatter:
-    pack to u32 words, build a word-level popcount prefix (tiny cumsum),
-    binary-search the word holding the k-th survivor, then locate the
-    in-word bit with masked popcounts. Returns (cand, n_set)."""
+class _RankTable(NamedTuple):
+    """The word-level rank table of a mask: its bits packed to u32 words,
+    each word's popcount, their inclusive prefix (a tiny cumsum) and the
+    population. ``_ranked_positions`` finds the k-th set bit from it without
+    any full-width cumsum/sort/scatter."""
+    words: jnp.ndarray
+    wpc: jnp.ndarray
+    wcnt: jnp.ndarray
+    n_set: jnp.ndarray
+
+
+def _rank_table(mask) -> _RankTable:
     words = _pack_bits(mask)
     wpc = lax.population_count(words).astype(_I32)
     wcnt = jnp.cumsum(wpc)
-    n_set = wcnt[-1]
-    k = jnp.arange(capacity, dtype=_I32)
+    return _RankTable(words, wpc, wcnt, wcnt[-1])
+
+
+def _ranked_positions(table, k):
+    """Positions of the set bits of ranks ``k`` ((K,) int32, 0-based), -1
+    beyond the population: binary-search the word holding the k-th
+    survivor, then locate the in-word bit with masked popcounts."""
+    words, wpc, wcnt, n_set = table
     wi = jnp.searchsorted(wcnt, k + 1, side="left").astype(_I32)
     excl = jnp.take(wcnt - wpc, jnp.clip(wi, 0, wcnt.shape[0] - 1), mode="clip")
     r = k + 1 - excl                              # target rank within word: 1..32
@@ -431,24 +444,38 @@ def _compact_mask(mask, capacity: int):
     pcnt = lax.population_count(word[:, None] & incl[None, :])
     hit = (pcnt == r[:, None]) & (((word[:, None] >> lanes[None, :]) & 1) == 1)
     lane = jnp.argmax(hit, axis=1).astype(_I32)
-    cand = jnp.where(k < n_set, wi * 32 + lane, _I32(-1))
-    return cand, n_set
+    return jnp.where(k < n_set, wi * 32 + lane, _I32(-1))
 
 
-# Sentinel bounds for the logical cursor: anything outside [0, n] behaves
-# identically (it can never equal the physical cursor at EOF), so clamping is
-# exact unless the cursor needs to *re-enter* range — tracked per lane.
-@jax.named_scope("check")
-def _check_lanes(
+def _compact_mask(mask, capacity: int):
+    """Compact set positions of ``mask`` into a (capacity,) index buffer
+    (-1 beyond the population). Returns (cand, n_set)."""
+    table = _rank_table(mask)
+    k = jnp.arange(capacity, dtype=_I32)
+    return _ranked_positions(table, k), table.n_set
+
+
+def lane_capacity(w: int) -> int:
+    """Lanes the check of a ``w``-byte window can hold: a window with more
+    stage-0 survivors escapes whole to the host engine."""
+    return max(w // 32, 4096)
+
+
+#: Lanes a block of the count's lane stage (``_count_lanes``). The stage runs
+#: ``ceil(n_survivors / LANE_BLOCK)`` blocks, a number read on the device
+#: from the window itself, so a window pays for its survivors and not for
+#: the worst window the format allows.
+LANE_BLOCK = 16384
+
+
+def _flag_stage(
     padded, lengths, num_contigs, n, at_eof,
-    reads_to_check: int = 10, flags_impl: str = "xla",
-    pallas_interpret: bool = False, funnel: bool = False,
+    flags_impl: str, pallas_interpret: bool, funnel: bool,
 ):
-    """Flag pass + survivor compaction + lane walk, WITHOUT the full-width
-    scatters: the shared core of ``check_window`` (which scatters the lanes
-    back to (W,) arrays) and the funnel count path (which reduces the lanes
-    directly — for two scalars the scatters are pure overhead that XLA
-    cannot eliminate through the sums)."""
+    """Stage 0, position-wide and run once a window: the flag pass (the
+    prefilter under the funnel), the survivors it leaves and every
+    non-survivor's verdict straight from F. Also ``misc_at``, which reads
+    remaining/body_end at lane positions for the walk."""
     w = padded.shape[0] - PAD
     with jax.named_scope("flags"):
         if funnel:
@@ -474,7 +501,7 @@ def _check_lanes(
             F = _compute_flags(padded, lengths, num_contigs, n)
     if funnel:
         # Lane-width misc: the walk only ever reads remaining/body_end at
-        # (capacity,) positions — full-width materialization is the single
+        # lane positions — full-width materialization is the single
         # biggest non-prefilter cost on the funnel path.
         misc_at = functools.partial(_misc_at, padded, n)
     else:
@@ -503,48 +530,52 @@ def _check_lanes(
     res0 = jnp.where(fail0, jnp.int8(-1), jnp.int8(0))
     res0 = jnp.where(esc0, jnp.int8(2), res0)
     fail_mask0 = jnp.where(fail0, F, _I32(0))
+    return {
+        "F": F, "misc_at": misc_at, "survivor": survivor, "res0": res0,
+        "fail_mask0": fail_mask0, "inexact0": inexact0,
+    }
 
-    # --- survivor compaction ---------------------------------------------
-    capacity = max(w // 32, 4096)
-    if funnel:
-        with jax.named_scope("funnel"):
-            cand, n_survivors = _compact_mask(survivor, capacity)
-            tables = _funnel_tables(padded, n)
-        overflow = n_survivors > capacity
-        live = cand >= 0
-        # Stage 1: full 19-bit flags once at candidate positions, scattered
-        # to a full-width array so the chain walk can look them up by
-        # position. A walked position either passes the prefilter (then its
-        # deep mask is here — deep-failing candidates resolve inside the
-        # walk's step logic exactly like fail0/esc0/inexact0 above) or
-        # fails it (then the prefilter bits alone are verdict-equivalent).
-        with jax.named_scope("flags"):
-            F_cand = _deep_flags_at(
-                padded, lengths, num_contigs, n, tables,
-                jnp.where(live, cand, _I32(0)),
-            )
-        with jax.named_scope("funnel"):
-            F_cand = jnp.where(live, F_cand, _I32(0))
-            tgt0 = jnp.where(live, cand, _I32(w))
-            F_deep = jnp.zeros(w + 1, dtype=_I32).at[tgt0].set(
-                F_cand, mode="drop"
-            )[:w]
 
-        def flags_lookup(pi):
-            pre = jnp.take(F, pi, mode="clip")
-            return jnp.where(pre == 0, jnp.take(F_deep, pi, mode="clip"), pre)
-    else:
-        # No funnel: the survivors' compaction is the walk's own prologue.
-        with jax.named_scope("chain_walk"):
-            n_survivors = jnp.sum(survivor.astype(_I32))
-            (cand,) = jnp.nonzero(survivor, size=capacity, fill_value=-1)
-            cand = cand.astype(_I32)
-        overflow = n_survivors > capacity
-        live = cand >= 0
+def _deep_lanes(padded, lengths, num_contigs, n, tables, cand, live):
+    """Stage 1 at the lanes ``cand``: the full 19-bit flags once at
+    candidate positions, as the ``(targets, masks)`` of a scatter into a
+    position-wide array, so the chain walk can look them up by position
+    (dead lanes target the pad slot ``w``). A walked position either passes
+    the prefilter (then its deep mask is there — deep-failing candidates
+    resolve inside the walk's step logic exactly like fail0/esc0/inexact0)
+    or fails it (then the prefilter bits alone are verdict-equivalent)."""
+    w = padded.shape[0] - PAD
+    with jax.named_scope("flags"):
+        F_cand = _deep_flags_at(
+            padded, lengths, num_contigs, n, tables,
+            jnp.where(live, cand, _I32(0)),
+        )
+    with jax.named_scope("funnel"):
+        F_cand = jnp.where(live, F_cand, _I32(0))
+        tgt0 = jnp.where(live, cand, _I32(w))
+    return tgt0, F_cand
 
-        def flags_lookup(pi):
-            return jnp.take(F, pi, mode="clip")
 
+def _funnel_lookup(F, F_deep):
+    """The walk's flag lookup under the funnel: the prefilter's mask where
+    it rejects, the deep mask where it passed."""
+    def flags_lookup(pi):
+        pre = jnp.take(F, pi, mode="clip")
+        return jnp.where(pre == 0, jnp.take(F_deep, pi, mode="clip"), pre)
+
+    return flags_lookup
+
+
+# Sentinel bounds for the logical cursor: anything outside [0, n] behaves
+# identically (it can never equal the physical cursor at EOF), so clamping is
+# exact unless the cursor needs to *re-enter* range — tracked per lane.
+def _walk_lanes(
+    cand, live, flags_lookup, misc_at, n, at_eof, w: int,
+    reads_to_check: int, unroll,
+):
+    """The chain walk over the lanes ``cand`` (any number of them: lanes are
+    independent), ``reads_to_check`` gather rounds; per-lane verdicts."""
+    capacity = cand.shape[0]
     logical = jnp.where(live, cand, _I32(0))
     physical = logical
     l_overflowed = jnp.zeros(capacity, dtype=bool)
@@ -614,14 +645,10 @@ def _check_lanes(
         ), None
 
     state = (logical, physical, l_overflowed, res, fail_mask, reads_before, reads_parsed, exact)
-    # Unrolled under the funnel: the loop-carried scan blocks XLA from
-    # fusing the lane gathers with their producers (~25% of the funnel
-    # path); ten lane-width steps unroll cheaply. The funnel=False scan is
-    # kept rolled so the funnel A/B baseline measures the original kernel.
     with jax.named_scope("chain_walk"):
         state, _ = lax.scan(
             step, state, jnp.arange(reads_to_check, dtype=_I32),
-            unroll=True if funnel else 1,
+            unroll=unroll,
         )
     logical, physical, l_overflowed, res, fail_mask, reads_before, reads_parsed, exact = state
 
@@ -629,11 +656,155 @@ def _check_lanes(
     res = jnp.where(full_chain, jnp.int8(1), res)
     reads_parsed = jnp.where(full_chain, _I32(reads_to_check), reads_parsed)
     return {
-        "survivor": survivor, "res0": res0, "fail_mask0": fail_mask0,
-        "inexact0": inexact0, "cand": cand, "live": live, "res": res,
-        "fail_mask": fail_mask, "reads_before": reads_before,
+        "res": res, "fail_mask": fail_mask, "reads_before": reads_before,
         "reads_parsed": reads_parsed, "exact": exact,
+    }
+
+
+@jax.named_scope("check")
+def _check_lanes(
+    padded, lengths, num_contigs, n, at_eof,
+    reads_to_check: int = 10, flags_impl: str = "xla",
+    pallas_interpret: bool = False, funnel: bool = False,
+):
+    """Flag pass + survivor compaction + lane walk at the window's full
+    lane capacity, WITHOUT the full-width scatters: the core of
+    ``check_window``, which scatters the lanes back to (W,) arrays. (The
+    count reduces its lanes directly, and sizes the lane stage by the
+    window's survivors: ``_count_lanes``.)"""
+    w = padded.shape[0] - PAD
+    S = _flag_stage(
+        padded, lengths, num_contigs, n, at_eof,
+        flags_impl, pallas_interpret, funnel,
+    )
+    F, survivor = S["F"], S["survivor"]
+
+    # --- survivor compaction ---------------------------------------------
+    capacity = lane_capacity(w)
+    if funnel:
+        with jax.named_scope("funnel"):
+            cand, n_survivors = _compact_mask(survivor, capacity)
+            tables = _funnel_tables(padded, n)
+        overflow = n_survivors > capacity
+        live = cand >= 0
+        tgt0, F_cand = _deep_lanes(
+            padded, lengths, num_contigs, n, tables, cand, live)
+        with jax.named_scope("funnel"):
+            F_deep = jnp.zeros(w + 1, dtype=_I32).at[tgt0].set(
+                F_cand, mode="drop"
+            )[:w]
+        flags_lookup = _funnel_lookup(F, F_deep)
+    else:
+        # No funnel: the survivors' compaction is the walk's own prologue.
+        with jax.named_scope("chain_walk"):
+            n_survivors = jnp.sum(survivor.astype(_I32))
+            (cand,) = jnp.nonzero(survivor, size=capacity, fill_value=-1)
+            cand = cand.astype(_I32)
+        overflow = n_survivors > capacity
+        live = cand >= 0
+
+        def flags_lookup(pi):
+            return jnp.take(F, pi, mode="clip")
+
+    # Unrolled under the funnel: the loop-carried scan blocks XLA from
+    # fusing the lane gathers with their producers (~25% of the funnel
+    # path); ten lane-width steps unroll cheaply. The funnel=False scan is
+    # kept rolled so the funnel A/B baseline measures the original kernel.
+    lanes = _walk_lanes(
+        cand, live, flags_lookup, S["misc_at"], n, at_eof, w,
+        reads_to_check, unroll=True if funnel else 1,
+    )
+    return {
+        "survivor": survivor, "res0": S["res0"],
+        "fail_mask0": S["fail_mask0"], "inexact0": S["inexact0"],
+        "cand": cand, "live": live, **lanes,
         "overflow": overflow, "n_survivors": n_survivors,
+    }
+
+
+@jax.named_scope("check")
+def _count_lanes(
+    padded, lengths, num_contigs, n, at_eof, lo, own,
+    reads_to_check: int, flags_impl: str, pallas_interpret: bool,
+    block: int = LANE_BLOCK,
+):
+    """The funnelled check reduced to the count's scalars, its lane stage
+    sized by the window's own survivors.
+
+    Stage 0 is ``_check_lanes``'s, run once. The lane stage (compaction →
+    deep flags → their scatter → the walk → the lane reduction) runs in
+    blocks of ``block`` lanes, ``ceil(n_survivors / block)`` of them: a trip
+    count the device reads from the window. Pass 1 compacts block k (ranks
+    ``k·block …``), deep-checks it and scatters its masks into the carried
+    position-wide ``F_deep``; only then can pass 2 walk, since a lane's
+    chain visits survivors of later blocks. Lanes are independent, so every
+    verdict is what the full-capacity stage gives; a window over
+    ``lane_capacity`` runs every block and reports the overflow as that
+    stage does. Under ``vmap`` the trip count is the rows' maximum (a row's
+    blocks beyond its own hold dead lanes only)."""
+    w = padded.shape[0] - PAD
+    S = _flag_stage(
+        padded, lengths, num_contigs, n, at_eof,
+        flags_impl, pallas_interpret, True,
+    )
+    F, misc_at = S["F"], S["misc_at"]
+    capacity = lane_capacity(w)
+    block = min(block, capacity)
+    max_blocks = -(-capacity // block)
+    with jax.named_scope("funnel"):
+        # The barrier makes the survivors one materialized (W,) mask. Left to
+        # fuse, the word packing re-derives F in its own (W/32, 32) shape
+        # from nine position-wide operands, each laid out again at four
+        # times its bytes (32 of a tile's 128 lanes): 4.6 GiB of a v5e's
+        # temporaries, and the relayouts' time.
+        table = _rank_table(lax.optimization_barrier(S["survivor"]))
+        tables = _funnel_tables(padded, n)
+    n_survivors = table.n_set
+    overflow = n_survivors > capacity
+    blocks = jnp.minimum(
+        lax.div(n_survivors + _I32(block - 1), _I32(block)), _I32(max_blocks))
+    ranks = jnp.arange(block, dtype=_I32)
+
+    def deep_block(k, carry):
+        F_deep, cands = carry
+        with jax.named_scope("funnel"):
+            cand = _ranked_positions(table, k * block + ranks)
+        tgt0, F_cand = _deep_lanes(
+            padded, lengths, num_contigs, n, tables, cand, cand >= 0)
+        with jax.named_scope("funnel"):
+            return (
+                F_deep.at[tgt0].set(F_cand, mode="drop"),
+                lax.dynamic_update_slice(cands, cand, (k * block,)),
+            )
+
+    F_deep, cands = lax.fori_loop(
+        0, blocks, deep_block,
+        (jnp.zeros(w + 1, dtype=_I32),
+         jnp.full(max_blocks * block, -1, dtype=_I32)),
+    )
+    flags_lookup = _funnel_lookup(F, F_deep)
+
+    def walk_block(k, carry):
+        count, esc = carry
+        with jax.named_scope("chain_walk"):
+            cand = lax.dynamic_slice(cands, (k * block,), (block,))
+            live = cand >= 0
+        res = _walk_lanes(
+            cand, live, flags_lookup, misc_at, n, at_eof, w,
+            reads_to_check, unroll=True,
+        )["res"]
+        with jax.named_scope("chain_walk"):
+            own_lane = live & (cand >= lo) & (cand < own)
+            return (
+                count + jnp.sum(own_lane & (res == 1)),
+                esc + jnp.sum(own_lane & (res == 2)),
+            )
+
+    count, esc = lax.fori_loop(
+        0, blocks, walk_block, (_I32(0), _I32(0)))
+    return {
+        "count": count, "esc": esc, "res0": S["res0"], "overflow": overflow,
+        "n_survivors": n_survivors, "lanes": blocks * _I32(block),
     }
 
 
@@ -726,6 +897,33 @@ def _scatter_lanes(L: dict, w: int) -> dict:
     }
 
 
+def _count_funnel(
+    padded, lengths, num_contigs, n, at_eof, lo, own,
+    reads_to_check: int, flags_impl: str, pallas_interpret: bool,
+    block: int = LANE_BLOCK,
+):
+    """``count_window`` under the funnel. Scatter-free reduction: verdicts
+    live only on survivor lanes (non-survivors never reach res==1) and
+    escapes split cleanly into prefilter-rejected positions (res0==2) plus
+    lane escapes, so both scalars reduce over lanes without materializing
+    the (W,) arrays."""
+    w = padded.shape[0] - PAD
+    i = jnp.arange(w, dtype=_I32)
+    m = (i >= lo) & (i < own)
+    L = _count_lanes(
+        padded, lengths, num_contigs, n, at_eof, lo, own,
+        reads_to_check, flags_impl, pallas_interpret, block,
+    )
+    with jax.named_scope("reduce"):
+        esc = jnp.sum(m & (L["res0"] == 2)) + L["esc"]
+        count = jnp.where(L["overflow"], 0, L["count"])
+        esc = jnp.where(L["overflow"], jnp.sum(m), esc)
+    return {
+        "count": count, "esc_count": esc, "survivors": L["n_survivors"],
+        "lanes": L["lanes"],
+    }
+
+
 @functools.partial(
     jax.jit,
     static_argnames=(
@@ -745,32 +943,19 @@ def count_window(
     dead-code-eliminates everything the two scalars don't need — the
     fail_mask/reads_* scatters and the per-position arrays themselves.
     (Escapes are rare; the caller falls back to the exact spans path when
-    ``esc_count`` is ever nonzero.)
+    ``esc_count`` is ever nonzero.) Beside the two scalars: ``survivors``
+    (stage 0's) and ``lanes``, the lanes the lane stage ran — under the
+    funnel as many blocks as hold the survivors (``_count_lanes``), without
+    it the window's whole capacity.
     """
+    if funnel:
+        return _count_funnel(
+            padded, lengths, num_contigs, n, at_eof, lo, own,
+            reads_to_check, flags_impl, pallas_interpret,
+        )
     w = padded.shape[0] - PAD
     i = jnp.arange(w, dtype=_I32)
     m = (i >= lo) & (i < own)
-    if funnel:
-        # Scatter-free reduction: verdicts live only on survivor lanes
-        # (non-survivors never reach res==1) and escapes split cleanly into
-        # prefilter-rejected positions (res0==2) plus lane escapes, so both
-        # scalars reduce over lanes without materializing the (W,) arrays.
-        L = _check_lanes(
-            padded, lengths, num_contigs, n, at_eof,
-            reads_to_check=reads_to_check, flags_impl=flags_impl,
-            pallas_interpret=pallas_interpret, funnel=True,
-        )
-        with jax.named_scope("reduce"):
-            own_lane = L["live"] & (L["cand"] >= lo) & (L["cand"] < own)
-            count = jnp.sum(own_lane & (L["res"] == 1))
-            esc = jnp.sum(m & (L["res0"] == 2)) + jnp.sum(
-                own_lane & (L["res"] == 2)
-            )
-            count = jnp.where(L["overflow"], 0, count)
-            esc = jnp.where(L["overflow"], jnp.sum(m), esc)
-        return {
-            "count": count, "esc_count": esc, "survivors": L["n_survivors"],
-        }
     res = check_window(
         padded, lengths, num_contigs, n, at_eof,
         reads_to_check=reads_to_check, window=window,
@@ -782,6 +967,7 @@ def count_window(
             "count": jnp.sum(m & res["verdict"]),
             "esc_count": jnp.sum(m & res["escaped"]),
             "survivors": res["survivors"],
+            "lanes": _I32(lane_capacity(w)),
         }
 
 

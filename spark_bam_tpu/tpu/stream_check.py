@@ -60,6 +60,7 @@ from spark_bam_tpu import obs
 from spark_bam_tpu.bam.header import read_header
 from spark_bam_tpu.check.vectorized import check_flat
 from spark_bam_tpu.core.config import Config
+from spark_bam_tpu.tpu.checker import lane_capacity
 from spark_bam_tpu.tpu.inflate import InflatePipeline
 
 
@@ -202,16 +203,21 @@ class StreamChecker:
     def _flags_impl(self) -> str:
         return self.config.flags_impl
 
-    def _funnel_add(self, screened: int, survivors: int):
+    def _funnel_add(self, screened: int, survivors: int, lanes: int):
         """Fold one window's (or chunk's) funnel totals into the stats
-        surface and the ``funnel.*`` observability counters."""
+        surface and the ``funnel.*`` observability counters: positions
+        stage 0 screened, the survivors it left, and the lanes the lane
+        stage ran for them (the count sizes that stage by its window's
+        survivors; the other projections run the window's whole capacity)."""
         if self.funnel_stats is None:
-            self.funnel_stats = {"screened": 0, "survivors": 0}
+            self.funnel_stats = {"screened": 0, "survivors": 0, "lanes": 0}
         self.funnel_stats["screened"] += screened
         self.funnel_stats["survivors"] += survivors
+        self.funnel_stats["lanes"] += lanes
         if obs.enabled():
             obs.count("funnel.positions", screened)
             obs.count("funnel.survivors", survivors)
+            obs.count("funnel.lanes", lanes)
 
     def _launcher(self, full_masks: bool = False):
         """Full-output launch (the spans path)."""
@@ -402,7 +408,9 @@ class StreamChecker:
             with obs.span("check.window", base=base, own=own_end):
                 res = self._materialize(buf, at_eof, out)
                 if funnel:
-                    self._funnel_add(len(buf), int(res["survivors"]))
+                    self._funnel_add(
+                        len(buf), int(res["survivors"]),
+                        lane_capacity(self.kernel_window))
                 spans = [res[f][:own_end].copy() for f in fields]
                 bad = res["escaped"][:own_end]
                 if defer_inexact:
@@ -469,7 +477,7 @@ class StreamChecker:
         w = self.kernel_window
 
         total = 0
-        dev_total = dev_esc = dev_surv = None
+        acc = None  # the windows' scalars since the last flush, on device
         windows = 0
         chunk = 0
         screened = 0
@@ -508,18 +516,8 @@ class StreamChecker:
                     if observer is not None:
                         observer.window(
                             operand, t_put, out["count"], t_dispatch)
-                    dev_total = (
-                        out["count"] if dev_total is None
-                        else dev_total + out["count"]
-                    )
-                    dev_esc = (
-                        out["esc_count"] if dev_esc is None
-                        else dev_esc + out["esc_count"]
-                    )
-                    dev_surv = (
-                        out["survivors"] if dev_surv is None
-                        else dev_surv + out["survivors"]
-                    )
+                    acc = out if acc is None else {
+                        k: acc[k] + out[k] for k in acc}
                     screened += n
                     ring.append(out["count"])
                     if len(ring) > self.ring_depth:
@@ -539,24 +537,27 @@ class StreamChecker:
                     # is not synced per window.
                     if windows == 4 or chunk >= flush_every:
                         with obs.span("check.flush"):
-                            if int(dev_esc):
+                            if int(acc["esc_count"]):
                                 escaped = True
                             elif chunk >= flush_every:
-                                total += int(dev_total)
+                                total += int(acc["count"])
                                 if funnel:
                                     self._funnel_add(
-                                        screened, int(dev_surv))
-                                dev_total = dev_esc = dev_surv = None
+                                        screened, int(acc["survivors"]),
+                                        int(acc["lanes"]))
+                                acc = None
                                 chunk = 0
                                 screened = 0
-            if not escaped and dev_total is not None:
+            if not escaped and acc is not None:
                 with obs.span("check.flush"):
-                    if int(dev_esc):
+                    if int(acc["esc_count"]):
                         escaped = True
                     else:
-                        total += int(dev_total)
+                        total += int(acc["count"])
                         if funnel:
-                            self._funnel_add(screened, int(dev_surv))
+                            self._funnel_add(
+                                screened, int(acc["survivors"]),
+                                int(acc["lanes"]))
         finally:
             # Closing the generator shuts the pipeline's pool and channel
             # before the exact path (if any) reopens the file.
@@ -666,6 +667,15 @@ class StreamChecker:
                 jnp.asarray(owns),
             )
 
+        def dispatch(rows):
+            """One chunk's pending ``(count, esc, survivors, positions,
+            lanes)``: the scan runs every row of the bucket, padding too,
+            at the window's whole lane capacity."""
+            out = flush(rows)
+            return (out["count"], out["esc_count"], out["survivors"],
+                    sum(len(r[0]) for r in rows),
+                    _next_pow2(len(rows)) * lane_capacity(w))
+
         rows: list = []
         chunks = 0
         cap = first_chunk_windows
@@ -680,24 +690,20 @@ class StreamChecker:
                 pos_flushed = base + own_end
                 obs.count("check.windows")
                 if len(rows) >= cap:
-                    out = flush(rows)
-                    scr = sum(len(r[0]) for r in rows)
+                    pend.append(dispatch(rows))
                     rows = []
                     chunks += 1
                     cap = chunk_windows
-                    pend.append(
-                        (out["count"], out["esc_count"], out["survivors"], scr)
-                    )
                     # Sync the first (small) chunk's scalars immediately;
                     # after that, one chunk behind.
                     if chunks == 1 or len(pend) > 1:
-                        cnt, esc, surv, scr = pend.pop(0)
+                        cnt, esc, surv, scr, lanes = pend.pop(0)
                         if int(esc):
                             escaped = True
                             break
                         total += int(cnt)
                         if funnel:
-                            self._funnel_add(scr, int(surv))
+                            self._funnel_add(scr, int(surv), lanes)
                     # Progress at dispatch points only: buffered-but-unsent
                     # windows must not inflate the forensics position.
                     if self.progress is not None:
@@ -706,18 +712,14 @@ class StreamChecker:
             gen.close()
         if not escaped:
             if rows:
-                out = flush(rows)
-                scr = sum(len(r[0]) for r in rows)
-                pend.append(
-                    (out["count"], out["esc_count"], out["survivors"], scr)
-                )
-            for cnt, esc, surv, scr in pend:
+                pend.append(dispatch(rows))
+            for cnt, esc, surv, scr, lanes in pend:
                 if int(esc):
                     escaped = True
                     break
                 total += int(cnt)
                 if funnel:
-                    self._funnel_add(scr, int(surv))
+                    self._funnel_add(scr, int(surv), lanes)
             if not escaped and self.progress is not None and windows_done:
                 self.progress(windows_done, pos_flushed, self.total)
         if escaped:

@@ -125,6 +125,18 @@ def test_fuzz_mutant_parity(kernels, tmp_path):
     assert checked >= 5, f"only {checked} mutants survived decode"
 
 
+def _adversarial(kind: str, corpus, w: int):
+    """Byte soup, or a corpus window with 1% of its bytes flipped."""
+    rng = np.random.default_rng(11)
+    if kind == "soup":
+        return rng.integers(0, 256, size=w, dtype=np.uint8)
+    data = np.array(flatten_file(corpus[0]).data[:w], dtype=np.uint8,
+                    copy=True)
+    flips = rng.integers(0, len(data), size=max(1, len(data) // 100))
+    data[flips] ^= rng.integers(1, 256, size=len(flips)).astype(np.uint8)
+    return data
+
+
 def _assert_superset(pd, ld, nc, n):
     """Every prefilter bit must also be set by the full pass — hence
     full-pass survivors (F == 0) are a subset of prefilter survivors."""
@@ -149,17 +161,10 @@ def test_superset_on_adversarial_windows(corpus):
     """Byte soup and bit-flipped corpus windows: the superset property is
     structural (prefilter bits are a subset of full-pass bits at every
     position), so it must hold on arbitrary garbage, not just valid BAM."""
-    rng = np.random.default_rng(11)
     ld, nc = _lens_of(corpus[0])
-    soup = rng.integers(0, 256, size=W, dtype=np.uint8)
-    pd, n = _window_of(soup)
-    _assert_superset(pd, ld, nc, n)
-
-    data = np.array(flatten_file(corpus[0]).data[:W], dtype=np.uint8, copy=True)
-    flips = rng.integers(0, len(data), size=max(1, len(data) // 100))
-    data[flips] ^= rng.integers(1, 256, size=len(flips)).astype(np.uint8)
-    pd, n = _window_of(data)
-    _assert_superset(pd, ld, nc, n)
+    for kind in ("soup", "bit-flips"):
+        pd, n = _window_of(_adversarial(kind, corpus, W))
+        _assert_superset(pd, ld, nc, n)
 
 
 def test_pallas_prefilter_matches_xla(corpus):
@@ -237,3 +242,247 @@ def test_config_flush_every_and_ring_depth():
     assert Config(ring_depth=4).ring_depth == 4
     cfg = Config.from_env({"SPARK_BAM_RING_DEPTH": "3"})
     assert cfg.ring_depth == 3
+
+
+# ------------------------------------------------------------------------
+# The count's lane stage, sized by its window's survivors (PR 30). One
+# reference for every case: ``check_window`` under the funnel, which runs
+# the lane stage once at the window's whole capacity (``w // 32`` lanes)
+# and whose owned verdicts and escapes ARE the count's two scalars.
+
+W1 = 1 << 20                      # capacity 32,768 lanes: blocks are distinct
+CAPACITY = ck.lane_capacity(W1)
+BLOCKS = (512, 1024, 4096, CAPACITY)  # forced lanes a block: 64 … 1 blocks
+
+
+def _lanes_rule(survivors: int, block: int, capacity: int = CAPACITY) -> int:
+    """Lanes the stage runs for ``survivors``: whole blocks, at most the
+    capacity (a window over it runs every block and escapes whole)."""
+    return min(-(-survivors // block), -(-capacity // block)) * block
+
+
+@pytest.fixture(scope="module")
+def full_stage():
+    return ck.make_check_window(W1, 10, funnel=True)
+
+
+@pytest.fixture(scope="module")
+def blocked():
+    """``block -> jitted _count_funnel``: the count's program with the lane
+    stage forced to ``block`` lanes a block."""
+    import functools
+
+    import jax
+
+    cache = {}
+
+    def get(block):
+        if block not in cache:
+            cache[block] = jax.jit(functools.partial(
+                ck._count_funnel, reads_to_check=10, flags_impl="xla",
+                pallas_interpret=False, block=block))
+        return cache[block]
+
+    return get
+
+
+def _want(full_stage, pd, ld, nc, n, at_eof, lo, own):
+    r = full_stage(pd, ld, nc, n, jnp.bool_(at_eof))
+    i = np.arange(W1)
+    m = (i >= lo) & (i < own)
+    return (
+        int(np.sum(m & np.asarray(r["verdict"]))),
+        int(np.sum(m & np.asarray(r["escaped"]))),
+        int(r["survivors"]),
+    )
+
+
+def _got(out):
+    return int(out["count"]), int(out["esc_count"]), int(out["survivors"])
+
+
+def _assert_blocked_matches(full_stage, blocked, block, pd, ld, nc, n):
+    """Every owned span, both ``at_eof``: the blocked stage's three scalars
+    are the full stage's, and it ran the lanes the rule gives."""
+    for at_eof in (True, False):
+        for lo, own in ((0, int(n)), (1000, int(n) // 2)):
+            want = _want(full_stage, pd, ld, nc, n, at_eof, lo, own)
+            out = blocked(block)(
+                pd, ld, nc, n, jnp.bool_(at_eof), jnp.int32(lo),
+                jnp.int32(own))
+            assert _got(out) == want, (block, at_eof, lo, own)
+            lanes = int(out["lanes"])
+            assert lanes == _lanes_rule(want[2], block)
+            assert lanes >= min(want[2], CAPACITY)
+
+
+@pytest.fixture(scope="module")
+def generated_windows(tmp_path_factory):
+    """1 MiB windows of the benchmark's two configurations: ≈ 3,100
+    survivors of short reads (7 blocks of 512), ≈ 40 of long reads."""
+    from bench.tests.conftest import generate
+
+    out = {}
+    for name in ("wgs-short", "longread-hifi"):
+        p = tmp_path_factory.mktemp(name) / "file.bam"
+        generate(name, 2 ** 31 + 30, p, 3 << 20)
+        out[name] = (_window_of(flatten_file(p).data, W1), _lens_of(p))
+    return out
+
+
+@pytest.mark.parametrize("block", BLOCKS)
+@pytest.mark.parametrize("name", ["wgs-short", "longread-hifi"])
+def test_lane_blocks_match_the_full_stage_on_generated_files(
+        generated_windows, full_stage, blocked, name, block):
+    (pd, n), (ld, nc) = generated_windows[name]
+    _assert_blocked_matches(full_stage, blocked, block, pd, ld, nc, n)
+
+
+@pytest.mark.parametrize("block", BLOCKS)
+@pytest.mark.parametrize("which", [0, 1])
+def test_lane_blocks_match_the_full_stage_on_corpora(
+        corpus, full_stage, blocked, which, block):
+    p = corpus[which]
+    pd, n = _window_of(flatten_file(p).data, W1)
+    ld, nc = _lens_of(p)
+    _assert_blocked_matches(full_stage, blocked, block, pd, ld, nc, n)
+
+
+@pytest.mark.parametrize("block", BLOCKS)
+@pytest.mark.parametrize("kind", ["soup", "bit-flips"])
+def test_lane_blocks_match_the_full_stage_on_adversarial_windows(
+        corpus, full_stage, blocked, kind, block):
+    """The windows ``test_superset_on_adversarial_windows`` builds, a MiB
+    wide: byte soup and a bit-flipped corpus window."""
+    pd, n = _window_of(_adversarial(kind, corpus, W1), W1)
+    ld, nc = _lens_of(corpus[0])
+    _assert_blocked_matches(full_stage, blocked, block, pd, ld, nc, n)
+
+
+def _planted(survivors: int, stride: int = 32):
+    """A zeroed window with exactly ``survivors`` stage-0 survivors: a
+    36-byte fixed block every ``stride`` bytes (remaining 40, refID 0 / 40,
+    name_len 2, unmapped, nothing else), whose shifted readings all fail
+    the prefilter (their ``remaining`` reads 0 or 2 against an implied 34).
+    At stride 24 a block's ``remaining`` lands in the block before's
+    next_refID, so the header must hold more than 40 contigs."""
+    data = np.zeros(W1, dtype=np.uint8)
+    at = np.arange(survivors) * stride
+    assert survivors == 0 or at[-1] + 36 <= W1
+    data[at] = 40          # remaining (little-endian, one byte)
+    data[at + 12] = 2      # l_read_name
+    data[at + 18] = 4      # flag: unmapped
+    return data
+
+
+def _planted_lens():
+    lens = np.zeros(1024, dtype=np.int32)
+    lens[:64] = 1_000_000
+    return jnp.asarray(lens), jnp.int32(64)
+
+
+EDGE_BLOCK = 4096
+#: survivors → stride. The block's edges, none, the capacity's edges, and
+#: one window over it (stride 24 fits 43,690 blocks in a MiB).
+PLANTED = {
+    0: 32, 1: 32, EDGE_BLOCK - 1: 32, EDGE_BLOCK: 32, EDGE_BLOCK + 1: 32,
+    CAPACITY - 1: 32, CAPACITY: 24, CAPACITY + 1: 24, 40_000: 24,
+}
+
+
+@pytest.mark.parametrize("survivors", sorted(PLANTED))
+def test_lane_blocks_at_their_edges(full_stage, blocked, survivors):
+    pd, n = _window_of(_planted(survivors, PLANTED[survivors]), W1)
+    ld, nc = _planted_lens()
+    lo, own = 0, int(n)
+    for at_eof in (True, False):
+        want = _want(full_stage, pd, ld, nc, n, at_eof, lo, own)
+        assert want[2] == survivors  # the window holds what was planted
+        out = blocked(EDGE_BLOCK)(
+            pd, ld, nc, n, jnp.bool_(at_eof), jnp.int32(lo), jnp.int32(own))
+        assert _got(out) == want
+        lanes = int(out["lanes"])
+        assert lanes == _lanes_rule(survivors, EDGE_BLOCK)
+        if survivors > CAPACITY:
+            # The overflow escape, as the full stage reports it: every
+            # owned position unresolved, nothing counted, every block run.
+            assert _got(out)[:2] == (0, own - lo)
+            assert lanes == CAPACITY
+        else:
+            assert survivors <= lanes < survivors + EDGE_BLOCK
+
+
+@pytest.mark.parametrize("name", ["wgs-short", "longread-hifi"])
+def test_count_window_runs_the_lanes_its_survivors_need(
+        generated_windows, full_stage, name):
+    """The public program at the module's own ``LANE_BLOCK``."""
+    (pd, n), (ld, nc) = generated_windows[name]
+    kernel = ck.make_count_window(W1, 10, funnel=True)
+    lo, own = 0, int(n)
+    out = kernel(pd, ld, nc, n, jnp.bool_(True), jnp.int32(lo), jnp.int32(own))
+    want = _want(full_stage, pd, ld, nc, n, True, lo, own)
+    assert _got(out) == want
+    assert int(out["lanes"]) == _lanes_rule(want[2], ck.LANE_BLOCK)
+    assert int(out["lanes"]) < CAPACITY  # fewer than the full stage's
+    # Without the funnel the one stage there is runs the whole capacity.
+    off = ck.make_count_window(W1, 10, funnel=False)(
+        pd, ld, nc, n, jnp.bool_(True), jnp.int32(lo), jnp.int32(own))
+    assert _got(off)[:2] == want[:2] and int(off["lanes"]) == CAPACITY
+
+
+def test_vmapped_count_window_loops_to_the_rows_maximum(
+        generated_windows, full_stage):
+    """Rows of one device under ``vmap`` (the mesh step with several rows a
+    chip): each row's scalars are its own, its lanes its own blocks, and
+    the program still holds ONE lane stage: two ``while`` loops whose trip
+    count is the rows' maximum, no branch or select over stages."""
+    import functools
+
+    import jax
+
+    (pd_s, n_s), (ld, nc) = generated_windows["wgs-short"]
+    (pd_l, n_l), _ = generated_windows["longread-hifi"]
+    empty = jnp.zeros_like(pd_s)
+    rows = jnp.stack([pd_s, empty, pd_l])
+    ns = jnp.stack([n_s, jnp.int32(0), n_l])
+    los = jnp.zeros(3, jnp.int32)
+    one = functools.partial(
+        ck.count_window, reads_to_check=10, funnel=True)
+    batched = jax.jit(jax.vmap(
+        lambda w, n, lo, own: one(w, ld, nc, n, jnp.bool_(False), lo, own)))
+    out = batched(rows, ns, los, ns)
+    for r, (pd, n) in enumerate(((pd_s, n_s), (empty, jnp.int32(0)),
+                                 (pd_l, n_l))):
+        want = _want(full_stage, pd, ld, nc, n, False, 0, int(n))
+        assert tuple(int(out[k][r]) for k in
+                     ("count", "esc_count", "survivors")) == want
+        assert int(out["lanes"][r]) == _lanes_rule(want[2], ck.LANE_BLOCK)
+    assert int(out["lanes"][1]) == 0  # a padding row runs no lane
+
+    def primitives(jaxpr, into):
+        for eqn in jaxpr.eqns:
+            into.append(eqn.primitive.name)
+            for v in eqn.params.values():
+                for sub in (v if isinstance(v, (list, tuple)) else [v]):
+                    inner = getattr(sub, "jaxpr", None)
+                    if inner is not None:
+                        primitives(getattr(inner, "jaxpr", inner), into)
+        return into
+
+    def whiles(fn, *args):
+        names = primitives(jax.make_jaxpr(fn)(*args).jaxpr, [])
+        assert "cond" not in names  # no switch over lane stages
+        return names.count("while")
+
+    # The blocks of the two passes; ``searchsorted``'s own loop is a
+    # ``scan``. Batched or not, the same two.
+    assert whiles(batched, rows, ns, los, ns) == 2
+    assert whiles(
+        lambda w, n: one(w, ld, nc, n, jnp.bool_(False), jnp.int32(0), n),
+        pd_s, n_s) == 2
+    # ``check_window`` (the served step, check-bam) keeps its one
+    # full-capacity stage: no loop over blocks.
+    assert whiles(
+        lambda w, n: ck.check_window(
+            w, ld, nc, n, jnp.bool_(False), reads_to_check=10, funnel=True),
+        pd_s, n_s) == 0

@@ -21,7 +21,7 @@ from spark_bam_tpu.parallel.mesh import make_mesh, mesh_steps
 from spark_bam_tpu.parallel.stream_mesh import (
     _ShardedStream, count_reads_sharded,
 )
-from spark_bam_tpu.tpu.checker import PAD
+from spark_bam_tpu.tpu.checker import LANE_BLOCK, PAD, lane_capacity
 from spark_bam_tpu.tpu.stream_check import StreamChecker
 
 MEMBER = 0xFF00  # htslib's payload: what the generators fill every member to
@@ -77,6 +77,17 @@ def _assert_host_fed(counters: dict, hists: dict) -> None:
     assert set(counters) | set(hists) <= NAMES
 
 
+def _assert_lanes_sized_by_survivors(counters: dict, stages: int,
+                                     kernel_window: int) -> None:
+    """The funnel's evidence over ``stages`` lane stages (windows or rows):
+    whole blocks that hold the survivors, fewer than the ``w // 32`` lanes
+    a stage each would run at its full capacity."""
+    survivors, lanes = counters["funnel.survivors"], counters["funnel.lanes"]
+    assert 0 < survivors <= lanes
+    assert lanes % min(LANE_BLOCK, lane_capacity(kernel_window)) == 0
+    assert lanes < stages * lane_capacity(kernel_window)
+
+
 @pytest.mark.parametrize("backend", ["cpu", "tpu"])
 def test_one_device_count_is_host_fed_and_measured(
         generated, backend, monkeypatch):
@@ -101,6 +112,9 @@ def test_one_device_count_is_host_fed_and_measured(
     # The host inflater's own evidence: every byte of the file, once.
     assert counters["inflate.bytes"] == index["uncompressed_bytes"]
     assert counters["inflate.windows"] == windows
+    # The funnel screened every byte and ran the lanes its survivors need.
+    assert counters["funnel.positions"] == index["uncompressed_bytes"]
+    _assert_lanes_sized_by_survivors(counters, windows, checker.kernel_window)
 
 
 def test_one_device_count_carries_the_halo_and_paces(short48):
@@ -174,6 +188,10 @@ def test_mesh_count_of_eight_rows_lands_two_a_device(
     assert counters["mesh.h2d_bytes"] == steps * 4 * width
     # The file's bytes plus the halo each row re-inflates past its span.
     assert counters["inflate.bytes"] > index["uncompressed_bytes"]
+    # The same evidence the one-device stream gives, summed over the rows
+    # (each a lane stage of one block here: a 512 KiB row holds no more).
+    assert 0 < counters["funnel.survivors"] <= counters["funnel.lanes"]
+    assert counters["funnel.lanes"] == 8 * lane_capacity(st.kernel_window)
 
 
 def test_a_short_last_step_is_dealt_over_the_devices(short48):
@@ -208,6 +226,7 @@ def test_mesh_count_of_long_reads_is_host_fed(generated):
     _assert_host_fed(counters, hists)
     assert counters["mesh.rows"] == stats["rows"] >= 3
     assert hists["mesh.step_device_ms"] == stats["steps"]
+    _assert_lanes_sized_by_survivors(counters, stats["rows"], 2 << 20)
 
 
 # ------------------------------------------- the one-device count is exact
@@ -277,7 +296,7 @@ def test_one_device_count_populates_the_funnel_stats(tmp_path):
     checker.count_reads()
     stats = checker.funnel_stats
     assert stats is not None and stats["screened"] > 0
-    assert 0 < stats["survivors"] <= stats["screened"]
+    assert 0 < stats["survivors"] <= stats["lanes"] <= stats["screened"]
 
 
 # ------------------------------------------------ the mesh count is exact
@@ -333,7 +352,8 @@ def test_the_shares_add_up(mesh_file):
     batches = st.row_batches()
     try:
         for args, _done, c0 in batches:
-            count, escapes = np.asarray(step(*args)).tolist()
+            count, escapes, _survivors, _lanes = np.asarray(
+                step(*args)).tolist()
             assert escapes == 0
             lo = int(st.flat_starts[c0])
             hi = lo + int(st.sizes[c0])

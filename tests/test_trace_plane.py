@@ -103,7 +103,12 @@ def test_scopes_are_in_the_lowered_program(program, lower, scopes):
         assert any(f"{before}{scope}{after}/" in text for before, after in
                    (("/", ""), ('"', ""), ("(", ")"))), scope
     if program == "count_window":
-        assert "check/flags/" in text and "check/chain_walk/" in text
+        # Stage 0 directly under ``check``; the lane stage's three scopes
+        # inside its block loops (``bench/readers/trace_scope.py`` finds a
+        # scope anywhere on the path).
+        assert "check/flags/" in text and "check/funnel/" in text
+        for scope in ("funnel", "flags", "chain_walk"):
+            assert f"check/while/body/{scope}/" in text
 
 
 def test_the_programs_cover_the_catalogue():
@@ -214,7 +219,8 @@ def _count_schedule(monkeypatch, path):
                         threading.current_thread().name))
             return {"count": _Scalar(log, f"count{k}", 1),
                     "esc_count": _Scalar(log, f"esc{k}"),
-                    "survivors": _Scalar(log, f"surv{k}", 2)}
+                    "survivors": _Scalar(log, f"surv{k}", 2),
+                    "lanes": _Scalar(log, f"lanes{k}", 4)}
         return kernel
 
     monkeypatch.setattr(checker, "make_count_window", make_kernel)
