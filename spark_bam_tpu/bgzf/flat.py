@@ -34,6 +34,10 @@ class FlatView:
     block_flat: np.ndarray    # int64, flat offset of each block's first byte
     file_total: int | None    # total flat size of the *whole* file, if known
     at_eof: bool = False      # view ends exactly at the file's uncompressed end
+    # Where the view was inflated into a buffer of the caller's (``into`` of
+    # ``inflate_blocks``): that buffer, and the offset of ``data`` in it.
+    frame: np.ndarray | None = None
+    lead: int = 0
 
     @property
     def size(self) -> int:
@@ -197,12 +201,16 @@ def inflate_blocks(
     file_total: int | None = None,
     at_eof: bool = False,
     threads: int = 8,
+    into: tuple[np.ndarray, int] | None = None,
 ) -> FlatView:
     """Inflate a run of blocks into one flat buffer.
 
     Prefers the native table-driven decoder (~1.3-2x zlib, single call for the
     whole run); falls back to parallel host zlib when the native library is
-    unavailable.
+    unavailable. ``into`` = ``(frame, lead)``: inflate into ``frame`` from
+    offset ``lead`` on (the caller's buffer, with room behind for the run
+    and 8 bytes) and zero what is left of it, instead of allocating; the
+    view then names the frame.
     """
     usizes = np.array([m.uncompressed_size for m in metas], dtype=np.int64)
     block_flat = np.zeros(len(metas), dtype=np.int64)
@@ -211,7 +219,9 @@ def inflate_blocks(
     total = int(usizes.sum())
     # 8 bytes of slack: the native decoder's word copies may overrun a
     # block's end (never the allocation); the view handed out is exact.
-    out_alloc = np.empty(total + 8, dtype=np.uint8)
+    frame, lead = into if into is not None else (None, 0)
+    out_alloc = (np.empty(total + 8, dtype=np.uint8) if frame is None
+                 else frame[lead: lead + total + 8])
     out = out_alloc[:total]
     with obs.span("inflate.window", blocks=len(metas), bytes=total) as sp:
         native = _inflate_fast_native(
@@ -231,6 +241,8 @@ def inflate_blocks(
             else:
                 for i, m in enumerate(metas):
                     _inflate_one(ch, m, out, int(block_flat[i]))
+        if frame is not None:
+            frame[lead + total:] = 0
         sp.set(engine="native" if native else "zlib")
     obs.count("inflate.windows")
     obs.count("inflate.blocks", len(metas))
@@ -241,6 +253,8 @@ def inflate_blocks(
         block_flat,
         file_total,
         at_eof or (file_total is not None and total == file_total),
+        frame,
+        lead,
     )
 
 

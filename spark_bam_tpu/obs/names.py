@@ -49,6 +49,8 @@ NAMES = frozenset({
     # check — record-boundary checker
     "check.accepted", "check.candidates", "check.count_escape_retries",
     "check.defer_resolved", "check.defer_retries", "check.deferred",
+    "check.escape_candidates", "check.escape_overflows",
+    "check.escape_resolve", "check.escape_resolved",
     "check.escaped", "check.find_record_start", "check.flush",
     "check.fused_demotions", "check.pace",
     "check.window", "check.windows",

@@ -583,8 +583,7 @@ def _count_steps(st: "_ShardedStream", config: Config, progress):
             obs.count("funnel.lanes", int(totals[3]))
         if progress is not None:
             progress(steps, done, st.total)
-        # Pathological guard (mirrors count_reads' window-4 escape
-        # checkpoint): if nearly every step escapes, the halo is
+        # Pathological guard: if nearly every step escapes, the halo is
         # undersized for this input — stop burning device work and take
         # the whole-file exact path.
         return _mostly_dirty(dirty, steps)

@@ -467,6 +467,13 @@ def lane_capacity(w: int) -> int:
 #: the worst window the format allows.
 LANE_BLOCK = 16384
 
+#: Slots of the count's escape list: the window positions of the owned lanes
+#: whose chains ran past the buffer, which the stream resolves on the host
+#: from the bytes the following windows bring. A real window holds tens (the
+#: ``reads_to_check`` records before one longer than the halo, and that
+#: one); a window with more reports an overflow and the pass starts over.
+ESCAPE_LIST = 64
+
 
 def _flag_stage(
     padded, lengths, num_contigs, n, at_eof,
@@ -726,7 +733,7 @@ def _check_lanes(
 def _count_lanes(
     padded, lengths, num_contigs, n, at_eof, lo, own,
     reads_to_check: int, flags_impl: str, pallas_interpret: bool,
-    block: int = LANE_BLOCK,
+    block: int = LANE_BLOCK, escapes: int = 0,
 ):
     """The funnelled check reduced to the count's scalars, its lane stage
     sized by the window's own survivors.
@@ -741,7 +748,13 @@ def _count_lanes(
     verdict is what the full-capacity stage gives; a window over
     ``lane_capacity`` runs every block and reports the overflow as that
     stage does. Under ``vmap`` the trip count is the rows' maximum (a row's
-    blocks beyond its own hold dead lanes only)."""
+    blocks beyond its own hold dead lanes only).
+
+    With ``escapes`` slots the walk also lists WHICH owned lanes escaped
+    (``esc_pos``: their window positions, ascending, -1 beyond them): each
+    block holds its lanes' positions and results already, so nothing
+    position-wide is added. Escapes beyond the slots are counted in ``esc``
+    and not listed."""
     w = padded.shape[0] - PAD
     S = _flag_stage(
         padded, lengths, num_contigs, n, at_eof,
@@ -785,7 +798,7 @@ def _count_lanes(
     flags_lookup = _funnel_lookup(F, F_deep)
 
     def walk_block(k, carry):
-        count, esc = carry
+        count, esc, *listed = carry
         with jax.named_scope("chain_walk"):
             cand = lax.dynamic_slice(cands, (k * block,), (block,))
             live = cand >= 0
@@ -795,17 +808,36 @@ def _count_lanes(
         )["res"]
         with jax.named_scope("chain_walk"):
             own_lane = live & (cand >= lo) & (cand < own)
-            return (
-                count + jnp.sum(own_lane & (res == 1)),
-                esc + jnp.sum(own_lane & (res == 2)),
-            )
+            counted = count + jnp.sum(own_lane & (res == 1))
+            escaped = own_lane & (res == 2)
+            if listed:
+                listed = [_list_escapes(listed[0], esc, escaped, cand)]
+            return counted, esc + jnp.sum(escaped), *listed
 
-    count, esc = lax.fori_loop(
-        0, blocks, walk_block, (_I32(0), _I32(0)))
-    return {
+    listed = (jnp.full(escapes, -1, dtype=_I32),) if escapes else ()
+    count, esc, *listed = lax.fori_loop(
+        0, blocks, walk_block, (_I32(0), _I32(0), *listed))
+    out = {
         "count": count, "esc": esc, "res0": S["res0"], "overflow": overflow,
         "n_survivors": n_survivors, "lanes": blocks * _I32(block),
     }
+    if listed:
+        out["esc_pos"] = listed[0]
+    return out
+
+
+def _list_escapes(esc_pos, before, escaped, cand):
+    """``esc_pos`` with one block's escaped lanes appended: slot ``j`` takes
+    the position of the block's escape of rank ``j - before`` (``before``
+    escapes are listed already; blocks come in rank order, so the list is
+    ascending). Word-level ranks over the block's lanes and a gather of
+    ``len(esc_pos)``: no scatter, nothing as wide as the block but the bit
+    packing."""
+    rank = jnp.arange(esc_pos.shape[0], dtype=_I32) - before
+    lane = _ranked_positions(_rank_table(escaped), rank)
+    mine = (rank >= 0) & (lane >= 0)
+    return jnp.where(
+        mine, jnp.take(cand, jnp.maximum(lane, 0), mode="clip"), esc_pos)
 
 
 @functools.partial(
@@ -900,41 +932,56 @@ def _scatter_lanes(L: dict, w: int) -> dict:
 def _count_funnel(
     padded, lengths, num_contigs, n, at_eof, lo, own,
     reads_to_check: int, flags_impl: str, pallas_interpret: bool,
-    block: int = LANE_BLOCK,
+    block: int = LANE_BLOCK, escapes: int = 0,
 ):
     """``count_window`` under the funnel. Scatter-free reduction: verdicts
     live only on survivor lanes (non-survivors never reach res==1) and
     escapes split cleanly into prefilter-rejected positions (res0==2) plus
     lane escapes, so both scalars reduce over lanes without materializing
-    the (W,) arrays."""
+    the (W,) arrays.
+
+    The escape list (``escapes`` slots) holds the lane escapes alone. A
+    position stage 0 rejects escapes only with nothing but boundary flags
+    set, which under the prefilter means the buffer ends within its fixed
+    block: the last 35 bytes, never owned under a halo of 36 bytes or more
+    (the default is 4 MiB). Should one be owned all the same, the window
+    reports ``esc_overflow`` like one whose escapes outnumber the slots or
+    whose survivors outnumber the lanes, rather than lose it."""
     w = padded.shape[0] - PAD
     i = jnp.arange(w, dtype=_I32)
     m = (i >= lo) & (i < own)
     L = _count_lanes(
         padded, lengths, num_contigs, n, at_eof, lo, own,
-        reads_to_check, flags_impl, pallas_interpret, block,
+        reads_to_check, flags_impl, pallas_interpret, block, escapes,
     )
     with jax.named_scope("reduce"):
-        esc = jnp.sum(m & (L["res0"] == 2)) + L["esc"]
+        esc0 = jnp.sum(m & (L["res0"] == 2))
+        esc = esc0 + L["esc"]
         count = jnp.where(L["overflow"], 0, L["count"])
         esc = jnp.where(L["overflow"], jnp.sum(m), esc)
-    return {
+    out = {
         "count": count, "esc_count": esc, "survivors": L["n_survivors"],
         "lanes": L["lanes"],
     }
+    if escapes:
+        out["esc_pos"] = L["esc_pos"]
+        out["esc_overflow"] = (
+            L["overflow"] | (esc0 > 0) | (L["esc"] > escapes))
+    return out
 
 
 @functools.partial(
     jax.jit,
     static_argnames=(
-        "reads_to_check", "window", "flags_impl", "pallas_interpret", "funnel"
+        "reads_to_check", "window", "flags_impl", "pallas_interpret", "funnel",
+        "escapes",
     ),
 )
 def count_window(
     padded, lengths, num_contigs, n, at_eof, lo, own,
     reads_to_check: int = 10, window: int | None = None,
     flags_impl: str = "xla", pallas_interpret: bool = False,
-    funnel: bool = False,
+    funnel: bool = False, escapes: int = 0,
 ):
     """check_window fused with its owned-span count reduction.
 
@@ -942,16 +989,22 @@ def count_window(
     and XLA
     dead-code-eliminates everything the two scalars don't need — the
     fail_mask/reads_* scatters and the per-position arrays themselves.
-    (Escapes are rare; the caller falls back to the exact spans path when
-    ``esc_count`` is ever nonzero.) Beside the two scalars: ``survivors``
-    (stage 0's) and ``lanes``, the lanes the lane stage ran — under the
-    funnel as many blocks as hold the survivors (``_count_lanes``), without
-    it the window's whole capacity.
+    Beside the two scalars: ``survivors`` (stage 0's) and ``lanes``, the
+    lanes the lane stage ran — under the funnel as many blocks as hold the
+    survivors (``_count_lanes``), without it the window's whole capacity.
+
+    Escapes are rare, and a caller that cannot resolve them starts over on
+    the exact spans path when ``esc_count`` is ever nonzero (the mesh step:
+    ``escapes`` 0, the program it always was). The one-chip stream asks for
+    ``escapes`` slots and gets ``esc_pos``, the window positions of the
+    owned escaped candidates in ascending order (-1 beyond them), and
+    ``esc_overflow``: more of them than slots, or a window that escaped
+    whole, which is the case that still starts over.
     """
     if funnel:
         return _count_funnel(
             padded, lengths, num_contigs, n, at_eof, lo, own,
-            reads_to_check, flags_impl, pallas_interpret,
+            reads_to_check, flags_impl, pallas_interpret, escapes=escapes,
         )
     w = padded.shape[0] - PAD
     i = jnp.arange(w, dtype=_I32)
@@ -963,12 +1016,18 @@ def count_window(
         funnel=funnel,
     )
     with jax.named_scope("reduce"):
-        return {
+        out = {
             "count": jnp.sum(m & res["verdict"]),
             "esc_count": jnp.sum(m & res["escaped"]),
             "survivors": res["survivors"],
             "lanes": _I32(lane_capacity(w)),
         }
+        if escapes:
+            (at,) = jnp.nonzero(
+                m & res["escaped"], size=escapes, fill_value=-1)
+            out["esc_pos"] = at.astype(_I32)
+            out["esc_overflow"] = out["esc_count"] > escapes
+        return out
 
 
 def _pallas_interpret_for(impl: str) -> bool:
@@ -982,7 +1041,7 @@ def _pallas_interpret_for(impl: str) -> bool:
 
 def make_count_window(
     window: int, reads_to_check: int = 10, flags_impl: str = "xla",
-    funnel: bool = False,
+    funnel: bool = False, escapes: int = 0,
 ):
     """A jit-compiled fused count kernel for fixed ``window`` size."""
     pallas_interpret = _pallas_interpret_for(flags_impl)
@@ -992,7 +1051,7 @@ def make_count_window(
             padded, lengths, num_contigs, n, at_eof, lo, own,
             reads_to_check=reads_to_check, window=window,
             flags_impl=flags_impl, pallas_interpret=pallas_interpret,
-            funnel=funnel,
+            funnel=funnel, escapes=escapes,
         )
 
     return run

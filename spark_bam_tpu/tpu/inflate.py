@@ -12,7 +12,9 @@ way to inflate.
 
 ``InflatePipeline`` overlaps the stages: worker threads inflate up to
 ``depth`` window groups ahead while the consumer feeds the previous window
-to the device. ``DeviceObserver`` times a window's H2D and program off the
+to the device; for the count they inflate into ``FRAMES``, host buffers
+that are the windows' padded operands and outlive the pass.
+``DeviceObserver`` times a window's H2D and program off the
 feeding thread under a live registry, and ``maybe_profile_window`` captures
 one steady window for ``--profile``.
 """
@@ -33,6 +35,7 @@ from spark_bam_tpu import obs
 log = logging.getLogger(__name__)
 
 import jax
+import numpy as np
 
 from spark_bam_tpu.bgzf.block import Metadata
 from spark_bam_tpu.bgzf.flat import FlatView, inflate_blocks
@@ -200,10 +203,51 @@ def window_plan(metas: list[Metadata], window_uncompressed: int) -> list[list[Me
     return groups
 
 
+class _Frames:
+    """The host buffers the count's windows are inflated into and put from,
+    kept from one window and one pass to the next.
+
+    A window's buffer is 36 MiB. Allocated anew each window it is fresh
+    memory as often as not (glibc hands back what it freed, or maps new
+    pages, by the state of its heap), and writing fresh memory costs page
+    faults: 26 ms a window against 4 on a v5e host, which at the head of a
+    pass is time the chip waits (``PERF.md`` section 6, PR 31). ``KEEP`` is
+    what a pass has in flight: ``depth`` being inflated, one being put, the
+    count's ring."""
+
+    KEEP = 6
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._free: list = []
+
+    def take(self, size: int):
+        with self._lock:
+            for i, frame in enumerate(self._free):
+                if len(frame) == size:
+                    return self._free.pop(i)
+        return np.empty(size, dtype=np.uint8)
+
+    def give(self, frame) -> None:
+        """Hand back a frame nothing reads any more (its window's program
+        has run). Frames of another size than the newest go."""
+        with self._lock:
+            same = [f for f in self._free if len(f) == len(frame)]
+            self._free = same[: self.KEEP - 1] + [frame]
+
+
+FRAMES = _Frames()
+
+
 class InflatePipeline:
     """Double-buffered host-inflate → device-window stream: worker threads
     inflate up to ``depth`` window groups ahead of the consumer, so the
-    inflate of window k+1 overlaps the device's check of window k."""
+    inflate of window k+1 overlaps the device's check of window k. The
+    first group has the host to itself until the consumer comes back for
+    the second: nothing runs on the device before the first window is put
+    and dispatched, and the groups behind it are not needed for a window's
+    time yet (their workers' Python, which holds the GIL, would otherwise
+    stand in the feeding thread's way just then)."""
 
     def __init__(
         self,
@@ -231,6 +275,17 @@ class InflatePipeline:
         self.depth = max(1, depth)
 
     def __iter__(self) -> Iterator[FlatView]:
+        return self._views(None)
+
+    def frames(self, lead: int, size: int) -> Iterator[FlatView]:
+        """The same views, each inflated into a frame of ``size`` bytes
+        (``FRAMES``) from offset ``lead`` on, zeros behind it: room in
+        front for the window's carry, and the padded operand in place. The
+        consumer gives a view's ``frame`` back once nothing reads it. A
+        group must fit: ``lead`` + its bytes + 8 within ``size``."""
+        return self._views((lead, size))
+
+    def _views(self, framed: tuple[int, int] | None) -> Iterator[FlatView]:
         ch = open_channel(self.path)
         if hasattr(ch, "set_plan"):
             # Remote data plane (core/remote_plan.py): the block table IS
@@ -241,15 +296,33 @@ class InflatePipeline:
                 (m.start, m.start + m.compressed_size) for m in self.metas
             )
         pool = ThreadPoolExecutor(max_workers=self.depth)
+        # Set once the consumer is back for the second view: by then the
+        # first window is on its way to the device.
+        first_taken = threading.Event()
 
-        def produce(group):
+        def produce(i):
+            if i:
+                first_taken.wait()
+            group = self.groups[i]
+            into = None
+            if framed is not None:
+                lead, size = framed
+                total = sum(m.uncompressed_size for m in group)
+                if lead + total + 8 > size:
+                    # The native inflater writes where it is told to.
+                    raise ValueError(
+                        f"a group of {total} bytes does not fit a frame of "
+                        f"{size} with {lead} in front")
+                into = (FRAMES.take(size), lead)
             return inflate_blocks(
-                ch, group, file_total=self.total, threads=self.threads
+                ch, group, file_total=self.total, threads=self.threads,
+                into=into,
             )
 
         try:
             pending = [
-                pool.submit(produce, g) for g in self.groups[: self.depth]
+                pool.submit(produce, i)
+                for i in range(min(self.depth, len(self.groups)))
             ]
             for i in range(len(self.groups)):
                 fut = pending.pop(0)
@@ -266,15 +339,15 @@ class InflatePipeline:
                         view = fut.result()
                     nxt = i + self.depth
                     if nxt < len(self.groups):
-                        pending.append(
-                            pool.submit(produce, self.groups[nxt])
-                        )
+                        pending.append(pool.submit(produce, nxt))
                 if i == len(self.groups) - 1:
                     view.at_eof = True
                 yield view
+                first_taken.set()
         finally:
             # Wait for in-flight produce calls: they hold zero-copy views of
             # the mmap, and closing it under them raises BufferError (or
             # worse). Queued-but-unstarted work is cancelled.
+            first_taken.set()
             pool.shutdown(wait=True, cancel_futures=True)
             ch.close()

@@ -38,12 +38,18 @@ spill decode — the load workload).
 
 ``count_reads()`` never materializes per-position arrays on host: each
 window runs one fused kernel whose owned-span count reduces on-chip, the
-scalars accumulate on device, and a handful of integers cross the wire
-per ~2^30 positions (reference workload: count-reads,
+sums accumulate on device, and a handful of integers cross the wire
+per ~2^30 positions, beside one escape count a window, read where the
+loop paces anyway (reference workload: count-reads,
 docs/benchmarks.md:53-59). On every backend its windows reach the device
 as inflated bytes: the pipeline's workers run the native inflate ahead of
 the feeding thread, one H2D a window carries the window, and the device
 program is the check and its count reduction (``checker.count_window``).
+The count's escapes are a list of positions from that program, not
+per-position arrays: ``_CountEscapes`` defers them into the same side
+buffer, fed from the host buffers of the windows still in flight, and
+adds what resolves to the total, so a record longer than the halo costs
+its own candidates and not a second pass over the file.
 """
 
 from __future__ import annotations
@@ -89,12 +95,24 @@ def halo_windows(pipeline, halo: int, header_end: int):
       buffer owns through EOF;
     - ``lo`` clamps the owned span's start past the BAM header, so header
       bytes are never counted as record starts.
+
+    A view that was inflated into a frame (``InflatePipeline.frames``: room
+    for a halo in front of its bytes) takes the carry there, and ``buf`` is
+    the frame from the carry's start on: nothing else is copied.
     """
     carry = np.empty(0, dtype=np.uint8)
     base_next = 0
     for view in pipeline:
         base = base_next
-        buf = np.concatenate([carry, view.data]) if len(carry) else view.data
+        frame = getattr(view, "frame", None)
+        if not len(carry):
+            buf = view.data
+        elif frame is None:
+            buf = np.concatenate([carry, view.data])
+        else:
+            start = view.lead - len(carry)
+            frame[start: view.lead] = carry
+            buf = frame[start: view.lead + len(view.data)]
         n = len(buf)
         at_eof = view.at_eof
         own_end = n if at_eof else max(n - halo, 0)
@@ -110,6 +128,66 @@ def _reduce_span(verdict, escaped, lo, hi):
     i = jnp.arange(verdict.shape[0], dtype=jnp.int32)
     m = (i >= lo) & (i < hi)
     return jnp.sum(m & verdict), jnp.sum(m & escaped)
+
+
+class _CountEscapes:
+    """The count's escaped candidates: the owned positions a window's
+    program listed because their chains ran past its buffer, resolved on
+    the host from bytes the stream inflates anyway.
+
+    ``settle`` takes the oldest window of the count's ring once its escape
+    count is read. The ring still holds that window's host buffer and those
+    of the windows dispatched since, so a candidate is deferred with the
+    bytes from its own position on (``_Deferred``, the spans path's side
+    buffer), grown by one later window at a time and walked after each,
+    until nothing is pending. ``starts`` counts the record starts among the
+    resolved.
+    ``overflowed`` says the pass must start over: the program could not
+    list the window's escapes, or one keeps asking for lookahead beyond
+    ``(reads_to_check + 2) * max_read_size`` (the mesh's cap,
+    ``parallel/stream_mesh._RowGrowth``: adversarial size fields)."""
+
+    def __init__(self, lengths: np.ndarray, config: Config):
+        self.deferred = StreamChecker._Deferred(lengths, config.reads_to_check)
+        self.cap_bytes = (config.reads_to_check + 2) * config.max_read_size
+        self.starts = 0
+        self.overflowed = False
+
+    def settle(self, escaped: int, ring: list, at_eof: bool) -> None:
+        """Pop ``ring[0]``, whose window reported ``escaped`` owned escapes;
+        ``at_eof``: the newest window of the ring ends the file."""
+        out, base, buf = ring.pop(0)
+        deferred = self.deferred
+        if not escaped and not len(deferred):
+            return
+        with obs.span("check.escape_resolve", escaped=escaped) as span:
+            if escaped:
+                if bool(out["esc_overflow"]):
+                    self.overflowed = True
+                    return
+                at = np.asarray(out["esc_pos"])[:escaped].astype(np.int64)
+                deferred.add(base + at, buf, base)
+                obs.count("check.escape_candidates", escaped)
+            # One later window at a time, and no further than the pending
+            # chains ask: most end in the window after their own.
+            held = len(deferred.buf)
+            if not ring:
+                self._walk(at_eof)
+            for i, (_out, later_base, later) in enumerate(ring):
+                if not len(deferred):
+                    break
+                deferred.extend(later, later_base)
+                held = max(held, len(deferred.buf))
+                self._walk(at_eof and i == len(ring) - 1)
+            span.set(bytes=held)
+            # What is left starts at the earliest unresolved candidate: a
+            # chain that this much lookahead did not settle.
+            self.overflowed = len(deferred.buf) > self.cap_bytes
+
+    def _walk(self, at_eof: bool) -> None:
+        for _pos, (verdicts,) in self.deferred.resolve(at_eof, ("verdict",)):
+            self.starts += int(verdicts.sum())
+            obs.count("check.escape_resolved", len(verdicts))
 
 
 class StreamChecker:
@@ -446,49 +524,88 @@ class StreamChecker:
 
         On device, each window runs ONE fused kernel (``count_window``: the
         check and its owned-span count reduction), and the per-window
-        scalars accumulate *on device* — nothing crosses the wire until
-        EOF. The windows arrive as inflated bytes: the pipeline's workers
+        scalars accumulate *on device* — no count crosses the wire until a
+        flush. The windows arrive as inflated bytes: the pipeline's workers
         inflate ``depth`` groups ahead (``inflate.stall_ms`` is this
-        thread's wait for them), this thread lays the carry and the window
-        into a zero-padded buffer and puts it (``inflate.h2d``), and
-        dispatches (``inflate.device_kernel``). A pacing sync on a
-        two-windows-old scalar (``check.pace``) bounds in-flight windows
-        (and HBM) without a transfer, so this thread runs up to
-        ``ring_depth`` windows ahead of the device and the device waits for
-        it at the head of a pass only. Under a live registry a
+        thread's wait for them), each into a frame that is the window's
+        padded operand too (``InflatePipeline.frames``: room for the carry
+        in front, zeros behind, kept from window to window and from pass to
+        pass); this thread copies the 4 MiB carry in front, puts the frame
+        (``inflate.h2d``) and dispatches (``inflate.device_kernel``). A
+        pacing read of the two-windows-old escape count (``check.pace``:
+        four bytes) bounds in-flight windows (and HBM), so this thread runs
+        up to ``ring_depth`` windows ahead of the device and the device
+        waits for it at the head of a pass only. Under a live registry a
         ``DeviceObserver`` takes ``inflate.device_ms`` off this thread,
         which dispatches and waits exactly as it does with the registry
-        off. If any owned candidate escaped (chains beyond the halo —
-        ultra-long reads), the exact spans() path re-runs the file with
-        full deferral; on real data with the default halo this never
-        triggers.
+        off.
+
+        Owned candidates whose chains ran past their window's buffer (a
+        record longer than the halo: ultra-long reads) come back from that
+        read as a short list of positions. They resolve on this thread
+        (``check.escape_resolve``: the native tri-state walk over the bytes
+        the following windows bring, exact at EOF) and what resolves to a
+        record start joins the total: the pass costs its escaped candidates
+        and not the file. Only a window that overflows the list or its
+        lanes, or a candidate that asks for more lookahead than
+        ``(reads_to_check + 2) * max_read_size``, sends the whole file
+        through the exact ``spans()`` path (``check.count_escape_retries``).
         """
         if not self.use_device:
             return self._count_via_spans()
-        from spark_bam_tpu.tpu.checker import PAD, make_count_window
-        from spark_bam_tpu.tpu.inflate import DeviceObserver
+        from spark_bam_tpu.tpu.checker import (
+            ESCAPE_LIST, PAD, make_count_window,
+        )
+        from spark_bam_tpu.tpu.inflate import FRAMES, DeviceObserver
 
         funnel = self.config.funnel_enabled()
         kernel = make_count_window(
             self.kernel_window, self.config.reads_to_check,
-            flags_impl=self._flags_impl(), funnel=funnel,
+            flags_impl=self._flags_impl(), funnel=funnel, escapes=ESCAPE_LIST,
         )
         lens_dev, nc = self._device_inputs()
         w = self.kernel_window
 
         total = 0
-        acc = None  # the windows' scalars since the last flush, on device
+        acc = None  # the windows' sums since the last flush, on device
         windows = 0
         chunk = 0
         screened = 0
         flush_every = self.flush_every
-        escaped = False
-        # pacing: keep ≤ ring_depth windows' scalars un-synced
+        # Windows dispatched and not yet read, oldest first, each with the
+        # host buffer its escapes would resolve from: at most ring_depth + 1.
         ring: list = []
+        escapes = _CountEscapes(self.lengths, self.config)
         observer = DeviceObserver.maybe()
-        rows = halo_windows(self.pipeline, self.halo, self.header_end_abs)
+        # Every window is inflated into a frame that is its padded operand
+        # too (carry in front, zeros behind); ``views`` and ``held`` name
+        # the frames of the row in hand and of the ring's windows.
+        views: list = []
+        held: list = []
+
+        def tap():
+            for view in self.pipeline.frames(self.halo, self.halo + w + PAD):
+                views.append(view)
+                yield view
+
+        rows = halo_windows(tap(), self.halo, self.header_end_abs)
+
+        def settle(escaped: int, at_eof: bool):
+            """The ring's oldest window: its escapes, then its frame back."""
+            escapes.settle(escaped, ring, at_eof)
+            FRAMES.give(held.pop(0))
+
+        def fold():
+            """The device sums since the last fold, into the host's."""
+            nonlocal total, acc, chunk, screened
+            total += int(acc["count"])
+            if funnel:
+                self._funnel_add(
+                    screened, int(acc["survivors"]), int(acc["lanes"]))
+            acc, chunk, screened = None, 0, 0
+
         try:
-            while not escaped:
+            while not escapes.overflowed:
                 with obs.span("check.window", window=windows):
                     # The pipeline's wait for the host inflate
                     # (``inflate.stall_ms``) is inside this ``next``.
@@ -498,13 +615,15 @@ class StreamChecker:
                     buf, base, own_end, lo, at_eof = row
                     n = len(buf)
                     t_put = time.perf_counter()
+                    view = views.pop(0)
                     with obs.span("inflate.h2d", bytes=w + PAD):
-                        # Fresh buffer per window (never mutated after
-                        # dispatch): safe under async dispatch even when
-                        # jnp.asarray aliases zero-copy on the CPU backend.
-                        padded = np.zeros(w + PAD, dtype=np.uint8)
-                        padded[:n] = buf
-                        operand = jnp.asarray(padded)
+                        # Not written again until the window has left the
+                        # ring (its program has run): safe under async
+                        # dispatch even when jnp.asarray aliases zero-copy
+                        # on the CPU backend.
+                        start = view.lead + len(view.data) - n
+                        operand = jnp.asarray(
+                            view.frame[start: start + w + PAD])
                     obs.count("inflate.h2d_bytes", w + PAD)
                     t_dispatch = time.perf_counter()
                     with obs.span("inflate.device_kernel"):
@@ -516,65 +635,49 @@ class StreamChecker:
                     if observer is not None:
                         observer.window(
                             operand, t_put, out["count"], t_dispatch)
-                    acc = out if acc is None else {
-                        k: acc[k] + out[k] for k in acc}
+                    acc = {k: out[k] if acc is None else acc[k] + out[k]
+                           for k in ("count", "survivors", "lanes")}
                     screened += n
-                    ring.append(out["count"])
+                    ring.append((out, base, buf))
+                    held.append(view.frame)
                     if len(ring) > self.ring_depth:
                         with obs.span("check.pace"):
-                            ring.pop(0).block_until_ready()
+                            escaped = int(ring[0][0]["esc_count"])
+                        settle(escaped, at_eof)
                     windows += 1
                     chunk += 1
                     obs.count("check.windows")
                     if self.progress is not None:
                         self.progress(windows, base + own_end, self.total)
-                    # One early escape checkpoint (window 4): escape-prone
-                    # inputs (ultra-long reads vs this halo) abort to the
-                    # exact path after ~4 windows instead of after a whole
-                    # flush interval (up to 2^30 positions of doomed device
-                    # work). Costs a single extra device sync per file; the
-                    # steady-state policy stays flush-aligned so the device
-                    # is not synced per window.
-                    if windows == 4 or chunk >= flush_every:
+                    if chunk >= flush_every:
                         with obs.span("check.flush"):
-                            if int(acc["esc_count"]):
-                                escaped = True
-                            elif chunk >= flush_every:
-                                total += int(acc["count"])
-                                if funnel:
-                                    self._funnel_add(
-                                        screened, int(acc["survivors"]),
-                                        int(acc["lanes"]))
-                                acc = None
-                                chunk = 0
-                                screened = 0
-            if not escaped and acc is not None:
-                with obs.span("check.flush"):
-                    if int(acc["esc_count"]):
-                        escaped = True
-                    else:
-                        total += int(acc["count"])
-                        if funnel:
-                            self._funnel_add(
-                                screened, int(acc["survivors"]),
-                                int(acc["lanes"]))
+                            fold()
+            # The stream's end: the windows still in flight, oldest first,
+            # then the sums.
+            with obs.span("check.flush"):
+                while ring and not escapes.overflowed:
+                    settle(int(ring[0][0]["esc_count"]), True)
+                if acc is not None and not escapes.overflowed:
+                    fold()
         finally:
             # Closing the generator shuts the pipeline's pool and channel
             # before the exact path (if any) reopens the file.
             rows.close()
             if observer is not None:
                 observer.close()
-        if escaped:
-            # Rare exact path (chains outran the halo — ultra-long reads):
-            # the spans path resolves every deferral bit-exactly. Suppress
-            # progress so consumers don't see the counters restart.
+        if escapes.overflowed:
+            # The pass that starts over: the spans path resolves every
+            # deferral bit-exactly. Suppress progress so consumers don't
+            # see the counters restart.
+            obs.count("check.escape_overflows")
             obs.count("check.count_escape_retries")
             saved, self.progress = self.progress, None
             try:
                 return self._count_via_spans()
             finally:
                 self.progress = saved
-        return total
+        assert not len(escapes.deferred), "escapes must resolve by EOF"
+        return total + escapes.starts
 
     def count_reads_resident(
         self, chunk_windows: int | None = None,
@@ -590,7 +693,8 @@ class StreamChecker:
         XLA program — the round-trip is paid once per ~``chunk_windows``
         windows. The first chunk is small (``first_chunk_windows``) so
         escape-prone inputs (ultra-long reads vs this halo) abort to the
-        exact path early, mirroring ``count_reads``'s window-4 checkpoint.
+        exact path early: the scan sums its escapes and does not list them,
+        so here, unlike in ``count_reads``, one escape starts the file over.
 
         Chunk device buffers are K·w+PAD bytes with K bucketed to a power
         of two (dummy rows own nothing), bounding recompiles to one per

@@ -89,10 +89,13 @@ def test_count_window_xla_funnel_compiles_at_32mib(chip):
     on every backend (the windows arrive inflated). Its temporaries are the
     check's alone, 0.92 GiB since the survivors are materialized once and
     the lane stage runs in blocks (2.72 GiB before, the bound of PR 30's
-    issue): the two ``while`` loops are that stage."""
-    from spark_bam_tpu.tpu.checker import make_count_window
+    issue): the two ``while`` loops are that stage. Compiled as the stream
+    runs it, with the escape list (PR 31: 0.894 GiB against 0.908 without,
+    chipless), whose 64 slots ride in the walk's loop."""
+    from spark_bam_tpu.tpu.checker import ESCAPE_LIST, make_count_window
 
-    kernel = jax.jit(make_count_window(WINDOW, 10, "xla", funnel=True))
+    kernel = jax.jit(make_count_window(
+        WINDOW, 10, "xla", funnel=True, escapes=ESCAPE_LIST))
     compiled = kernel.lower(
         chip((WINDOW + PAD,), jnp.uint8), chip((CMAX,), jnp.int32),
         *_scalars(chip, jnp.int32, jnp.int32, jnp.bool_, jnp.int32,
@@ -102,6 +105,7 @@ def test_count_window_xla_funnel_compiles_at_32mib(chip):
     text = compiled.as_text()
     assert "gather" in text  # the lane walk: a real program
     assert text.count(" while(") >= 2  # deep-check blocks, then walk blocks
+    assert f"s32[{ESCAPE_LIST}]" in text  # the list, carried by the walk
 
 
 def _count_step_shapes(shape, repl, devices: int, rows: int):

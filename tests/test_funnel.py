@@ -486,3 +486,113 @@ def test_vmapped_count_window_loops_to_the_rows_maximum(
         lambda w, n: ck.check_window(
             w, ld, nc, n, jnp.bool_(False), reads_to_check=10, funnel=True),
         pd_s, n_s) == 0
+
+
+# ------------------------------------------------ the count's escape list
+
+def _owned_escapes(full_stage, pd, ld, nc, n, lo, own):
+    """``check_window``'s escaped positions inside ``[lo, own)``."""
+    r = full_stage(pd, ld, nc, n, jnp.bool_(False))
+    i = np.arange(W1)
+    return np.flatnonzero((i >= lo) & (i < own) & np.asarray(r["escaped"]))
+
+
+def _listed(out, r=None):
+    at = np.asarray(out["esc_pos"] if r is None else out["esc_pos"][r])
+    assert np.all(at[np.argmax(at < 0):] < 0) or np.all(at >= 0)
+    return at[at >= 0]
+
+
+#: Owned ends of a 1 MiB window that is not the file's last: none of its
+#: tail owned (no escape), the usual case, and all but the last 64 bytes
+#: (every candidate whose chain reaches the end).
+OWNED = ((0, W1 // 2), (1000, W1 - (64 << 10)), (0, W1 - 64))
+
+
+@pytest.mark.parametrize("funnel", [True, False])
+@pytest.mark.parametrize("name", ["wgs-short", "longread-hifi"])
+def test_the_escape_list_is_check_windows_owned_escapes(
+        generated_windows, full_stage, name, funnel):
+    (pd, n), (ld, nc) = generated_windows[name]
+    kernel = ck.make_count_window(W1, 10, funnel=funnel, escapes=64)
+    for lo, own in OWNED:
+        want = _owned_escapes(full_stage, pd, ld, nc, n, lo, own)
+        out = kernel(pd, ld, nc, n, jnp.bool_(False), jnp.int32(lo),
+                     jnp.int32(own))
+        assert int(out["esc_count"]) == len(want) <= 64, (lo, own)
+        assert np.array_equal(_listed(out), want), (lo, own)
+        assert not bool(out["esc_overflow"])
+        # The scalars are the program's without the list.
+        plain = ck.make_count_window(W1, 10, funnel=funnel)(
+            pd, ld, nc, n, jnp.bool_(False), jnp.int32(lo), jnp.int32(own))
+        assert "esc_pos" not in plain
+        assert all(int(out[k]) == int(plain[k]) for k in plain)
+    assert len(_owned_escapes(full_stage, pd, ld, nc, n, *OWNED[-1])) >= 9
+
+
+@pytest.mark.parametrize("block", (512, 4096))
+def test_the_escape_list_spans_lane_blocks_and_reports_its_overflow(
+        generated_windows, full_stage, block):
+    """Short reads, 512 lanes a block: the escapes of the window's tail lie
+    in its last blocks, after blocks with none. With fewer slots than
+    escapes the list holds the first of them and says so; an owned span
+    that reaches the buffer's last 35 bytes (stage 0's own escapes, which
+    the list does not hold) is an overflow too."""
+    import functools
+
+    import jax
+
+    (pd, n), (ld, nc) = generated_windows["wgs-short"]
+    lo, own = OWNED[-1]
+    want = _owned_escapes(full_stage, pd, ld, nc, n, lo, own)
+    assert len(want) > 8
+    for slots, overflow in ((64, False), (8, True), (1, True)):
+        fn = jax.jit(functools.partial(
+            ck._count_funnel, reads_to_check=10, flags_impl="xla",
+            pallas_interpret=False, block=block, escapes=slots))
+        out = fn(pd, ld, nc, n, jnp.bool_(False), jnp.int32(lo),
+                 jnp.int32(own))
+        assert int(out["esc_count"]) == len(want)
+        assert np.array_equal(_listed(out), want[:slots])
+        assert bool(out["esc_overflow"]) is overflow
+    out = fn(pd, ld, nc, n, jnp.bool_(False), jnp.int32(0), n)
+    tail = _owned_escapes(full_stage, pd, ld, nc, n, 0, int(n))
+    assert int(out["esc_count"]) == len(tail) > len(want)
+    assert bool(out["esc_overflow"])
+
+
+def test_a_window_over_its_lane_capacity_is_an_escape_overflow(full_stage):
+    pd, n = _window_of(_planted(CAPACITY + 1, 24), W1)
+    ld, nc = _planted_lens()
+    out = ck.make_count_window(W1, 10, funnel=True, escapes=64)(
+        pd, ld, nc, n, jnp.bool_(False), jnp.int32(0), n)
+    assert int(out["esc_count"]) == int(n) and bool(out["esc_overflow"])
+
+
+def test_the_vmapped_escape_list_is_each_rows_own(
+        generated_windows, full_stage):
+    """Rows under ``vmap`` (a device with several rows): each row's list is
+    its own owned escapes, a padding row's is empty."""
+    import functools
+
+    import jax
+
+    (pd_s, n_s), (ld, nc) = generated_windows["wgs-short"]
+    (pd_l, n_l), _ = generated_windows["longread-hifi"]
+    empty = jnp.zeros_like(pd_s)
+    rows = jnp.stack([pd_s, empty, pd_l])
+    ns = jnp.stack([n_s, jnp.int32(0), n_l])
+    owns = jnp.stack([n_s - 64, jnp.int32(0), n_l - (64 << 10)])
+    one = functools.partial(
+        ck.count_window, reads_to_check=10, funnel=True, escapes=64)
+    out = jax.jit(jax.vmap(
+        lambda w, n, own: one(
+            w, ld, nc, n, jnp.bool_(False), jnp.int32(0), own)))(
+        rows, ns, owns)
+    for r, (pd, n) in enumerate(((pd_s, n_s), (empty, jnp.int32(0)),
+                                 (pd_l, n_l))):
+        want = _owned_escapes(full_stage, pd, ld, nc, n, 0, int(owns[r]))
+        assert int(out["esc_count"][r]) == len(want)
+        assert np.array_equal(_listed(out, r), want)
+        assert not bool(out["esc_overflow"][r])
+    assert len(_listed(out, 0)) >= 9 and len(_listed(out, 1)) == 0

@@ -118,9 +118,10 @@ def test_one_device_count_is_host_fed_and_measured(
 
 
 def test_one_device_count_carries_the_halo_and_paces(short48):
-    """Eight windows with a carried halo: the pacing wait and the window-4
-    checkpoint are on the feeding thread under their names, and the windows'
-    owned spans still add up to the index."""
+    """Eight windows with a carried halo: the pacing read is on the feeding
+    thread under its name, the one flush is the stream's end (the window-4
+    checkpoint went when every window's escape count came to be read at
+    the pace), and the windows' owned spans still add up to the index."""
     path, index = short48
     config = Config(window_size=6 * MEMBER, halo_size=64 << 10)
     checker = StreamChecker(path, config)
@@ -130,7 +131,8 @@ def test_one_device_count_carries_the_halo_and_paces(short48):
     assert counters["check.windows"] == counters["inflate.windows"] == 8
     assert counters["inflate.bytes"] == index["uncompressed_bytes"]
     assert hists["check.pace"] == 8 - config.ring_depth
-    assert hists["check.flush"] == 2  # window 4's escape checkpoint, EOF
+    assert hists["check.flush"] == 1
+    assert not counters.get("check.escape_candidates")
     assert hists["inflate.device_ms"] == 8
     # The same count with no registry: the same dispatches and waits.
     assert StreamChecker(path, config).count_reads() == got
@@ -272,9 +274,10 @@ ONE_DEVICE = {
             t, 14, contigs=(("chr1", 5_000_000), ("chr2", 3_000_000)),
             dup_rate=0.1),
         Config(), dict(window_uncompressed=64 << 10, halo=16 << 10)),
-    # Chains beyond the halo (long reads, a tiny halo) escape to the exact
-    # spans path: never a wrong count.
-    "escape-falls-back-exact": (
+    # Chains beyond the halo (long reads, a tiny halo) escape in every
+    # window and resolve on the host: never a wrong count, never a pass
+    # that starts over.
+    "escapes-resolve-on-the-host": (
         _longread, Config(),
         dict(window_uncompressed=256 << 10, halo=16 << 10)),
 }
@@ -287,8 +290,13 @@ def test_one_device_count_matches_the_numpy_engine(case, tmp_path):
     run = StreamChecker(path, config, **cfg).count_reads
     got, counters, _hists = _observed(run)
     assert got == _numpy_count(path, **cfg) > 0
-    if case == "escape-falls-back-exact":
-        assert counters["check.count_escape_retries"] == 1
+    assert not counters.get("check.count_escape_retries")
+    # Whatever escaped (the small windows of "multi-contig-and-carry" have
+    # a few too) resolved on the host.
+    assert (counters.get("check.escape_candidates", 0)
+            == counters.get("check.escape_resolved", 0))
+    if case == "escapes-resolve-on-the-host":
+        assert counters["check.escape_candidates"] >= 1
 
 
 def test_one_device_count_populates_the_funnel_stats(tmp_path):

@@ -90,10 +90,12 @@ def test_count_reads_streaming(bam1):
     )
 
 
-def test_count_reads_device_escape_fallback(tmp_path):
+def test_count_reads_device_escapes_resolve_without_a_fallback(tmp_path):
     """Device count path with a halo far smaller than the chain span: the
-    on-device escape counter must trip and the exact spans fallback must
-    still return the true count (ultra-long-read robustness)."""
+    on-device escape counter trips in every window, the listed candidates
+    resolve on the host from the following windows' bytes, and the true
+    count comes back WITHOUT the whole-file spans fallback (ultra-long-read
+    robustness at the cost of the escaped candidates, not of the file)."""
     import numpy as np
 
     from spark_bam_tpu.bam.header import BamHeader, ContigLengths
@@ -127,8 +129,10 @@ def test_count_reads_device_escape_fallback(tmp_path):
     checker = StreamChecker(
         path, Config(), window_uncompressed=256 << 10, halo=64 << 10
     )
-    # The fallback must actually run (guard against a future config change
-    # silently un-exercising this path).
+    # Escapes must actually happen (guard against a future config change
+    # silently un-exercising this path), and the fallback must not run.
+    from tests.test_host_fed_count import _observed
+
     calls = []
     orig = StreamChecker._count_via_spans
 
@@ -138,10 +142,14 @@ def test_count_reads_device_escape_fallback(tmp_path):
 
     StreamChecker._count_via_spans = spy
     try:
-        assert checker.count_reads() == 30
+        got, counters, _spans = _observed(checker.count_reads)
     finally:
         StreamChecker._count_via_spans = orig
-    assert calls, "escape fallback was not exercised"
+    assert got == 30
+    assert not calls, "the whole-file fallback ran"
+    assert (counters["check.escape_candidates"]
+            == counters["check.escape_resolved"] >= 20)  # of 30 records
+    assert not counters.get("check.count_escape_retries")
 
 
 def test_count_reads_flush_chunks(bam1):
