@@ -212,12 +212,14 @@ def _misc_at(p, n, pos):
 # ---------------------------------------------------------------------------
 # Candidate funnel: stage 0 = cheap prefilter over every position, stage 1 =
 # compact survivors and deep-check only those. The prefilter evaluates ONLY
-# fixed-block-derivable bits (remaining bounds, refID/pos ranges, name_len
-# sanity, implied-size consistency) — no name-byte scans, no cigar scans — so
-# it is provably a superset filter: every bit it can set is also set by the
-# full pass at the same position, hence full-pass survivors (F == 0) always
-# pass the prefilter. Deep-only bits (name charset/termination, cigar ops,
-# empty-mapped) are evaluated once at candidate positions via K-sized gathers
+# fixed-block-derivable bits (remaining bounds, refID ranges, positions
+# against the longest contig, name_len sanity, implied-size consistency) — no
+# name-byte scans, no cigar scans, no lookup in the contig table — so it is
+# provably a superset filter: every bit it can set is also set by the full
+# pass at the same position, hence full-pass survivors (F == 0) always pass
+# the prefilter. Deep-only tests (a position against its OWN contig's length,
+# name charset/termination, cigar ops, empty-mapped) are evaluated once at
+# candidate positions via K-sized gathers
 # against word-level hierarchical tables (full-width cumsums cost ~60 ms per
 # 8 MB window on CPU XLA; packed-u32 popcount prefixes cost ~3 ms).
 
@@ -227,9 +229,15 @@ _U32 = jnp.uint32
 def _prefilter_flags(p, lengths, num_contigs, n):
     """Stage-0 funnel pass: the fixed-block-derivable subset of the 19 bits.
 
-    Mirrors the corresponding prefix of ``_compute_flags`` exactly,
-    including the ``tooFewFixedBlockBytes`` *overwrite* (not OR) — so at
-    few-fixed positions the prefilter mask equals the full mask."""
+    A subset of ``_compute_flags``'s mask at every position, bit for bit:
+    the same tests on the same fields, but a position is held against ONE
+    bound, the longest contig, and not its own contig's length (a gather
+    costs per index: a lookup at every position was most of a window).
+    ``pos > max(len)`` implies ``pos > len[idx]``, so every bit set here
+    the full pass sets too; where only the exact test rejects, the position
+    survives and ``_deep_flags_at`` looks its length up. The
+    ``tooFewFixedBlockBytes`` *overwrite* (not OR) is kept — at few-fixed
+    positions the prefilter mask equals the full mask."""
     w = p.shape[0] - PAD
     u = _i32_at(p, w)
     i32 = lax.bitcast_convert_type(u, jnp.int32)
@@ -243,16 +251,16 @@ def _prefilter_flags(p, lengths, num_contigs, n):
     next_ref_pos = i32[28: w + 28]
 
     c = num_contigs
-    cmax = lengths.shape[0]
-    len_r = jnp.take(lengths, jnp.clip(ref_idx, 0, cmax - 1), mode="clip")
-    len_n = jnp.take(lengths, jnp.clip(next_ref_idx, 0, cmax - 1), mode="clip")
+    # No valid index without a contig: the bound is then never compared.
+    contig = jnp.arange(lengths.shape[0], dtype=_I32) < c
+    len_max = jnp.max(jnp.where(contig, lengths, _I32(0)))
     F = _ref_pos_bits(
-        ref_idx, ref_pos, c, len_r,
+        ref_idx, ref_pos, c, len_max,
         BIT["negativeReadIdx"], BIT["tooLargeReadIdx"],
         BIT["negativeReadPos"], BIT["tooLargeReadPos"],
     )
     F = F | _ref_pos_bits(
-        next_ref_idx, next_ref_pos, c, len_n,
+        next_ref_idx, next_ref_pos, c, len_max,
         BIT["negativeNextReadIdx"], BIT["tooLargeNextReadIdx"],
         BIT["negativeNextReadPos"], BIT["tooLargeNextReadPos"],
     )
@@ -872,10 +880,12 @@ def check_window(
     compact, and the deep bits are evaluated once at candidate positions
     only. Verdicts (and hence record-start positions) are identical to
     ``funnel=False``; the documented differences are that ``fail_mask`` at
-    prefilter-rejected positions carries only the prefilter bits, and
-    ``exact`` may be True where the full pass reports a (definitively
-    failing) lane as inexact — both only affect forensic projections, which
-    run with the funnel off (Config.funnel="auto").
+    prefilter-rejected positions carries only the prefilter bits (a subset
+    of the full mask: no deep bit, and the two ``tooLarge*ReadPos`` bits
+    only past the longest contig; at a position the prefilter passes it is
+    the full mask), and ``exact`` may be True where the full pass reports a
+    (definitively failing) lane as inexact — both only affect forensic
+    projections, which run with the funnel off (Config.funnel="auto").
 
     Returns dict of (W,) arrays: verdict, fail_mask, reads_parsed,
     reads_before, exact, escaped — plus the () int32 ``survivors`` count

@@ -4,10 +4,11 @@ Two kernels, verified bit-exact against the engines they mirror:
 
 ``prefilter_flags_kernel`` — stage 0 of the candidate funnel: only the
 flag bits derivable from the fixed 36-byte block (``remaining`` bounds,
-refID/pos range, name-length sanity), no name-byte scans and no cigar
-scans, so the slab halo shrinks from ``PAD`` to one DMA tile and the
-254-way unroll disappears entirely.  Positions it cannot reject go on to
-the deep pass in tpu/checker.py.
+refID range, positions against the longest contig, name-length sanity),
+no name-byte scans, no cigar scans and no per-lane contig lookup, so the
+slab halo shrinks from ``PAD`` to one DMA tile and the 254-way unroll
+disappears entirely.  Positions it cannot reject go on to the deep pass
+in tpu/checker.py, which looks the contig's own length up.
 
 ``full_flags_kernel`` — ALL 19 flag bits of the checker error model
 (check/flags.py; reference full/Checker.scala:17-198) computed in-kernel,
@@ -253,25 +254,17 @@ def _prefilter_flags_kernel(p_hbm, lengths_ref, nc_ref, n_ref, out_ref, slab, se
 
     abs_i = base + _iota(t)
 
-    # --- contig-length lookup without gather: scalar loop over SMEM ------
-    def contig_body(j, carry):
-        len_r, len_n = carry
-        lj = lengths_ref[j]
-        len_r = jnp.where(ref_idx == j, lj, len_r)
-        len_n = jnp.where(next_ref_idx == j, lj, len_n)
-        return len_r, len_n
+    # --- the longest contig bounds every position (checker's stage 0; the
+    # exact length is looked up at the survivors): a scalar max over SMEM --
+    len_max = lax.fori_loop(
+        0, c, lambda j, m: jnp.maximum(m, lengths_ref[j]), _I32(0))
 
-    len_r, len_n = lax.fori_loop(
-        0, c, contig_body,
-        (jnp.zeros(t, dtype=_I32), jnp.zeros(t, dtype=_I32)),
-    )
-
-    def ref_bits(idx, pos, len_at, b_neg_idx, b_large_idx, b_neg_pos, b_large_pos):
+    def ref_bits(idx, pos, b_neg_idx, b_large_idx, b_neg_pos, b_large_pos):
         neg_idx = idx < -1
         large_idx = (~neg_idx) & (idx >= c)
         neg_pos = pos < -1
         idx_ok = (~neg_idx) & (~large_idx)
-        large_pos = idx_ok & (~neg_pos) & (idx >= 0) & (pos > len_at)
+        large_pos = idx_ok & (~neg_pos) & (idx >= 0) & (pos > len_max)
         return (
             jnp.where(neg_idx, _I32(b_neg_idx), _I32(0))
             | jnp.where(large_idx, _I32(b_large_idx), _I32(0))
@@ -280,12 +273,12 @@ def _prefilter_flags_kernel(p_hbm, lengths_ref, nc_ref, n_ref, out_ref, slab, se
         )
 
     F = ref_bits(
-        ref_idx, ref_pos, len_r,
+        ref_idx, ref_pos,
         BIT["negativeReadIdx"], BIT["tooLargeReadIdx"],
         BIT["negativeReadPos"], BIT["tooLargeReadPos"],
     )
     F = F | ref_bits(
-        next_ref_idx, next_ref_pos, len_n,
+        next_ref_idx, next_ref_pos,
         BIT["negativeNextReadIdx"], BIT["tooLargeNextReadIdx"],
         BIT["negativeNextReadPos"], BIT["tooLargeNextReadPos"],
     )

@@ -73,6 +73,22 @@ def _device_bytes(compiled) -> int:
             + ma.output_size_in_bytes)
 
 
+def _position_wide_lookups(text: str, positions: int) -> list:
+    """The ``s32`` gathers of a compiled program that return a value for
+    every one of ``positions``: what the contig-length lookup of stage 0
+    was until PR 32 (two of them, 225 ms each a 32 MiB window on the chip,
+    whatever the 1,024-entry table held: a gather costs per index)."""
+    import math
+    import re
+
+    found = []
+    for line in text.splitlines():
+        m = re.search(r"= s32\[([\d,]*)\]\S* gather\(", line)
+        if m and math.prod(int(d) for d in m.group(1).split(",") if d) >= positions:
+            found.append(line.strip()[:120])
+    return found
+
+
 def _mesh_shapes(topo, n_devices: int):
     mesh = Mesh(np.array(topo.devices[:n_devices]), ("data",))
     rows, repl = NamedSharding(mesh, P("data")), NamedSharding(mesh, P())
@@ -87,11 +103,13 @@ def _mesh_shapes(topo, n_devices: int):
 def test_count_window_xla_funnel_compiles_at_32mib(chip):
     """``jit_count_window``: the whole device program of the one-chip count
     on every backend (the windows arrive inflated). Its temporaries are the
-    check's alone, 0.92 GiB since the survivors are materialized once and
+    check's alone, 0.91 GiB since the survivors are materialized once and
     the lane stage runs in blocks (2.72 GiB before, the bound of PR 30's
     issue): the two ``while`` loops are that stage. Compiled as the stream
-    runs it, with the escape list (PR 31: 0.894 GiB against 0.908 without,
-    chipless), whose 64 slots ride in the walk's loop."""
+    runs it, with the escape list, whose 64 slots ride in the walk's loop
+    (chipless: 0.894 GiB with it against 0.908 without at PR 31; 0.9085
+    since PR 32 took stage 0's two lookups, the funnel's tables being
+    scheduled before the peak, the survivors' word packing)."""
     from spark_bam_tpu.tpu.checker import ESCAPE_LIST, make_count_window
 
     kernel = jax.jit(make_count_window(
@@ -106,6 +124,7 @@ def test_count_window_xla_funnel_compiles_at_32mib(chip):
     assert "gather" in text  # the lane walk: a real program
     assert text.count(" while(") >= 2  # deep-check blocks, then walk blocks
     assert f"s32[{ESCAPE_LIST}]" in text  # the list, carried by the walk
+    assert not _position_wide_lookups(text, WINDOW)
 
 
 def _count_step_shapes(shape, repl, devices: int, rows: int):
@@ -161,6 +180,7 @@ def test_count_step_compiles_for_four_chips_at_one_row_a_chip(topo, chip):
     text = compiled.as_text()
     assert "all-reduce" in text  # the psum, and nothing gathers the rows
     assert "all-gather" not in text and "all-to-all" not in text
+    assert not _position_wide_lookups(text, WINDOW)
 
 
 # ----------------------------------------------------------------- small
@@ -179,6 +199,7 @@ def test_serve_step_compiles_at_serve_config_defaults(topo, chip):
         shape((b,), jnp.int32),
     ).compile()
     assert _device_bytes(compiled) < HBM
+    assert not _position_wide_lookups(compiled.as_text(), b * cfg.window)
 
 
 def test_aggregate_reduction_compiles(chip):

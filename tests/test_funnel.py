@@ -596,3 +596,233 @@ def test_the_vmapped_escape_list_is_each_rows_own(
         assert np.array_equal(_listed(out, r), want)
         assert not bool(out["esc_overflow"][r])
     assert len(_listed(out, 0)) >= 9 and len(_listed(out, 1)) == 0
+
+
+# ------------------------------------------------------------------------
+# Stage 0 holds a position against the LONGEST contig (PR 32); the exact
+# ``pos > len[idx]`` test is the deep flags', at the survivors. A forged
+# fixed block that passes every other prefilter check, names a SHORT contig
+# and lies past its end (but not past the longest's) is therefore a stage-0
+# survivor now; its verdict, and that of every chain that steps onto it, is
+# the full pass's all the same.
+
+SHORT, LONG = 1_000, 1_000_000
+
+
+def _block(ref=(-1, -1), next_ref=(-1, -1)):
+    """A 38-byte unmapped record named ``r``: remaining 34, no cigar, no
+    sequence. With both refs ``(-1, -1)`` it passes all 19 checks."""
+    rec = np.zeros(38, dtype=np.uint8)
+    i32 = rec[:36].view("<i4")
+    i32[0] = 34
+    i32[1], i32[2] = ref
+    rec[12] = 2            # l_read_name
+    rec[18] = 4            # flag: unmapped
+    i32[6], i32[7] = next_ref
+    rec[36] = ord("r")
+    return rec
+
+
+def _forged_window(field: str, short_idx: int):
+    """``(data, forged, stepped_from, beyond)``: five real records, a forged
+    one (the chains of the five step onto it), twelve real ones, then two
+    forged blocks on their own and one whose position lies past the longest
+    contig too, which stage 0 still rejects."""
+    def forged(pos):
+        return _block(**{field: (short_idx, pos)})
+
+    parts, at, marks = [], 0, []
+
+    def put(rec, mark=False):
+        nonlocal at
+        if mark:
+            marks.append(at)
+        parts.append(rec)
+        at += len(rec)
+
+    for _ in range(5):
+        put(_block())
+    stepped_from = at - 38
+    put(forged(SHORT + 4_000), mark=True)
+    for _ in range(12):
+        put(_block())
+    for pos in (SHORT + 1, LONG):
+        put(np.zeros(64, dtype=np.uint8))
+        put(forged(pos), mark=True)
+    put(np.zeros(64, dtype=np.uint8))
+    beyond = at
+    put(forged(LONG + 1))
+    for _ in range(3):
+        put(_block())
+    return np.concatenate(parts), np.array(marks), stepped_from, beyond
+
+
+def _lens(values, cmax=1024, fill=0):
+    lens = np.full(cmax, fill, dtype=np.int32)
+    lens[: len(values)] = values
+    return jnp.asarray(lens)
+
+
+_LARGE_POS = {"ref": "tooLargeReadPos", "next_ref": "tooLargeNextReadPos"}
+
+
+@pytest.mark.parametrize("program", ["count_window", "check_window"])
+@pytest.mark.parametrize("at_eof", [True, False])
+@pytest.mark.parametrize("field", ["ref", "next_ref"])
+def test_a_position_past_a_short_contig_survives_stage_0_and_fails_deep(
+        kernels, field, at_eof, program):
+    from spark_bam_tpu.check.flags import BIT
+
+    data, forged, stepped_from, beyond = _forged_window(field, short_idx=1)
+    pd, n = _window_of(data)
+    ld, nc = _lens([LONG, SHORT]), jnp.int32(2)
+    bit = BIT[_LARGE_POS[field]]
+
+    pre = np.asarray(ck._prefilter_flags(pd, ld, nc, n))
+    full = np.asarray(ck._compute_flags(pd, ld, nc, n))
+    _assert_superset(pd, ld, nc, n)
+    assert not pre[forged].any()             # stage-0 survivors ...
+    assert np.all(full[forged] & bit)        # ... that only the lookup rejects
+    assert pre[beyond] & bit                 # past the longest: still stage 0's
+
+    on, off = kernels
+    if program == "check_window":
+        a = on(pd, ld, nc, n, jnp.bool_(at_eof))
+        b = off(pd, ld, nc, n, jnp.bool_(at_eof))
+        for k in PARITY_KEYS:
+            np.testing.assert_array_equal(
+                np.asarray(a[k]), np.asarray(b[k]), err_msg=k)
+        verdict = np.asarray(a["verdict"])
+        assert not verdict[forged].any() and not verdict[beyond]
+        assert int(a["survivors"]) == int(np.sum(pre[: int(n)] == 0))
+        # The chain of the record before the first forged block steps onto
+        # it and fails there, one read in, on the block's own deep mask.
+        for r in (a, b):
+            assert not np.asarray(r["verdict"])[stepped_from]
+            assert int(np.asarray(r["reads_before"])[stepped_from]) == 1
+            assert int(np.asarray(r["fail_mask"])[stepped_from]) == int(
+                full[forged[0]])
+        # At the forged blocks the funnel's mask is the full pass's now.
+        np.testing.assert_array_equal(
+            np.asarray(a["fail_mask"])[forged], full[forged])
+    else:
+        for lo, own in ((0, int(n)), (38, int(forged[1]) + 1)):
+            a, b = (
+                ck.make_count_window(W, 10, funnel=f)(
+                    pd, ld, nc, n, jnp.bool_(at_eof), jnp.int32(lo),
+                    jnp.int32(own))
+                for f in (True, False)
+            )
+            assert int(a["count"]) == int(b["count"]), (lo, own)
+            assert int(a["esc_count"]) == int(b["esc_count"]), (lo, own)
+        assert int(a["survivors"]) == int(np.sum(pre[: int(n)] == 0))
+        assert int(a["survivors"]) >= int(b["survivors"]) + len(forged)
+
+
+@pytest.mark.parametrize("header", ["no_contigs", "last_is_longest"])
+def test_the_stage_0_bound_on_edge_headers(kernels, header):
+    """``num_contigs`` 0 (no index is valid, whatever the table's padding
+    holds: the bound is never compared) and a header whose LAST contig is
+    the longest (the reduction reaches the table's last valid entry)."""
+    from spark_bam_tpu.check.flags import BIT
+
+    on, off = kernels
+    if header == "no_contigs":
+        data, forged, _, beyond = _forged_window("ref", short_idx=1)
+        ld, nc = _lens([], fill=7), jnp.int32(0)
+    else:
+        data, forged, _, beyond = _forged_window("ref", short_idx=0)
+        ld, nc = _lens([SHORT, LONG], fill=2 * LONG), jnp.int32(2)
+    pd, n = _window_of(data)
+    _assert_superset(pd, ld, nc, n)
+    pre = np.asarray(ck._prefilter_flags(pd, ld, nc, n))
+    if header == "no_contigs":
+        assert np.all(pre[forged] & BIT["tooLargeReadIdx"])
+    else:
+        # Survivors up to the LAST contig's length, and not up to the
+        # padding's: the bound is the header's, nothing beyond it.
+        assert not pre[forged].any()
+        assert pre[beyond] & BIT["tooLargeReadPos"]
+    for at_eof in (True, False):
+        a = on(pd, ld, nc, n, jnp.bool_(at_eof))
+        b = off(pd, ld, nc, n, jnp.bool_(at_eof))
+        for k in PARITY_KEYS:
+            np.testing.assert_array_equal(
+                np.asarray(a[k]), np.asarray(b[k]), err_msg=f"{at_eof} {k}")
+        # At EOF the last three records' chains end on the file's edge.
+        assert np.asarray(a["verdict"]).sum() >= (3 if at_eof else 0)
+        c, d = (
+            ck.make_count_window(W, 10, funnel=f)(
+                pd, ld, nc, n, jnp.bool_(at_eof), jnp.int32(0), n)
+            for f in (True, False)
+        )
+        assert int(c["count"]) == int(d["count"]) == int(
+            np.asarray(a["verdict"]).sum())
+        assert int(c["esc_count"]) == int(d["esc_count"])
+
+
+# The lookup must not come back position-wide unnoticed: in the lowered text
+# of the funnelled programs no gather FROM the contig table is as wide as
+# the window (a gather costs per index: two of them were 77-92% of a window
+# on the chip). The table gets a length nothing else in the program has.
+_TABLE = 1021
+
+
+def _table_gathers(text: str, w: int):
+    """``(wide, narrow)``: result sizes of the gathers whose operand is the
+    contig table (``[_TABLE]``, or ``[rows, _TABLE]`` under ``vmap``)."""
+    import math
+    import re
+
+    wide, narrow = [], []
+    for line in text.splitlines():
+        if "stablehlo.gather" not in line:
+            continue
+        m = re.search(r":\s*\(tensor<([^>]*)>.*\)\s*->\s*tensor<([^>]*)>",
+                      line)
+        assert m, line
+        operand = m.group(1).split("x")[:-1]
+        if not operand or operand[-1] != str(_TABLE):
+            continue
+        rows = math.prod(int(d) for d in operand[:-1])
+        size = math.prod(int(d) for d in m.group(2).split("x")[:-1])
+        (wide if size >= rows * w else narrow).append(size)
+    return wide, narrow
+
+
+@pytest.mark.parametrize("program", ["count_window", "serve_step"])
+def test_no_window_wide_gather_from_the_contig_table_under_the_funnel(
+        program):
+    import jax
+
+    w = 64 << 10
+    S = jax.ShapeDtypeStruct
+    scalars = [S((), jnp.int32), S((), jnp.int32), S((), jnp.bool_)]
+
+    def lowered(funnel):
+        if program == "count_window":
+            fn = jax.jit(ck.make_count_window(w, 10, funnel=funnel))
+            return fn.lower(
+                S((w + ck.PAD,), jnp.uint8), S((_TABLE,), jnp.int32),
+                *scalars, S((), jnp.int32), S((), jnp.int32)).as_text()
+        from spark_bam_tpu.parallel.mesh import (
+            make_mesh, make_shard_map_serve_step,
+        )
+
+        rows = 2
+        step = make_shard_map_serve_step(
+            make_mesh(jax.devices()[:1]), 10, funnel=funnel)
+        return step.lower(
+            S((rows, w + ck.PAD), jnp.uint8), S((rows,), jnp.int32),
+            S((rows,), jnp.bool_), S((rows,), jnp.int32),
+            S((rows,), jnp.int32), S((rows, _TABLE), jnp.int32),
+            S((rows,), jnp.int32)).as_text()
+
+    wide, narrow = _table_gathers(lowered(True), w)
+    assert not wide, wide
+    # The exact lookup is still there, at the lanes (one ``_take`` in the
+    # text serves ref and next_ref alike).
+    assert narrow and max(narrow) < w
+    # Without the funnel the full pass looks every position up, as it did.
+    wide_off, _ = _table_gathers(lowered(False), w)
+    assert wide_off
