@@ -314,6 +314,7 @@ def windows_per_device(path: Path, mesh) -> dict:
 def phase_mesh(compiles: Compiles, path: Path, reads: int) -> bool:
     """The four-chip path and what it is compared with — nothing else."""
     import jax
+    import numpy as np
 
     from spark_bam_tpu.core.config import Config
     from spark_bam_tpu.parallel.mesh import make_mesh
@@ -349,7 +350,7 @@ def phase_mesh(compiles: Compiles, path: Path, reads: int) -> bool:
             answers[label] = (n, conf)
         ok &= ph.ok
     same = answers["all"][0] == answers["one"][0] and all(
-        answers["all"][1][k] == answers["one"][1][k]
+        np.array_equal(answers["all"][1][k], answers["one"][1][k])
         for k in answers["all"][1] if k != "devices"
     )
     emit({"phase": "mesh_compare", "ok": same,

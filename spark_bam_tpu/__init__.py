@@ -36,6 +36,7 @@ __all__ = [
     "export",
     "aggregate",
     "count_reads_tpu",
+    "check_bam_tpu",
     "load_reads_columnar",
     "record_starts_streaming",
     "stream_read_batches",
@@ -58,7 +59,8 @@ _LAZY = {
     **{
         name: "spark_bam_tpu.load.tpu_load"
         for name in (
-            "count_reads_tpu", "load_reads_columnar", "record_starts",
+            "count_reads_tpu", "check_bam_tpu", "load_reads_columnar",
+            "record_starts",
             "record_starts_streaming", "stream_read_batches",
         )
     },

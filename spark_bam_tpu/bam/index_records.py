@@ -11,6 +11,9 @@ from __future__ import annotations
 import logging
 import os
 import time
+import warnings
+
+import numpy as np
 
 from spark_bam_tpu.bam.iterators import PosStream
 from spark_bam_tpu.core.channel import open_channel
@@ -36,6 +39,48 @@ def read_records_index(path) -> list[Pos]:
         for line in read_text(path).splitlines()
         if line.strip()
     ]
+
+
+def read_records_arrays(path, chunk_bytes: int = 64 << 20):
+    """The sidecar as two int64 arrays ``(block_pos, offset)``, in file
+    order: the text is parsed by numpy in chunks of ``chunk_bytes`` cut at
+    line ends, so no Python object is made a record (a 60 GB BAM's sidecar
+    holds 170 million lines). Blank lines and a missing or present trailing
+    newline are tolerated, as ``read_records_index`` tolerates them; a line
+    that is not ``blockPos,offset`` raises ``ValueError``."""
+    blocks, offsets = [], []
+    with open_channel(path) as ch:
+        pos, carry = 0, b""
+        while pos < ch.size or carry:
+            data = carry + bytes(ch.read_at(pos, min(chunk_bytes, ch.size - pos)))
+            pos = min(pos + chunk_bytes, ch.size)
+            cut = len(data) if pos >= ch.size else data.rfind(b"\n") + 1
+            data, carry = data[:cut], data[cut:]
+            if not data.strip():
+                continue  # blank lines alone: numpy reads them as a number
+            with warnings.catch_warnings():
+                # numpy warns of text it could not read to its end and
+                # returns what it had: the count below catches that.
+                warnings.simplefilter("ignore", DeprecationWarning)
+                values = np.fromstring(
+                    data.replace(b",", b" "), dtype=np.int64, sep=" ")
+            # One comma in every line that holds anything, two numbers a
+            # comma: commas and the starts of the runs between whitespace
+            # must alternate.
+            raw = np.frombuffer(data, dtype=np.uint8)
+            inside = raw > 32
+            starts = inside & ~np.concatenate(([False], inside[:-1]))
+            line_of_comma = np.cumsum(starts)[raw == 44]
+            if len(values) != 2 * len(line_of_comma) or not np.array_equal(
+                line_of_comma, np.arange(1, int(starts.sum()) + 1)
+            ):
+                raise ValueError(
+                    f"{path}: not a .records sidecar (blockPos,offset a line)")
+            blocks.append(values[0::2])
+            offsets.append(values[1::2])
+    if not blocks:
+        return np.empty(0, np.int64), np.empty(0, np.int64)
+    return np.concatenate(blocks), np.concatenate(offsets)
 
 
 def index_records(

@@ -101,6 +101,27 @@ def funnel_status_line(
     return f"funnel: on ({mode})"
 
 
+#: Uncompressed bytes from which ``auto`` takes the device engine (one
+#: kernel window): below it the NumPy engine resolves a file faster than a
+#: kernel compiles and launches.
+DEVICE_FROM_BYTES = 32 << 20
+
+
+def device_engine(backend: str, uncompressed_bytes: int) -> bool:
+    """Whether the eager checker of an input this large runs on the device:
+    a backend that names it, or ``auto`` on a TPU once the input outweighs a
+    kernel's compile and launch (small files resolve faster in the NumPy
+    engine). The one place that decides it: ``CheckerContext`` asks with
+    its view's size, ``check-bam -s`` with the block table's sum."""
+    if backend in ("tpu", "pallas"):
+        return True
+    if backend != "auto" or uncompressed_bytes < DEVICE_FROM_BYTES:
+        return False
+    import jax
+
+    return jax.default_backend() == "tpu"
+
+
 class CheckerContext:
     def __init__(
         self,
@@ -194,24 +215,13 @@ class CheckerContext:
         )
 
     def _use_tpu_backend(self) -> bool:
-        if self.config.backend == "numpy":
+        if not device_engine(self.config.backend, self.view.size):
             return False
-        if self.config.backend in ("tpu", "pallas"):
-            return True
         if self.config.backend == "auto":
-            # Device pays off once the input outweighs kernel compile+launch;
-            # small files resolve faster in the NumPy engine.
-            if self.view.size < (32 << 20):
-                return False
-            import jax
-
-            if jax.default_backend() != "tpu":
-                return False
             from spark_bam_tpu.core.platform import enable_compile_cache
 
             enable_compile_cache()
-            return True
-        return False
+        return True
 
     @cached_property
     def eager_verdict(self) -> np.ndarray:

@@ -10,6 +10,8 @@ windows, and re-checks the (rare) escaped candidates.
   known false calls — SURVEY.md §6 "spark-bam miscalls: 0 known")
 - ``count_reads_tpu``: boundary count — the count-reads workload with zero
   per-record host work
+- ``check_bam_tpu``: check-bam — the verdict at every position against the
+  ``.records`` truth, the confusion matrix and where the two disagree
 - ``load_reads_columnar``: ReadBatch columnar views of all (or
   interval/flag-filtered) records
 """
@@ -338,6 +340,37 @@ def count_reads_tpu(path, config: Config = Config()) -> int:
             n = StreamChecker(path, config).count_reads()
     obs.count("load.records", n)
     return n
+
+
+def check_bam_tpu(
+    path, config: Config = Config(), records_path=None, metas=None,
+    progress=None,
+) -> dict:
+    """check-bam on the device: the checker's verdict at EVERY uncompressed
+    position of ``path`` (header bytes included, as upstream's check-bam
+    has it) against the ``.records`` truth (``records_path``, by default
+    the sidecar beside the file), in O(window) host memory. The rows of a
+    step are inflated on the host, checked on the chips this process sees
+    (one or several: the same step, ``jit_confusion_step``) and compared
+    with the truth there (``parallel/stream_mesh.check_bam_sharded``).
+
+    Returns the confusion matrix (``true_positives``, ``false_positives``,
+    ``false_negatives``, ``true_negatives``, ``positions``, ``devices``)
+    and WHERE the two disagree: ``false_positive_positions`` and
+    ``false_negative_positions``, sorted absolute flat offsets (int64),
+    complete. A caller that has scanned the block table hands it on
+    (``metas``), and one that wants to hear of every step gives a
+    ``progress(steps done, positions done, positions in all)``:
+    ``check_bam_sharded``'s."""
+    from spark_bam_tpu.parallel.mesh import local_mesh
+    from spark_bam_tpu.parallel.stream_mesh import check_bam_sharded
+
+    with obs.span("load.check_bam", path=str(path)):
+        out = check_bam_sharded(
+            path, config, mesh=local_mesh(), records_path=records_path,
+            metas=metas, progress=progress)
+    obs.count("checkbam.passes")
+    return out
 
 
 def load_reads_columnar(

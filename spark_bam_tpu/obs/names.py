@@ -22,7 +22,7 @@ from __future__ import annotations
 #: Layer prefixes (the segment before the first dot). A new layer means
 #: a new subsystem — add it here alongside its names.
 LAYERS = frozenset({
-    "account", "agg", "bgzf", "cache", "chaos", "check", "cli",
+    "account", "agg", "bgzf", "cache", "chaos", "check", "checkbam", "cli",
     "columnar", "compress", "deflate", "fabric", "faults", "funnel",
     "guard", "inflate", "jobs", "load", "mesh", "progress", "remote",
     "sampler", "scrub", "serve", "slo", "timer", "transport", "ts",
@@ -54,6 +54,10 @@ NAMES = frozenset({
     "check.escaped", "check.find_record_start", "check.flush",
     "check.fused_demotions", "check.pace",
     "check.window", "check.windows",
+    # checkbam — check-bam against the .records truth on the mesh
+    # (load/tpu_load.check_bam_tpu, parallel/stream_mesh.check_bam_sharded)
+    "checkbam.list_overflows", "checkbam.mismatches", "checkbam.passes",
+    "checkbam.truth_load",
     # cli — root spans, one per subcommand (cli/main.py)
     "cli.aggregate", "cli.check-bam", "cli.check-blocks",
     "cli.compare-splits", "cli.compute-splits", "cli.count-reads",
@@ -116,7 +120,7 @@ NAMES = frozenset({
     "jobs.redone_bytes", "jobs.resumed", "jobs.rewrite", "jobs.scrub",
     "jobs.submitted",
     # load — partition execution
-    "load.count", "load.fleet_files", "load.parse", "load.partition",
+    "load.check_bam", "load.count", "load.fleet_files", "load.parse", "load.partition",
     "load.partitions", "load.record_starts", "load.records",
     "load.split_resolutions",
     # mesh — compiled-step registry + shard_map dispatch
@@ -171,10 +175,13 @@ NAMES = frozenset({
 #: by these, so they are a contract like the span names above. The window
 #: program (tpu/checker.count_window) and the steps of parallel/mesh.py
 #: have ``check`` with its children ``flags``, ``funnel`` and
-#: ``chain_walk``, and ``reduce`` (the count sums, a step's psum);
-#: agg/kernels.py has ``agg_reduce``.
+#: ``chain_walk``, and ``reduce`` (the count sums, a step's psum, the
+#: confusion step's sums and mismatch list); ``check_window`` (the served
+#: step, check-bam) has ``check/scatter`` besides, the lanes' verdicts
+#: scattered back over every position; agg/kernels.py has ``agg_reduce``.
 SCOPES = frozenset({
     "agg_reduce", "chain_walk", "check", "flags", "funnel", "reduce",
+    "scatter",
 })
 
 #: Names of the jitted programs the scopes live in: ``jit_<name>`` is the

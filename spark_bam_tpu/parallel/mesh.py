@@ -30,6 +30,12 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from spark_bam_tpu import obs
 from spark_bam_tpu.tpu.checker import PAD, check_window, count_window
 
+#: Slots of the confusion step's mismatch list, a row: the row-local
+#: positions of the owned positions where verdict and truth differ. A real
+#: file holds a handful in all (upstream's ``1.bam``: 5 in 1.6 M positions);
+#: a row with more reports its count and is re-derived on the host.
+MISMATCH_LIST = 64
+
 
 def make_mesh(devices=None, axis: str = "data") -> Mesh:
     devices = devices if devices is not None else jax.devices()
@@ -379,6 +385,31 @@ def make_shard_map_count_step(
     )
 
 
+def _list_positions(mask, slots: int, block: int = 1024):
+    """The first ``slots`` set positions of a position-wide ``mask`` in
+    ascending order (-1 beyond them) and the number set. Two levels: the
+    count of every ``block`` positions (a reduction over rows of ``block``
+    lanes: no 32-wide word packing, which a TPU lays out at four times its
+    bytes), their prefix, then for each slot the block holding it and the
+    rank inside that block's own ``block`` bits."""
+    if mask.shape[0] % block:
+        mask = jnp.pad(mask, (0, -mask.shape[0] % block))
+    w = mask.shape[0]
+    blocks = mask.reshape(w // block, block)
+    per_block = jnp.sum(blocks, axis=1, dtype=jnp.int32)
+    upto = jnp.cumsum(per_block)
+    k = jnp.arange(slots, dtype=jnp.int32)
+    b = jnp.minimum(
+        jnp.searchsorted(upto, k + 1, side="left").astype(jnp.int32),
+        w // block - 1)
+    inside = k - (jnp.take(upto, b) - jnp.take(per_block, b))
+    bits = jnp.take(blocks, b, axis=0)  # (slots, block)
+    hit = bits & (jnp.cumsum(bits, axis=1, dtype=jnp.int32)
+                  == inside[:, None] + 1)
+    at = b * block + jnp.argmax(hit, axis=1).astype(jnp.int32)
+    return jnp.where(k < upto[-1], at, jnp.int32(-1)), upto[-1]
+
+
 def make_shard_map_confusion_step(
     mesh: Mesh, reads_to_check: int = 10, axis: str = "data",
     flags_impl: str = "xla", funnel: bool = False,
@@ -390,6 +421,16 @@ def make_shard_map_confusion_step(
     per-row ``check_window`` + owned-span mask [lo, own), per-device
     ``vmap``. ``funnel=True`` runs the two-stage candidate funnel per row
     (verdicts are what this step projects; the funnel preserves them).
+
+    Returns ``(totals, differ_pos, differ_count)``. Beside the sums the step
+    says WHERE verdict and truth differ (reference CheckerApp.scala:102-134
+    prints those positions): ``differ_pos`` ``(rows, MISMATCH_LIST)`` int32,
+    each row's owned mismatching positions in ascending row-local order, -1
+    beyond them, and ``differ_count`` ``(rows,)``, the row's mismatches (a
+    row with more than the slots is re-derived by the caller). A position in
+    a row's halo is the next row's to report. Both are gathered over the
+    mesh axis, so every process reads every row's, in the order the rows'
+    operands are sharded.
 
     Every counter psum'd here is record-scale (≤ positions/40 per step),
     never position-scale: the reduction is int32 and a position-scale
@@ -413,19 +454,24 @@ def make_shard_map_confusion_step(
             m = (i >= lo) & (i < own)
             v = res["verdict"] & m
             t = tr & m
+            differ_pos, differ_count = _list_positions(v ^ t, MISMATCH_LIST)
             return jnp.stack([
                 jnp.sum((v & t).astype(jnp.int32)),    # true positives
                 jnp.sum((v & ~t).astype(jnp.int32)),   # false positives
                 jnp.sum((~v & t).astype(jnp.int32)),   # false negatives
                 jnp.sum((res["escaped"] & m).astype(jnp.int32)),
-            ])
+            ]), differ_pos, differ_count
 
     def confusion_step(windows, ns, at_eofs, truth, los, owns, lengths, nc):
-        stats = jax.vmap(
+        stats, differ_pos, differ_count = jax.vmap(
             lambda wd, n, e, t, lo, ow: one(wd, n, e, t, lo, ow, lengths, nc)
         )(windows, ns, at_eofs, truth, los, owns)
         with jax.named_scope("reduce"):
-            return jax.lax.psum(jnp.sum(stats, axis=0), axis)  # ← ICI
+            return (
+                jax.lax.psum(jnp.sum(stats, axis=0), axis),  # ← ICI
+                jax.lax.all_gather(differ_pos, axis, tiled=True),
+                jax.lax.all_gather(differ_count, axis, tiled=True),
+            )
 
     return jax.jit(
         jax.shard_map(

@@ -195,6 +195,8 @@ class _ShardedStream:
             self.step_rows_local = min(self.step_rows_local, self.per_proc)
         self._zero_rows: dict = {}
         self.with_truth = with_truth
+        # What the every-position steps' spans say they serve.
+        self.workload = "check_bam" if with_truth else "full_check"
 
         self.row_sharding = NamedSharding(self.mesh, P(self.axis))
         repl = NamedSharding(self.mesh, P())
@@ -211,29 +213,36 @@ class _ShardedStream:
         return view.data, view.size, b1 == len(self.metas)
 
     def _assemble(self, ch, c0: int, fill_row):
-        """One step's process-local arrays for the workloads that report
-        on every position, header bytes included (fixed shapes; padding
-        rows are all-zero and own nothing)."""
+        """One step's operands for the workloads that report on every
+        position, header bytes included, ON the devices (fixed shapes;
+        padding rows are all-zero and own nothing): the rows inflated one
+        after another into process-local arrays (``mesh.assemble``), then
+        put (``_sharded_args``)."""
         kw = self.kernel_window
         k = self.step_rows_local
-        ws = np.zeros((k, kw + PAD), dtype=np.uint8)
-        ns = np.zeros(k, dtype=np.int32)
-        eofs = np.zeros(k, dtype=bool)
-        los = np.zeros(k, dtype=np.int32)
-        owns = np.zeros(k, dtype=np.int32)
-        truth = np.zeros((k, kw), dtype=bool) if self.with_truth else None
-        for j in range(k):
-            g = self.process_id * self.per_proc + c0 + j
-            if c0 + j >= self.per_proc or g >= len(self.groups):
-                continue
-            buf, n, at_eof = self._row(ch, g)
-            ws[j, :n] = buf
-            ns[j] = n
-            eofs[j] = at_eof
-            owns[j], los[j] = self._row_span(g, n, at_eof, False)
-            if fill_row is not None:
-                fill_row(truth[j], buf, int(self.flat_starts[g]), n)
-        return ws, ns, eofs, los, owns, truth
+        g0 = self.process_id * self.per_proc + c0
+        live = [  # (slot, global row)
+            (j, g0 + j) for j in range(k)
+            if c0 + j < self.per_proc and g0 + j < len(self.groups)
+        ]
+        with obs.span("mesh.assemble", c0=c0, rows=len(live),
+                      workload=self.workload):
+            ws = np.zeros((k, kw + PAD), dtype=np.uint8)
+            ns = np.zeros(k, dtype=np.int32)
+            eofs = np.zeros(k, dtype=bool)
+            los = np.zeros(k, dtype=np.int32)
+            owns = np.zeros(k, dtype=np.int32)
+            truth = np.zeros((k, kw), dtype=bool) if self.with_truth else None
+            for j, g in live:
+                buf, n, at_eof = self._row(ch, g)
+                ws[j, :n] = buf
+                ns[j] = n
+                eofs[j] = at_eof
+                owns[j], los[j] = self._row_span(g, n, at_eof, False)
+                if fill_row is not None:
+                    fill_row(truth[j], buf, int(self.flat_starts[g]), n)
+        return self._sharded_args(
+            (ws, ns, eofs, los, owns, truth), c0, len(live))
 
     def _row_span(self, g: int, n: int, at_eof: bool, header_clamp: bool):
         """Row ``g``'s owned span ``[lo, own)`` in row-local offsets, given
@@ -242,13 +251,13 @@ class _ShardedStream:
         he = self.header_end if header_clamp else 0
         return own, min(max(he - int(self.flat_starts[g]), 0), own)
 
-    def _steps(self, assemble, finish=None):
+    def _steps(self, assemble):
         """Yield ``(step operands, positions_done, c0)`` per step (``c0`` =
         the step's first process-local row index — row ``j`` of the step is
-        global group ``process_id * per_proc + c0 + j``), assembling the
-        next step's rows on one worker thread while the caller's device
-        work runs (one step of lookahead). ``assemble(ch, c0)`` runs on
-        that thread; ``finish`` (if any) on the caller's, at hand-over."""
+        global group ``process_id * per_proc + c0 + j``), assembling and
+        putting the next step's rows on one worker thread while the
+        caller's device work runs (one step of lookahead):
+        ``assemble(ch, c0)`` runs on that thread."""
         if not self.per_proc:
             return
         steps = list(range(0, self.per_proc, self.step_rows_local))
@@ -269,16 +278,13 @@ class _ShardedStream:
                     len(self.groups),
                 ) - 1
                 done = int(self.flat_starts[g_hi] + self.sizes[g_hi])
-                yield (finish(arrays) if finish else arrays), done, c0
+                yield arrays, done, c0
 
     def batches(self, fill_row=None):
-        """The check-bam / full-check steps: rows inflated to host arrays
-        one after another (``_assemble``), placed sharded at hand-over
-        (``_sharded_args``)."""
-        return self._steps(
-            lambda ch, c0: self._assemble(ch, c0, fill_row),
-            self._sharded_args,
-        )
+        """The check-bam / full-check steps: ``(operands on the devices,
+        done, c0)``, rows inflated to host arrays one after another and put
+        one step ahead of the caller (``_assemble``)."""
+        return self._steps(lambda ch, c0: self._assemble(ch, c0, fill_row))
 
     # ------------------------------------------------------- count assembly
     def row_slots(self, c0: int) -> list[tuple[int, int, int]]:
@@ -357,17 +363,26 @@ class _ShardedStream:
                 lambda ch, c0: self._assemble_rows(ch, c0, rows_pool)
             )
 
-    def _sharded_args(self, arrays):
+    def _sharded_args(self, arrays, c0: int, rows: int):
+        """``_assemble``'s arrays put row-sharded, and waited for: the span
+        is the transfer, and the feeding thread is handed operands that
+        have arrived (as ``_assemble_rows`` hands over the count's)."""
         ws, ns, eofs, los, owns, truth = arrays
         rs = self.row_sharding
+        nbytes = ws.nbytes + (0 if truth is None else truth.nbytes)
 
         def put(a):
             return jax.make_array_from_process_local_data(rs, a)
 
-        args = [put(ws), put(ns), put(eofs)]
-        if truth is not None:
-            args.append(put(truth))
-        args += [put(los), put(owns)]
+        with obs.span("mesh.h2d", c0=c0, rows=rows, bytes=nbytes,
+                      workload=self.workload):
+            args = [put(ws), put(ns), put(eofs)]
+            if truth is not None:
+                args.append(put(truth))
+            args += [put(los), put(owns)]
+            jax.block_until_ready(args)
+        obs.count("mesh.rows", rows)
+        obs.count("mesh.h2d_bytes", nbytes)
         return args + [self.lengths_d, self.nc]
 
 
@@ -934,29 +949,57 @@ def host_shard_plan(
 
 
 def _truth_flats(path, records_path, metas) -> np.ndarray:
-    """The ``.records`` ground truth as sorted absolute flat offsets."""
-    from spark_bam_tpu.bam.index_records import read_records_index
+    """The ``.records`` ground truth as sorted absolute flat offsets: the
+    sidecar's two columns as arrays (no object a record), mapped through
+    the block table."""
+    from spark_bam_tpu.bam.index_records import read_records_arrays
     from spark_bam_tpu.bgzf.flat import metas_block_table
-    from spark_bam_tpu.bgzf.index_blocks import blocks_metadata
 
     records_path = (
         str(path) + ".records" if records_path is None else records_path
     )
-    positions = read_records_index(records_path)
-    metas = list(blocks_metadata(path)) if metas is None else metas
-    block_starts, block_flat = metas_block_table(metas)
-    blocks = np.array([p.block_pos for p in positions], dtype=np.int64)
-    offs = np.array([p.offset for p in positions], dtype=np.int64)
-    idx = np.searchsorted(block_starts, blocks)
-    if len(idx) and (
-        idx.max() >= len(block_starts)
-        or not np.array_equal(block_starts[idx], blocks)
-    ):
-        raise ValueError(
-            f"{records_path}: block positions not in {path}'s block table "
-            "(stale sidecar?)"
-        )
-    return np.sort(block_flat[idx] + offs)
+    with obs.span("checkbam.truth_load", path=str(records_path)):
+        blocks, offs = read_records_arrays(records_path)
+        block_starts, block_flat = metas_block_table(metas)
+        idx = np.searchsorted(block_starts, blocks)
+        if len(idx) and (
+            idx.max() >= len(block_starts)
+            or not np.array_equal(block_starts[idx], blocks)
+        ):
+            raise ValueError(
+                f"{records_path}: block positions not in {path}'s block "
+                "table (stale sidecar?)"
+            )
+        return np.sort(block_flat[idx] + offs)
+
+
+def _in_sorted(values: np.ndarray, among: np.ndarray) -> np.ndarray:
+    """Which of ``values`` are in the sorted ``among`` (a binary search a
+    value: the truth of a 60 GB file is 170 million offsets)."""
+    if not len(among):
+        return np.zeros(len(values), dtype=bool)
+    i = np.minimum(np.searchsorted(among, values), len(among) - 1)
+    return among[i] == values
+
+
+def _confusion(pred: np.ndarray, truth: np.ndarray):
+    """``(tp, fp positions, fn positions)`` of sorted predicted record
+    starts against sorted true ones."""
+    hit = _in_sorted(pred, truth)
+    return int(hit.sum()), pred[~hit], truth[~_in_sorted(truth, pred)]
+
+
+def _confusion_result(tp: int, fp, fn, total: int, devices: int) -> dict:
+    return {
+        "true_positives": tp,
+        "false_positives": len(fp),
+        "false_negatives": len(fn),
+        "true_negatives": total - tp - len(fp) - len(fn),
+        "positions": total,
+        "devices": devices,
+        "false_positive_positions": fp,
+        "false_negative_positions": fn,
+    }
 
 
 def check_bam_sharded(
@@ -977,11 +1020,21 @@ def check_bam_sharded(
     confusion matrix ``psum``'d per sharded step.
 
     Returns ``{"true_positives", "false_positives", "false_negatives",
-    "true_negatives", "positions", "devices"}`` (``devices`` = the mesh
-    size the verdicts actually ran on). Escaped chains fall back to the
-    single-device deferral-exact spans path, so the returned matrix is
+    "true_negatives", "positions", "devices", "false_positive_positions",
+    "false_negative_positions"}`` (``devices`` = the mesh size the verdicts
+    actually ran on). The two position lists are sorted absolute flat
+    offsets (int64), complete: every position where the verdict stands
+    without the truth (``fp``) or the truth without the verdict (``fn``),
+    read from each step's mismatch list (``mesh.MISMATCH_LIST`` slots a
+    row). A row with more mismatches than slots is re-derived exactly on
+    the host (``checkbam.list_overflows``), as the rows of a step with
+    escaped chains are (``mesh.dirty_steps``); when that cannot be done the
+    whole file goes through the single-device deferral-exact spans path
+    (``check.fused_demotions``), so the returned matrix and lists are
     always exact.
     """
+    from spark_bam_tpu.parallel.mesh import MISMATCH_LIST
+
     st = _ShardedStream(
         path, config, mesh, window_uncompressed, halo, metas,
         with_truth=True, num_processes=num_processes, process_id=process_id,
@@ -999,25 +1052,43 @@ def check_bam_sharded(
     # Device stats are [tp, fp, fn, escapes] — record-scale counters only.
     # Position totals and tn are host-derived (owned spans tile [0, total)
     # exactly), which keeps the device reduction int32-safe at mesh scale.
-    agg = np.zeros(4, dtype=np.int64)
+    agg = np.zeros(3, dtype=np.int64)
+    differ: list[np.ndarray] = []  # flat offsets where verdict != truth
     steps = 0
-    dirty: list[int] = []  # local row offsets (c0) of escaped steps
+    dirty: list[int] = []     # local row offsets (c0) of escaped steps
+    overflowed: set = set()   # global rows with more mismatches than slots
     whole_file = False
     batches = st.batches(fill_row=fill_row)
+    observer = _StepObserver.maybe()
     try:
         for args, done, c0 in batches:
             with obs.span("mesh.step", workload="check_bam", c0=c0):
-                totals = np.asarray(step(*args), dtype=np.int64)
+                t_dispatch = time.perf_counter()
+                out = step(*args)
+                if observer is not None:
+                    observer.window(None, 0.0, out[0], t_dispatch)
+                totals, at, counts = (np.asarray(a) for a in out)
             steps += 1
             obs.count("mesh.steps")
             if totals[3]:
                 obs.count("mesh.dirty_steps")
                 # Escape-localized handling (see count_reads_sharded):
-                # the dirty step's confusion counters are untrusted and
-                # its rows re-derive exactly on host below.
+                # the dirty step's confusion counters and list are
+                # untrusted and its rows re-derive exactly on host below.
                 dirty.append(c0)
             else:
-                agg += totals
+                agg += totals[:3]
+                # Row i of the gathered lists is process i // k's local
+                # row i % k of this step, as the operands are sharded.
+                k = st.step_rows_local
+                for i in np.flatnonzero(counts):
+                    g = (i // k) * st.per_proc + c0 + i % k
+                    if counts[i] > MISMATCH_LIST:
+                        overflowed.add(int(g))
+                    else:
+                        differ.append(
+                            st.flat_starts[g]
+                            + at[i, : counts[i]].astype(np.int64))
             if progress is not None:
                 progress(steps, done, st.total)
             if _mostly_dirty(dirty, steps):
@@ -1025,46 +1096,51 @@ def check_bam_sharded(
                 break
     finally:
         batches.close()
+        if observer is not None:
+            observer.close()
 
-    if dirty and not whole_file:
-        rows = {g for c0 in dirty for g in _step_global_rows(st, c0)}
+    redo = {g for c0 in dirty for g in _step_global_rows(st, c0)}
+    if (redo or overflowed) and not whole_file:
+        obs.count("checkbam.list_overflows", len(overflowed))
         with open_channel(path) as ch:
-            for g in rows:
+            for g in sorted(redo | overflowed):
                 pos = _exact_row_true_positions(st, g, 0, ch)
                 if pos is None:
                     whole_file = True  # no native lib / adversarial growth
                     break
                 lo = int(st.flat_starts[g])
-                hi = lo + int(st.sizes[g])
-                i0, i1 = np.searchsorted(truth_flats, (lo, hi))
-                t = truth_flats[i0:i1]
-                tp_g = int(np.isin(pos, t).sum())
-                agg[0] += tp_g
-                agg[1] += len(pos) - tp_g
-                agg[2] += len(t) - tp_g
+                i0, i1 = np.searchsorted(
+                    truth_flats, (lo, lo + int(st.sizes[g])))
+                tp_g, fp_g, fn_g = _confusion(pos, truth_flats[i0:i1])
+                differ += [fp_g, fn_g]
+                if g in redo:  # an overflowed row's sums stand
+                    agg += (tp_g, len(fp_g), len(fn_g))
     if whole_file:
-        stats = _check_bam_exact(
+        return _check_bam_exact(
             path, config, st.fresh, st.halo, st.metas, truth_flats,
             st.total,
         )
-        stats["devices"] = 1  # the exact fallback is single-device
-        return stats
-    tp, fp, fn = int(agg[0]), int(agg[1]), int(agg[2])
-    return {
-        "true_positives": tp,
-        "false_positives": fp,
-        "false_negatives": fn,
-        "true_negatives": st.total - tp - fp - fn,
-        "positions": st.total,
-        "devices": st.n_global,
-    }
+    differ = np.sort(
+        np.concatenate(differ) if differ else np.empty(0, dtype=np.int64))
+    missed = _in_sorted(differ, truth_flats)  # truth without the verdict
+    obs.count("checkbam.mismatches", len(differ))
+    if (len(differ) - int(missed.sum()), int(missed.sum())) != (
+            int(agg[1]), int(agg[2])):
+        raise RuntimeError(
+            f"{path}: the steps listed {len(differ)} mismatches and summed "
+            f"{int(agg[1])} + {int(agg[2])}"
+        )
+    return _confusion_result(
+        int(agg[0]), differ[~missed], differ[missed], st.total, st.n_global)
 
 
 def _check_bam_exact(
     path, config, fresh, halo, metas, truth_flats, total
 ) -> dict:
     """Escape fallback: predicted-boundary set from the deferral-exact
-    single-device spans, confusion by set arithmetic."""
+    single-device spans, confusion by set arithmetic. It leaves the mesh,
+    and says so (``check.fused_demotions``)."""
+    obs.count("check.fused_demotions")
     checker = StreamChecker(
         path, config, window_uncompressed=fresh, halo=halo, metas=metas
     )
@@ -1073,13 +1149,6 @@ def _check_bam_exact(
         np.sort(np.concatenate(parts)) if parts
         else np.empty(0, dtype=np.int64)
     )
-    tp = int(np.isin(pred, truth_flats).sum())
-    fp = len(pred) - tp
-    fn = len(truth_flats) - tp
-    return {
-        "true_positives": tp,
-        "false_positives": fp,
-        "false_negatives": fn,
-        "true_negatives": total - tp - fp - fn,
-        "positions": total,
-    }
+    tp, fp, fn = _confusion(pred, truth_flats)
+    obs.count("checkbam.mismatches", len(fp) + len(fn))
+    return _confusion_result(tp, fp, fn, total, 1)  # single-device

@@ -901,9 +901,11 @@ def check_window(
 
 
 @jax.named_scope("check")
+@jax.named_scope("scatter")
 def _scatter_lanes(L: dict, w: int) -> dict:
     """``_check_lanes``' verdicts scattered back over the F-derived base:
-    the (W,) arrays ``check_window`` returns."""
+    the (W,) arrays ``check_window`` returns. Under ``check/scatter``, so a
+    trace tells the scatter from the lane stage."""
     survivor, res0 = L["survivor"], L["res0"]
     fail_mask0, inexact0 = L["fail_mask0"], L["inexact0"]
     cand, live, res = L["cand"], L["live"], L["res"]

@@ -161,6 +161,71 @@ def test_sharded_count_step_fits_one_chip_at_its_row_cap(topo, chip):
     assert _device_bytes(compiled) < HBM
 
 
+@pytest.fixture(scope="module")
+def confusion_step(topo, chip):
+    """``jit_confusion_step`` as ``check_bam_tpu`` runs it on one v5e chip
+    (the cell ``wgs-short-checkbam.check-bam``), compiled once: three 32 MiB
+    rows under ``vmap``, ``check_window`` at full lane capacity, the verdicts
+    scattered over every position and held against a byte of truth a
+    position."""
+    from spark_bam_tpu.parallel.mesh import make_shard_map_confusion_step
+
+    rows = 3
+    mesh, shape, repl = _mesh_shapes(topo, 1)
+    step = make_shard_map_confusion_step(mesh, 10, "data", "xla", funnel=True)
+    return rows, step.lower(
+        shape((rows, WINDOW + PAD), jnp.uint8), shape((rows,), jnp.int32),
+        shape((rows,), jnp.bool_), shape((rows, WINDOW), jnp.bool_),
+        shape((rows,), jnp.int32), shape((rows,), jnp.int32),
+        shape((CMAX,), jnp.int32, repl), shape((), jnp.int32, repl),
+    ).compile()
+
+
+def test_confusion_step_fits_one_chip_at_three_rows(confusion_step):
+    """The fullest program of the repo: 8.23 GiB of temporaries, which the
+    mismatch list (``MISMATCH_LIST`` slots a row, two levels of 1,024
+    positions) leaves where they were (9.56 GiB when it packed the mask
+    into 32-bit words: tiled at four times its bytes)."""
+    from spark_bam_tpu.parallel.mesh import MISMATCH_LIST
+
+    rows, compiled = confusion_step
+    ma = compiled.memory_analysis()
+    assert ma.temp_size_in_bytes < 9 << 30
+    # Two steps' operands are alive at once: one running, one put ahead.
+    assert _device_bytes(compiled) + ma.argument_size_in_bytes < HBM
+    assert f"s32[{rows},{MISMATCH_LIST}]" in compiled.as_text()
+
+
+def test_the_nameless_int8_operations_are_the_verdict_scatter(confusion_step):
+    """What ``scatter_device_ms`` rests on (``bench/readers/trace_orphans``).
+    The chip's compiler flattens the batched scatter of ``_scatter_lanes``:
+    the index arithmetic keeps the path ``check/scatter/scatter``; the sort,
+    the flat scatter and the row-at-a-time copy back come out with no
+    metadata, so no scope finds them. int8 is the walk's verdict code and
+    nothing else in this program: the nameless operations that make an int8
+    array are that expansion, all of it and nothing besides."""
+    import re
+
+    from bench.readers.trace_orphans import result_types
+
+    _rows, compiled = confusion_step
+    text = compiled.as_text()
+    assert 'check/scatter/scatter"' in text
+    moves_nothing = {"parameter", "get-tuple-element", "tuple", "constant",
+                     "bitcast"}
+    kinds = set()
+    for line in text.splitlines():
+        m = re.search(r"\) ([\w\-]+)\(|\] ([\w\-]+)\(",
+                      re.sub(r"\{[^{}]*\}", "", line.partition(" = ")[2]))
+        kind = m and (m.group(1) or m.group(2))
+        if (kind and kind not in moves_nothing and "metadata=" not in line
+                and "s8" in result_types(line.strip())):
+            kinds.add(kind)
+    assert {"sort", "scatter", "while", "dynamic-update-slice"} <= kinds
+    assert kinds <= {"sort", "scatter", "fusion", "while", "broadcast",
+                     "dynamic-slice", "reshape", "dynamic-update-slice"}
+
+
 def test_count_step_compiles_for_four_chips_at_one_row_a_chip(topo, chip):
     """``jit_count_step`` as the whole-file count runs it on a v5e host: one
     host-inflated 32 MiB row a chip, flat, the count pair ``psum``'d. A

@@ -5,6 +5,7 @@ counts (2.bam = 2500 reads, 1.bam = 4917 — reference
 docs/command-line.md:46-53, cli golden output/check-bam/1.bam)."""
 
 import jax
+import numpy as np
 
 from spark_bam_tpu.core.config import Config
 from spark_bam_tpu.parallel.mesh import make_mesh
@@ -100,6 +101,8 @@ def test_check_bam_sharded_bam2_all_match():
         BAM2, Config(), mesh=_mesh(),
         window_uncompressed=128 << 10, halo=32 << 10,
     )
+    assert not len(stats.pop("false_positive_positions"))
+    assert not len(stats.pop("false_negative_positions"))
     assert stats == {
         "true_positives": 2500,
         "false_positives": 0,
@@ -148,6 +151,8 @@ def test_check_bam_sharded_escape_patch_matches_device_pass(longread_bam):
     expected_devices = 8 if load_native() is not None else 1
     assert via_escape.pop("devices") == expected_devices
     assert via_device.pop("devices") == 8
+    for key in ("false_positive_positions", "false_negative_positions"):
+        assert np.array_equal(via_escape.pop(key), via_device.pop(key))
     assert via_escape == via_device
 
 

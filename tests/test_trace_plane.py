@@ -80,11 +80,29 @@ def _lower_agg_update():
     return update_fn(plan, 1).lower(state_zeros(plan, 1), planes)
 
 
+def _lower_confusion_step():
+    from spark_bam_tpu.parallel.mesh import (
+        local_mesh, make_shard_map_confusion_step,
+    )
+    from spark_bam_tpu.tpu.checker import PAD
+
+    mesh = local_mesh()
+    b = mesh.devices.size
+    i32 = jnp.int32
+    return make_shard_map_confusion_step(mesh, funnel=True).lower(
+        jnp.zeros((b, W + PAD), jnp.uint8), jnp.zeros(b, i32),
+        jnp.zeros(b, bool), jnp.zeros((b, W), bool), jnp.zeros(b, i32),
+        jnp.zeros(b, i32), jnp.zeros(8, i32), i32(1),
+    )
+
+
 CHECK = {"check", "flags", "funnel", "chain_walk"}
 PROGRAM_SCOPES = [
     ("count_window", _lower_count_window, CHECK | {"reduce"}),
     ("count_step", _lower_count_step, CHECK | {"reduce"}),
-    ("serve_step", _lower_serve_step, CHECK | {"reduce"}),
+    ("serve_step", _lower_serve_step, CHECK | {"reduce", "scatter"}),
+    ("confusion_step", _lower_confusion_step,
+     CHECK | {"reduce", "scatter"}),
     ("agg_update", _lower_agg_update, {"agg_reduce"}),
 ]
 
@@ -102,6 +120,10 @@ def test_scopes_are_in_the_lowered_program(program, lower, scopes):
         # ``check/flags/...`` or, directly under a vmap, ``vmap(reduce)/...``.
         assert any(f"{before}{scope}{after}/" in text for before, after in
                    (("/", ""), ('"', ""), ("(", ")"))), scope
+    if "scatter" in scopes:
+        # ``check_window``'s verdicts back over every position, told from
+        # the lane stage: a child of ``check``.
+        assert "check/scatter/" in text
     if program == "count_window":
         # Stage 0 directly under ``check``; the lane stage's three scopes
         # inside its block loops (``bench/readers/trace_scope.py`` finds a
