@@ -102,7 +102,12 @@ NAMES = frozenset({
     # faults — retry/hedge/quarantine ledger (docs/robustness.md)
     "faults.attempt_ms", "faults.hedges", "faults.quarantined",
     "faults.quarantined_blocks", "faults.retries",
-    # funnel — two-stage checker candidate funnel (docs/design.md)
+    # funnel — two-stage checker candidate funnel (docs/design.md).
+    # survivors / lanes: stage 0's survivors and the lanes the lane stage
+    # ran for them (whole blocks, each window's or row's own), from every
+    # path that runs it: the count's stream and mesh steps, the served
+    # tick (also serve.tick_lanes, one observation a tick) and check-bam's
+    # steps (also mesh.step_lanes, one a step). positions: stream only.
     "funnel.lanes", "funnel.positions", "funnel.survivors",
     # guard — untrusted-byte decode boundary (core/guard.py)
     "guard.quarantined_blocks", "guard.quarantined_records",
@@ -128,7 +133,7 @@ NAMES = frozenset({
     "mesh.h2d", "mesh.h2d_bytes",
     "mesh.patch_chunk_positions", "mesh.patch_chunks", "mesh.patch_rows",
     "mesh.rows", "mesh.stall", "mesh.step",
-    "mesh.step_device_ms", "mesh.steps",
+    "mesh.step_device_ms", "mesh.step_lanes", "mesh.steps",
     # progress — long-run heartbeats
     "progress.beats",
     # remote — plan-driven data plane (docs/remote.md)
@@ -151,7 +156,7 @@ NAMES = frozenset({
     "serve.parse", "serve.queue_depth", "serve.queue_ms", "serve.request",
     "serve.requests", "serve.rewrite", "serve.scatter", "serve.shed",
     "serve.step", "serve.stream_aborts",
-    "serve.tick", "serve.tuned",
+    "serve.tick", "serve.tick_lanes", "serve.tuned",
     # serve shm — segment lifecycle + encoded-frame cache
     # (docs/serving.md "Transport")
     "serve.frame_cache_hits", "serve.frame_cache_misses",
@@ -179,6 +184,9 @@ NAMES = frozenset({
 #: confusion step's sums and mismatch list); ``check_window`` (the served
 #: step, check-bam) has ``check/scatter`` besides, the lanes' verdicts
 #: scattered back over every position; agg/kernels.py has ``agg_reduce``.
+#: Under the funnel the lane stage's three (``flags``, ``funnel``,
+#: ``chain_walk``) sit inside its block loops (``check/while/body/...``),
+#: in ``check_window`` as in the count; ``scatter`` runs once, after them.
 SCOPES = frozenset({
     "agg_reduce", "chain_walk", "check", "flags", "funnel", "reduce",
     "scatter",

@@ -385,6 +385,19 @@ def make_shard_map_count_step(
     )
 
 
+def _rows_in_turn(one, *operands):
+    """``one`` over a device's rows, one row after another inside the step
+    (``lax.map``), not batched. Each row then runs the flat one-row program:
+    its lane gathers read an ``(N,)`` row and not a row-major ``(k, N)``
+    operand, its block loops run to its OWN survivors (under ``vmap`` every
+    row runs to the rows' maximum, with a carry as wide as all rows selected
+    a trip), and the step's temporaries are one row's. On the chip (PR 34,
+    ``PERF.md`` §6): the confusion step of three 32 MiB rows 531.5 ms in
+    turn against 842.7 batched (2.01 against 3.47 GiB of temporaries), the
+    served step of eight 1 MiB rows 40.1 against 57.1 at the same block."""
+    return jax.lax.map(lambda row: one(*row), operands)
+
+
 def _list_positions(mask, slots: int, block: int = 1024):
     """The first ``slots`` set positions of a position-wide ``mask`` in
     ascending order (-1 beyond them) and the number set. Two levels: the
@@ -418,11 +431,16 @@ def make_shard_map_confusion_step(
     position, the (tp, fp, fn, escapes) counters ``psum``'d over the mesh
     axis — the check-bam validation workload (reference
     CheckerApp.scala:59-70's accumulators) as one mesh-partitioned unit:
-    per-row ``check_window`` + owned-span mask [lo, own), per-device
-    ``vmap``. ``funnel=True`` runs the two-stage candidate funnel per row
-    (verdicts are what this step projects; the funnel preserves them).
+    per-row ``check_window`` + owned-span mask [lo, own), a device's rows
+    one after another (``_rows_in_turn``). ``funnel=True`` runs the
+    two-stage candidate funnel per row (verdicts are what this step
+    projects; the funnel preserves them), its lane stage as many blocks as
+    hold the row's survivors.
 
-    Returns ``(totals, differ_pos, differ_count)``. Beside the sums the step
+    Returns ``(totals, differ_pos, differ_count)``; ``totals`` is ``[tp, fp,
+    fn, escapes, survivors, lanes]``, the last two the funnel's evidence
+    (``funnel.survivors``, ``funnel.lanes``, ``mesh.step_lanes``): the rows'
+    stage-0 survivors and the lanes their blocks ran. Beside the sums the step
     says WHERE verdict and truth differ (reference CheckerApp.scala:102-134
     prints those positions): ``differ_pos`` ``(rows, MISMATCH_LIST)`` int32,
     each row's owned mismatching positions in ascending row-local order, -1
@@ -460,12 +478,13 @@ def make_shard_map_confusion_step(
                 jnp.sum((v & ~t).astype(jnp.int32)),   # false positives
                 jnp.sum((~v & t).astype(jnp.int32)),   # false negatives
                 jnp.sum((res["escaped"] & m).astype(jnp.int32)),
+                res["survivors"], res["lanes"],
             ]), differ_pos, differ_count
 
     def confusion_step(windows, ns, at_eofs, truth, los, owns, lengths, nc):
-        stats, differ_pos, differ_count = jax.vmap(
-            lambda wd, n, e, t, lo, ow: one(wd, n, e, t, lo, ow, lengths, nc)
-        )(windows, ns, at_eofs, truth, los, owns)
+        stats, differ_pos, differ_count = _rows_in_turn(
+            lambda wd, n, e, t, lo, ow: one(wd, n, e, t, lo, ow, lengths, nc),
+            windows, ns, at_eofs, truth, los, owns)
         with jax.named_scope("reduce"):
             return (
                 jax.lax.psum(jnp.sum(stats, axis=0), axis),  # ← ICI
@@ -588,10 +607,13 @@ def make_shard_map_serve_step(
     mesh: Mesh, reads_to_check: int = 10, axis: str = "data",
     flags_impl: str = "xla", funnel: bool = False,
 ):
-    """Sharded serving step: PER-ROW (boundary count, owned escapes) with
-    NO cross-device reduction — ``out_specs=P(axis)`` keeps each row's
-    pair on its shard so the host can scatter results back to the
-    individual requests a batch coalesced (parallel/serve batching).
+    """Sharded serving step: PER-ROW (boundary count, owned escapes,
+    stage-0 survivors, lanes run) with NO cross-device reduction —
+    ``out_specs=P(axis)`` keeps each row's four on its shard so the host
+    can scatter results back to the individual requests a batch coalesced
+    (parallel/serve batching). A device's rows run one after another
+    (``_rows_in_turn``), each its own blocks of lanes (``check_window``): a
+    padding row runs none.
 
     Unlike the count step, ``lengths``/``num_contigs`` are per-row
     ``(B, Cmax)`` / ``(B,)`` inputs sharded with the batch: rows from
@@ -615,10 +637,12 @@ def make_shard_map_serve_step(
             return jnp.stack([
                 jnp.sum((res["verdict"] & m).astype(jnp.int32)),
                 jnp.sum((res["escaped"] & m).astype(jnp.int32)),
+                res["survivors"], res["lanes"],
             ])
 
     def serve_step(windows, ns, at_eofs, los, owns, lengths, ncs):
-        return jax.vmap(one)(windows, ns, at_eofs, los, owns, lengths, ncs)
+        return _rows_in_turn(
+            one, windows, ns, at_eofs, los, owns, lengths, ncs)
 
     return jax.jit(
         jax.shard_map(

@@ -1049,7 +1049,8 @@ def check_bam_sharded(
         i0, i1 = np.searchsorted(truth_flats, (base, base + n))
         row[truth_flats[i0:i1] - base] = True
 
-    # Device stats are [tp, fp, fn, escapes] — record-scale counters only.
+    # Device stats are [tp, fp, fn, escapes, survivors, lanes] — record-scale
+    # counters only.
     # Position totals and tn are host-derived (owned spans tile [0, total)
     # exactly), which keeps the device reduction int32-safe at mesh scale.
     agg = np.zeros(3, dtype=np.int64)
@@ -1070,6 +1071,11 @@ def check_bam_sharded(
                 totals, at, counts = (np.asarray(a) for a in out)
             steps += 1
             obs.count("mesh.steps")
+            # The funnel's evidence (see count_reads_sharded): the rows'
+            # stage-0 survivors and the lanes the step ran for them.
+            obs.count("funnel.survivors", int(totals[4]))
+            obs.count("funnel.lanes", int(totals[5]))
+            obs.observe("mesh.step_lanes", int(totals[5]))
             if totals[3]:
                 obs.count("mesh.dirty_steps")
                 # Escape-localized handling (see count_reads_sharded):

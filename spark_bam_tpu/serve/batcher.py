@@ -251,6 +251,13 @@ class Batcher:
         obs.count("serve.batches")
         obs.observe("serve.batch_rows", len(batch))
         obs.count("serve.h2d_bytes", sum(len(t.window) for t in batch))
+        # The funnel's evidence, as the count's paths name it: the rows'
+        # stage-0 survivors and the lanes the step ran for them (each row
+        # its own blocks; a padding row runs none).
+        lanes = int(res[:, 3].sum())
+        obs.count("funnel.survivors", int(res[:, 2].sum()))
+        obs.count("funnel.lanes", lanes)
+        obs.observe("serve.tick_lanes", lanes)
         # Per-row cost attribution: the same queue_ms the histogram saw,
         # an even 1/rows share of the tick's device time, and the row's
         # own window bytes — shares sum back to serve.tick / the

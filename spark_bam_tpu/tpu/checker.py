@@ -455,25 +455,34 @@ def _ranked_positions(table, k):
     return jnp.where(k < n_set, wi * 32 + lane, _I32(-1))
 
 
-def _compact_mask(mask, capacity: int):
-    """Compact set positions of ``mask`` into a (capacity,) index buffer
-    (-1 beyond the population). Returns (cand, n_set)."""
-    table = _rank_table(mask)
-    k = jnp.arange(capacity, dtype=_I32)
-    return _ranked_positions(table, k), table.n_set
-
-
 def lane_capacity(w: int) -> int:
     """Lanes the check of a ``w``-byte window can hold: a window with more
     stage-0 survivors escapes whole to the host engine."""
     return max(w // 32, 4096)
 
 
-#: Lanes a block of the count's lane stage (``_count_lanes``). The stage runs
-#: ``ceil(n_survivors / LANE_BLOCK)`` blocks, a number read on the device
+#: Most lanes a block of the funnel's lane stage (``lane_block``). The stage
+#: runs ``ceil(n_survivors / block)`` blocks, a number read on the device
 #: from the window itself, so a window pays for its survivors and not for
 #: the worst window the format allows.
 LANE_BLOCK = 16384
+
+#: Fewest lanes a block (the narrowest block the sweep ran was 512, no
+#: faster than this).
+LANE_BLOCK_MIN = 1024
+
+
+def lane_block(w: int) -> int:
+    """Lanes a block of the lane stage of a ``w``-byte window: a 32nd of
+    the window's capacity within ``[LANE_BLOCK_MIN, LANE_BLOCK]``, so 1,024
+    at a served row's 1 MiB and 16,384 at the count's and check-bam's
+    32 MiB. A block costs its lanes and next to nothing besides (on the
+    chip a served step of eight 1 MiB rows of ≈ 2,970 survivors took 33.4
+    ms at 1,024, 40.1 at 2,048 and at 4,096, 73.6 at 8,192, 140.8 at
+    16,384: ``PERF.md`` §6, PR 34), so the narrow row wants the block that
+    leaves the fewest dead lanes; the wide one keeps PR 30's."""
+    return max(LANE_BLOCK_MIN, min(LANE_BLOCK, lane_capacity(w) // 32))
+
 
 #: Slots of the count's escape list: the window positions of the owned lanes
 #: whose chains ran past the buffer, which the stream resolves on the host
@@ -676,101 +685,48 @@ def _walk_lanes(
     }
 
 
-@jax.named_scope("check")
-def _check_lanes(
+class _LaneBlocks(NamedTuple):
+    """Stage 0 and pass 1 of the funnel's lane stage (``_deep_blocks``), as
+    pass 2 (``_walk_blocks``) and its consumer take them."""
+    S: dict                  # stage 0 (``_flag_stage``)
+    F_deep: jnp.ndarray      # (w + 1,) the deep masks at the survivors
+    cands: jnp.ndarray       # (max_blocks * block,) lane positions by rank
+    blocks: jnp.ndarray      # () blocks holding the survivors: the trip count
+    block: int
+    overflow: jnp.ndarray    # () more survivors than ``lane_capacity``
+    n_survivors: jnp.ndarray
+
+    @property
+    def lanes(self):
+        """Lanes the stage runs: whole blocks."""
+        return self.blocks * _I32(self.block)
+
+
+def _deep_blocks(
     padded, lengths, num_contigs, n, at_eof,
-    reads_to_check: int = 10, flags_impl: str = "xla",
-    pallas_interpret: bool = False, funnel: bool = False,
-):
-    """Flag pass + survivor compaction + lane walk at the window's full
-    lane capacity, WITHOUT the full-width scatters: the core of
-    ``check_window``, which scatters the lanes back to (W,) arrays. (The
-    count reduces its lanes directly, and sizes the lane stage by the
-    window's survivors: ``_count_lanes``.)"""
-    w = padded.shape[0] - PAD
-    S = _flag_stage(
-        padded, lengths, num_contigs, n, at_eof,
-        flags_impl, pallas_interpret, funnel,
-    )
-    F, survivor = S["F"], S["survivor"]
+    flags_impl: str, pallas_interpret: bool, block: int | None,
+) -> _LaneBlocks:
+    """Stage 0 and pass 1 of THE lane stage of the funnel, sized by the
+    window's own survivors; ``_walk_blocks`` is pass 2.
 
-    # --- survivor compaction ---------------------------------------------
-    capacity = lane_capacity(w)
-    if funnel:
-        with jax.named_scope("funnel"):
-            cand, n_survivors = _compact_mask(survivor, capacity)
-            tables = _funnel_tables(padded, n)
-        overflow = n_survivors > capacity
-        live = cand >= 0
-        tgt0, F_cand = _deep_lanes(
-            padded, lengths, num_contigs, n, tables, cand, live)
-        with jax.named_scope("funnel"):
-            F_deep = jnp.zeros(w + 1, dtype=_I32).at[tgt0].set(
-                F_cand, mode="drop"
-            )[:w]
-        flags_lookup = _funnel_lookup(F, F_deep)
-    else:
-        # No funnel: the survivors' compaction is the walk's own prologue.
-        with jax.named_scope("chain_walk"):
-            n_survivors = jnp.sum(survivor.astype(_I32))
-            (cand,) = jnp.nonzero(survivor, size=capacity, fill_value=-1)
-            cand = cand.astype(_I32)
-        overflow = n_survivors > capacity
-        live = cand >= 0
-
-        def flags_lookup(pi):
-            return jnp.take(F, pi, mode="clip")
-
-    # Unrolled under the funnel: the loop-carried scan blocks XLA from
-    # fusing the lane gathers with their producers (~25% of the funnel
-    # path); ten lane-width steps unroll cheaply. The funnel=False scan is
-    # kept rolled so the funnel A/B baseline measures the original kernel.
-    lanes = _walk_lanes(
-        cand, live, flags_lookup, S["misc_at"], n, at_eof, w,
-        reads_to_check, unroll=True if funnel else 1,
-    )
-    return {
-        "survivor": survivor, "res0": S["res0"],
-        "fail_mask0": S["fail_mask0"], "inexact0": S["inexact0"],
-        "cand": cand, "live": live, **lanes,
-        "overflow": overflow, "n_survivors": n_survivors,
-    }
-
-
-@jax.named_scope("check")
-def _count_lanes(
-    padded, lengths, num_contigs, n, at_eof, lo, own,
-    reads_to_check: int, flags_impl: str, pallas_interpret: bool,
-    block: int = LANE_BLOCK, escapes: int = 0,
-):
-    """The funnelled check reduced to the count's scalars, its lane stage
-    sized by the window's own survivors.
-
-    Stage 0 is ``_check_lanes``'s, run once. The lane stage (compaction →
-    deep flags → their scatter → the walk → the lane reduction) runs in
-    blocks of ``block`` lanes, ``ceil(n_survivors / block)`` of them: a trip
-    count the device reads from the window. Pass 1 compacts block k (ranks
-    ``k·block …``), deep-checks it and scatters its masks into the carried
-    position-wide ``F_deep``; only then can pass 2 walk, since a lane's
-    chain visits survivors of later blocks. Lanes are independent, so every
-    verdict is what the full-capacity stage gives; a window over
-    ``lane_capacity`` runs every block and reports the overflow as that
-    stage does. Under ``vmap`` the trip count is the rows' maximum (a row's
-    blocks beyond its own hold dead lanes only).
-
-    With ``escapes`` slots the walk also lists WHICH owned lanes escaped
-    (``esc_pos``: their window positions, ascending, -1 beyond them): each
-    block holds its lanes' positions and results already, so nothing
-    position-wide is added. Escapes beyond the slots are counted in ``esc``
-    and not listed."""
+    Stage 0 (``_flag_stage``) runs once. The lane stage (compaction → deep
+    flags → their scatter → the walk → what the consumer keeps) runs in
+    blocks of ``lane_block(w)`` lanes, ``ceil(n_survivors / block)`` of
+    them: a trip count the device reads from the window. Pass 1 compacts
+    block k (ranks ``k·block …``), deep-checks it and scatters its masks
+    into the carried position-wide ``F_deep``; only then can pass 2 walk,
+    since a lane's chain visits survivors of later blocks. Lanes are
+    independent, so every verdict is what ONE stage of ``lane_capacity``
+    lanes gives; a window over that capacity runs every block and reports
+    ``overflow`` as that stage does. Under ``vmap`` the trip count is the
+    rows' maximum (a row's blocks beyond its own hold dead lanes only)."""
     w = padded.shape[0] - PAD
     S = _flag_stage(
         padded, lengths, num_contigs, n, at_eof,
         flags_impl, pallas_interpret, True,
     )
-    F, misc_at = S["F"], S["misc_at"]
     capacity = lane_capacity(w)
-    block = min(block, capacity)
+    block = min(block or lane_block(w), capacity)
     max_blocks = -(-capacity // block)
     with jax.named_scope("funnel"):
         # The barrier makes the survivors one materialized (W,) mask. Left to
@@ -803,31 +759,150 @@ def _count_lanes(
         (jnp.zeros(w + 1, dtype=_I32),
          jnp.full(max_blocks * block, -1, dtype=_I32)),
     )
-    flags_lookup = _funnel_lookup(F, F_deep)
+    return _LaneBlocks(S, F_deep, cands, blocks, block, overflow, n_survivors)
+
+
+def _walk_blocks(B: _LaneBlocks, n, at_eof, reads_to_check: int, fold, init):
+    """Pass 2 of the lane stage: walk block k and hand its lanes to the
+    stage's consumer, ``fold(carry, k, cand, live, lanes) -> carry`` with
+    ``lanes`` the per-lane verdicts of ``_walk_lanes``. The count folds them
+    into its scalars and its escape list (``_count_lanes``); ``check_window``
+    keeps them, lane for lane (``_check_lanes``)."""
+    w = B.F_deep.shape[0] - 1
+    flags_lookup = _funnel_lookup(B.S["F"], B.F_deep)
 
     def walk_block(k, carry):
-        count, esc, *listed = carry
         with jax.named_scope("chain_walk"):
-            cand = lax.dynamic_slice(cands, (k * block,), (block,))
+            cand = lax.dynamic_slice(B.cands, (k * B.block,), (B.block,))
             live = cand >= 0
-        res = _walk_lanes(
-            cand, live, flags_lookup, misc_at, n, at_eof, w,
+        lanes = _walk_lanes(
+            cand, live, flags_lookup, B.S["misc_at"], n, at_eof, w,
             reads_to_check, unroll=True,
-        )["res"]
+        )
         with jax.named_scope("chain_walk"):
-            own_lane = live & (cand >= lo) & (cand < own)
-            counted = count + jnp.sum(own_lane & (res == 1))
-            escaped = own_lane & (res == 2)
-            if listed:
-                listed = [_list_escapes(listed[0], esc, escaped, cand)]
-            return counted, esc + jnp.sum(escaped), *listed
+            return fold(carry, k, cand, live, lanes)
+
+    return lax.fori_loop(0, B.blocks, walk_block, init)
+
+
+#: What ``_walk_lanes`` says of a lane, in the order ``_check_lanes`` keeps it.
+_LANE_KEYS = ("res", "fail_mask", "reads_before", "reads_parsed", "exact")
+
+
+@jax.named_scope("check")
+def _check_lanes(
+    padded, lengths, num_contigs, n, at_eof,
+    reads_to_check: int = 10, flags_impl: str = "xla",
+    pallas_interpret: bool = False, funnel: bool = False,
+    block: int | None = None,
+):
+    """Flag pass + survivor compaction + lane walk, WITHOUT the full-width
+    scatters: the core of ``check_window``, which scatters the lanes back
+    to (W,) arrays (``_scatter_lanes``).
+
+    Under the funnel the lanes come from the one lane stage there is
+    (``_deep_blocks`` / ``_walk_blocks``), as many blocks as hold the row's
+    survivors: each block's verdicts go into lane-wide buffers beside its
+    positions, dead lanes (``cand < 0``: the block's tail, the blocks never
+    run) into the scatter's pad slot. The verdict code rides the loop as
+    int32: int8 is the scatter's own in this program, which is how a trace
+    tells the scatter's nameless expansion from the walk
+    (``bench/readers/trace_orphans``).
+
+    Without the funnel (``make_shard_map_full_step`` and the two check
+    steps: exact masks for forensics, and the rolled scan that is the
+    funnel's A/B baseline) the stage is ONE of the window's whole capacity,
+    as it always was: no cell runs it and its masks are another contract."""
+    w = padded.shape[0] - PAD
+    if funnel:
+        B = _deep_blocks(
+            padded, lengths, num_contigs, n, at_eof,
+            flags_impl, pallas_interpret, block,
+        )
+        S, cand = B.S, B.cands
+
+        def keep(carry, k, _cand, _live, lanes):
+            return tuple(
+                lax.dynamic_update_slice(
+                    buf, lanes[key].astype(buf.dtype), (k * B.block,))
+                for buf, key in zip(carry, _LANE_KEYS))
+
+        kept = _walk_blocks(B, n, at_eof, reads_to_check, keep, tuple(
+            jnp.zeros(cand.shape, dtype=bool if key == "exact" else _I32)
+            for key in _LANE_KEYS))
+        lanes = dict(zip(_LANE_KEYS, kept))
+        overflow, n_survivors, ran = B.overflow, B.n_survivors, B.lanes
+    else:
+        S = _flag_stage(
+            padded, lengths, num_contigs, n, at_eof,
+            flags_impl, pallas_interpret, False,
+        )
+        F, survivor = S["F"], S["survivor"]
+        capacity = lane_capacity(w)
+        # No funnel: the survivors' compaction is the walk's own prologue.
+        with jax.named_scope("chain_walk"):
+            n_survivors = jnp.sum(survivor.astype(_I32))
+            (cand,) = jnp.nonzero(survivor, size=capacity, fill_value=-1)
+            cand = cand.astype(_I32)
+        overflow = n_survivors > capacity
+
+        def flags_lookup(pi):
+            return jnp.take(F, pi, mode="clip")
+
+        # Rolled (under the funnel the walk is unrolled: the loop-carried
+        # scan blocks XLA from fusing the lane gathers with their
+        # producers), so the funnel A/B baseline measures the original
+        # kernel.
+        lanes = _walk_lanes(
+            cand, cand >= 0, flags_lookup, S["misc_at"], n, at_eof, w,
+            reads_to_check, unroll=1,
+        )
+        ran = _I32(capacity)
+    return {
+        "survivor": S["survivor"], "res0": S["res0"],
+        "fail_mask0": S["fail_mask0"], "inexact0": S["inexact0"],
+        "cand": cand, **lanes,
+        "overflow": overflow, "n_survivors": n_survivors, "lanes": ran,
+    }
+
+
+@jax.named_scope("check")
+def _count_lanes(
+    padded, lengths, num_contigs, n, at_eof, lo, own,
+    reads_to_check: int, flags_impl: str, pallas_interpret: bool,
+    block: int | None = None, escapes: int = 0,
+):
+    """The funnelled check reduced to the count's scalars: the lane stage
+    (``_deep_blocks`` / ``_walk_blocks``) with each block's lanes summed as
+    they are walked, nothing lane-wide kept.
+
+    With ``escapes`` slots the walk also lists WHICH owned lanes escaped
+    (``esc_pos``: their window positions, ascending, -1 beyond them): each
+    block holds its lanes' positions and results already, so nothing
+    position-wide is added. Escapes beyond the slots are counted in ``esc``
+    and not listed."""
+    B = _deep_blocks(
+        padded, lengths, num_contigs, n, at_eof,
+        flags_impl, pallas_interpret, block,
+    )
+
+    def tally(carry, _k, cand, live, lanes):
+        count, esc, *listed = carry
+        res = lanes["res"]
+        own_lane = live & (cand >= lo) & (cand < own)
+        counted = count + jnp.sum(own_lane & (res == 1))
+        escaped = own_lane & (res == 2)
+        if listed:
+            listed = [_list_escapes(listed[0], esc, escaped, cand)]
+        return counted, esc + jnp.sum(escaped), *listed
 
     listed = (jnp.full(escapes, -1, dtype=_I32),) if escapes else ()
-    count, esc, *listed = lax.fori_loop(
-        0, blocks, walk_block, (_I32(0), _I32(0), *listed))
+    count, esc, *listed = _walk_blocks(
+        B, n, at_eof, reads_to_check, tally, (_I32(0), _I32(0), *listed))
     out = {
-        "count": count, "esc": esc, "res0": S["res0"], "overflow": overflow,
-        "n_survivors": n_survivors, "lanes": blocks * _I32(block),
+        "count": count, "esc": esc, "res0": B.S["res0"],
+        "overflow": B.overflow, "n_survivors": B.n_survivors,
+        "lanes": B.lanes,
     }
     if listed:
         out["esc_pos"] = listed[0]
@@ -889,7 +964,11 @@ def check_window(
 
     Returns dict of (W,) arrays: verdict, fail_mask, reads_parsed,
     reads_before, exact, escaped — plus the () int32 ``survivors`` count
-    (stage-0 survivors under the funnel; full-pass survivors otherwise).
+    (stage-0 survivors under the funnel; full-pass survivors otherwise) and
+    ``lanes``, the lanes the lane stage ran for them: under the funnel whole
+    blocks of ``lane_block(w)``, as many as hold the survivors (a number
+    the device reads from the row: ``_deep_blocks``), otherwise the window's
+    whole ``lane_capacity``.
     """
     w = padded.shape[0] - PAD
     L = _check_lanes(
@@ -908,13 +987,15 @@ def _scatter_lanes(L: dict, w: int) -> dict:
     trace tells the scatter from the lane stage."""
     survivor, res0 = L["survivor"], L["res0"]
     fail_mask0, inexact0 = L["fail_mask0"], L["inexact0"]
-    cand, live, res = L["cand"], L["live"], L["res"]
+    cand, res = L["cand"], L["res"]
+    live = cand >= 0
     fail_mask, reads_before = L["fail_mask"], L["reads_before"]
     reads_parsed, exact = L["reads_parsed"], L["exact"]
     overflow, n_survivors = L["overflow"], L["n_survivors"]
     tgt = jnp.where(live, cand, _I32(w))  # dead lanes scatter into the pad row
+    # int8 from here on (the funnel's lane buffers hold the code as int32).
     res_full = jnp.zeros(w + 1, dtype=jnp.int8).at[tgt].set(
-        jnp.where(live, res, jnp.int8(0)), mode="drop"
+        jnp.where(live, res.astype(jnp.int8), jnp.int8(0)), mode="drop"
     )[:w]
     res_full = jnp.where(survivor, res_full, res0)
     fm_full = jnp.zeros(w + 1, dtype=_I32).at[tgt].set(fail_mask, mode="drop")[:w]
@@ -938,13 +1019,14 @@ def _scatter_lanes(L: dict, w: int) -> dict:
         "exact": exact_out,
         "escaped": escaped,
         "survivors": n_survivors,
+        "lanes": L["lanes"],
     }
 
 
 def _count_funnel(
     padded, lengths, num_contigs, n, at_eof, lo, own,
     reads_to_check: int, flags_impl: str, pallas_interpret: bool,
-    block: int = LANE_BLOCK, escapes: int = 0,
+    block: int | None = None, escapes: int = 0,
 ):
     """``count_window`` under the funnel. Scatter-free reduction: verdicts
     live only on survivor lanes (non-survivors never reach res==1) and
@@ -1003,7 +1085,7 @@ def count_window(
     fail_mask/reads_* scatters and the per-position arrays themselves.
     Beside the two scalars: ``survivors`` (stage 0's) and ``lanes``, the
     lanes the lane stage ran — under the funnel as many blocks as hold the
-    survivors (``_count_lanes``), without it the window's whole capacity.
+    survivors (``_deep_blocks``), without it the window's whole capacity.
 
     Escapes are rare, and a caller that cannot resolve them starts over on
     the exact spans path when ``esc_count`` is ever nonzero (the mesh step:
@@ -1032,7 +1114,7 @@ def count_window(
             "count": jnp.sum(m & res["verdict"]),
             "esc_count": jnp.sum(m & res["escaped"]),
             "survivors": res["survivors"],
-            "lanes": _I32(lane_capacity(w)),
+            "lanes": res["lanes"],
         }
         if escapes:
             (at,) = jnp.nonzero(

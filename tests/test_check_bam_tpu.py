@@ -133,9 +133,20 @@ def test_equals_the_eager_reference_at_every_position(
     for name in ("mesh.dirty_steps", "checkbam.list_overflows",
                  "check.fused_demotions"):
         assert not counters.get(name), name
-    for span in ("mesh.assemble", "mesh.h2d", "mesh.step_device_ms"):
+    for span in ("mesh.assemble", "mesh.h2d", "mesh.step_device_ms",
+                 "mesh.step_lanes"):
         assert hists[span] == counters["mesh.steps"], span
     assert hists["checkbam.truth_load"] == 1
+    # The step's two new columns: each row's stage-0 survivors and the
+    # lanes its blocks ran for them, far fewer than the rows' capacity.
+    from spark_bam_tpu.tpu.checker import lane_block, lane_capacity
+    from spark_bam_tpu.tpu.stream_check import _next_pow2
+
+    kernel_window = _next_pow2(WINDOW + HALO)
+    survivors, lanes = counters["funnel.survivors"], counters["funnel.lanes"]
+    assert len(index["record_starts"]) <= survivors <= lanes
+    assert lanes % lane_block(kernel_window) == 0
+    assert lanes < counters["mesh.rows"] * lane_capacity(kernel_window)
 
 
 def test_check_bam_tpu_runs_the_same_step_on_what_the_process_sees(

@@ -245,14 +245,21 @@ def test_config_flush_every_and_ring_depth():
 
 
 # ------------------------------------------------------------------------
-# The count's lane stage, sized by its window's survivors (PR 30). One
-# reference for every case: ``check_window`` under the funnel, which runs
-# the lane stage once at the window's whole capacity (``w // 32`` lanes)
-# and whose owned verdicts and escapes ARE the count's two scalars.
+# The funnel's lane stage, sized by its window's survivors (PR 30 for the
+# count, PR 34 for ``check_window``: one stage, two consumers). One reference
+# for every case: the stage the package had before, ONE compaction, deep
+# check and walk at the window's whole capacity (``w // 32`` lanes), kept
+# here from the package's own pieces. Its ``check_window`` is what the
+# blocked one must return, array for array, and its owned verdicts and
+# escapes ARE the count's two scalars.
 
 W1 = 1 << 20                      # capacity 32,768 lanes: blocks are distinct
 CAPACITY = ck.lane_capacity(W1)
 BLOCKS = (512, 1024, 4096, CAPACITY)  # forced lanes a block: 64 … 1 blocks
+
+#: Every array ``check_window`` returns (``lanes`` is held to the rule).
+CHECK_KEYS = ("verdict", "fail_mask", "reads_parsed", "reads_before",
+              "exact", "escaped", "survivors")
 
 
 def _lanes_rule(survivors: int, block: int, capacity: int = CAPACITY) -> int:
@@ -261,9 +268,38 @@ def _lanes_rule(survivors: int, block: int, capacity: int = CAPACITY) -> int:
     return min(-(-survivors // block), -(-capacity // block)) * block
 
 
+def _full_capacity_check_window(padded, lengths, num_contigs, n, at_eof):
+    """``check_window(funnel=True)`` with ONE lane stage of the window's
+    whole capacity: no blocks, no loop."""
+    w = padded.shape[0] - ck.PAD
+    S = ck._flag_stage(
+        padded, lengths, num_contigs, n, at_eof, "xla", False, True)
+    capacity = ck.lane_capacity(w)
+    table = ck._rank_table(S["survivor"])
+    cand = ck._ranked_positions(table, jnp.arange(capacity, dtype=jnp.int32))
+    live = cand >= 0
+    tgt0, F_cand = ck._deep_lanes(
+        padded, lengths, num_contigs, n, ck._funnel_tables(padded, n), cand,
+        live)
+    F_deep = jnp.zeros(w + 1, dtype=jnp.int32).at[tgt0].set(
+        F_cand, mode="drop")[:w]
+    lanes = ck._walk_lanes(
+        cand, live, ck._funnel_lookup(S["F"], F_deep), S["misc_at"], n,
+        at_eof, w, 10, unroll=True)
+    return ck._scatter_lanes({
+        "survivor": S["survivor"], "res0": S["res0"],
+        "fail_mask0": S["fail_mask0"], "inexact0": S["inexact0"],
+        "cand": cand, **lanes,
+        "overflow": table.n_set > capacity, "n_survivors": table.n_set,
+        "lanes": jnp.int32(capacity),
+    }, w)
+
+
 @pytest.fixture(scope="module")
 def full_stage():
-    return ck.make_check_window(W1, 10, funnel=True)
+    import jax
+
+    return jax.jit(_full_capacity_check_window)
 
 
 @pytest.fixture(scope="module")
@@ -286,6 +322,25 @@ def blocked():
     return get
 
 
+@pytest.fixture(scope="module")
+def blocked_check():
+    """``block -> jitted check_window`` under the funnel, its lane stage
+    forced to ``block`` lanes a block (None: the module's own rule)."""
+    import jax
+
+    cache = {}
+
+    def get(block):
+        if block not in cache:
+            cache[block] = jax.jit(
+                lambda pd, ld, nc, n, at_eof: ck._scatter_lanes(
+                    ck._check_lanes(pd, ld, nc, n, at_eof, funnel=True,
+                                    block=block), W1))
+        return cache[block]
+
+    return get
+
+
 def _want(full_stage, pd, ld, nc, n, at_eof, lo, own):
     r = full_stage(pd, ld, nc, n, jnp.bool_(at_eof))
     i = np.arange(W1)
@@ -301,10 +356,27 @@ def _got(out):
     return int(out["count"]), int(out["esc_count"]), int(out["survivors"])
 
 
-def _assert_blocked_matches(full_stage, blocked, block, pd, ld, nc, n):
-    """Every owned span, both ``at_eof``: the blocked stage's three scalars
-    are the full stage's, and it ran the lanes the rule gives."""
+def _assert_check_matches(want, got, block, why=""):
+    """EVERY array of the blocked ``check_window`` is the full-capacity
+    stage's, and it ran the lanes the rule gives."""
+    for k in CHECK_KEYS:
+        np.testing.assert_array_equal(
+            np.asarray(got[k]), np.asarray(want[k]), err_msg=f"{k} {why}")
+    assert int(got["lanes"]) == _lanes_rule(int(want["survivors"]), block)
+
+
+def _assert_blocked_matches(
+        full_stage, blocked, blocked_check, program, block, pd, ld, nc, n):
+    """Both ``at_eof``. The count: every owned span, the blocked stage's
+    three scalars are the full stage's, and it ran the lanes the rule
+    gives. ``check_window``: every returned array."""
     for at_eof in (True, False):
+        if program == "check_window":
+            _assert_check_matches(
+                full_stage(pd, ld, nc, n, jnp.bool_(at_eof)),
+                blocked_check(block)(pd, ld, nc, n, jnp.bool_(at_eof)),
+                block, f"block={block} at_eof={at_eof}")
+            continue
         for lo, own in ((0, int(n)), (1000, int(n) // 2)):
             want = _want(full_stage, pd, ld, nc, n, at_eof, lo, own)
             out = blocked(block)(
@@ -314,6 +386,9 @@ def _assert_blocked_matches(full_stage, blocked, block, pd, ld, nc, n):
             lanes = int(out["lanes"])
             assert lanes == _lanes_rule(want[2], block)
             assert lanes >= min(want[2], CAPACITY)
+
+
+PROGRAMS = ("count_window", "check_window")
 
 
 @pytest.fixture(scope="module")
@@ -330,33 +405,40 @@ def generated_windows(tmp_path_factory):
     return out
 
 
+@pytest.mark.parametrize("program", PROGRAMS)
 @pytest.mark.parametrize("block", BLOCKS)
 @pytest.mark.parametrize("name", ["wgs-short", "longread-hifi"])
 def test_lane_blocks_match_the_full_stage_on_generated_files(
-        generated_windows, full_stage, blocked, name, block):
+        generated_windows, full_stage, blocked, blocked_check, name, block,
+        program):
     (pd, n), (ld, nc) = generated_windows[name]
-    _assert_blocked_matches(full_stage, blocked, block, pd, ld, nc, n)
+    _assert_blocked_matches(
+        full_stage, blocked, blocked_check, program, block, pd, ld, nc, n)
 
 
+@pytest.mark.parametrize("program", PROGRAMS)
 @pytest.mark.parametrize("block", BLOCKS)
 @pytest.mark.parametrize("which", [0, 1])
 def test_lane_blocks_match_the_full_stage_on_corpora(
-        corpus, full_stage, blocked, which, block):
+        corpus, full_stage, blocked, blocked_check, which, block, program):
     p = corpus[which]
     pd, n = _window_of(flatten_file(p).data, W1)
     ld, nc = _lens_of(p)
-    _assert_blocked_matches(full_stage, blocked, block, pd, ld, nc, n)
+    _assert_blocked_matches(
+        full_stage, blocked, blocked_check, program, block, pd, ld, nc, n)
 
 
+@pytest.mark.parametrize("program", PROGRAMS)
 @pytest.mark.parametrize("block", BLOCKS)
 @pytest.mark.parametrize("kind", ["soup", "bit-flips"])
 def test_lane_blocks_match_the_full_stage_on_adversarial_windows(
-        corpus, full_stage, blocked, kind, block):
+        corpus, full_stage, blocked, blocked_check, kind, block, program):
     """The windows ``test_superset_on_adversarial_windows`` builds, a MiB
     wide: byte soup and a bit-flipped corpus window."""
     pd, n = _window_of(_adversarial(kind, corpus, W1), W1)
     ld, nc = _lens_of(corpus[0])
-    _assert_blocked_matches(full_stage, blocked, block, pd, ld, nc, n)
+    _assert_blocked_matches(
+        full_stage, blocked, blocked_check, program, block, pd, ld, nc, n)
 
 
 def _planted(survivors: int, stride: int = 32):
@@ -390,14 +472,27 @@ PLANTED = {
 }
 
 
+@pytest.mark.parametrize("program", PROGRAMS)
 @pytest.mark.parametrize("survivors", sorted(PLANTED))
-def test_lane_blocks_at_their_edges(full_stage, blocked, survivors):
+def test_lane_blocks_at_their_edges(
+        full_stage, blocked, blocked_check, survivors, program):
     pd, n = _window_of(_planted(survivors, PLANTED[survivors]), W1)
     ld, nc = _planted_lens()
     lo, own = 0, int(n)
     for at_eof in (True, False):
         want = _want(full_stage, pd, ld, nc, n, at_eof, lo, own)
         assert want[2] == survivors  # the window holds what was planted
+        if program == "check_window":
+            ref = full_stage(pd, ld, nc, n, jnp.bool_(at_eof))
+            got = blocked_check(EDGE_BLOCK)(pd, ld, nc, n, jnp.bool_(at_eof))
+            _assert_check_matches(ref, got, EDGE_BLOCK, f"at_eof={at_eof}")
+            if survivors > CAPACITY:
+                # Over the capacity: every block run, every position
+                # unresolved, as the full stage reports it.
+                assert int(got["lanes"]) == CAPACITY
+                assert np.asarray(got["escaped"]).all()
+                assert not np.asarray(got["verdict"]).any()
+            continue
         out = blocked(EDGE_BLOCK)(
             pd, ld, nc, n, jnp.bool_(at_eof), jnp.int32(lo), jnp.int32(own))
         assert _got(out) == want
@@ -415,14 +510,14 @@ def test_lane_blocks_at_their_edges(full_stage, blocked, survivors):
 @pytest.mark.parametrize("name", ["wgs-short", "longread-hifi"])
 def test_count_window_runs_the_lanes_its_survivors_need(
         generated_windows, full_stage, name):
-    """The public program at the module's own ``LANE_BLOCK``."""
+    """The public program at the module's own block (``lane_block``)."""
     (pd, n), (ld, nc) = generated_windows[name]
     kernel = ck.make_count_window(W1, 10, funnel=True)
     lo, own = 0, int(n)
     out = kernel(pd, ld, nc, n, jnp.bool_(True), jnp.int32(lo), jnp.int32(own))
     want = _want(full_stage, pd, ld, nc, n, True, lo, own)
     assert _got(out) == want
-    assert int(out["lanes"]) == _lanes_rule(want[2], ck.LANE_BLOCK)
+    assert int(out["lanes"]) == _lanes_rule(want[2], ck.lane_block(W1))
     assert int(out["lanes"]) < CAPACITY  # fewer than the full stage's
     # Without the funnel the one stage there is runs the whole capacity.
     off = ck.make_count_window(W1, 10, funnel=False)(
@@ -456,8 +551,19 @@ def test_vmapped_count_window_loops_to_the_rows_maximum(
         want = _want(full_stage, pd, ld, nc, n, False, 0, int(n))
         assert tuple(int(out[k][r]) for k in
                      ("count", "esc_count", "survivors")) == want
-        assert int(out["lanes"][r]) == _lanes_rule(want[2], ck.LANE_BLOCK)
+        assert int(out["lanes"][r]) == _lanes_rule(want[2], ck.lane_block(W1))
     assert int(out["lanes"][1]) == 0  # a padding row runs no lane
+    assert _whiles(batched, rows, ns, los, ns) == 2
+    assert _whiles(
+        lambda w, n: one(w, ld, nc, n, jnp.bool_(False), jnp.int32(0), n),
+        pd_s, n_s) == 2
+
+
+def _whiles(fn, *args) -> int:
+    """The ``while`` loops of a traced program: the blocks of the lane
+    stage's two passes (``searchsorted``'s own loop is a ``scan``). Also
+    holds that no ``cond`` switches over lane stages."""
+    import jax
 
     def primitives(jaxpr, into):
         for eqn in jaxpr.eqns:
@@ -469,23 +575,59 @@ def test_vmapped_count_window_loops_to_the_rows_maximum(
                         primitives(getattr(inner, "jaxpr", inner), into)
         return into
 
-    def whiles(fn, *args):
-        names = primitives(jax.make_jaxpr(fn)(*args).jaxpr, [])
-        assert "cond" not in names  # no switch over lane stages
-        return names.count("while")
+    names = primitives(jax.make_jaxpr(fn)(*args).jaxpr, [])
+    assert "cond" not in names  # no switch over lane stages
+    return names.count("while")
 
-    # The blocks of the two passes; ``searchsorted``'s own loop is a
-    # ``scan``. Batched or not, the same two.
-    assert whiles(batched, rows, ns, los, ns) == 2
-    assert whiles(
-        lambda w, n: one(w, ld, nc, n, jnp.bool_(False), jnp.int32(0), n),
-        pd_s, n_s) == 2
-    # ``check_window`` (the served step, check-bam) keeps its one
-    # full-capacity stage: no loop over blocks.
-    assert whiles(
+
+def test_vmapped_check_window_gives_each_row_its_own_arrays(
+        generated_windows, full_stage):
+    """``check_window`` over a short-read row, an empty (padding) row and a
+    long-read row under ``vmap``: each row's arrays are the full-capacity
+    stage's of that row alone, its ``lanes`` its own blocks (none for the
+    padding row), and the program holds ONE lane stage, batched or not: the
+    two ``while`` loops of ``_deep_blocks`` / ``_walk_blocks``."""
+    import functools
+
+    import jax
+
+    (pd_s, n_s), (ld, nc) = generated_windows["wgs-short"]
+    (pd_l, n_l), _ = generated_windows["longread-hifi"]
+    empty = jnp.zeros_like(pd_s)
+    rows = jnp.stack([pd_s, empty, pd_l])
+    ns = jnp.stack([n_s, jnp.int32(0), n_l])
+    one = functools.partial(
+        ck.check_window, reads_to_check=10, funnel=True)
+    batched = jax.jit(jax.vmap(
+        lambda w, n: one(w, ld, nc, n, jnp.bool_(False))))
+    out = batched(rows, ns)
+    block = ck.lane_block(W1)
+    for r, (pd, n) in enumerate(((pd_s, n_s), (empty, jnp.int32(0)),
+                                 (pd_l, n_l))):
+        _assert_check_matches(
+            full_stage(pd, ld, nc, n, jnp.bool_(False)),
+            {k: v[r] for k, v in out.items()}, block, f"row {r}")
+    lanes = [int(x) for x in out["lanes"]]
+    assert lanes[1] == 0 < lanes[2] <= lanes[0] < CAPACITY
+    assert _whiles(batched, rows, ns) == 2
+    assert _whiles(lambda w, n: one(w, ld, nc, n, jnp.bool_(False)),
+                   pd_s, n_s) == 2
+    # Without the funnel: the one full-capacity stage, no loop over blocks.
+    assert _whiles(
         lambda w, n: ck.check_window(
-            w, ld, nc, n, jnp.bool_(False), reads_to_check=10, funnel=True),
+            w, ld, nc, n, jnp.bool_(False), reads_to_check=10),
         pd_s, n_s) == 0
+
+
+def test_the_block_follows_the_rows_width():
+    """A 32nd of the capacity within its bounds: the count's 32 MiB
+    windows keep PR 30's 16,384, a served row of 1 MiB runs the swept
+    1,024, and no window's block is wider than the window's capacity."""
+    assert ck.lane_block(32 << 20) == ck.LANE_BLOCK == 16384
+    assert ck.lane_block(1 << 20) == ck.LANE_BLOCK_MIN == 1024
+    assert ck.lane_block(16 << 20) == 16384 and ck.lane_block(4 << 20) == 4096
+    for w in (32 << 10, 64 << 10, W, W1, 2 << 20, 32 << 20):
+        assert ck.LANE_BLOCK_MIN <= ck.lane_block(w) <= ck.lane_capacity(w)
 
 
 # ------------------------------------------------ the count's escape list

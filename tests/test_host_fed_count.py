@@ -21,7 +21,7 @@ from spark_bam_tpu.parallel.mesh import make_mesh, mesh_steps
 from spark_bam_tpu.parallel.stream_mesh import (
     _ShardedStream, count_reads_sharded,
 )
-from spark_bam_tpu.tpu.checker import LANE_BLOCK, PAD, lane_capacity
+from spark_bam_tpu.tpu.checker import PAD, lane_block, lane_capacity
 from spark_bam_tpu.tpu.stream_check import StreamChecker
 
 MEMBER = 0xFF00  # htslib's payload: what the generators fill every member to
@@ -84,7 +84,7 @@ def _assert_lanes_sized_by_survivors(counters: dict, stages: int,
     a stage each would run at its full capacity."""
     survivors, lanes = counters["funnel.survivors"], counters["funnel.lanes"]
     assert 0 < survivors <= lanes
-    assert lanes % min(LANE_BLOCK, lane_capacity(kernel_window)) == 0
+    assert lanes % lane_block(kernel_window) == 0
     assert lanes < stages * lane_capacity(kernel_window)
 
 
@@ -191,9 +191,8 @@ def test_mesh_count_of_eight_rows_lands_two_a_device(
     # The file's bytes plus the halo each row re-inflates past its span.
     assert counters["inflate.bytes"] > index["uncompressed_bytes"]
     # The same evidence the one-device stream gives, summed over the rows
-    # (each a lane stage of one block here: a 512 KiB row holds no more).
-    assert 0 < counters["funnel.survivors"] <= counters["funnel.lanes"]
-    assert counters["funnel.lanes"] == 8 * lane_capacity(st.kernel_window)
+    # (each whole blocks of an eighth of a 512 KiB row's capacity).
+    _assert_lanes_sized_by_survivors(counters, 8, st.kernel_window)
 
 
 def test_a_short_last_step_is_dealt_over_the_devices(short48):

@@ -506,7 +506,7 @@ class _FakeSteps:
         import numpy as np
 
         def step(ws, ns, eofs, los, owns, lens, ncs):
-            return np.zeros((ws.shape[0], 2), dtype=np.int32)
+            return np.zeros((ws.shape[0], 4), dtype=np.int32)
 
         return step
 

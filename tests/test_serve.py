@@ -151,6 +151,48 @@ def test_batched_counts_byte_identical_to_sequential(service, bam_path):
     assert any(size > 1 for size in service.batcher.batch_sizes)
 
 
+def test_a_tick_reports_its_rows_survivors_and_lanes(tmp_path):
+    """The served step's two new columns reach the funnel's counters and one
+    ``serve.tick_lanes`` observation a tick: on a generated short-read file
+    (the benchmark's kind) a row runs whole blocks of ``lane_block`` that
+    hold its stage-0 survivors, far fewer than its capacity."""
+    import json
+    from pathlib import Path
+
+    from bench.generators import shortread
+    from spark_bam_tpu.tpu.checker import lane_block, lane_capacity
+
+    root = Path(__file__).resolve().parents[1]
+    params = json.loads(
+        (root / "bench" / "configs" / "wgs-short.json").read_text())["params"]
+    path = tmp_path / "short.bam"
+    index = shortread.generate(params, 7, 600_000, path)
+    obs.configure()
+    try:
+        svc = SplitService(Config(serve=SERVE_SPEC))
+        try:
+            resp = svc.submit(
+                {"op": "count", "path": str(path)}).result(timeout=120)
+        finally:
+            svc.close()
+        snap = obs.registry().snapshot()
+    finally:
+        obs.shutdown()
+    assert resp["ok"] and resp["count"] == len(index["record_starts"])
+    counters = {c["name"]: c["value"] for c in snap["counters"]}
+    hists = {h["name"]: h for h in snap["hists"]}
+    window = ServeConfig.parse(SERVE_SPEC).window
+    rows = hists["serve.batch_rows"]["sum"]
+    survivors, lanes = counters["funnel.survivors"], counters["funnel.lanes"]
+    # Every owned record start is a stage-0 survivor of its row (the halo's
+    # are counted again in the next row).
+    assert resp["count"] <= survivors <= lanes
+    assert lanes % lane_block(window) == 0
+    assert lanes < rows * lane_capacity(window)
+    assert hists["serve.tick_lanes"]["count"] == counters["serve.batches"]
+    assert hists["serve.tick_lanes"]["sum"] == lanes
+
+
 def test_fleet_coalesces_across_files(service, bam_path, tmp_path):
     """Rows from different files batch in one tick (per-row contig
     dictionaries); the fleet verdict equals per-file counts."""

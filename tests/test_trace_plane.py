@@ -316,7 +316,8 @@ class _Steps:
     def serve_step(self, **_kw):
         def step(ws, ns, *_rest):
             time.sleep(0.002)
-            return np.stack([ns, np.zeros_like(ns)], axis=1)
+            zero = np.zeros_like(ns)  # escapes, survivors, lanes
+            return np.stack([ns, zero, zero, zero], axis=1)
         return step
 
 
