@@ -7,7 +7,7 @@ forks a series the fleet view can't join, and a per-request dynamic
 name grows the registry without bound. The contract (obs/names.py):
 
 - a literal name passed to ``obs.count``/``observe``/``span``/
-  ``counter``/``gauge``/``histogram`` must be in ``obs.names.NAMES``
+  ``pass_span``/``counter``/``gauge``/``histogram`` must be in ``obs.names.NAMES``
   and follow the dotted lower-case ``layer.stage`` convention (P1 when
   unregistered — add the constant to obs/names.py);
 - an f-string name is P2 when its literal prefix starts with a
@@ -30,7 +30,8 @@ from spark_bam_tpu.analysis.base import LintContext, Rule, const_str, register
 from spark_bam_tpu.obs import names as obs_names
 
 #: obs entry points whose first positional arg is a series/span name
-NAME_FNS = {"count", "observe", "span", "counter", "gauge", "histogram"}
+NAME_FNS = {"count", "observe", "span", "pass_span", "counter", "gauge",
+            "histogram"}
 #: of those, the ones whose kwargs are series labels (span kwargs = attrs)
 LABELED_FNS = {"observe", "counter", "gauge", "histogram"}
 

@@ -3,6 +3,7 @@
 
 from __future__ import annotations
 
+from spark_bam_tpu import obs
 from spark_bam_tpu.cli.output import Printer, UsageError
 from spark_bam_tpu.core.config import Config
 from spark_bam_tpu.load.api import load_bam, load_reads
@@ -64,9 +65,11 @@ def run(
         from spark_bam_tpu.utils.timer import heartbeat_progress
 
         def sharded_once():
+            # One pass, as ``load.tpu_load.count_reads_tpu``'s is: a trace
+            # of its own in the ``--metrics-out`` file.
             with heartbeat_progress(
                 f"count-reads --sharded {path}"
-            ) as progress:
+            ) as progress, obs.pass_span("load.count", path=str(path)):
                 return count_reads_sharded(path, config, progress=progress)
 
         timed_loop(sharded_once)

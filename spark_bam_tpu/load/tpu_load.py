@@ -327,8 +327,11 @@ def count_reads_tpu(path, config: Config = Config()) -> int:
     (``parallel/stream_mesh.count_reads_sharded``: the rows of a step are
     inflated side by side and every chip checks its own); on one device the
     streaming checker's windows are put and dispatched up to ``ring_depth``
-    ahead of it. This is the same code path chip_smoke.py drives."""
-    with obs.span("load.count", path=str(path)):
+    ahead of it. This is the same code path chip_smoke.py drives.
+
+    A call is one pass (``obs.pass_span``): a trace of its own under a
+    live registry, with ``load.head_ms`` / ``load.drain_ms`` at its end."""
+    with obs.pass_span("load.count", path=str(path)):
         if counts_across_chips():
             from spark_bam_tpu.parallel.mesh import local_mesh
             from spark_bam_tpu.parallel.stream_mesh import count_reads_sharded
@@ -361,11 +364,12 @@ def check_bam_tpu(
     complete. A caller that has scanned the block table hands it on
     (``metas``), and one that wants to hear of every step gives a
     ``progress(steps done, positions done, positions in all)``:
-    ``check_bam_sharded``'s."""
+    ``check_bam_sharded``'s. A call is one pass, as ``count_reads_tpu``'s
+    is."""
     from spark_bam_tpu.parallel.mesh import local_mesh
     from spark_bam_tpu.parallel.stream_mesh import check_bam_sharded
 
-    with obs.span("load.check_bam", path=str(path)):
+    with obs.pass_span("load.check_bam", path=str(path)):
         out = check_bam_sharded(
             path, config, mesh=local_mesh(), records_path=records_path,
             metas=metas, progress=progress)

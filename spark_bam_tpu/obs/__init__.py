@@ -20,16 +20,19 @@ from spark_bam_tpu.obs.registry import (
     Counter,
     Gauge,
     Histogram,
+    PassSpan,
     Registry,
     Span,
     configure,
     count,
     counter,
+    dispatched,
     enabled,
     export_jsonl,
     gauge,
     histogram,
     observe,
+    pass_span,
     read_jsonl,
     registry,
     resolve_metrics_path,
@@ -42,18 +45,21 @@ __all__ = [
     "Counter",
     "Gauge",
     "Histogram",
+    "PassSpan",
     "Registry",
     "Span",
     "account",
     "configure",
     "count",
     "counter",
+    "dispatched",
     "enabled",
     "export_jsonl",
     "flight",
     "gauge",
     "histogram",
     "observe",
+    "pass_span",
     "read_jsonl",
     "registry",
     "resolve_metrics_path",

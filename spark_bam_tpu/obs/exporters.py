@@ -174,7 +174,8 @@ def merge_snapshots(snapshots: list[dict]) -> dict:
     read as fleet totals) and take the max of peaks; histograms sum
     count/sum, merge min/max, and concatenate retained samples (capped)
     so fleet p50/p99 come from a cross-worker sample. Series identity is
-    ``(name, sorted labels)`` — the registry's own key.
+    ``(name, sorted labels)`` — the registry's own key. Of the workers'
+    slowest passes the fleet's is kept, a root name.
     """
     from spark_bam_tpu.obs.registry import _HIST_SAMPLE_CAP
 
@@ -184,11 +185,16 @@ def merge_snapshots(snapshots: list[dict]) -> dict:
     counters: dict = {}
     gauges: dict = {}
     hists: dict = {}
+    slowest: dict = {}
     dropped = 0
     for snap in snapshots:
         if not snap:
             continue
         dropped += int(snap.get("dropped_events", 0))
+        for p in snap.get("slowest_passes", []):
+            kept = slowest.get(p["root"])
+            if kept is None or p["ms"] > kept["ms"]:
+                slowest[p["root"]] = p
         for c in snap.get("counters", []):
             cur = counters.setdefault(
                 key(c), {"name": c["name"],
@@ -234,6 +240,7 @@ def merge_snapshots(snapshots: list[dict]) -> dict:
         "gauges": list(gauges.values()),
         "hists": list(hists.values()),
         "dropped_events": dropped,
+        "slowest_passes": list(slowest.values()),
     }
 
 

@@ -124,15 +124,22 @@ NAMES = frozenset({
     "jobs.journal_truncated", "jobs.paused", "jobs.preflight_rejects",
     "jobs.redone_bytes", "jobs.resumed", "jobs.rewrite", "jobs.scrub",
     "jobs.submitted",
-    # load — partition execution
-    "load.check_bam", "load.count", "load.fleet_files", "load.parse", "load.partition",
+    # load — partition execution, and the whole-file pass: the roots
+    # load.count / load.check_bam (obs.pass_span: one trace a pass), its
+    # phases on the feeding thread load.open (header, contig lengths and
+    # their put, the program's lookup) and load.drain (what follows the last
+    # dispatch and no other span holds), and its own account at the root's
+    # exit, load.head_ms / load.drain_ms (docs/observability.md "A pass")
+    "load.check_bam", "load.count", "load.drain", "load.drain_ms",
+    "load.fleet_files", "load.head_ms", "load.open", "load.parse",
+    "load.partition",
     "load.partitions", "load.record_starts", "load.records",
     "load.split_resolutions",
     # mesh — compiled-step registry + shard_map dispatch
     "mesh.assemble", "mesh.dirty_steps", "mesh.dispatch", "mesh.escapes",
     "mesh.h2d", "mesh.h2d_bytes",
     "mesh.patch_chunk_positions", "mesh.patch_chunks", "mesh.patch_rows",
-    "mesh.rows", "mesh.stall", "mesh.step",
+    "mesh.plan", "mesh.rows", "mesh.stall", "mesh.step",
     "mesh.step_device_ms", "mesh.step_lanes", "mesh.steps",
     # progress — long-run heartbeats
     "progress.beats",

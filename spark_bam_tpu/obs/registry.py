@@ -13,6 +13,11 @@ path:
   record wall time, emit one structured JSONL event each, and feed a
   per-name duration histogram so aggregate timings survive even when the
   raw trace is capped.
+- **Passes**: ``with obs.pass_span("load.count", path=p):`` is the root
+  span of one whole-file pass: a trace of its own (every span of the pass
+  carries its id), ``load.head_ms`` / ``load.drain_ms`` at its exit, and
+  the slowest pass of each root name kept with its spans summed by name
+  (``snapshot()["slowest_passes"]``).
 - **Exporters** (``obs.exporters``): JSONL trace file, Prometheus
   text-format snapshot, and a human summary in the reference's stats
   format (``core/stats.py``).
@@ -51,6 +56,11 @@ from spark_bam_tpu.obs import trace as _trace
 # context) its own properly-nested stack.
 _SPAN_STACK: "contextvars.ContextVar[tuple]" = contextvars.ContextVar(
     "spark_bam_span_stack", default=()
+)
+# The whole-file pass open on this thread (``pass_span``): the thread that
+# feeds the chip marks its dispatches on it (``dispatched``).
+_PASS: "contextvars.ContextVar[PassSpan | None]" = contextvars.ContextVar(
+    "spark_bam_pass", default=None
 )
 
 # Histograms keep raw samples (for reference-style stats rendering) up to
@@ -231,7 +241,7 @@ class Span:
             top = stack[-1]
             self.parent = top.name
             self.depth = len(stack)
-            if top.trace_id is not None:
+            if self.trace_id is None and top.trace_id is not None:
                 self.trace_id = top.trace_id
                 self.parent_span_id = top.span_id
         if self.trace_id is None:
@@ -260,7 +270,53 @@ class Span:
         if self._ctx_token is not None:
             _trace.reset(self._ctx_token)
             self._ctx_token = None
+        self._finish(ms)
+
+    def _finish(self, ms: float) -> None:
         self.registry._finish_span(self, ms)
+
+
+class PassSpan(Span):
+    """The root span of one whole-file pass (``load.count``,
+    ``load.check_bam``): a trace of its own, so that every span of the
+    pass, on the threads it starts too (``obs.trace.carried``), carries
+    one identifier and the JSONL reads as one tree a pass.
+
+    The feeding thread marks each dispatch that has returned
+    (``dispatched``). At its exit the pass observes ``load.head_ms``, its
+    start to the return of its first dispatch (what the chip waits for at
+    the head of a pass, on the host's clock), and ``load.drain_ms``, the
+    return of its last dispatch to its end, and hands itself to the
+    registry, which keeps the slowest pass of each root name with its
+    spans summed by name (``Registry._keep_slowest``)."""
+
+    __slots__ = ("_t_first", "_t_last", "_pass_token", "_mark")
+
+    def __init__(self, registry: "Registry", name: str, attrs: dict):
+        super().__init__(registry, name, attrs)
+        self._t_first = self._t_last = None
+
+    def __enter__(self) -> "PassSpan":
+        self.trace_id = _trace.new_id()
+        self._pass_token = _PASS.set(self)
+        self._mark = self.registry._event_mark()
+        return super().__enter__()
+
+    def dispatched(self) -> None:
+        self._t_last = time.perf_counter()
+        if self._t_first is None:
+            self._t_first = self._t_last
+
+    def _finish(self, ms: float) -> None:
+        _PASS.reset(self._pass_token)
+        if self._t_first is not None:
+            r = self.registry
+            r.histogram("load.head_ms", unit="ms").observe(
+                (self._t_first - self._t0) * 1e3)
+            r.histogram("load.drain_ms", unit="ms").observe(
+                ms - (self._t_last - self._t0) * 1e3)
+        super()._finish(ms)
+        self.registry._keep_slowest(self, ms)
 
 
 class _NoopMetric:
@@ -310,6 +366,9 @@ class Registry:
         # the event buffer compacts once the set is large enough, so a
         # dropped request costs one set-add, not an O(events) sweep.
         self._dropped_traces: set = set()
+        self._compactions = 0
+        # The slowest pass seen of each root name (``_keep_slowest``).
+        self._slowest: dict[str, dict] = {}
 
     # ------------------------------------------------------------- metrics
     def _get(self, table: dict, cls, name: str, labels: dict):
@@ -419,6 +478,46 @@ class Registry:
         self._append_event(event)
         return span_id
 
+    # -------------------------------------------------------------- passes
+    def _event_mark(self) -> tuple:
+        """Where the event buffer stands: a pass's events lie behind the
+        mark taken at its start (unless the buffer was compacted since)."""
+        return len(self._events), self._compactions
+
+    def _keep_slowest(self, root: PassSpan, ms: float) -> None:
+        """Keep ``root`` if it is the longest pass of its name so far:
+        ``{root, ms, t, at_s, trace, spans: {name: [count, summed ms, max
+        ms]}}`` (``at_s``: its start, in seconds of this registry's life),
+        the pass's span events (those that carry its trace, the
+        root's own left out) summed by name. Summed once, here, and only
+        for a pass that sets the record; the other passes leave nothing
+        but what the histograms hold. What the trace buffer dropped for
+        its cap is not in the sums."""
+        kept = self._slowest.get(root.name)
+        if kept is not None and kept["ms"] >= ms:
+            return
+        start, compactions = root._mark
+        with self._lock:
+            if compactions != self._compactions:
+                start = 0
+            mine = [e for e in self._events[start:]
+                    if e.get("trace") == root.trace_id
+                    and e.get("span") != root.span_id]
+        spans: dict[str, list] = {}
+        for e in mine:
+            row = spans.setdefault(e["name"], [0, 0.0, 0.0])
+            row[0] += 1
+            row[1] = round(row[1] + e["ms"], 3)
+            row[2] = max(row[2], e["ms"])
+        record = {
+            "root": root.name, "ms": round(ms, 3),
+            "t": round(root.t_wall, 6),
+            "at_s": round(root.t_wall - self.t_start, 3),
+            "trace": root.trace_id, "spans": spans,
+        }
+        with self._lock:
+            self._slowest[root.name] = record
+
     # ------------------------------------------------------------ snapshot
     def snapshot(self) -> dict:
         """A point-in-time copy of every series (no trace events)."""
@@ -442,6 +541,10 @@ class Registry:
                     for h in self._hists.values()
                 ],
                 "dropped_events": self._dropped,
+                "slowest_passes": [
+                    {**p, "spans": {k: list(v) for k, v in p["spans"].items()}}
+                    for p in self._slowest.values()
+                ],
             }
 
     def events(self) -> list[dict]:
@@ -470,6 +573,7 @@ class Registry:
                     if e.get("trace") not in dropped
                 ]
                 self._dropped_traces = set()
+                self._compactions += 1
 
 
 # ------------------------------------------------------- module-level state
@@ -524,6 +628,23 @@ def span(name: str, **attrs):
     return NOOP if r is None else Span(r, name, attrs)
 
 
+def pass_span(name: str, **attrs):
+    """The root span of a whole-file pass (``PassSpan``); the shared no-op
+    when disabled."""
+    r = _active
+    return NOOP if r is None else PassSpan(r, name, attrs)
+
+
+def dispatched() -> None:
+    """The feeding thread's mark that a dispatch of the pass open on it
+    has returned (``PassSpan.dispatched``). Nothing without a live
+    registry, or outside a pass."""
+    if _active is not None:
+        p = _PASS.get()
+        if p is not None:
+            p.dispatched()
+
+
 def count(name: str, n: int = 1) -> None:
     """One-shot unlabeled counter bump — the hot-loop shorthand."""
     r = _active
@@ -542,7 +663,8 @@ def export_jsonl(path, reg: Registry | None = None) -> str:
     """Write a registry's trace + final metric snapshot as JSONL.
 
     One JSON object per line: a ``meta`` header, every span event in
-    completion order, then ``counter``/``gauge``/``hist`` snapshot lines.
+    completion order, then ``counter``/``gauge``/``hist`` snapshot lines
+    and one ``slowest_pass`` line a root name that ran.
     Exports the live registry by default (safe to call with observability
     disabled — writes an empty-run file); pass ``reg`` to export an
     explicit instance (per-worker test registries).
@@ -567,6 +689,8 @@ def export_jsonl(path, reg: Registry | None = None) -> str:
             lines.append(json.dumps({"e": "gauge", **g}))
         for h in snap["hists"]:
             lines.append(json.dumps({"e": "hist", **h}))
+        for p in snap["slowest_passes"]:
+            lines.append(json.dumps({"e": "slowest_pass", **p}))
         if snap["dropped_events"]:
             lines.append(json.dumps(
                 {"e": "dropped", "count": snap["dropped_events"]}

@@ -4,6 +4,11 @@ The ``spark-bam-tpu metrics-report`` subcommand consumes this: parse the JSONL a
 regroup span events by name, and render per-stage duration statistics
 with the same ``core/stats.py`` formatting the golden CLI reports use.
 
+A whole-file pass (``count-reads`` / ``check-bam`` on the device) is a
+trace of its own (``obs.pass_span``), so its spans render as one tree a
+pass, the slowest pass the registry kept first, with that record's
+spans summed by name above it.
+
 Multi-process traces: when several files are given (router + N fabric
 workers, each exporting its own registry), span events carrying trace
 ids are merged *across files* by ``trace_id`` and rendered as one tree
@@ -35,6 +40,8 @@ def load_trace(path) -> dict:
             snapshot["gauges"].append(ev)
         elif kind == "hist":
             snapshot["hists"].append(ev)
+        elif kind == "slowest_pass":
+            snapshot.setdefault("slowest_passes", []).append(ev)
         elif kind == "meta":
             meta = ev
         elif kind == "dropped":
@@ -107,18 +114,47 @@ def render_trace_tree(events: list[dict]) -> str:
     return "\n".join(lines)
 
 
-def render_report(path) -> str:
+def _trace_blocks(traces: dict, snapshot: dict, max_traces: int) -> list:
+    """The report's blocks below the stats: the slowest pass of each root
+    name (its spans summed by name), then one span tree a trace, those
+    passes' first and the rest largest first, ``max_traces`` in all."""
+    blocks = []
+    slowest = snapshot.get("slowest_passes", [])
+    for p in slowest:
+        rows = sorted(p["spans"].items(), key=lambda kv: -kv[1][1])
+        blocks.append(
+            f"slowest {p['root']} pass: {p['ms']:.3f}ms"
+            f" (trace {p.get('trace')})\n" + "\n".join(
+                f"  {name}: {n} x, {total:.3f}ms, max {top:.3f}ms"
+                for name, (n, total, top) in rows))
+    first = [p.get("trace") for p in slowest]
+    ranked = sorted(traces.items(),
+                    key=lambda kv: (kv[0] not in first, -len(kv[1])))
+    for tid, events in ranked[:max_traces]:
+        blocks.append(
+            f"trace {tid} ({len(events)} spans):\n"
+            + render_trace_tree(events)
+        )
+    if len(traces) > max_traces:
+        blocks.append(f"... {len(traces) - max_traces} more traces omitted")
+    return blocks
+
+
+def render_report(path, max_traces: int = 8) -> str:
     """The full metrics-report text for one trace file."""
-    trace = load_trace(path)
-    spans = trace["spans_by_name"]
+    merged = merge_traces([path])
+    spans = merged["spans_by_name"]
+    snapshot = merged["snapshot"]
     header = [
         f"metrics trace: {path}",
         f"span events: {sum(len(v) for v in spans.values())}"
-        + (f" (+{trace['snapshot']['dropped_events']} dropped)"
-           if trace["snapshot"]["dropped_events"] else ""),
+        + (f" (+{snapshot['dropped_events']} dropped)"
+           if snapshot["dropped_events"] else ""),
     ]
-    body = stats_summary(trace["snapshot"], spans_by_name=spans)
-    return "\n".join(header) + "\n\n" + body
+    blocks = ["\n".join(header),
+              stats_summary(snapshot, spans_by_name=spans).rstrip("\n")]
+    blocks += _trace_blocks(merged["traces"], snapshot, max_traces)
+    return "\n\n".join(blocks) + "\n"
 
 
 def render_merged_report(paths, max_traces: int = 8) -> str:
@@ -135,15 +171,5 @@ def render_merged_report(paths, max_traces: int = 8) -> str:
     ]
     blocks = ["\n".join(header), stats_summary(
         merged["snapshot"], spans_by_name=spans).rstrip("\n")]
-    ranked = sorted(merged["traces"].items(),
-                    key=lambda kv: -len(kv[1]))[:max_traces]
-    for tid, events in ranked:
-        blocks.append(
-            f"trace {tid} ({len(events)} spans):\n"
-            + render_trace_tree(events)
-        )
-    if len(merged["traces"]) > max_traces:
-        blocks.append(
-            f"... {len(merged['traces']) - max_traces} more traces omitted"
-        )
+    blocks += _trace_blocks(merged["traces"], merged["snapshot"], max_traces)
     return "\n\n".join(blocks) + "\n"

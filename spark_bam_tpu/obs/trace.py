@@ -81,6 +81,25 @@ def reset(token: contextvars.Token) -> None:
     _current.reset(token)
 
 
+def carried(fn):
+    """``fn`` for a thread the caller starts or a pool it submits to:
+    run there, it has the context bound HERE (a pool's threads begin with
+    an empty one), so its spans join the caller's trace under the
+    caller's span. ``fn`` itself when no context is bound."""
+    ctx = _current.get()
+    if ctx is None:
+        return fn
+
+    def run(*args, **kwargs):
+        token = _current.set(ctx)
+        try:
+            return fn(*args, **kwargs)
+        finally:
+            _current.reset(token)
+
+    return run
+
+
 # ------------------------------------------------------------------ wire
 def carrier(ctx: TraceContext | None = None) -> dict | None:
     """The request-field dict for ``ctx`` (default: the bound context)."""

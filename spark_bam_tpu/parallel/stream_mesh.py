@@ -157,16 +157,39 @@ class _ShardedStream:
         self.num_processes = num_processes
         self.process_id = process_id
 
-        header = read_header(path)
-        lens_list = header.contig_lengths.lengths_list()
-        self.num_contigs = len(lens_list)
-        self.lengths = pad_contig_lengths(np.asarray(lens_list, dtype=np.int32))
-        self.header_end = header.uncompressed_size
+        # The phases of a pass's head on the thread that feeds the chips,
+        # each under a span of its own: the header and the contig lengths'
+        # put, the member walk, the row plan (docs/observability.md).
+        with obs.span("load.open", path=str(path)):
+            header = read_header(path)
+            lens_list = header.contig_lengths.lengths_list()
+            self.num_contigs = len(lens_list)
+            self.lengths = pad_contig_lengths(
+                np.asarray(lens_list, dtype=np.int32))
+            self.header_end = header.uncompressed_size
+            self.lengths_d = jax.device_put(
+                jnp.asarray(self.lengths), NamedSharding(self.mesh, P()))
+            self.nc = jnp.int32(self.num_contigs)
 
         self.fresh = window_uncompressed or config.window_size
         halo = config.halo_size if halo is None else halo
         self.halo = min(halo, self.fresh // 2)
-        self.metas = list(blocks_metadata(path)) if metas is None else metas
+        if metas is None:
+            # The whole-file header walk, under the name the one-chip
+            # stream gives it (``InflatePipeline``).
+            with obs.span("bgzf.read", kind="metadata_scan", path=str(path)):
+                metas = list(blocks_metadata(path))
+        self.metas = metas
+        with obs.span("mesh.plan", members=len(metas)):
+            self._plan(num_processes, chunk_bytes)
+        self._zero_rows: dict = {}
+        self.with_truth = with_truth
+        # What the every-position steps' spans say they serve.
+        self.workload = "check_bam" if with_truth else "full_check"
+
+    def _plan(self, num_processes: int, chunk_bytes: int) -> None:
+        """The row plan and what follows from it: the kernel's width, this
+        process's devices, the rows a step holds, the rows' sharding."""
         (
             self.groups, self.sizes, self.flat_starts, self.first_block,
             self.per_proc,
@@ -193,15 +216,7 @@ class _ShardedStream:
         )
         if self.per_proc:
             self.step_rows_local = min(self.step_rows_local, self.per_proc)
-        self._zero_rows: dict = {}
-        self.with_truth = with_truth
-        # What the every-position steps' spans say they serve.
-        self.workload = "check_bam" if with_truth else "full_check"
-
         self.row_sharding = NamedSharding(self.mesh, P(self.axis))
-        repl = NamedSharding(self.mesh, P())
-        self.lengths_d = jax.device_put(jnp.asarray(self.lengths), repl)
-        self.nc = jnp.int32(self.num_contigs)
 
     # ------------------------------------------------------------- assembly
     def _row(self, ch, g: int):
@@ -261,6 +276,9 @@ class _ShardedStream:
         if not self.per_proc:
             return
         steps = list(range(0, self.per_proc, self.step_rows_local))
+        # The assembly thread begins with an empty context: it takes the
+        # pass's here (``mesh.assemble`` / ``mesh.h2d`` in the pass's trace).
+        assemble = obs.trace.carried(assemble)
         with open_channel(self.path) as ch, ThreadPoolExecutor(1) as pool:
             pending = pool.submit(assemble, ch, steps[0])
             for i, c0 in enumerate(steps):
@@ -329,7 +347,7 @@ class _ShardedStream:
             owns[i], los[i] = self._row_span(g, n, at_eof, True)
 
         with obs.span("mesh.assemble", c0=c0, rows=len(slots)):
-            list(rows_pool.map(fill, slots))
+            list(rows_pool.map(obs.trace.carried(fill), slots))
         with obs.span("mesh.h2d", c0=c0, rows=len(slots)):
             shards = []
             for d, device in enumerate(self.local_devices):
@@ -564,12 +582,13 @@ def _count_steps(st: "_ShardedStream", config: Config, progress):
     one step late."""
     # Cached per (mesh, params): repeat invocations — and the serve/
     # daemon's ticks — reuse one traced executable instead of re-jitting.
-    step = mesh_steps(st.mesh, st.axis).count_step(
-        reads_to_check=config.reads_to_check, flags_impl=config.flags_impl,
-        funnel=config.funnel_enabled(),
-    )
+    with obs.span("load.open", program="count_step"):
+        step = mesh_steps(st.mesh, st.axis).count_step(
+            reads_to_check=config.reads_to_check,
+            flags_impl=config.flags_impl, funnel=config.funnel_enabled(),
+        )
+        observer = _StepObserver.maybe()
     batches = st.row_batches()
-    observer = _StepObserver.maybe()
     count = escapes = steps = 0
     dirty: list[int] = []  # local row offsets (c0) of escaped steps
 
@@ -613,6 +632,7 @@ def _count_steps(st: "_ShardedStream", config: Config, progress):
             with obs.span("mesh.step", workload="count", c0=c0):
                 t_dispatch = time.perf_counter()
                 out = step(*args)  # the step's totals
+                obs.dispatched()
                 if observer is not None:
                     observer.window(None, 0.0, out, t_dispatch)
                 whole_file = unread is not None and settle(*unread)
@@ -623,9 +643,10 @@ def _count_steps(st: "_ShardedStream", config: Config, progress):
             with obs.span("mesh.step", workload="count", c0=unread[2]):
                 whole_file = settle(*unread)
     finally:
-        batches.close()
-        if observer is not None:
-            observer.close()
+        with obs.span("load.drain"):
+            batches.close()
+            if observer is not None:
+                observer.close()
     if whole_file and unread is not None:
         # The step in flight is dropped, and waited for: the whole-file
         # path that takes over finds an idle mesh.
@@ -1040,10 +1061,12 @@ def check_bam_sharded(
         with_truth=True, num_processes=num_processes, process_id=process_id,
     )
     truth_flats = _truth_flats(path, records_path, st.metas)
-    step = mesh_steps(st.mesh, st.axis).confusion_step(
-        reads_to_check=config.reads_to_check,
-        flags_impl=config.flags_impl, funnel=config.funnel_enabled(),
-    )
+    with obs.span("load.open", program="confusion_step"):
+        step = mesh_steps(st.mesh, st.axis).confusion_step(
+            reads_to_check=config.reads_to_check,
+            flags_impl=config.flags_impl, funnel=config.funnel_enabled(),
+        )
+        observer = _StepObserver.maybe()
 
     def fill_row(row, buf, base, n):
         i0, i1 = np.searchsorted(truth_flats, (base, base + n))
@@ -1060,12 +1083,12 @@ def check_bam_sharded(
     overflowed: set = set()   # global rows with more mismatches than slots
     whole_file = False
     batches = st.batches(fill_row=fill_row)
-    observer = _StepObserver.maybe()
     try:
         for args, done, c0 in batches:
             with obs.span("mesh.step", workload="check_bam", c0=c0):
                 t_dispatch = time.perf_counter()
                 out = step(*args)
+                obs.dispatched()
                 if observer is not None:
                     observer.window(None, 0.0, out[0], t_dispatch)
                 totals, at, counts = (np.asarray(a) for a in out)
@@ -1101,43 +1124,47 @@ def check_bam_sharded(
                 whole_file = True
                 break
     finally:
-        batches.close()
-        if observer is not None:
-            observer.close()
-
-    redo = {g for c0 in dirty for g in _step_global_rows(st, c0)}
-    if (redo or overflowed) and not whole_file:
-        obs.count("checkbam.list_overflows", len(overflowed))
-        with open_channel(path) as ch:
-            for g in sorted(redo | overflowed):
-                pos = _exact_row_true_positions(st, g, 0, ch)
-                if pos is None:
-                    whole_file = True  # no native lib / adversarial growth
-                    break
-                lo = int(st.flat_starts[g])
-                i0, i1 = np.searchsorted(
-                    truth_flats, (lo, lo + int(st.sizes[g])))
-                tp_g, fp_g, fn_g = _confusion(pos, truth_flats[i0:i1])
-                differ += [fp_g, fn_g]
-                if g in redo:  # an overflowed row's sums stand
-                    agg += (tp_g, len(fp_g), len(fn_g))
-    if whole_file:
-        return _check_bam_exact(
-            path, config, st.fresh, st.halo, st.metas, truth_flats,
-            st.total,
-        )
-    differ = np.sort(
-        np.concatenate(differ) if differ else np.empty(0, dtype=np.int64))
-    missed = _in_sorted(differ, truth_flats)  # truth without the verdict
-    obs.count("checkbam.mismatches", len(differ))
-    if (len(differ) - int(missed.sum()), int(missed.sum())) != (
-            int(agg[1]), int(agg[2])):
-        raise RuntimeError(
-            f"{path}: the steps listed {len(differ)} mismatches and summed "
-            f"{int(agg[1])} + {int(agg[2])}"
-        )
-    return _confusion_result(
-        int(agg[0]), differ[~missed], differ[missed], st.total, st.n_global)
+        with obs.span("load.drain", what="close"):
+            batches.close()
+            if observer is not None:
+                observer.close()
+    # What follows the last step: the rows of dirty steps and of overflowed
+    # lists re-derived on the host, the listed positions sorted and split
+    # by the truth, the matrix.
+    with obs.span("load.drain", what="result"):
+        redo = {g for c0 in dirty for g in _step_global_rows(st, c0)}
+        if (redo or overflowed) and not whole_file:
+            obs.count("checkbam.list_overflows", len(overflowed))
+            with open_channel(path) as ch:
+                for g in sorted(redo | overflowed):
+                    pos = _exact_row_true_positions(st, g, 0, ch)
+                    if pos is None:
+                        whole_file = True  # no native lib / adversarial growth
+                        break
+                    lo = int(st.flat_starts[g])
+                    i0, i1 = np.searchsorted(
+                        truth_flats, (lo, lo + int(st.sizes[g])))
+                    tp_g, fp_g, fn_g = _confusion(pos, truth_flats[i0:i1])
+                    differ += [fp_g, fn_g]
+                    if g in redo:  # an overflowed row's sums stand
+                        agg += (tp_g, len(fp_g), len(fn_g))
+        if whole_file:
+            return _check_bam_exact(
+                path, config, st.fresh, st.halo, st.metas, truth_flats,
+                st.total,
+            )
+        differ = np.sort(
+            np.concatenate(differ) if differ else np.empty(0, dtype=np.int64))
+        missed = _in_sorted(differ, truth_flats)  # truth without the verdict
+        obs.count("checkbam.mismatches", len(differ))
+        if (len(differ) - int(missed.sum()), int(missed.sum())) != (
+                int(agg[1]), int(agg[2])):
+            raise RuntimeError(
+                f"{path}: the steps listed {len(differ)} mismatches and summed "
+                f"{int(agg[1])} + {int(agg[2])}"
+            )
+        return _confusion_result(
+            int(agg[0]), differ[~missed], differ[missed], st.total, st.n_global)
 
 
 def _check_bam_exact(

@@ -94,8 +94,9 @@ class DeviceObserver:
                     # its own next wait; there is nothing to time here.
                     log.debug("%s: wait failed", name, exc_info=True)
 
-        self._threads.append(
-            threading.Thread(target=run, name=name, daemon=True))
+        # Started inside a pass: whatever it emits joins the pass's trace.
+        self._threads.append(threading.Thread(
+            target=obs.trace.carried(run), name=name, daemon=True))
         self._threads[-1].start()
         return q
 
@@ -320,8 +321,10 @@ class InflatePipeline:
             )
 
         try:
+            # The pool's threads begin with an empty context: each group
+            # takes the submitter's (``inflate.window`` in the pass's trace).
             pending = [
-                pool.submit(produce, i)
+                pool.submit(obs.trace.carried(produce), i)
                 for i in range(min(self.depth, len(self.groups)))
             ]
             for i in range(len(self.groups)):
@@ -339,7 +342,8 @@ class InflatePipeline:
                         view = fut.result()
                     nxt = i + self.depth
                     if nxt < len(self.groups):
-                        pending.append(pool.submit(produce, nxt))
+                        pending.append(
+                            pool.submit(obs.trace.carried(produce), nxt))
                 if i == len(self.groups) - 1:
                     view.at_eof = True
                 yield view

@@ -216,10 +216,11 @@ class StreamChecker:
         self.config = config
         self.use_device = use_device
         self.progress = progress
-        self.header = read_header(path)
-        self.lengths = np.array(
-            self.header.contig_lengths.lengths_list(), dtype=np.int32
-        )
+        with obs.span("load.open", path=str(path)):
+            self.header = read_header(path)
+            self.lengths = np.array(
+                self.header.contig_lengths.lengths_list(), dtype=np.int32
+            )
         fresh = window_uncompressed or config.window_size
         halo = config.halo_size if halo is None else halo
         # The halo must leave room to advance; chains needing more lookahead
@@ -538,7 +539,9 @@ class StreamChecker:
         waits for it at the head of a pass only. Under a live registry a
         ``DeviceObserver`` takes ``inflate.device_ms`` off this thread,
         which dispatches and waits exactly as it does with the registry
-        off.
+        off. Inside a pass (``obs.pass_span``) the two ends of this loop lie
+        under spans of their own, ``load.open`` and ``load.drain``, and each
+        dispatch that has returned is marked (``obs.dispatched``).
 
         Owned candidates whose chains ran past their window's buffer (a
         record longer than the halo: ultra-long reads) come back from that
@@ -559,11 +562,14 @@ class StreamChecker:
         from spark_bam_tpu.tpu.inflate import FRAMES, DeviceObserver
 
         funnel = self.config.funnel_enabled()
-        kernel = make_count_window(
-            self.kernel_window, self.config.reads_to_check,
-            flags_impl=self._flags_impl(), funnel=funnel, escapes=ESCAPE_LIST,
-        )
-        lens_dev, nc = self._device_inputs()
+        with obs.span("load.open", program="count_window"):
+            kernel = make_count_window(
+                self.kernel_window, self.config.reads_to_check,
+                flags_impl=self._flags_impl(), funnel=funnel,
+                escapes=ESCAPE_LIST,
+            )
+            lens_dev, nc = self._device_inputs()
+            observer = DeviceObserver.maybe()
         w = self.kernel_window
 
         total = 0
@@ -576,7 +582,6 @@ class StreamChecker:
         # host buffer its escapes would resolve from: at most ring_depth + 1.
         ring: list = []
         escapes = _CountEscapes(self.lengths, self.config)
-        observer = DeviceObserver.maybe()
         # Every window is inflated into a frame that is its padded operand
         # too (carry in front, zeros behind); ``views`` and ``held`` name
         # the frames of the row in hand and of the ring's windows.
@@ -632,6 +637,7 @@ class StreamChecker:
                             jnp.bool_(at_eof), jnp.int32(lo),
                             jnp.int32(own_end),
                         )
+                    obs.dispatched()
                     if observer is not None:
                         observer.window(
                             operand, t_put, out["count"], t_dispatch)
@@ -662,9 +668,10 @@ class StreamChecker:
         finally:
             # Closing the generator shuts the pipeline's pool and channel
             # before the exact path (if any) reopens the file.
-            rows.close()
-            if observer is not None:
-                observer.close()
+            with obs.span("load.drain"):
+                rows.close()
+                if observer is not None:
+                    observer.close()
         if escapes.overflowed:
             # The pass that starts over: the spans path resolves every
             # deferral bit-exactly. Suppress progress so consumers don't

@@ -1,3 +1,3 @@
-from spark_bam_tpu.utils.timer import Timer, heartbeat, profile_trace
+from spark_bam_tpu.utils.timer import Timer, heartbeat
 
-__all__ = ["Timer", "heartbeat", "profile_trace"]
+__all__ = ["Timer", "heartbeat"]

@@ -1,4 +1,4 @@
-"""Timers, heartbeats, and profiler hooks — thin shims over ``obs``.
+"""Timers and heartbeats — thin shims over ``obs``.
 
 The reference's observability is wall-clock ``Timer.time`` blocks and
 heartbeat logging (SURVEY.md §5: ComputeSplits.scala:74-106,
@@ -7,17 +7,14 @@ These helpers predate the unified observability layer
 (``spark_bam_tpu.obs``) and are kept as shims: a named ``Timer`` feeds
 its duration into the live registry's ``timer.<name>`` histogram, and
 heartbeats bump ``progress.beats``. New instrumentation should use
-``obs.span``/``obs.counter`` directly. ``profile_trace`` wraps any block
-in a TensorBoard-viewable device trace when ``SPARK_BAM_PROFILE_DIR`` is
-set, and is a no-op otherwise — it composes with ``--metrics-out``
-(wall-clock spans and a device trace can capture the same run).
+``obs.span``/``obs.counter`` directly; a device trace is ``--profile``
+(``tpu/inflate.maybe_profile_window``, docs/observability.md).
 """
 
 from __future__ import annotations
 
 import contextlib
 import logging
-import os
 import time
 
 from spark_bam_tpu import obs
@@ -83,16 +80,3 @@ def heartbeat_progress(
         yield lambda k, done, total: beat(
             f"{unit} {k}, {done}/{total} positions"
         )
-
-
-@contextlib.contextmanager
-def profile_trace(name: str = "spark-bam-tpu"):
-    """JAX device trace when SPARK_BAM_PROFILE_DIR is set; else no-op."""
-    trace_dir = os.environ.get("SPARK_BAM_PROFILE_DIR")
-    if not trace_dir:
-        yield
-        return
-    import jax
-
-    with jax.profiler.trace(os.path.join(trace_dir, name)):
-        yield

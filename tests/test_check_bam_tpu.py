@@ -23,7 +23,9 @@ from spark_bam_tpu.core.config import Config
 from spark_bam_tpu.parallel import stream_mesh
 from spark_bam_tpu.parallel.mesh import MISMATCH_LIST, make_mesh
 from spark_bam_tpu.parallel.stream_mesh import check_bam_sharded
-from tests.test_host_fed_count import _observed
+from tests.test_host_fed_count import (
+    _observed, _traced, assert_one_trace_a_pass,
+)
 
 ROOT = Path(__file__).resolve().parents[1]
 MEMBER = 0xFF00
@@ -164,6 +166,29 @@ def test_check_bam_tpu_runs_the_same_step_on_what_the_process_sees(
     got, counters, hists = _observed(lambda: check_bam_tpu(local, config))
     assert_same(got, expected(verdict, truth), jax.local_device_count())
     assert counters["checkbam.passes"] == hists["load.check_bam"] == 1
+
+
+def test_a_check_bam_pass_is_one_trace_with_its_own_account(bam, tmp_path):
+    """``check_bam_tpu`` twice: two traces, the assembly thread's spans in
+    them, the walk, the plan, the truth and both ends under spans."""
+    from spark_bam_tpu.load.tpu_load import check_bam_tpu
+
+    path, index, verdict = bam
+    truth, _, _ = perturbed(index, seed=12)
+    local = tmp_path / "short.bam"
+    local.symlink_to(path)
+    write_sidecar(index, truth, str(local) + ".records")
+    config = Config(window_size=WINDOW, halo_size=HALO)
+    values, events, hists = _traced(lambda: check_bam_tpu(local, config))
+    for got in values:
+        assert_same(got, expected(verdict, truth), jax.local_device_count())
+    assert_one_trace_a_pass(
+        events, hists, "load.check_bam", 2,
+        phases={"load.open", "bgzf.read", "mesh.plan", "checkbam.truth_load",
+                "mesh.stall", "mesh.step", "mesh.dispatch", "load.drain"},
+        threads={"mesh.assemble", "mesh.h2d", "inflate.window"})
+    assert hists["bgzf.read"] == hists["mesh.plan"] == 2
+    assert hists["load.drain"] == 4  # the close, then the result, a pass
 
 
 def test_a_row_over_its_list_is_rederived_and_still_exact(bam, tmp_path):

@@ -64,10 +64,51 @@ def _observed(run):
     finally:
         obs.shutdown()
     counters = {c["name"]: c["value"] for c in snap["counters"]}
-    hists = {}
+    return value, counters, _hist_counts(snap)
+
+
+def _hist_counts(snap: dict) -> dict:
+    hists: dict = {}
     for h in snap["hists"]:
         hists[h["name"]] = hists.get(h["name"], 0) + h["count"]
-    return value, counters, hists
+    return hists
+
+
+def _traced(run, passes: int = 2):
+    """``run()`` ``passes`` times under one live registry: ``(values, span
+    events, histogram counts)``."""
+    obs.shutdown()
+    obs.configure()
+    try:
+        values = [run() for _ in range(passes)]
+        events = obs.registry().events()
+        snap = obs.registry().snapshot()
+    finally:
+        obs.shutdown()
+    return values, events, _hist_counts(snap)
+
+
+def assert_one_trace_a_pass(events: list, hists: dict, root: str,
+                            passes: int, phases: set, threads: set) -> None:
+    """What ``obs.pass_span`` promises of ``passes`` passes: every span
+    event carries the trace of exactly one root, those of the pool and
+    assembly threads (``threads``) too, each under a span of its own pass;
+    the phases of the head and the tail are there, once a pass at least;
+    the pass's own account is observed once a pass."""
+    roots = [e for e in events if e["name"] == root]
+    assert len(roots) == passes
+    traces = [e["trace"] for e in roots]
+    assert len(set(traces)) == passes
+    assert all("pspan" not in e for e in roots)
+    for trace in traces:
+        mine = [e for e in events if e.get("trace") == trace]
+        ids = {e["span"] for e in mine}
+        names = {e["name"] for e in mine}
+        assert phases | threads <= names, (phases | threads) - names
+        assert all(e["pspan"] in ids for e in mine if e["name"] != root)
+    assert all(e.get("trace") in traces for e in events)
+    assert hists["load.head_ms"] == hists["load.drain_ms"] == passes
+    assert {e["name"] for e in events} <= NAMES
 
 
 def _assert_host_fed(counters: dict, hists: dict) -> None:
@@ -193,6 +234,48 @@ def test_mesh_count_of_eight_rows_lands_two_a_device(
     # The same evidence the one-device stream gives, summed over the rows
     # (each whole blocks of an eighth of a 512 KiB row's capacity).
     _assert_lanes_sized_by_survivors(counters, 8, st.kernel_window)
+
+
+def test_a_stream_pass_is_one_trace_with_its_own_account(short48):
+    """``count_reads_tpu`` on one device, twice: the worker pool's
+    ``inflate.window`` spans belong to their pass, the member walk and the
+    two ends of the pass lie under spans of their own."""
+    from spark_bam_tpu.load.tpu_load import count_reads_tpu
+
+    path, index = short48
+    config = Config(window_size=6 * MEMBER, halo_size=64 << 10)
+    values, events, hists = _traced(lambda: count_reads_tpu(path, config))
+    assert values == [len(index["record_starts"])] * 2
+    assert_one_trace_a_pass(
+        events, hists, "load.count", 2,
+        phases={"load.open", "bgzf.read", "check.window", "check.flush",
+                "load.drain"},
+        threads={"inflate.window"})
+    assert hists["inflate.window"] == 16 and hists["bgzf.read"] == 2
+    assert hists["load.open"] == 4  # the file, then the program, a pass
+
+
+def test_a_mesh_pass_is_one_trace_with_its_own_account(short48, monkeypatch):
+    """The same entry on a host of four chips: the assembly thread's
+    ``mesh.assemble`` / ``mesh.h2d`` and its row pool's ``inflate.window``
+    belong to their pass; the member walk has the stream's name."""
+    from spark_bam_tpu.load import tpu_load
+    from spark_bam_tpu.parallel import mesh as mesh_module
+
+    path, index = short48
+    monkeypatch.setattr(tpu_load, "counts_across_chips", lambda: True)
+    monkeypatch.setattr(mesh_module, "local_mesh", _mesh)
+    config = Config(window_size=6 * MEMBER, halo_size=64 << 10)
+    values, events, hists = _traced(
+        lambda: tpu_load.count_reads_tpu(path, config))
+    assert values == [len(index["record_starts"])] * 2
+    assert_one_trace_a_pass(
+        events, hists, "load.count", 2,
+        phases={"load.open", "bgzf.read", "mesh.plan", "mesh.stall",
+                "mesh.step", "mesh.dispatch", "load.drain"},
+        threads={"mesh.assemble", "mesh.h2d", "inflate.window"})
+    assert hists["inflate.window"] == 16 and hists["bgzf.read"] == 2
+    assert hists["mesh.plan"] == 2
 
 
 def test_a_short_last_step_is_dealt_over_the_devices(short48):

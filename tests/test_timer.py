@@ -3,7 +3,7 @@
 import logging
 import time
 
-from spark_bam_tpu.utils.timer import Timer, heartbeat, profile_trace
+from spark_bam_tpu.utils.timer import Timer, heartbeat
 
 
 def test_timer_measures_and_echoes():
@@ -52,20 +52,6 @@ def test_heartbeat_rate_limits(caplog):
             beat("p2")          # suppressed again
     messages = [r.getMessage() for r in caplog.records]
     assert messages == ["indexing: p1"]
-
-
-def test_profile_trace_noop_and_enabled(tmp_path, monkeypatch):
-    monkeypatch.delenv("SPARK_BAM_PROFILE_DIR", raising=False)
-    with profile_trace("t"):
-        pass  # no-op path
-
-    monkeypatch.setenv("SPARK_BAM_PROFILE_DIR", str(tmp_path))
-    import jax.numpy as jnp
-
-    with profile_trace("t"):
-        jnp.zeros(8).block_until_ready()
-    # A trace directory with profiler artifacts must exist.
-    assert any((tmp_path / "t").rglob("*")), "no profiler artifacts written"
 
 
 def test_heartbeat_progress_shape_and_rate(caplog):
