@@ -54,6 +54,13 @@ def _i32_at(p: jnp.ndarray, w: int) -> jnp.ndarray:
     return u
 
 
+def _words_at(p: jnp.ndarray) -> jnp.ndarray:
+    """The window's word view: the little-endian int32 at every byte offset
+    of the padded buffer, ``(w + PAD - 3,)``, which the funnel's lane stage
+    gathers its fields from (one dtype, so it is materialized once)."""
+    return lax.bitcast_convert_type(_i32_at(p, p.shape[0] - PAD), jnp.int32)
+
+
 def _ref_pos_bits(idx, pos, c, len_at, b_neg_idx, b_large_idx, b_neg_pos, b_large_pos):
     neg_idx = idx < -1
     large_idx = (~neg_idx) & (idx >= c)
@@ -182,20 +189,23 @@ def _compute_misc(p, n):
     return remaining, body_end
 
 
-def _misc_at(p, n, pos):
+def _misc_at(U, n, pos):
     """``_compute_misc`` evaluated at arbitrary positions (K,) int32.
 
     The funnel walk needs remaining/body_end only at lane positions, so it
-    gathers the seven fixed-block bytes there instead of materializing two
-    full-width arrays; value-identical to indexing ``_compute_misc``'s
-    outputs at ``pos`` (``pos`` pre-clipped to [0, w), PAD covers the +17)."""
-    def byte(off):
-        return jnp.take(p, pos + off, mode="clip").astype(jnp.uint32)
+    gathers there instead of materializing two full-width arrays: THREE
+    words of the window's word view ``U`` (``_words_at``: the int32 at every
+    byte offset), ``remaining = U[pos]``, ``name_len = U[pos + 12] & 0xFF``,
+    ``n_cigar = U[pos + 16] & 0xFFFF``. A gather costs per index, and a word
+    is one index where its bytes were up to four (seven byte gathers until
+    PR 36). Value-identical to indexing ``_compute_misc``'s outputs at ``pos``
+    (``pos`` pre-clipped to [0, w), PAD covers the +16)."""
+    def word(off):
+        return jnp.take(U, pos + off, mode="clip")
 
-    u = byte(0) | (byte(1) << 8) | (byte(2) << 16) | (byte(3) << 24)
-    remaining = lax.bitcast_convert_type(u, jnp.int32)
-    name_len = byte(12).astype(_I32)
-    n_cigar = (byte(16) | (byte(17) << 8)).astype(_I32)
+    remaining = word(0)
+    name_len = word(12) & 0xFF
+    n_cigar = word(16) & 0xFFFF
     has_name = name_len >= 2
     name_eof = has_name & (pos + 36 + name_len > n)
     name_in = has_name & (~name_eof)
@@ -226,7 +236,7 @@ def _misc_at(p, n, pos):
 _U32 = jnp.uint32
 
 
-def _prefilter_flags(p, lengths, num_contigs, n):
+def _prefilter_flags(p, lengths, num_contigs, n, U=None):
     """Stage-0 funnel pass: the fixed-block-derivable subset of the 19 bits.
 
     A subset of ``_compute_flags``'s mask at every position, bit for bit:
@@ -237,15 +247,18 @@ def _prefilter_flags(p, lengths, num_contigs, n):
     the full pass sets too; where only the exact test rejects, the position
     survives and ``_deep_flags_at`` looks its length up. The
     ``tooFewFixedBlockBytes`` *overwrite* (not OR) is kept — at few-fixed
-    positions the prefilter mask equals the full mask."""
+    positions the prefilter mask equals the full mask.
+
+    ``U`` is the window's word view (``_words_at``) where the caller holds
+    it materialized for the lane stage: the fields are then slices of that
+    one array and the words are not assembled a second time."""
     w = p.shape[0] - PAD
-    u = _i32_at(p, w)
-    i32 = lax.bitcast_convert_type(u, jnp.int32)
+    i32 = _words_at(p) if U is None else U
     remaining = i32[0:w]
     ref_idx = i32[4: w + 4]
     ref_pos = i32[8: w + 8]
     name_len = p[12: w + 12].astype(_I32)
-    n_cigar = (u[16: w + 16] & 0xFFFF).astype(_I32)
+    n_cigar = i32[16: w + 16] & 0xFFFF
     seq_len = i32[20: w + 20]
     next_ref_idx = i32[24: w + 24]
     next_ref_pos = i32[28: w + 28]
@@ -328,34 +341,35 @@ def _badops_before(cwords, cwpre4, q, c):
     return jnp.take(cwpre4, wi * 4 + c, mode="clip") + part.astype(_I32)
 
 
-def _deep_flags_at(p, lengths, num_contigs, n, tables, pos):
+def _deep_flags_at(p, U, lengths, num_contigs, n, tables, pos):
     """The full 19-bit mask of ``_compute_flags`` at arbitrary positions
-    (K,), via K-sized slab gathers + the hierarchical tables. Field-for-field
-    identical to the full pass (same overwrite, same reference quirks)."""
+    (K,), via K-sized gathers + the hierarchical tables. The fixed block's
+    eight fields are EIGHT words of the window's word view ``U``
+    (``_words_at``), one index each (a 36-byte slab of byte indices until
+    PR 36; bytes 32-35 were never read); the name's last byte is the one
+    gather left that reads the bytes ``p``. Field-for-field identical to
+    the full pass (same overwrite, same reference quirks).
+
+    Returns ``(F, remaining, body_end)``: the last two are ``_misc_at``'s
+    at ``pos``, from fields read here already, so the walk's first step
+    (which stands on the lane's own position) gathers nothing."""
     nwords, nwpre, cwords, cwpre4 = tables
     total = p.shape[0]
     pc = jnp.clip(pos, 0, total - 36)
-    slab = jnp.take(p, pc[:, None] + jnp.arange(36, dtype=_I32)[None, :], mode="clip")
 
-    def i32at(off):
-        u = (
-            slab[:, off].astype(_U32)
-            | (slab[:, off + 1].astype(_U32) << 8)
-            | (slab[:, off + 2].astype(_U32) << 16)
-            | (slab[:, off + 3].astype(_U32) << 24)
-        )
-        return lax.bitcast_convert_type(u, jnp.int32)
+    def word(off):
+        return jnp.take(U, pc + off, mode="clip")
 
-    remaining = i32at(0)
-    ref_idx = i32at(4)
-    ref_pos = i32at(8)
-    name_len = slab[:, 12].astype(_I32)
-    fnc = lax.bitcast_convert_type(i32at(16), _U32)
-    n_cigar = (fnc & 0xFFFF).astype(_I32)
+    remaining = word(0)
+    ref_idx = word(4)
+    ref_pos = word(8)
+    name_len = word(12) & 0xFF
+    fnc = word(16)
+    n_cigar = fnc & 0xFFFF
     mapped = ((fnc >> 18) & 1) == 0
-    seq_len = i32at(20)
-    next_ref_idx = i32at(24)
-    next_ref_pos = i32at(28)
+    seq_len = word(20)
+    next_ref_idx = word(24)
+    next_ref_pos = word(28)
 
     c = num_contigs
     cmax = lengths.shape[0]
@@ -417,7 +431,9 @@ def _deep_flags_at(p, lengths, num_contigs, n, tables, pos):
 
     few_fixed = pos > n - 36
     F = jnp.where(few_fixed, _I32(BIT["tooFewFixedBlockBytes"]), F)
-    return F
+    body_end = jnp.where(
+        few_fixed, pos + 36, jnp.where(name_eof, cig_start, cig_end))
+    return F, remaining, body_end
 
 
 class _RankTable(NamedTuple):
@@ -479,7 +495,9 @@ def lane_block(w: int) -> int:
     32 MiB. A block costs its lanes and next to nothing besides (on the
     chip a served step of eight 1 MiB rows of ≈ 2,970 survivors took 33.4
     ms at 1,024, 40.1 at 2,048 and at 4,096, 73.6 at 8,192, 140.8 at
-    16,384: ``PERF.md`` §6, PR 34), so the narrow row wants the block that
+    16,384: ``PERF.md`` §6, PR 34, a lane then costing 1.17 µs; the widths
+    were not swept again after PR 36 took a lane to ≈ 0.6 µs there, 39.7
+    to 20.5 ms a step at 1,024), so the narrow row wants the block that
     leaves the fewest dead lanes; the wide one keeps PR 30's."""
     return max(LANE_BLOCK_MIN, min(LANE_BLOCK, lane_capacity(w) // 32))
 
@@ -497,12 +515,21 @@ def _flag_stage(
     flags_impl: str, pallas_interpret: bool, funnel: bool,
 ):
     """Stage 0, position-wide and run once a window: the flag pass (the
-    prefilter under the funnel), the survivors it leaves and every
-    non-survivor's verdict straight from F. Also ``misc_at``, which reads
-    remaining/body_end at lane positions for the walk."""
+    prefilter under the funnel, over the word view), the survivors it
+    leaves and every non-survivor's verdict straight from F. Also
+    ``misc_at``, which reads remaining/body_end at lane positions for the
+    walk, and under the funnel ``U``, the window's word view that it and
+    the deep flags gather from."""
     w = padded.shape[0] - PAD
+    U = None
     with jax.named_scope("flags"):
         if funnel:
+            # The lane stage pays per gather index, so it gathers 32-bit
+            # words: the int32 at every byte offset, ONE materialized array
+            # a window (the barrier: left to fuse, each lane gather would
+            # assemble its word from four byte gathers again), which the
+            # prefilter reads its fields from too.
+            U = lax.optimization_barrier(_words_at(padded))
             if flags_impl == "pallas":
                 from spark_bam_tpu.tpu.pallas_kernels import (
                     prefilter_check_flags,
@@ -513,7 +540,7 @@ def _flag_stage(
                     interpret=pallas_interpret,
                 )
             else:
-                F = _prefilter_flags(padded, lengths, num_contigs, n)
+                F = _prefilter_flags(padded, lengths, num_contigs, n, U)
         elif flags_impl == "pallas":
             from spark_bam_tpu.tpu.pallas_kernels import full_check_flags
 
@@ -527,7 +554,7 @@ def _flag_stage(
         # Lane-width misc: the walk only ever reads remaining/body_end at
         # lane positions — full-width materialization is the single
         # biggest non-prefilter cost on the funnel path.
-        misc_at = functools.partial(_misc_at, padded, n)
+        misc_at = functools.partial(_misc_at, U, n)
     else:
         with jax.named_scope("flags"):
             remaining, body_end = _compute_misc(padded, n)
@@ -555,39 +582,40 @@ def _flag_stage(
     res0 = jnp.where(esc0, jnp.int8(2), res0)
     fail_mask0 = jnp.where(fail0, F, _I32(0))
     return {
-        "F": F, "misc_at": misc_at, "survivor": survivor, "res0": res0,
+        "F": F, "U": U, "misc_at": misc_at, "survivor": survivor, "res0": res0,
         "fail_mask0": fail_mask0, "inexact0": inexact0,
     }
 
 
-def _deep_lanes(padded, lengths, num_contigs, n, tables, cand, live):
+def _deep_lanes(padded, U, lengths, num_contigs, n, tables, cand, live):
     """Stage 1 at the lanes ``cand``: the full 19-bit flags once at
-    candidate positions, as the ``(targets, masks)`` of a scatter into a
-    position-wide array, so the chain walk can look them up by position
-    (dead lanes target the pad slot ``w``). A walked position either passes
-    the prefilter (then its deep mask is there — deep-failing candidates
+    candidate positions. Returns the targets of their scatter ONTO the
+    position-wide prefilter mask (``_lane_flags``), so the chain walk looks
+    a position's flags up once (dead lanes target the pad slot ``w``), and
+    ``(masks, remaining, body_end)``: what the walk's first step would look
+    up at the lane's own position. A walked position either passes the
+    prefilter (then its deep mask is there — deep-failing candidates
     resolve inside the walk's step logic exactly like fail0/esc0/inexact0)
     or fails it (then the prefilter bits alone are verdict-equivalent)."""
     w = padded.shape[0] - PAD
     with jax.named_scope("flags"):
-        F_cand = _deep_flags_at(
-            padded, lengths, num_contigs, n, tables,
+        F_cand, remaining, body_end = _deep_flags_at(
+            padded, U, lengths, num_contigs, n, tables,
             jnp.where(live, cand, _I32(0)),
         )
     with jax.named_scope("funnel"):
         F_cand = jnp.where(live, F_cand, _I32(0))
         tgt0 = jnp.where(live, cand, _I32(w))
-    return tgt0, F_cand
+    return tgt0, (F_cand, remaining, body_end)
 
 
-def _funnel_lookup(F, F_deep):
-    """The walk's flag lookup under the funnel: the prefilter's mask where
-    it rejects, the deep mask where it passed."""
-    def flags_lookup(pi):
-        pre = jnp.take(F, pi, mode="clip")
-        return jnp.where(pre == 0, jnp.take(F_deep, pi, mode="clip"), pre)
-
-    return flags_lookup
+def _lane_flags(F):
+    """The array the deep masks are scattered onto: the prefilter's ``F``
+    and the pad slot ``w`` for dead lanes. A survivor has ``F == 0`` by
+    definition and the deep masks land at survivors only, so once every
+    block is in, the array is the prefilter's mask where it rejects and the
+    deep mask where it passed, at every position."""
+    return jnp.concatenate([F, jnp.zeros(1, dtype=F.dtype)])
 
 
 # Sentinel bounds for the logical cursor: anything outside [0, n] behaves
@@ -595,10 +623,13 @@ def _funnel_lookup(F, F_deep):
 # exact unless the cursor needs to *re-enter* range — tracked per lane.
 def _walk_lanes(
     cand, live, flags_lookup, misc_at, n, at_eof, w: int,
-    reads_to_check: int, unroll,
+    reads_to_check: int, unroll, first=None,
 ):
     """The chain walk over the lanes ``cand`` (any number of them: lanes are
-    independent), ``reads_to_check`` gather rounds; per-lane verdicts."""
+    independent), ``reads_to_check`` gather rounds; per-lane verdicts.
+    With ``first`` (``_deep_lanes``: the flags, remaining and body_end at
+    the lanes' own positions) the first round takes them and gathers
+    nothing: ``reads_to_check - 1`` gather rounds."""
     capacity = cand.shape[0]
     logical = jnp.where(live, cand, _I32(0))
     physical = logical
@@ -609,7 +640,7 @@ def _walk_lanes(
     reads_parsed = jnp.zeros(capacity, dtype=_I32)
     exact = jnp.ones(capacity, dtype=bool)
 
-    def step(state, step_idx):
+    def step(state, step_idx, looked=None):
         logical, physical, l_overflowed, res, fail_mask, reads_before, reads_parsed, exact = state
         run = res == 0
 
@@ -628,7 +659,10 @@ def _walk_lanes(
         res = jnp.where(eof_esc, jnp.int8(2), res)
         run = res == 0
 
-        f = flags_lookup(jnp.clip(physical, 0, w - 1))
+        if looked is None:
+            f = flags_lookup(jnp.clip(physical, 0, w - 1))
+        else:
+            f, rem, b_end = looked
         f = jnp.where(run, f, _I32(0))
         definitive = f & DEFINITIVE_MASK
         boundary = f & ESCAPE_MASK
@@ -644,8 +678,10 @@ def _walk_lanes(
         run = res == 0
 
         ok = run & (f == 0)
-        pi = jnp.clip(physical, 0, w - 1)
-        rem, b_end = misc_at(pi)
+        if looked is None:
+            # Here and not beside the flags' lookup: the order the program
+            # without the funnel has always lowered in.
+            rem, b_end = misc_at(jnp.clip(physical, 0, w - 1))
         # int32-safe logical advance: out-of-range values collapse to
         # sentinels (n+64 / -64) that preserve all future comparisons unless
         # the cursor would legitimately re-enter [0, n] — flagged for host
@@ -670,10 +706,11 @@ def _walk_lanes(
 
     state = (logical, physical, l_overflowed, res, fail_mask, reads_before, reads_parsed, exact)
     with jax.named_scope("chain_walk"):
-        state, _ = lax.scan(
-            step, state, jnp.arange(reads_to_check, dtype=_I32),
-            unroll=unroll,
-        )
+        rounds = jnp.arange(reads_to_check, dtype=_I32)
+        if first is not None:
+            state, _ = step(state, rounds[0], first)
+            rounds = rounds[1:]
+        state, _ = lax.scan(step, state, rounds, unroll=unroll)
     logical, physical, l_overflowed, res, fail_mask, reads_before, reads_parsed, exact = state
 
     full_chain = live & (res == 0)
@@ -689,8 +726,9 @@ class _LaneBlocks(NamedTuple):
     """Stage 0 and pass 1 of the funnel's lane stage (``_deep_blocks``), as
     pass 2 (``_walk_blocks``) and its consumer take them."""
     S: dict                  # stage 0 (``_flag_stage``)
-    F_deep: jnp.ndarray      # (w + 1,) the deep masks at the survivors
+    F_lane: jnp.ndarray      # (w + 1,) F, the deep masks at the survivors
     cands: jnp.ndarray       # (max_blocks * block,) lane positions by rank
+    first: tuple             # as wide: flags, remaining, body_end at them
     blocks: jnp.ndarray      # () blocks holding the survivors: the trip count
     block: int
     overflow: jnp.ndarray    # () more survivors than ``lane_capacity``
@@ -713,8 +751,11 @@ def _deep_blocks(
     flags → their scatter → the walk → what the consumer keeps) runs in
     blocks of ``lane_block(w)`` lanes, ``ceil(n_survivors / block)`` of
     them: a trip count the device reads from the window. Pass 1 compacts
-    block k (ranks ``k·block …``), deep-checks it and scatters its masks
-    into the carried position-wide ``F_deep``; only then can pass 2 walk,
+    block k (ranks ``k·block …``), deep-checks it (eight words a lane of
+    stage 0's word view ``U``) and scatters its masks onto the carried
+    position-wide ``F_lane``, which starts as the prefilter's ``F``
+    (``_lane_flags``), keeping what the walk's first step needs lane-wide
+    beside the positions; only then can pass 2 walk,
     since a lane's chain visits survivors of later blocks. Lanes are
     independent, so every verdict is what ONE stage of ``lane_capacity``
     lanes gives; a window over that capacity runs every block and reports
@@ -743,23 +784,28 @@ def _deep_blocks(
     ranks = jnp.arange(block, dtype=_I32)
 
     def deep_block(k, carry):
-        F_deep, cands = carry
+        F_lane, cands, first = carry
         with jax.named_scope("funnel"):
             cand = _ranked_positions(table, k * block + ranks)
-        tgt0, F_cand = _deep_lanes(
-            padded, lengths, num_contigs, n, tables, cand, cand >= 0)
+        tgt0, at_cand = _deep_lanes(
+            padded, S["U"], lengths, num_contigs, n, tables, cand, cand >= 0)
         with jax.named_scope("funnel"):
             return (
-                F_deep.at[tgt0].set(F_cand, mode="drop"),
+                F_lane.at[tgt0].set(at_cand[0], mode="drop"),
                 lax.dynamic_update_slice(cands, cand, (k * block,)),
+                tuple(lax.dynamic_update_slice(buf, x, (k * block,))
+                      for buf, x in zip(first, at_cand)),
             )
 
-    F_deep, cands = lax.fori_loop(
+    with jax.named_scope("funnel"):
+        F0 = _lane_flags(S["F"])
+        lane_wide = jnp.zeros(max_blocks * block, dtype=_I32)
+    F_lane, cands, first = lax.fori_loop(
         0, blocks, deep_block,
-        (jnp.zeros(w + 1, dtype=_I32),
-         jnp.full(max_blocks * block, -1, dtype=_I32)),
+        (F0, jnp.full(max_blocks * block, -1, dtype=_I32), (lane_wide,) * 3),
     )
-    return _LaneBlocks(S, F_deep, cands, blocks, block, overflow, n_survivors)
+    return _LaneBlocks(
+        S, F_lane, cands, first, blocks, block, overflow, n_survivors)
 
 
 def _walk_blocks(B: _LaneBlocks, n, at_eof, reads_to_check: int, fold, init):
@@ -768,16 +814,21 @@ def _walk_blocks(B: _LaneBlocks, n, at_eof, reads_to_check: int, fold, init):
     ``lanes`` the per-lane verdicts of ``_walk_lanes``. The count folds them
     into its scalars and its escape list (``_count_lanes``); ``check_window``
     keeps them, lane for lane (``_check_lanes``)."""
-    w = B.F_deep.shape[0] - 1
-    flags_lookup = _funnel_lookup(B.S["F"], B.F_deep)
+    w = B.F_lane.shape[0] - 1
+
+    def flags_lookup(pi):
+        # ONE gather a step: the merged array (``_lane_flags``).
+        return jnp.take(B.F_lane, pi, mode="clip")
 
     def walk_block(k, carry):
         with jax.named_scope("chain_walk"):
-            cand = lax.dynamic_slice(B.cands, (k * B.block,), (B.block,))
+            cand, *first = (
+                lax.dynamic_slice(buf, (k * B.block,), (B.block,))
+                for buf in (B.cands, *B.first))
             live = cand >= 0
         lanes = _walk_lanes(
             cand, live, flags_lookup, B.S["misc_at"], n, at_eof, w,
-            reads_to_check, unroll=True,
+            reads_to_check, unroll=True, first=tuple(first),
         )
         with jax.named_scope("chain_walk"):
             return fold(carry, k, cand, live, lanes)

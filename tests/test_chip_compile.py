@@ -89,6 +89,31 @@ def _position_wide_lookups(text: str, positions: int) -> list:
     return found
 
 
+def _word_views(text: str, window: int) -> list:
+    """The fusions of a compiled program that make the window's word view
+    (``checker._words_at``: the int32 at every byte offset, ``window + PAD
+    - 3`` of them): what the lane stage gathers its fields from since PR
+    36. One a row's program: the view is materialized once, behind its
+    barrier, and neither assembled again by stage 0 nor kept a second time
+    as uint32."""
+    import re
+
+    words = window + PAD - 3
+    return [line[:100] for line in text.splitlines()
+            if re.match(rf"%\S+ \(.*\) -> [su]32\[{words}\]", line)]
+
+
+def _byte_gathers(text: str) -> list:
+    """Result types of the gathers of a compiled program that read bytes.
+    A gather costs per index, so the lane stage reads words: the one byte
+    gather left is the name's last byte, a block of lanes wide (until PR 36
+    a 36-byte slab a lane, ``u8[589824]`` a block, and seven bytes a lane a
+    step of the walk)."""
+    import re
+
+    return re.findall(r"= (u8\[[\d,]*\])\S* gather\(", text)
+
+
 def _whiles_of_no_constant_trip_count(text: str) -> list:
     """The ``while`` loops of a compiled program whose condition holds the
     counter against no constant: a trip count the device reads from its
@@ -129,8 +154,12 @@ def test_count_window_xla_funnel_compiles_at_32mib(chip):
     runs it, with the escape list, whose 64 slots ride in the walk's loop
     (chipless: 0.894 GiB with it against 0.908 without at PR 31; 0.9085
     since PR 32 took stage 0's two lookups, the funnel's tables being
-    scheduled before the peak, the survivors' word packing)."""
-    from spark_bam_tpu.tpu.checker import ESCAPE_LIST, make_count_window
+    scheduled before the peak, the survivors' word packing; **1.0192 since
+    PR 36**: the window's word view, 0.126 GiB, is alive from stage 0 to the
+    last block of the walk)."""
+    from spark_bam_tpu.tpu.checker import (
+        ESCAPE_LIST, LANE_BLOCK, make_count_window,
+    )
 
     kernel = jax.jit(make_count_window(
         WINDOW, 10, "xla", funnel=True, escapes=ESCAPE_LIST))
@@ -139,12 +168,15 @@ def test_count_window_xla_funnel_compiles_at_32mib(chip):
         *_scalars(chip, jnp.int32, jnp.int32, jnp.bool_, jnp.int32,
                   jnp.int32),
     ).compile()
-    assert 1 << 29 < _device_bytes(compiled) < 3 << 29
+    # 1.0192 GiB of temporaries + the 32.25 MiB operand = 1.051 GiB read.
+    assert 1 << 30 < _device_bytes(compiled) < 9 << 27
     text = compiled.as_text()
     assert "gather" in text  # the lane walk: a real program
     assert text.count(" while(") >= 2  # deep-check blocks, then walk blocks
     assert f"s32[{ESCAPE_LIST}]" in text  # the list, carried by the walk
     assert not _position_wide_lookups(text, WINDOW)
+    assert len(_word_views(text, WINDOW)) == 1
+    assert _byte_gathers(text) == [f"u8[{LANE_BLOCK}]"]
 
 
 def _count_step_shapes(shape, repl, devices: int, rows: int):
@@ -202,14 +234,19 @@ def confusion_step(topo, chip):
 
 
 def test_confusion_step_fits_one_chip_at_three_rows(confusion_step):
-    """2.01 GiB of temporaries: ONE row's, since the rows run in turn and
+    """2.27 GiB of temporaries: ONE row's, since the rows run in turn and
     the lane stage in blocks behind a materialized survivor mask (8.23 GiB
     until PR 34: three rows batched, each a full-capacity stage whose word
     packing re-derived the flags at four times their bytes; 3.47 with the
-    blocks and the rows still batched). The mismatch list (``MISMATCH_LIST``
-    slots a row, two levels of 1,024 positions) adds nothing to speak of
-    (1.33 GiB when it packed the mask into 32-bit words)."""
+    blocks and the rows still batched; 2.0142 until PR 36, whose word view
+    is 0.126 GiB of the 0.2509 more: the most bytes alive at once are
+    1,913,398,011 before and after, at the row's reduce, the rest is how the
+    compiler packs its heap around a buffer that lives through both loops).
+    The mismatch list (``MISMATCH_LIST`` slots a row, two levels of 1,024
+    positions) adds nothing to speak of (1.33 GiB when it packed the mask
+    into 32-bit words)."""
     from spark_bam_tpu.parallel.mesh import MISMATCH_LIST
+    from spark_bam_tpu.tpu.checker import LANE_BLOCK
 
     rows, compiled = confusion_step
     ma = compiled.memory_analysis()
@@ -221,6 +258,10 @@ def test_confusion_step_fits_one_chip_at_three_rows(confusion_step):
     # Both passes of the lane stage run as many blocks as the row needs.
     assert len(_whiles_of_no_constant_trip_count(text)) >= 2
     assert not _position_wide_lookups(text, WINDOW)
+    # The word view once a row (the row's program is written once, in the
+    # loop over the rows), and no lane gather reads bytes but the name's.
+    assert len(_word_views(text, WINDOW)) == 1
+    assert _byte_gathers(text) == [f"u8[{LANE_BLOCK}]"]
 
 
 def test_the_nameless_int8_operations_are_the_verdict_scatter(confusion_step):
@@ -268,9 +309,11 @@ def test_count_step_compiles_for_four_chips_at_one_row_a_chip(topo, chip):
     host-inflated 32 MiB row a chip, flat, the count pair ``psum``'d. A
     chip holds its own row's bytes once (a ``(1, N)`` u8 block of a
     row-major operand would be tiled four rows high). The compiler sets
-    0.94 GiB aside for the one row, as on a mesh of one chip (5.9 against
+    1.05 GiB aside for the one row (1.0192 GiB of temporaries and the row;
+    0.94 until PR 36's word view), as on a mesh of one chip (5.9 against
     2.7 GiB before PR 30: ``PERF.md`` §6)."""
     from spark_bam_tpu.parallel.mesh import make_shard_map_count_step
+    from spark_bam_tpu.tpu.checker import LANE_BLOCK
 
     n = 4
     mesh, shape, repl = _mesh_shapes(topo, n)
@@ -278,17 +321,20 @@ def test_count_step_compiles_for_four_chips_at_one_row_a_chip(topo, chip):
     compiled = step.lower(*_count_step_shapes(shape, repl, n, 1)).compile()
     ma = compiled.memory_analysis()
     assert WINDOW < ma.argument_size_in_bytes < WINDOW + (1 << 20)
-    assert 1 << 29 < _device_bytes(compiled) < 3 << 29
+    assert 1 << 30 < _device_bytes(compiled) < 9 << 27
     text = compiled.as_text()
     assert "all-reduce" in text  # the psum, and nothing gathers the rows
     assert "all-gather" not in text and "all-to-all" not in text
     assert not _position_wide_lookups(text, WINDOW)
+    assert len(_word_views(text, WINDOW)) == 1
+    assert _byte_gathers(text) == [f"u8[{LANE_BLOCK}]"]
 
 
 # ----------------------------------------------------------------- small
 def test_serve_step_compiles_at_serve_config_defaults(topo, chip):
     from spark_bam_tpu.parallel.mesh import make_shard_map_serve_step
     from spark_bam_tpu.serve.config import MAX_CONTIGS, ServeConfig
+    from spark_bam_tpu.tpu.checker import lane_block
 
     cfg = ServeConfig()
     b, width = cfg.batch_rows, cfg.window + PAD
@@ -303,6 +349,8 @@ def test_serve_step_compiles_at_serve_config_defaults(topo, chip):
     assert _device_bytes(compiled) < HBM
     text = compiled.as_text()
     assert not _position_wide_lookups(text, b * cfg.window)
+    assert len(_word_views(text, cfg.window)) == 1
+    assert _byte_gathers(text) == [f"u8[{lane_block(cfg.window)}]"]
     # The tick pays for its rows' survivors: both passes of the lane stage
     # are loops whose trip count the device reads from the row.
     assert len(_whiles_of_no_constant_trip_count(text)) >= 2

@@ -8,6 +8,8 @@ adversarial byte soup. Everything here runs on the virtual CPU mesh;
 Pallas coverage uses interpret mode.
 """
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -278,14 +280,23 @@ def _full_capacity_check_window(padded, lengths, num_contigs, n, at_eof):
     table = ck._rank_table(S["survivor"])
     cand = ck._ranked_positions(table, jnp.arange(capacity, dtype=jnp.int32))
     live = cand >= 0
-    tgt0, F_cand = ck._deep_lanes(
-        padded, lengths, num_contigs, n, ck._funnel_tables(padded, n), cand,
-        live)
+    tgt0, (F_cand, _rem, _b_end) = ck._deep_lanes(
+        padded, S["U"], lengths, num_contigs, n,
+        ck._funnel_tables(padded, n), cand, live)
     F_deep = jnp.zeros(w + 1, dtype=jnp.int32).at[tgt0].set(
         F_cand, mode="drop")[:w]
+
+    def flags_lookup(pi):
+        # Two arrays, as the package looked a flag up until PR 36: what its
+        # ONE merged array (``_lane_flags``) must read at every position.
+        pre = jnp.take(S["F"], pi, mode="clip")
+        return jnp.where(pre == 0, jnp.take(F_deep, pi, mode="clip"), pre)
+
+    # ... and every one of the ten steps looks its position up, the first
+    # too (the package's takes what pass 1 read at the lane's position).
     lanes = ck._walk_lanes(
-        cand, live, ck._funnel_lookup(S["F"], F_deep), S["misc_at"], n,
-        at_eof, w, 10, unroll=True)
+        cand, live, flags_lookup, S["misc_at"], n, at_eof, w, 10,
+        unroll=True)
     return ck._scatter_lanes({
         "survivor": S["survivor"], "res0": S["res0"],
         "fail_mask0": S["fail_mask0"], "inexact0": S["inexact0"],
@@ -935,36 +946,239 @@ def _table_gathers(text: str, w: int):
 @pytest.mark.parametrize("program", ["count_window", "serve_step"])
 def test_no_window_wide_gather_from_the_contig_table_under_the_funnel(
         program):
-    import jax
-
     w = 64 << 10
-    S = jax.ShapeDtypeStruct
-    scalars = [S((), jnp.int32), S((), jnp.int32), S((), jnp.bool_)]
-
-    def lowered(funnel):
-        if program == "count_window":
-            fn = jax.jit(ck.make_count_window(w, 10, funnel=funnel))
-            return fn.lower(
-                S((w + ck.PAD,), jnp.uint8), S((_TABLE,), jnp.int32),
-                *scalars, S((), jnp.int32), S((), jnp.int32)).as_text()
-        from spark_bam_tpu.parallel.mesh import (
-            make_mesh, make_shard_map_serve_step,
-        )
-
-        rows = 2
-        step = make_shard_map_serve_step(
-            make_mesh(jax.devices()[:1]), 10, funnel=funnel)
-        return step.lower(
-            S((rows, w + ck.PAD), jnp.uint8), S((rows,), jnp.int32),
-            S((rows,), jnp.bool_), S((rows,), jnp.int32),
-            S((rows,), jnp.int32), S((rows, _TABLE), jnp.int32),
-            S((rows,), jnp.int32)).as_text()
-
-    wide, narrow = _table_gathers(lowered(True), w)
+    wide, narrow = _table_gathers(_lower_lane_program(program, True, w), w)
     assert not wide, wide
     # The exact lookup is still there, at the lanes (one ``_take`` in the
     # text serves ref and next_ref alike).
     assert narrow and max(narrow) < w
     # Without the funnel the full pass looks every position up, as it did.
-    wide_off, _ = _table_gathers(lowered(False), w)
+    wide_off, _ = _table_gathers(_lower_lane_program(program, False, w), w)
     assert wide_off
+
+
+# The lane stage pays per gather index (PR 32 measured it; PR 36 acts on it),
+# so under the funnel it reads 32-bit words of ONE materialized view of the
+# window and looks a flag up in ONE array. Counted in the lowered text, a
+# call each time it is written: the one lane-wide gather left that reads the
+# window's bytes is the name's last byte; the deep flags read eight words a
+# lane; a step of the walk one flag and three words, and its first step
+# nothing at all.
+_READS = 10
+
+
+def _gathers_by_function(text: str):
+    """``reached(name) -> [(operand type, result size), ...]``: the gathers
+    a function of a lowered module runs, those of the functions it calls
+    included, a call counted each time it is written."""
+    import math
+    import re
+
+    own, calls, name = {}, {}, None
+    for line in text.splitlines():
+        m = re.match(r"\s*func\.func \w+ @([\w.]+)\(", line)
+        if m:
+            name = m.group(1)
+            own[name], calls[name] = [], []
+            continue
+        if "stablehlo.gather" in line:
+            m = re.search(
+                r":\s*\(tensor<([^>]*)>.*\)\s*->\s*tensor<([^>]*)>", line)
+            assert m, line
+            size = math.prod(int(d) for d in m.group(2).split("x")[:-1])
+            own[name].append((m.group(1), size))
+        if name is not None:
+            calls[name] += re.findall(r"call @([\w.]+)\(", line)
+    memo = {}
+
+    def reached(fn):
+        if fn not in memo:
+            memo[fn] = own[fn] + [g for c in calls[fn] for g in reached(c)]
+        return memo[fn]
+
+    return own, calls, reached
+
+
+@functools.lru_cache(maxsize=None)
+def _lower_lane_program(program: str, funnel: bool, w: int) -> str:
+    import jax
+
+    S = jax.ShapeDtypeStruct
+    scalars = [S((), jnp.int32), S((), jnp.int32), S((), jnp.bool_)]
+    window = S((w + ck.PAD,), jnp.uint8)
+    if program == "count_window":
+        fn = jax.jit(ck.make_count_window(w, _READS, funnel=funnel))
+        return fn.lower(
+            window, S((_TABLE,), jnp.int32), *scalars, S((), jnp.int32),
+            S((), jnp.int32)).as_text()
+    if program == "check_window":
+        fn = jax.jit(ck.make_check_window(w, _READS, funnel=funnel))
+        return fn.lower(window, S((_TABLE,), jnp.int32), *scalars).as_text()
+    from spark_bam_tpu.parallel.mesh import (
+        make_mesh, make_shard_map_serve_step,
+    )
+
+    rows = 2
+    step = make_shard_map_serve_step(
+        make_mesh(jax.devices()[:1]), _READS, funnel=funnel)
+    return step.lower(
+        S((rows, w + ck.PAD), jnp.uint8), S((rows,), jnp.int32),
+        S((rows,), jnp.bool_), S((rows,), jnp.int32), S((rows,), jnp.int32),
+        S((rows, _TABLE), jnp.int32), S((rows,), jnp.int32)).as_text()
+
+
+@pytest.mark.parametrize(
+    "program", ["count_window", "check_window", "serve_step"])
+def test_the_lane_stage_gathers_words_and_looks_a_flag_up_once(program):
+    w = 64 << 10
+    lanes = ck.lane_block(w)
+    window, words = f"{w + ck.PAD}xui8", f"{w + ck.PAD - 3}xi32"
+    merged = f"{w + 1}xi32"
+
+    own, calls, reached = _gathers_by_function(
+        _lower_lane_program(program, True, w))
+    run = reached("main")
+    # No gather at lane width reads the bytes but the name's last byte.
+    assert [g for g in run if g[0] == window] == [(window, lanes)]
+    # Eight words a lane for the deep flags, three a step for the walk,
+    # whose first step gathers nothing (pass 1 read its position already).
+    steps_looked_up = _READS - 1
+    assert [g for g in run if g[0] == words] == [(words, lanes)] * (
+        8 + 3 * steps_looked_up)
+    # One flag lookup a step, in the merged array.
+    assert run.count((merged, lanes)) == steps_looked_up
+    assert not [g for g in run if g[0] == f"{w}xi32"]
+    # The walk's step, the function that calls the flag's ``take``, holds
+    # four gathers.
+    looks_up = {f for f in own if (merged, lanes) in own[f]}
+    steps = [f for f in own if looks_up & set(calls[f])]
+    assert steps
+    for f in steps:
+        assert len(reached(f)) <= 4, (f, reached(f))
+
+    # Without the funnel the program reads what it read: no word view, the
+    # name's last byte at every position, and a step of the rolled walk
+    # looks up F, remaining and body_end, each as wide as the window.
+    own, calls, reached = _gathers_by_function(
+        _lower_lane_program(program, False, w))
+    run = reached("main")
+    capacity = ck.lane_capacity(w)
+    assert not [g for g in run if g[0] in (words, merged)]
+    assert [g for g in run if g[0] == window] == [(window, w)]
+    assert run.count((f"{w}xi32", capacity)) == 3
+
+
+# The words at their edges (PR 36): the fields a lane reads as 32-bit words
+# of the window's word view, where a byte-wise reading and a word-wise one
+# could part: the last fixed block a buffer holds, the window's last
+# position, the widest body the format allows (the reach of ``PAD``) and a
+# ``remaining`` below zero (the sign through the view's int32). The oracle
+# is the program without the funnel, which slices position-wide fields and
+# never gathers a word.
+def _fixed_block(remaining, name_len=2, n_cigar=0, seq_len=0):
+    """A 36-byte fixed block, unmapped, both refs -1."""
+    rec = np.zeros(36, dtype=np.uint8)
+    i32 = rec.view("<i4")
+    i32[0] = remaining
+    i32[1] = i32[2] = i32[6] = i32[7] = -1
+    rec[12] = name_len
+    rec[16:18] = np.frombuffer(np.uint16(n_cigar).tobytes(), dtype=np.uint8)
+    rec[18] = 4            # flag: unmapped
+    i32[5] = seq_len
+    return rec
+
+
+def _word_edge_window(case: str):
+    """``(data, marks)``: a buffer of real records around the case's forged
+    blocks, and the positions the case is about."""
+    real = [_block() for _ in range(6)]
+    if case == "last_block":
+        # A stage-0 survivor in the buffer's last 36 bytes: its name lies
+        # past ``n``. The six records' chains step onto it.
+        parts = real + [_fixed_block(34)]
+        data = np.concatenate(parts)
+        return data, [len(data) - 36]
+    if case == "window_end":
+        # ``n == w``: a block at ``w - 36``, and a record whose chain steps
+        # to ``w - 1``, the last slot before the flag array's pad slot.
+        at = W - 200
+        stepper = _block()
+        stepper[:4] = np.frombuffer(
+            np.int32(W - 5 - at).tobytes(), dtype=np.uint8)
+        head = np.zeros(at - 38 * len(real), dtype=np.uint8)
+        gap = np.zeros(W - 36 - (at + 38), dtype=np.uint8)
+        data = np.concatenate([head] + real + [stepper, gap, _fixed_block(34)])
+        assert len(data) == W
+        return data, [at, W - 36]
+    if case == "widest_body":
+        # l_read_name 255 and n_cigar 65,535: a body of 262,431 bytes, past
+        # the end of this window from wherever it starts.
+        wide = np.concatenate([
+            _fixed_block(32 + 255 + 4 * 65535, 255, 65535),
+            np.full(254, ord("a"), dtype=np.uint8), np.zeros(1, np.uint8)])
+        tail = np.zeros(4096, dtype=np.uint8)
+        data = np.concatenate(real + [wide, tail] + real + [wide[:36]])
+        return data, [38 * len(real), len(data) - 36]
+    if case == "long_cigar":
+        # name_len and n_cigar with their top bits set (255; 40,000), in a
+        # record that is valid whole: ``remaining`` is short of the body
+        # because the implied size wraps low (seq_len -100,000), so the
+        # chain goes on from the body's END, which only both fields give.
+        forged = np.concatenate([
+            _fixed_block(10_288, 255, 40_000, seq_len=-100_000),
+            np.full(254, ord("a"), dtype=np.uint8), np.zeros(1, np.uint8),
+            np.zeros(4 * 40_000, dtype=np.uint8)])
+        data = np.concatenate(real + [forged] + [_block() for _ in range(14)])
+        return data, [38 * len(real)]
+    assert case == "negative_remaining"
+    # ``remaining`` -5 passes the implied-size test when the implied size
+    # wraps below it (JVM int32: seq_len 1.5e9), and the chain goes on from
+    # the body's end, not from ``pos + 4 + remaining``.
+    forged = np.concatenate([
+        _fixed_block(-5, seq_len=1_500_000_000),
+        np.array([ord("r"), 0], dtype=np.uint8)])
+    data = np.concatenate(real + [forged] + [_block() for _ in range(14)])
+    return data, [38 * len(real)]
+
+
+@pytest.mark.parametrize("program", PROGRAMS)
+@pytest.mark.parametrize(
+    "case", ["last_block", "window_end", "widest_body", "long_cigar",
+             "negative_remaining"])
+def test_lane_words_at_their_edges(kernels, case, program):
+    data, marks = _word_edge_window(case)
+    pd, n = _window_of(data)
+    ld, nc = _lens([LONG]), jnp.int32(1)
+    pre = np.asarray(ck._prefilter_flags(pd, ld, nc, n))
+    assert not pre[marks].any()  # what the case plants survives stage 0
+    _assert_superset(pd, ld, nc, n)
+    if case == "negative_remaining":
+        assert int(np.asarray(ck._words_at(pd))[marks[0]]) == -5
+    for at_eof in (True, False):
+        if program == "check_window":
+            on, off = kernels
+            a = on(pd, ld, nc, n, jnp.bool_(at_eof))
+            b = off(pd, ld, nc, n, jnp.bool_(at_eof))
+            for k in PARITY_KEYS:
+                np.testing.assert_array_equal(
+                    np.asarray(a[k]), np.asarray(b[k]),
+                    err_msg=f"{case} at_eof={at_eof} {k}")
+            if case in ("negative_remaining", "long_cigar") and at_eof:
+                # The forged record is a record: its chain, and the six
+                # before it, run on through the fourteen behind.
+                assert np.asarray(a["verdict"])[: marks[0] + 1: 38].all()
+            continue
+        # Not at EOF stage 0 escapes the buffer's last 35 positions itself,
+        # which the funnel's list leaves to ``esc_overflow``: own up to them.
+        end = int(n) if at_eof else int(n) - 64
+        for lo, own in ((0, end), (38, end // 2)):
+            a, b = (
+                ck.make_count_window(
+                    W, 10, funnel=f, escapes=ck.ESCAPE_LIST)(
+                    pd, ld, nc, n, jnp.bool_(at_eof), jnp.int32(lo),
+                    jnp.int32(own))
+                for f in (True, False))
+            for k in ("count", "esc_count", "esc_pos", "esc_overflow"):
+                np.testing.assert_array_equal(
+                    np.asarray(a[k]), np.asarray(b[k]),
+                    err_msg=f"{case} at_eof={at_eof} [{lo}, {own}) {k}")
