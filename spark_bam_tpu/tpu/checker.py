@@ -477,28 +477,39 @@ def lane_capacity(w: int) -> int:
     return max(w // 32, 4096)
 
 
-#: Most lanes a block of the funnel's lane stage (``lane_block``). The stage
-#: runs ``ceil(n_survivors / block)`` blocks, a number read on the device
-#: from the window itself, so a window pays for its survivors and not for
-#: the worst window the format allows.
-LANE_BLOCK = 16384
+#: Most lanes a block of the funnel's lane stage (``lane_block``): the
+#: block of every window of 2 MiB and more, the count's and check-bam's
+#: 32 MiB among them. The stage runs ``ceil(n_survivors / block)`` blocks, a
+#: number read on the device from the window itself, so a window pays for
+#: its survivors rounded up to this and not for the worst window the format
+#: allows. Swept on the chip at 32 MiB (``PERF.md`` §6, PR 40; programs
+#: alone, ms at 16,384 / 8,192 / 4,096 / 2,048 / 1,024): a long-read window
+#: of 965 survivors 29.4 / 21.2 / 17.0 / 14.8 / 13.7, a short-read window of
+#: 87,178 98.9 / 90.8 / 91.1 / 89.9 / 93.0, check-bam's step of three rows
+#: 363.3 / 342.7 / 340.2 / 335.1 / 343.6. A lane run costs ≈ 0.9 µs live and
+#: 1.05 dead, and a block ≈ 0.07 ms besides (its hundred-odd operations'
+#: fixed cost), which is what 1,024 loses to 2,048 on a window of 87,000
+#: survivors (86 blocks against 43) and wins on one of 965 (a millisecond of
+#: dead lanes).
+LANE_BLOCK = 2048
 
-#: Fewest lanes a block (the narrowest block the sweep ran was 512, no
-#: faster than this).
+#: Fewest lanes a block: a served row's (1 MiB). Swept at that width alone
+#: (PR 34): the narrowest block run was 512, no faster than this.
 LANE_BLOCK_MIN = 1024
 
 
 def lane_block(w: int) -> int:
     """Lanes a block of the lane stage of a ``w``-byte window: a 32nd of
     the window's capacity within ``[LANE_BLOCK_MIN, LANE_BLOCK]``, so 1,024
-    at a served row's 1 MiB and 16,384 at the count's and check-bam's
-    32 MiB. A block costs its lanes and next to nothing besides (on the
-    chip a served step of eight 1 MiB rows of ≈ 2,970 survivors took 33.4
-    ms at 1,024, 40.1 at 2,048 and at 4,096, 73.6 at 8,192, 140.8 at
-    16,384: ``PERF.md`` §6, PR 34, a lane then costing 1.17 µs; the widths
-    were not swept again after PR 36 took a lane to ≈ 0.6 µs there, 39.7
-    to 20.5 ms a step at 1,024), so the narrow row wants the block that
-    leaves the fewest dead lanes; the wide one keeps PR 30's."""
+    at a served row's 1 MiB and 2,048 from 2 MiB up, at the count's and
+    check-bam's 32 MiB. A block costs its lanes and little besides, so a
+    row wants the block that leaves the fewest dead lanes until the blocks'
+    own fixed cost shows. At 1 MiB (a served step of eight rows of ≈ 2,970
+    survivors: 33.4 ms at 1,024, 40.1 at 2,048 and at 4,096, 73.6 at 8,192,
+    140.8 at 16,384; ``PERF.md`` §6, PR 34, before PR 36 halved a lane's
+    cost) that is 1,024, where a row runs three blocks; at 32 MiB, where a
+    short-read window runs its survivors in 43 blocks or in 86, it is 2,048
+    (``LANE_BLOCK`` has the sweep)."""
     return max(LANE_BLOCK_MIN, min(LANE_BLOCK, lane_capacity(w) // 32))
 
 
@@ -760,7 +771,13 @@ def _deep_blocks(
     independent, so every verdict is what ONE stage of ``lane_capacity``
     lanes gives; a window over that capacity runs every block and reports
     ``overflow`` as that stage does. Under ``vmap`` the trip count is the
-    rows' maximum (a row's blocks beyond its own hold dead lanes only)."""
+    rows' maximum (a row's blocks beyond its own hold dead lanes only).
+
+    The block is the one static width here, and what the window pays for:
+    its survivors rounded up to whole blocks (``LANE_BLOCK`` has the sweep
+    and what a dead lane and a block cost). One width a window, so one copy
+    of each loop's body: wide blocks followed by a remainder in narrow ones
+    was swept against (PR 40) and not needed."""
     w = padded.shape[0] - PAD
     S = _flag_stage(
         padded, lengths, num_contigs, n, at_eof,

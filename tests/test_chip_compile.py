@@ -156,7 +156,8 @@ def test_count_window_xla_funnel_compiles_at_32mib(chip):
     since PR 32 took stage 0's two lookups, the funnel's tables being
     scheduled before the peak, the survivors' word packing; **1.0192 since
     PR 36**: the window's word view, 0.126 GiB, is alive from stage 0 to the
-    last block of the walk)."""
+    last block of the walk; 1.0174 at PR 40's block of 2,048 lanes: the one
+    byte gather left is a block wide, so its shape says the block)."""
     from spark_bam_tpu.tpu.checker import (
         ESCAPE_LIST, LANE_BLOCK, make_count_window,
     )
@@ -168,7 +169,7 @@ def test_count_window_xla_funnel_compiles_at_32mib(chip):
         *_scalars(chip, jnp.int32, jnp.int32, jnp.bool_, jnp.int32,
                   jnp.int32),
     ).compile()
-    # 1.0192 GiB of temporaries + the 32.25 MiB operand = 1.051 GiB read.
+    # 1.0174 GiB of temporaries + the 32.25 MiB operand = 1.049 GiB read.
     assert 1 << 30 < _device_bytes(compiled) < 9 << 27
     text = compiled.as_text()
     assert "gather" in text  # the lane walk: a real program
@@ -241,7 +242,8 @@ def test_confusion_step_fits_one_chip_at_three_rows(confusion_step):
     blocks and the rows still batched; 2.0142 until PR 36, whose word view
     is 0.126 GiB of the 0.2509 more: the most bytes alive at once are
     1,913,398,011 before and after, at the row's reduce, the rest is how the
-    compiler packs its heap around a buffer that lives through both loops).
+    compiler packs its heap around a buffer that lives through both loops;
+    2.2628 at PR 40's block of 2,048).
     The mismatch list (``MISMATCH_LIST`` slots a row, two levels of 1,024
     positions) adds nothing to speak of (1.33 GiB when it packed the mask
     into 32-bit words)."""
@@ -309,7 +311,7 @@ def test_count_step_compiles_for_four_chips_at_one_row_a_chip(topo, chip):
     host-inflated 32 MiB row a chip, flat, the count pair ``psum``'d. A
     chip holds its own row's bytes once (a ``(1, N)`` u8 block of a
     row-major operand would be tiled four rows high). The compiler sets
-    1.05 GiB aside for the one row (1.0192 GiB of temporaries and the row;
+    1.05 GiB aside for the one row (1.0174 GiB of temporaries and the row;
     0.94 until PR 36's word view), as on a mesh of one chip (5.9 against
     2.7 GiB before PR 30: ``PERF.md`` §6)."""
     from spark_bam_tpu.parallel.mesh import make_shard_map_count_step

@@ -257,7 +257,9 @@ def test_config_flush_every_and_ring_depth():
 
 W1 = 1 << 20                      # capacity 32,768 lanes: blocks are distinct
 CAPACITY = ck.lane_capacity(W1)
-BLOCKS = (512, 1024, 4096, CAPACITY)  # forced lanes a block: 64 … 1 blocks
+# Forced lanes a block, 64 … 1 blocks: the widths the package runs (1,024
+# at a served row, ``LANE_BLOCK`` = 2,048 at the 32 MiB windows) among them.
+BLOCKS = (512, 1024, ck.LANE_BLOCK, 4096, CAPACITY)
 
 #: Every array ``check_window`` returns (``lanes`` is held to the rule).
 CHECK_KEYS = ("verdict", "fail_mask", "reads_parsed", "reads_before",
@@ -630,15 +632,50 @@ def test_vmapped_check_window_gives_each_row_its_own_arrays(
         pd_s, n_s) == 0
 
 
+@pytest.mark.parametrize("program", PROGRAMS)
+@pytest.mark.parametrize("name", ["longread-hifi", "wgs-short"])
+def test_the_lanes_follow_the_survivors(
+        generated_windows, full_stage, blocked, blocked_check, name, program):
+    """The block the 32 MiB windows run (``lane_block(32 << 20)``, forced
+    here onto the tests' 1 MiB window, where the suite can afford to run
+    it; the 32 MiB programs are lowered in ``tests/test_chip_compile.py``):
+    the lanes are the survivors rounded up to whole blocks and never a
+    whole block more, so a long-read window, whose ≈ 40 survivors fill 4%
+    of a block, runs ONE block and not the sixteen-times-wider one it ran
+    until PR 40 (16,384 lanes for 979 survivors on the chip)."""
+    block = ck.lane_block(32 << 20)
+    (pd, n), (ld, nc) = generated_windows[name]
+    at_eof = jnp.bool_(True)
+    if program == "check_window":
+        out = blocked_check(block)(pd, ld, nc, n, at_eof)
+    else:
+        out = blocked(block)(
+            pd, ld, nc, n, at_eof, jnp.int32(0), jnp.int32(int(n)))
+    survivors, lanes = int(out["survivors"]), int(out["lanes"])
+    assert survivors == int(full_stage(pd, ld, nc, n, at_eof)["survivors"])
+    assert 0 < survivors <= CAPACITY
+    assert lanes == _lanes_rule(survivors, block)
+    assert 0 <= lanes - survivors < block
+    if name == "longread-hifi":
+        assert lanes == block  # one block holds a long-read window
+
+
 def test_the_block_follows_the_rows_width():
-    """A 32nd of the capacity within its bounds: the count's 32 MiB
-    windows keep PR 30's 16,384, a served row of 1 MiB runs the swept
-    1,024, and no window's block is wider than the window's capacity."""
-    assert ck.lane_block(32 << 20) == ck.LANE_BLOCK == 16384
-    assert ck.lane_block(1 << 20) == ck.LANE_BLOCK_MIN == 1024
-    assert ck.lane_block(16 << 20) == 16384 and ck.lane_block(4 << 20) == 4096
+    """A 32nd of the capacity within its bounds: a served row of 1 MiB
+    runs the 1,024 swept there (PR 34), every window from 2 MiB up, the
+    count's and check-bam's 32 MiB among them, the 2,048 swept at 32 MiB
+    (PR 40; 16,384 until then: sixteen times the served row's block, and a
+    long-read window's one block 94% dead), and no window's block is wider
+    than the window's capacity."""
+    assert ck.LANE_BLOCK_MIN == 1024 and ck.LANE_BLOCK == 2048
+    assert ck.lane_block(1 << 20) == ck.LANE_BLOCK_MIN
+    for w in (2 << 20, 4 << 20, 16 << 20, 32 << 20, 64 << 20):
+        assert ck.lane_block(w) == ck.LANE_BLOCK
     for w in (32 << 10, 64 << 10, W, W1, 2 << 20, 32 << 20):
-        assert ck.LANE_BLOCK_MIN <= ck.lane_block(w) <= ck.lane_capacity(w)
+        assert ck.LANE_BLOCK_MIN <= ck.lane_block(w) <= ck.LANE_BLOCK
+        assert ck.lane_block(w) <= ck.lane_capacity(w)
+        assert ck.lane_capacity(w) % ck.lane_block(w) == 0
+    assert ck.LANE_BLOCK in BLOCKS and ck.LANE_BLOCK_MIN in BLOCKS
 
 
 # ------------------------------------------------ the count's escape list
