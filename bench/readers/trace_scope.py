@@ -7,7 +7,7 @@ fingerprint: one per compiled shape). With ``"scopes": []`` the value is the
 median duration of those executions. With scopes it is the self time of the
 ``XLA Ops`` events that lie inside an execution and whose name path (the
 ``tf_op`` stat of the event's metadata,
-``jit(count_window_tokens)/jit(count_window)/check/flags/gather:``) has one of
+``jit(count_window)/check/while/body/flags/gather:``) has one of
 the scopes among its components (``vmap(reduce)`` counts as ``reduce``),
 summed per execution; the value is the
 median over the executions (one cut by an edge of the slice does not move

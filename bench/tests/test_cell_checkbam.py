@@ -1,6 +1,6 @@
 """The cell ``wgs-short-checkbam.check-bam``, as far as the CPU can show it:
 the entry is the issue's, the cell rehearses through ``run.py`` with the
-declared metrics, its byte count makes exactly six rows, the oracle's copy
+declared metrics, its byte count makes exactly 18 rows, the oracle's copy
 against an index made by hand, the new roofline's least bytes, and the
 control: a pass scored against another truth than the oracle's is not
 ``correct``."""
@@ -11,7 +11,7 @@ import numpy as np
 import pytest
 
 from bench import oracle_checkbam
-from bench.tests.conftest import ROOT, config_of, generate
+from bench.tests.conftest import ROOT, config_of, generate, held_entry
 from bench.tests.test_run import last_line, run_py
 
 CELL = "wgs-short-checkbam.check-bam"
@@ -35,17 +35,12 @@ def traffic() -> dict:
 
 def test_the_entry_is_the_issues(benchmark_json):
     bm = benchmark_json
-    cell = next(w for w in bm["workloads"] if w["name"] == CELL)
-    assert (cell["config"], cell["traffic"], cell["chips"]) == (
-        CONFIG, "check-bam", 1)
-    mine = {m["name"]: m["layer"] for m in bm["per_layer"]
-            if CELL in m.get("workloads", [])}
-    assert mine == CHECKBAM_METRICS
+    mine = held_entry(bm, CELL, CONFIG, "check-bam", 1)
+    assert set(CHECKBAM_METRICS) <= mine
     for m in bm["per_layer"]:
-        if m["name"] in mine:
+        if m["name"] in CHECKBAM_METRICS:
+            assert m["layer"] == CHECKBAM_METRICS[m["name"]]
             assert m["workloads"] == [CELL] and m["moves"] == "scan_rate"
-    assert CELL in next(m for m in bm["end_to_end"]
-                        if m["name"] == "scan_rate")["workloads"]
     config, short = config_of(CONFIG), config_of("wgs-short")
     assert config["params"] == short["params"]  # the source's shapes
     assert config["generator"] == short["generator"] == "shortread"
@@ -88,10 +83,10 @@ def test_the_cell_rehearses(trace, benchmark_json):
 
 
 @pytest.mark.parametrize("seed", (3, 2 ** 31 + 27, 987654401))
-def test_the_byte_count_gives_exactly_six_rows(seed, tmp_path):
+def test_the_byte_count_gives_whole_steps_of_rows(seed, tmp_path):
     """The generator cuts the file at the first record past the target, so
     a file is ``header + target + (0 .. one record)`` bytes. Over that whole
-    range the engine's own planner must give 6 rows on one chip: two steps
+    range the engine's own planner must give 18 rows on one chip: six steps
     of three rows, no padding row, every row within the 32 MiB kernel
     window, and the seams where the oracle drops its records."""
     from types import SimpleNamespace
@@ -118,12 +113,12 @@ def test_the_byte_count_gives_exactly_six_rows(seed, tmp_path):
         metas = [Metadata(30_000 * i, 30_000, n) for i, n in enumerate(sizes)]
         groups, owned, flat, first_block, per_proc = _plan_rows(
             metas, cfg.window_size, 1, 1)
-        assert len(groups) == per_proc == shapes["rows_per_pass"]
+        assert len(groups) == per_proc == shapes["rows_per_pass"] == 18
         assert per_proc == (shapes["steps_per_pass"]
                             * shapes["rows_per_chip_per_step"])
         assert int(owned.max()) == shapes["row_owned_bytes"]
         assert flat.tolist() == [k * shapes["row_owned_bytes"]
-                                 for k in range(6)]
+                                 for k in range(per_proc)]
         for g in range(len(groups)):
             b0, b1 = _halo_block_range(
                 metas, groups, first_block, g, g + 1, cfg.halo_size)

@@ -1,42 +1,33 @@
 """The four-chip cell ``wgs-short-host4.count``, as far as the CPU can show
 it: the cell rehearses through ``run.py`` (on one CPU device, so through the
 one-device stream: the mesh engine is selected by TPU chips alone), its
-metrics are the declared ones, and its byte count makes exactly eight rows."""
+metrics are the declared ones, and its byte count makes exactly 24 rows."""
 
 import json
 
 import numpy as np
 import pytest
 
-from bench.tests.conftest import config_of, generate
+from bench.tests.conftest import config_of, generate, held_entry
 from bench.tests.test_run import last_line, run_py
 
 CELL = "wgs-short-host4.count"
 CONFIG = "wgs-short-host4"
 MESH_METRICS = {
-    "mesh_step_device_ms", "resolve_device_ms.mesh", "check_device_ms.mesh",
-    "mesh_assemble_host_ms", "mesh_h2d_ms", "mesh_stall_ms",
-    "device_idle_share.mesh", "idle_attributed_share.mesh",
-    "hbm_peak_gib.mesh", "count_tokens_step_roofline", "chip_balance",
-    "lz77_rounds.mesh", "assemble_device_ms.mesh", "tokenize_host_ms.mesh",
+    "mesh_step_device_ms", "check_device_ms.mesh", "mesh_assemble_host_ms",
+    "mesh_h2d_ms", "mesh_stall_ms", "device_idle_share.mesh",
+    "idle_attributed_share.mesh", "hbm_peak_gib.mesh", "count_step_roofline",
+    "chip_balance",
 }
 
 
 def test_the_entry_is_the_issues(benchmark_json):
     bm = benchmark_json
-    cell = next(w for w in bm["workloads"] if w["name"] == CELL)
-    assert (cell["config"], cell["traffic"], cell["chips"]) == (
-        CONFIG, "count", 4)
-    assert sum(w["chips"] == 4 for w in bm["workloads"]) <= max(
-        1, len(bm["workloads"]) // 2)
-    mine = {m["name"] for m in bm["per_layer"]
-            if CELL in m.get("workloads", [])}
-    assert mine == MESH_METRICS
+    mine = held_entry(bm, CELL, CONFIG, "count", 4)
+    assert MESH_METRICS <= mine
     for m in bm["per_layer"]:
-        if m["name"] in mine:
+        if m["name"] in MESH_METRICS:
             assert m["workloads"] == [CELL] and m["moves"] == "scan_rate"
-    assert CELL in next(m for m in bm["end_to_end"]
-                        if m["name"] == "scan_rate")["workloads"]
     config, short = config_of(CONFIG), config_of("wgs-short")
     assert config["params"] == short["params"]  # the source's shapes
     assert config["guarantees"] == short["guarantees"]
@@ -63,12 +54,12 @@ def test_the_cell_rehearses(trace, benchmark_json):
 
 
 @pytest.mark.parametrize("seed", (3, 2 ** 31 + 27, 987654401))
-def test_the_byte_count_gives_exactly_eight_rows(seed, tmp_path):
+def test_the_byte_count_gives_whole_steps_of_rows(seed, tmp_path):
     """The generator cuts the file at the first record past the target, so
     a file is ``header + target + (0 .. one record)`` bytes. Over that whole
-    range the engine's own planner must give 8 rows: two steps of one row a
-    chip, no padding row, every row within the 512 token rows and the
-    32 MiB kernel window of the one compiled shape."""
+    range the engine's own planner must give 24 rows: six steps of one row a
+    chip, no padding row, every row within the 32 MiB kernel window of the
+    one compiled shape."""
     from spark_bam_tpu.bgzf.block import Metadata
     from spark_bam_tpu.core.config import Config
     from spark_bam_tpu.parallel.stream_mesh import (
@@ -86,12 +77,14 @@ def test_the_byte_count_gives_exactly_eight_rows(seed, tmp_path):
         metas = [Metadata(30_000 * i, 30_000, n) for i, n in enumerate(sizes)]
         groups, owned, _flat, first_block, per_proc = _plan_rows(
             metas, cfg.window_size, 4, 1)
-        assert len(groups) == per_proc == config["shapes"]["rows_per_pass"]
+        shapes = config["shapes"]
+        assert len(groups) == per_proc == shapes["rows_per_pass"] == 24
+        assert per_proc == 4 * shapes["steps_per_pass"] * shapes[
+            "rows_per_chip_per_step"]
         assert int(owned.max()) == 385 * payload
         for g in range(len(groups)):
             b0, b1 = _halo_block_range(
                 metas, groups, first_block, g, g + 1, cfg.halo_size)
-            assert b1 - b0 <= config["shapes"]["token_rows"]
             assert sum(sizes[b0:b1]) <= config["shapes"]["kernel_window_bytes"]
 
 

@@ -11,7 +11,7 @@ import struct
 import numpy as np
 import pytest
 
-from bench.tests.conftest import config_of, generate
+from bench.tests.conftest import config_of, generate, held_entry
 from bench.tests.test_run import last_line, run_py
 
 CELL = "longread-ultra.count"
@@ -21,6 +21,8 @@ ULTRA_METRICS = {
     "window_device_ms.ultra", "inflate_stall_ms.ultra",
     "device_idle_share.ultra", "hbm_peak_gib.ultra",
 }
+#: The long-read cells' rate, under a bound of its own (PR 41).
+RATE = "scan_rate.longread"
 SEEDS = (3, 2 ** 31 + 27, 987654401)
 
 #: The configuration's shapes at a size the CPU writes in a blink: reads of
@@ -44,21 +46,13 @@ def small(seed: int, path, size: int = 1_500_000):
 
 def test_the_entry_is_the_issues(benchmark_json):
     bm = benchmark_json
-    cell = next(w for w in bm["workloads"] if w["name"] == CELL)
-    assert (cell["config"], cell["traffic"], cell["chips"]) == (
-        CONFIG, "count", 1)
-    assert len(bm["workloads"]) == 5
-    assert sum(w["chips"] == 4 for w in bm["workloads"]) == 1
-    mine = {m["name"] for m in bm["per_layer"]
-            if CELL in m.get("workloads", [])}
-    assert mine == ULTRA_METRICS
+    mine = held_entry(bm, CELL, CONFIG, "count", 1, rate=RATE)
+    assert ULTRA_METRICS <= mine
     for m in bm["per_layer"]:
-        if m["name"] in mine:
-            assert m["workloads"] == [CELL] and m["moves"] == "scan_rate"
+        if m["name"] in ULTRA_METRICS:
+            assert m["workloads"] == [CELL] and m["moves"] == RATE
             assert m["layer"] in ("streaming count (tpu/stream_check)",
                                   "device")
-    assert next(m for m in bm["end_to_end"]
-                if m["name"] == "scan_rate")["workloads"][-1] == CELL
     config, hifi = config_of(CONFIG), config_of("longread-hifi")
     assert config["scale"]["uncompressed_bytes"] == (
         hifi["scale"]["uncompressed_bytes"])
@@ -173,7 +167,7 @@ def test_the_index_is_what_the_programs_codec_finds(seed, tmp_path):
 
 
 def test_the_full_size_file_has_the_issues_shapes(tmp_path):
-    """The cell's own 64 MiB, once: the whale's record is 4.6-6.0 MB, above
+    """The cell's own 400 MiB, once: the whale's record is 4.6-6.0 MB, above
     the halo and under ``max_read_size``, starts in the last 256 KiB before
     the first window's owned end, and the other records keep under the
     halo; about 1.77 bytes a base."""
@@ -220,7 +214,7 @@ def test_the_cell_rehearses(trace, benchmark_json):
         assert line["metrics"]["escape_retries"]["value"] == 0
         assert line["metrics"]["escape_candidates"]["value"] == 0
     else:
-        assert set(line["metrics"]) == {"scan_rate", "setup_s"}
+        assert set(line["metrics"]) == {RATE, "setup_s"}
     checks = [json.loads(s) for s in proc.stdout.splitlines()
               if s.startswith('{"check"')]
     assert checks and all(c["ok"] for c in checks)

@@ -37,7 +37,8 @@ def test_rehearse_prints_the_contracts_last_line(cell, trace,
                    "--seconds", "1", "--trace", str(trace), "--rehearse"])
     line = last_line(proc)
     assert set(line) - {"breakdown"} == {"correct", "attempted", "failed",
-                                         "metrics", "device"}
+                                         "metrics", "device", "compared"}
+    assert list(line)[-1] == "compared"
     assert line["correct"] is False  # a rehearsal never measures
     assert line["attempted"] >= 1 and line["failed"] == 0
     assert line["device"]["platform"] == "cpu"
@@ -53,6 +54,14 @@ def test_rehearse_prints_the_contracts_last_line(cell, trace,
     checks = [json.loads(s) for s in proc.stdout.splitlines()
               if s.startswith('{"check"')]
     assert checks and all(c["ok"] and "limit" in c for c in checks)
+    # ... and once a kind in the line's last key and on stderr's last lines.
+    compared = line["compared"]
+    assert sum(row["n"] for row in compared.values()) == len(checks)
+    assert all(row["ok"] and row["got"] == row["limit"]
+               for row in compared.values())
+    said = [json.loads(s) for s in proc.stderr.strip().splitlines()
+            [-len(compared):]]
+    assert [s["compared"] for s in said] == list(compared)
 
 
 def test_without_a_chip_it_refuses():
@@ -109,6 +118,11 @@ def test_a_pass_that_miscounts_is_not_correct(run_cell, monkeypatch):
     monkeypatch.setattr(scan, "count_pass", off_by_one)
     out = run_cell("wgs-short.count")
     assert out["correct"] is False and out["failed"] == out["attempted"] > 0
+    # The line's last key keeps the first pass that failed beside its limit.
+    row = out["compared"]["pass.count"]
+    assert row["ok"] is False and row["got"] == row["limit"] + 1
+    assert row["n"] == out["attempted"]
+    assert out["compared"]["warm_up.count"]["ok"] is True
 
 
 def test_a_served_row_dropped_is_not_correct(run_cell, monkeypatch):
