@@ -139,8 +139,9 @@ NAMES = frozenset({
     "mesh.assemble", "mesh.dirty_steps", "mesh.dispatch", "mesh.escapes",
     "mesh.h2d", "mesh.h2d_bytes",
     "mesh.patch_chunk_positions", "mesh.patch_chunks", "mesh.patch_rows",
-    "mesh.plan", "mesh.rows", "mesh.stall", "mesh.step",
+    "mesh.plan", "mesh.row_inflate", "mesh.rows", "mesh.stall", "mesh.step",
     "mesh.step_device_ms", "mesh.step_lanes", "mesh.steps",
+    "mesh.truth_fill",
     # progress — long-run heartbeats
     "progress.beats",
     # remote — plan-driven data plane (docs/remote.md)
@@ -190,13 +191,15 @@ NAMES = frozenset({
 #: ``chain_walk``, and ``reduce`` (the count sums, a step's psum, the
 #: confusion step's sums and mismatch list); ``check_window`` (the served
 #: step, check-bam) has ``check/scatter`` besides, the lanes' verdicts
-#: scattered back over every position; agg/kernels.py has ``agg_reduce``.
+#: scattered back over every position; the confusion step's cross-chip
+#: tail (the ``psum`` of its sums, the mismatch lists gathered over the
+#: mesh) is ``collect``; agg/kernels.py has ``agg_reduce``.
 #: Under the funnel the lane stage's three (``flags``, ``funnel``,
 #: ``chain_walk``) sit inside its block loops (``check/while/body/...``),
 #: in ``check_window`` as in the count; ``scatter`` runs once, after them.
 SCOPES = frozenset({
-    "agg_reduce", "chain_walk", "check", "flags", "funnel", "reduce",
-    "scatter",
+    "agg_reduce", "chain_walk", "check", "collect", "flags", "funnel",
+    "reduce", "scatter",
 })
 
 #: Names of the jitted programs the scopes live in: ``jit_<name>`` is the

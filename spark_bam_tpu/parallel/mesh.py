@@ -486,8 +486,12 @@ def make_shard_map_confusion_step(
             lambda wd, n, e, t, lo, ow: one(wd, n, e, t, lo, ow, lengths, nc),
             windows, ns, at_eofs, truth, los, owns)
         with jax.named_scope("reduce"):
+            sums = jnp.sum(stats, axis=0)
+        # The step's cross-chip tail under a name of its own, so that a
+        # device trace tells it from ``check`` and ``reduce``.
+        with jax.named_scope("collect"):
             return (
-                jax.lax.psum(jnp.sum(stats, axis=0), axis),  # ← ICI
+                jax.lax.psum(sums, axis),  # ← ICI
                 jax.lax.all_gather(differ_pos, axis, tiled=True),
                 jax.lax.all_gather(differ_count, axis, tiled=True),
             )

@@ -27,13 +27,16 @@ Row discipline (the property multi-host needs — any row computable from
   ``StreamChecker.count_reads``. On real data with the default halo this
   never triggers.
 
-Every row is inflated on the host, on every backend: ``count_reads_sharded``
-inflates a step's rows side by side on a pool (the native inflater, the
-producer the one-chip stream has), puts each row's bytes on the chip that
-owns the row, and every chip runs the one-chip stream's check-and-count
-program on its rows (``mesh.make_shard_map_count_step``). The next step is
-inflated and put while the chips run the current one. The other workloads
-need the inflated bytes on the host anyway (truth masks, site lists).
+Every row is inflated on the host, on every backend, and every workload is
+fed by ONE assembly (``_ShardedStream._assemble_rows``): a step's rows are
+inflated side by side on a pool (the native inflater, the producer the
+one-chip stream has), each straight into its place in the operand of the
+chip that owns the row, check-bam's truth of a row filled beside it on the
+same worker, and every chip's operands put straight to that chip. The count
+runs the one-chip stream's check-and-count program on them
+(``mesh.make_shard_map_count_step``), check-bam and full-check the
+every-position steps. The next step is inflated and put while the chips run
+the current one.
 
 Workloads (SURVEY.md §2.8 maps file/block data-parallelism onto per-core
 batch pipelines; §2.9 replaces Spark accumulators with ``psum``):
@@ -142,7 +145,7 @@ class _ShardedStream:
         window_uncompressed: int | None,
         halo: int | None,
         metas: list | None,
-        with_truth: bool = False,
+        workload: str = "count",
         num_processes: int = 1,
         process_id: int = 0,
         chunk_bytes: int = 192 << 20,
@@ -183,9 +186,12 @@ class _ShardedStream:
         with obs.span("mesh.plan", members=len(metas)):
             self._plan(num_processes, chunk_bytes)
         self._zero_rows: dict = {}
-        self.with_truth = with_truth
-        # What the every-position steps' spans say they serve.
-        self.workload = "check_bam" if with_truth else "full_check"
+        # What the steps' spans say they serve: ``count`` (rows flat in a
+        # device's operand, the header's bytes owned by nobody) or one of
+        # the every-position workloads ``check_bam`` / ``full_check`` (a
+        # row dimension, header bytes included).
+        self.workload = workload
+        self.every_position = workload != "count"
 
     def _plan(self, num_processes: int, chunk_bytes: int) -> None:
         """The row plan and what follows from it: the kernel's width, this
@@ -219,45 +225,17 @@ class _ShardedStream:
         self.row_sharding = NamedSharding(self.mesh, P(self.axis))
 
     # ------------------------------------------------------------- assembly
-    def _row(self, ch, g: int):
-        """Inflate global row ``g`` to the host: returns (buf, n, at_eof)."""
+    def _row(self, ch, g: int, into: np.ndarray | None = None):
+        """Inflate global row ``g`` on the host, into the frame ``into``
+        where one is given (what is left of the frame zeroed), else into a
+        buffer of its own: returns (buf, n, at_eof)."""
         b0, b1 = _halo_block_range(
             self.metas, self.groups, self.first_block, g, g + 1, self.halo
         )
-        view = inflate_blocks(ch, self.metas[b0:b1], threads=8)
+        view = inflate_blocks(
+            ch, self.metas[b0:b1], threads=8,
+            into=None if into is None else (into, 0))
         return view.data, view.size, b1 == len(self.metas)
-
-    def _assemble(self, ch, c0: int, fill_row):
-        """One step's operands for the workloads that report on every
-        position, header bytes included, ON the devices (fixed shapes;
-        padding rows are all-zero and own nothing): the rows inflated one
-        after another into process-local arrays (``mesh.assemble``), then
-        put (``_sharded_args``)."""
-        kw = self.kernel_window
-        k = self.step_rows_local
-        g0 = self.process_id * self.per_proc + c0
-        live = [  # (slot, global row)
-            (j, g0 + j) for j in range(k)
-            if c0 + j < self.per_proc and g0 + j < len(self.groups)
-        ]
-        with obs.span("mesh.assemble", c0=c0, rows=len(live),
-                      workload=self.workload):
-            ws = np.zeros((k, kw + PAD), dtype=np.uint8)
-            ns = np.zeros(k, dtype=np.int32)
-            eofs = np.zeros(k, dtype=bool)
-            los = np.zeros(k, dtype=np.int32)
-            owns = np.zeros(k, dtype=np.int32)
-            truth = np.zeros((k, kw), dtype=bool) if self.with_truth else None
-            for j, g in live:
-                buf, n, at_eof = self._row(ch, g)
-                ws[j, :n] = buf
-                ns[j] = n
-                eofs[j] = at_eof
-                owns[j], los[j] = self._row_span(g, n, at_eof, False)
-                if fill_row is not None:
-                    fill_row(truth[j], buf, int(self.flat_starts[g]), n)
-        return self._sharded_args(
-            (ws, ns, eofs, los, owns, truth), c0, len(live))
 
     def _row_span(self, g: int, n: int, at_eof: bool, header_clamp: bool):
         """Row ``g``'s owned span ``[lo, own)`` in row-local offsets, given
@@ -268,11 +246,10 @@ class _ShardedStream:
 
     def _steps(self, assemble):
         """Yield ``(step operands, positions_done, c0)`` per step (``c0`` =
-        the step's first process-local row index — row ``j`` of the step is
-        global group ``process_id * per_proc + c0 + j``), assembling and
-        putting the next step's rows on one worker thread while the
-        caller's device work runs (one step of lookahead):
-        ``assemble(ch, c0)`` runs on that thread."""
+        the step's first process-local row index; ``row_slots`` says where
+        its rows lie), assembling and putting the next step's rows on one
+        worker thread while the caller's device work runs (one step of
+        lookahead): ``assemble(ch, c0)`` runs on that thread."""
         if not self.per_proc:
             return
         steps = list(range(0, self.per_proc, self.step_rows_local))
@@ -298,21 +275,14 @@ class _ShardedStream:
                 done = int(self.flat_starts[g_hi] + self.sizes[g_hi])
                 yield arrays, done, c0
 
-    def batches(self, fill_row=None):
-        """The check-bam / full-check steps: ``(operands on the devices,
-        done, c0)``, rows inflated to host arrays one after another and put
-        one step ahead of the caller (``_assemble``)."""
-        return self._steps(lambda ch, c0: self._assemble(ch, c0, fill_row))
-
-    # ------------------------------------------------------- count assembly
     def row_slots(self, c0: int) -> list[tuple[int, int, int]]:
-        """Where the count step at local row offset ``c0`` puts its rows:
+        """Where the step at local row offset ``c0`` puts its rows:
         ``(global row, local device, slot on that device)`` for every live
         row, dealt round-robin over this process's devices so the chips of
         a step are loaded within one row of each other whatever the step's
         width (a last step of 8 rows in a step 12 wide lands 2 a chip, not
-        3/3/2/0). A device's block of the step's flat operands is its
-        slots in order."""
+        3/3/2/0). A device's block of the step's operands is its slots in
+        order."""
         g0 = self.process_id * self.per_proc + c0
         return [
             (g0 + j, j % self.n_local, j // self.n_local)
@@ -320,18 +290,41 @@ class _ShardedStream:
             if c0 + j < self.per_proc and g0 + j < len(self.groups)
         ]
 
-    def _assemble_rows(self, ch, c0: int, rows_pool):
-        """One count step's operands, ON the devices: the step's rows are
-        inflated side by side (``rows_pool``), each into its slot of its
-        device's flat buffer, and every buffer goes straight to its chip
-        (``mesh.make_shard_map_count_step`` has the layout). Padding slots
-        are zeros and own nothing; a device without a live row keeps one
-        resident buffer of them."""
-        width = self.kernel_window + PAD
+    def step_row(self, c0: int, i: int) -> int:
+        """The global row behind index ``i`` of a result the step at ``c0``
+        gives a row, in the order its operands are sharded: process-major,
+        then device-major (``row_slots``). A padding slot's is a row the
+        file or the process does not have."""
         per_dev = self.step_rows_local // self.n_local
+        p, local = divmod(i, self.step_rows_local)
+        d, s = divmod(local, per_dev)
+        return p * self.per_proc + c0 + s * self.n_local + d
+
+    def _assemble_rows(self, ch, c0: int, rows_pool, fill_row=None):
+        """One step's operands, ON the devices, for every workload: the
+        step's rows are inflated side by side (``rows_pool``; one
+        ``mesh.row_inflate`` a row), each straight into its slot of its
+        device's buffer, check-bam's truth of the row filled beside it on
+        the same worker (``fill_row(row, base, n)``, ``mesh.truth_fill``),
+        and every device's operands go straight to that chip (``mesh.h2d``,
+        waited for). The count's rows lie flat in a device's block
+        (``mesh.make_shard_map_count_step`` has the layout), an
+        every-position step's as ``(rows, W + PAD)`` with a ``(rows, W)``
+        truth. Padding slots are zeros and own nothing; a device without a
+        live row keeps resident operands of them."""
+        kw = self.kernel_window
+        width = kw + PAD
+        per_dev = self.step_rows_local // self.n_local
+        with_truth = fill_row is not None
+        # A device's block as its step takes it.
+        shape = (per_dev, width) if self.every_position else (per_dev * width,)
+
+        def blocks():
+            return [np.zeros(per_dev * width, dtype=np.uint8)] + (
+                [np.zeros((per_dev, kw), dtype=bool)] if with_truth else [])
+
         slots = self.row_slots(c0)
-        bufs = {d: np.zeros(per_dev * width, dtype=np.uint8)
-                for d in {d for _g, d, _s in slots}}
+        bufs = {d: blocks() for d in sorted({d for _g, d, _s in slots})}
         k = self.step_rows_local
         ns = np.zeros(k, dtype=np.int32)
         eofs = np.zeros(k, dtype=bool)
@@ -340,68 +333,59 @@ class _ShardedStream:
 
         def fill(slot):
             g, d, s = slot
-            buf, n, at_eof = self._row(ch, g)
-            bufs[d][s * width: s * width + n] = buf
-            i = d * per_dev + s  # device-major, as the flat operand is
+            with obs.span("mesh.row_inflate", row=g):
+                _buf, n, at_eof = self._row(
+                    ch, g, bufs[d][0][s * width: (s + 1) * width])
+            i = d * per_dev + s  # device-major, as the operands are
             ns[i], eofs[i] = n, at_eof
-            owns[i], los[i] = self._row_span(g, n, at_eof, True)
+            owns[i], los[i] = self._row_span(
+                g, n, at_eof, not self.every_position)
+            if with_truth:
+                with obs.span("mesh.truth_fill", row=g):
+                    fill_row(bufs[d][1][s], int(self.flat_starts[g]), n)
 
-        with obs.span("mesh.assemble", c0=c0, rows=len(slots)):
+        attrs = dict(c0=c0, rows=len(slots), workload=self.workload)
+        with obs.span("mesh.assemble", **attrs):
             list(rows_pool.map(obs.trace.carried(fill), slots))
-        with obs.span("mesh.h2d", c0=c0, rows=len(slots)):
-            shards = []
+        nbytes = len(bufs) * per_dev * (width + (kw if with_truth else 0))
+        with obs.span("mesh.h2d", bytes=nbytes, **attrs):
+            shards = []  # a local device: its operands, on it
             for d, device in enumerate(self.local_devices):
-                if d in bufs:
-                    shards.append(jax.device_put(bufs[d], device))
-                else:
-                    if d not in self._zero_rows:
-                        self._zero_rows[d] = jax.device_put(
-                            np.zeros(per_dev * width, dtype=np.uint8), device
-                        )
+                if d not in bufs and d in self._zero_rows:
                     shards.append(self._zero_rows[d])
-            windows = jax.make_array_from_single_device_arrays(
-                (self.n_global * per_dev * width,), self.row_sharding, shards
+                    continue
+                windows, *truth = bufs.get(d) or blocks()
+                shards.append([
+                    jax.device_put(a, device)
+                    for a in (windows.reshape(shape), *truth)])
+                if d not in bufs:
+                    self._zero_rows[d] = shards[-1]
+            windows, *truth = (
+                jax.make_array_from_single_device_arrays(
+                    (self.n_global * one[0].shape[0],) + one[0].shape[1:],
+                    self.row_sharding, list(one))
+                for one in zip(*shards)
             )
-            args = [windows] + [
+            ns, eofs, los, owns = (
                 jax.make_array_from_process_local_data(self.row_sharding, a)
                 for a in (ns, eofs, los, owns)
-            ]
+            )
+            args = [windows, ns, eofs, *truth, los, owns]
             # Waited for HERE, registry or none: the span is the transfer,
             # and the feeding thread is handed operands that have arrived.
             jax.block_until_ready(args)
         obs.count("mesh.rows", len(slots))
-        obs.count("mesh.h2d_bytes", len(bufs) * per_dev * width)
+        obs.count("mesh.h2d_bytes", nbytes)
         return args + [self.lengths_d, self.nc]
 
-    def row_batches(self):
-        """The count's steps: ``(operands on the devices, done, c0)``."""
+    def row_batches(self, fill_row=None):
+        """A workload's steps: ``(operands on the devices, done, c0)``."""
         # Rows side by side, each on the inflater's own eight threads.
         with ThreadPoolExecutor(min(self.step_rows_local, 8)) as rows_pool:
             yield from self._steps(
-                lambda ch, c0: self._assemble_rows(ch, c0, rows_pool)
+                lambda ch, c0: self._assemble_rows(
+                    ch, c0, rows_pool, fill_row)
             )
-
-    def _sharded_args(self, arrays, c0: int, rows: int):
-        """``_assemble``'s arrays put row-sharded, and waited for: the span
-        is the transfer, and the feeding thread is handed operands that
-        have arrived (as ``_assemble_rows`` hands over the count's)."""
-        ws, ns, eofs, los, owns, truth = arrays
-        rs = self.row_sharding
-        nbytes = ws.nbytes + (0 if truth is None else truth.nbytes)
-
-        def put(a):
-            return jax.make_array_from_process_local_data(rs, a)
-
-        with obs.span("mesh.h2d", c0=c0, rows=rows, bytes=nbytes,
-                      workload=self.workload):
-            args = [put(ws), put(ns), put(eofs)]
-            if truth is not None:
-                args.append(put(truth))
-            args += [put(los), put(owns)]
-            jax.block_until_ready(args)
-        obs.count("mesh.rows", rows)
-        obs.count("mesh.h2d_bytes", nbytes)
-        return args + [self.lengths_d, self.nc]
 
 
 def _mostly_dirty(dirty: list, steps: int) -> bool:
@@ -763,7 +747,8 @@ def full_check_summary_sharded(
             "host or use the single-device streaming summary"
         )
     st = _ShardedStream(
-        path, config, mesh, window_uncompressed, halo, metas
+        path, config, mesh, window_uncompressed, halo, metas,
+        workload="full_check",
     )
     step = mesh_steps(st.mesh, st.axis).full_step(
         reads_to_check=config.reads_to_check,
@@ -779,7 +764,7 @@ def full_check_summary_sharded(
     defers = 0
     dirty: list[int] = []  # local row offsets (c0) of deferred steps
     steps = 0
-    batches = st.batches()
+    batches = st.row_batches()
     try:
         for args, done, c0 in batches:
             with obs.span("mesh.step", workload="full_check", c0=c0):
@@ -802,8 +787,9 @@ def full_check_summary_sharded(
                 continue
             agg += totals
             ci, cm, ti, tm = (np.asarray(a) for a in (ci, cm, ti, tm))
-            for j in range(ci.shape[0]):
-                g = c0 + j
+            # A step's rows in the file's order, not in the operands'.
+            for g, j in sorted(
+                    (st.step_row(c0, j), j) for j in range(ci.shape[0])):
                 if g >= len(st.groups):
                     continue  # padding row: no sites by construction
                 base = int(st.flat_starts[g])
@@ -994,6 +980,16 @@ def _truth_flats(path, records_path, metas) -> np.ndarray:
         return np.sort(block_flat[idx] + offs)
 
 
+def _truth_filler(truth_flats: np.ndarray):
+    """``fill_row(row, base, n)`` of a check-bam pass: sets in ``row``, a
+    row's bool a position, the truth's offsets that lie in the ``n`` bytes
+    the row holds from flat offset ``base`` on."""
+    def fill_row(row, base, n):
+        i0, i1 = np.searchsorted(truth_flats, (base, base + n))
+        row[truth_flats[i0:i1] - base] = True
+    return fill_row
+
+
 def _in_sorted(values: np.ndarray, among: np.ndarray) -> np.ndarray:
     """Which of ``values`` are in the sorted ``among`` (a binary search a
     value: the truth of a 60 GB file is 170 million offsets)."""
@@ -1058,7 +1054,8 @@ def check_bam_sharded(
 
     st = _ShardedStream(
         path, config, mesh, window_uncompressed, halo, metas,
-        with_truth=True, num_processes=num_processes, process_id=process_id,
+        workload="check_bam", num_processes=num_processes,
+        process_id=process_id,
     )
     truth_flats = _truth_flats(path, records_path, st.metas)
     with obs.span("load.open", program="confusion_step"):
@@ -1067,10 +1064,6 @@ def check_bam_sharded(
             flags_impl=config.flags_impl, funnel=config.funnel_enabled(),
         )
         observer = _StepObserver.maybe()
-
-    def fill_row(row, buf, base, n):
-        i0, i1 = np.searchsorted(truth_flats, (base, base + n))
-        row[truth_flats[i0:i1] - base] = True
 
     # Device stats are [tp, fp, fn, escapes, survivors, lanes] — record-scale
     # counters only.
@@ -1082,7 +1075,7 @@ def check_bam_sharded(
     dirty: list[int] = []     # local row offsets (c0) of escaped steps
     overflowed: set = set()   # global rows with more mismatches than slots
     whole_file = False
-    batches = st.batches(fill_row=fill_row)
+    batches = st.row_batches(_truth_filler(truth_flats))
     try:
         for args, done, c0 in batches:
             with obs.span("mesh.step", workload="check_bam", c0=c0):
@@ -1107,11 +1100,9 @@ def check_bam_sharded(
                 dirty.append(c0)
             else:
                 agg += totals[:3]
-                # Row i of the gathered lists is process i // k's local
-                # row i % k of this step, as the operands are sharded.
-                k = st.step_rows_local
+                # The gathered lists lie as the operands are sharded.
                 for i in np.flatnonzero(counts):
-                    g = (i // k) * st.per_proc + c0 + i % k
+                    g = st.step_row(c0, int(i))
                     if counts[i] > MISMATCH_LIST:
                         overflowed.add(int(g))
                     else:

@@ -102,7 +102,7 @@ PROGRAM_SCOPES = [
     ("count_step", _lower_count_step, CHECK | {"reduce"}),
     ("serve_step", _lower_serve_step, CHECK | {"reduce", "scatter"}),
     ("confusion_step", _lower_confusion_step,
-     CHECK | {"reduce", "scatter"}),
+     CHECK | {"reduce", "scatter", "collect"}),
     ("agg_update", _lower_agg_update, {"agg_reduce"}),
 ]
 
