@@ -136,7 +136,8 @@ NAMES = frozenset({
     "load.partitions", "load.record_starts", "load.records",
     "load.split_resolutions",
     # mesh — compiled-step registry + shard_map dispatch
-    "mesh.assemble", "mesh.dirty_steps", "mesh.dispatch", "mesh.escapes",
+    "mesh.assemble", "mesh.block_reuse", "mesh.dirty_steps", "mesh.dispatch",
+    "mesh.escapes",
     "mesh.h2d", "mesh.h2d_bytes",
     "mesh.patch_chunk_positions", "mesh.patch_chunks", "mesh.patch_rows",
     "mesh.plan", "mesh.row_inflate", "mesh.rows", "mesh.stall", "mesh.step",

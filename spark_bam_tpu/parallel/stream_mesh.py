@@ -36,7 +36,9 @@ same worker, and every chip's operands put straight to that chip. The count
 runs the one-chip stream's check-and-count program on them
 (``mesh.make_shard_map_count_step``), check-bam and full-check the
 every-position steps. The next step is inflated and put while the chips run
-the current one.
+the current one, and the host blocks a step is assembled in are kept from
+step to step and from pass to pass (``inflate.FRAMES``): written anew every
+step they would be fresh pages, and their faults the chips' wait.
 
 Workloads (SURVEY.md §2.8 maps file/block data-parallelism onto per-core
 batch pipelines; §2.9 replaces Spark accumulators with ``psum``):
@@ -51,6 +53,7 @@ batch pipelines; §2.9 replaces Spark accumulators with ``psum``):
 
 from __future__ import annotations
 
+import collections
 import time
 from concurrent.futures import ThreadPoolExecutor
 from typing import Callable
@@ -68,6 +71,7 @@ from spark_bam_tpu.bgzf.flat import inflate_blocks
 from spark_bam_tpu.core.channel import open_channel
 from spark_bam_tpu.core.config import Config
 from spark_bam_tpu.parallel.mesh import make_mesh, mesh_steps
+from spark_bam_tpu.tpu import inflate
 from spark_bam_tpu.tpu.checker import PAD
 from spark_bam_tpu.tpu.inflate import DeviceObserver, window_plan
 from spark_bam_tpu.tpu.stream_check import (
@@ -133,6 +137,10 @@ def _rows_fitting_device(device, kernel_window: int) -> int:
     )
 
 
+#: What a slot's ``used`` holds where its row was written and no truth set.
+_NO_TRUTH = np.empty(0, dtype=np.int64)
+
+
 class _ShardedStream:
     """Shared plumbing: plan the block groups, assemble this process's row
     slice into mesh-wide batches (double-buffered), build sharded args."""
@@ -186,6 +194,9 @@ class _ShardedStream:
         with obs.span("mesh.plan", members=len(metas)):
             self._plan(num_processes, chunk_bytes)
         self._zero_rows: dict = {}
+        # The host blocks of the steps handed to the consumer, oldest first,
+        # until their results are known to be read (``_hand_back``).
+        self._handed: collections.deque = collections.deque()
         # What the steps' spans say they serve: ``count`` (rows flat in a
         # device's operand, the header's bytes owned by nobody) or one of
         # the every-position workloads ``check_bam`` / ``full_check`` (a
@@ -249,7 +260,8 @@ class _ShardedStream:
         the step's first process-local row index; ``row_slots`` says where
         its rows lie), assembling and putting the next step's rows on one
         worker thread while the caller's device work runs (one step of
-        lookahead): ``assemble(ch, c0)`` runs on that thread."""
+        lookahead): ``assemble(ch, c0)`` runs on that thread and gives the
+        operands and the host blocks they were put from."""
         if not self.per_proc:
             return
         steps = list(range(0, self.per_proc, self.step_rows_local))
@@ -262,7 +274,12 @@ class _ShardedStream:
                 # The wait for a step's operands: what the lookahead
                 # exists to hide (all of the first step's assembly).
                 with obs.span("mesh.stall", c0=c0):
-                    arrays = pending.result()
+                    arrays, blocks = pending.result()
+                # The caller is back for step i, so it has read step i - 2
+                # (the count reads a step's totals one step late): that
+                # step's blocks are what step i + 1 is assembled in. Three
+                # steps' blocks are alive: assembled, dispatched, unread.
+                self._hand_back(unread=1)
                 if i + 1 < len(steps):
                     pending = pool.submit(assemble, ch, steps[i + 1])
                 # Highest global row completed this step (process-major row
@@ -273,7 +290,26 @@ class _ShardedStream:
                     len(self.groups),
                 ) - 1
                 done = int(self.flat_starts[g_hi] + self.sizes[g_hi])
+                self._handed.append(blocks)
                 yield arrays, done, c0
+            self._hand_back(unread=1)
+
+    def _hand_back(self, unread: int) -> None:
+        """Give the kept set the host blocks of the steps handed out, all
+        but the newest ``unread``. On the chip a put that was waited for has
+        copied its block; on the CPU backend it may alias it, so a block is
+        not written again before the result of the step that read it is on
+        the host."""
+        while len(self._handed) > unread:
+            for arrays, used in self._handed.popleft():
+                inflate.FRAMES.give(
+                    arrays, keep=3 * self.n_local, note=used)
+
+    def release(self) -> None:
+        """The caller has read every step it was handed: the last steps'
+        blocks go back too, for the next pass. (A pass that ends otherwise
+        drops them, which is always correct.)"""
+        self._hand_back(unread=0)
 
     def row_slots(self, c0: int) -> list[tuple[int, int, int]]:
         """Where the step at local row offset ``c0`` puts its rows:
@@ -301,51 +337,83 @@ class _ShardedStream:
         return p * self.per_proc + c0 + s * self.n_local + d
 
     def _assemble_rows(self, ch, c0: int, rows_pool, fill_row=None):
-        """One step's operands, ON the devices, for every workload: the
-        step's rows are inflated side by side (``rows_pool``; one
-        ``mesh.row_inflate`` a row), each straight into its slot of its
-        device's buffer, check-bam's truth of the row filled beside it on
-        the same worker (``fill_row(row, base, n)``, ``mesh.truth_fill``),
-        and every device's operands go straight to that chip (``mesh.h2d``,
-        waited for). The count's rows lie flat in a device's block
-        (``mesh.make_shard_map_count_step`` has the layout), an
-        every-position step's as ``(rows, W + PAD)`` with a ``(rows, W)``
+        """One step's operands, ON the devices, for every workload, and the
+        host blocks they were put from: the step's rows are inflated side by
+        side (``rows_pool``; one ``mesh.row_inflate`` a row), each straight
+        into its slot of its device's block, check-bam's truth of the row
+        filled beside it on the same worker (``fill_row(row, base, n)``,
+        ``mesh.truth_fill``), and every device's operands go straight to
+        that chip (``mesh.h2d``, waited for). The count's rows lie flat in a
+        device's block (``mesh.make_shard_map_count_step`` has the layout),
+        an every-position step's as ``(rows, W + PAD)`` with a ``(rows, W)``
         truth. Padding slots are zeros and own nothing; a device without a
-        live row keeps resident operands of them."""
+        live row keeps resident operands of them.
+
+        A device's blocks come from the kept set (``inflate.FRAMES``; made
+        anew, zeros, when it has none: ``mesh.block_reuse`` is the share of
+        the step's that were kept). With them comes ``used``, what each slot
+        holds of the blocks' earlier step: None while it is zeros, else the
+        offsets set in its truth. A live slot needs no clearing but those
+        (the inflater zeroes what is left of the slot behind the row); a
+        padding slot that was live is zeroed, row and truth."""
         kw = self.kernel_window
         width = kw + PAD
         per_dev = self.step_rows_local // self.n_local
         with_truth = fill_row is not None
         # A device's block as its step takes it.
         shape = (per_dev, width) if self.every_position else (per_dev * width,)
-
-        def blocks():
-            return [np.zeros(per_dev * width, dtype=np.uint8)] + (
-                [np.zeros((per_dev, kw), dtype=bool)] if with_truth else [])
+        specs = [((per_dev * width,), np.uint8)] + (
+            [((per_dev, kw), np.bool_)] if with_truth else [])
 
         slots = self.row_slots(c0)
-        bufs = {d: blocks() for d in sorted({d for _g, d, _s in slots})}
+        bufs, kept = {}, 0
+        for d in sorted({d for _g, d, _s in slots}):
+            arrays, used = inflate.FRAMES.take(specs, np.zeros)
+            kept += used is not None
+            bufs[d] = (arrays, [None] * per_dev if used is None else used)
+        if bufs:
+            obs.observe("mesh.block_reuse", kept / len(bufs))
         k = self.step_rows_local
         ns = np.zeros(k, dtype=np.int32)
         eofs = np.zeros(k, dtype=bool)
         los = np.zeros(k, dtype=np.int32)
         owns = np.zeros(k, dtype=np.int32)
 
+        def untrue(d, s):
+            """Slot ``s`` of device ``d``'s truth, what its block's earlier
+            step set there cleared."""
+            arrays, used = bufs[d]
+            if used[s] is not None:
+                arrays[1][s][used[s]] = False
+            return arrays[1][s]
+
         def fill(slot):
             g, d, s = slot
+            arrays, used = bufs[d]
             with obs.span("mesh.row_inflate", row=g):
                 _buf, n, at_eof = self._row(
-                    ch, g, bufs[d][0][s * width: (s + 1) * width])
+                    ch, g, arrays[0][s * width: (s + 1) * width])
             i = d * per_dev + s  # device-major, as the operands are
             ns[i], eofs[i] = n, at_eof
             owns[i], los[i] = self._row_span(
                 g, n, at_eof, not self.every_position)
             if with_truth:
                 with obs.span("mesh.truth_fill", row=g):
-                    fill_row(bufs[d][1][s], int(self.flat_starts[g]), n)
+                    used[s] = fill_row(
+                        untrue(d, s), int(self.flat_starts[g]), n)
+            else:
+                used[s] = _NO_TRUTH
 
         attrs = dict(c0=c0, rows=len(slots), workload=self.workload)
         with obs.span("mesh.assemble", **attrs):
+            live = {(d, s) for _g, d, s in slots}
+            for d, (arrays, used) in bufs.items():
+                for s in range(per_dev):
+                    if (d, s) not in live and used[s] is not None:
+                        arrays[0][s * width: (s + 1) * width] = 0
+                        if with_truth:
+                            untrue(d, s)
+                        used[s] = None
             list(rows_pool.map(obs.trace.carried(fill), slots))
         nbytes = len(bufs) * per_dev * (width + (kw if with_truth else 0))
         with obs.span("mesh.h2d", bytes=nbytes, **attrs):
@@ -354,7 +422,11 @@ class _ShardedStream:
                 if d not in bufs and d in self._zero_rows:
                     shards.append(self._zero_rows[d])
                     continue
-                windows, *truth = bufs.get(d) or blocks()
+                # Resident operands may alias their host blocks for the
+                # pass's life: those are made for them, and never kept.
+                windows, *truth = (
+                    bufs[d][0] if d in bufs
+                    else [np.zeros(*spec) for spec in specs])
                 shards.append([
                     jax.device_put(a, device)
                     for a in (windows.reshape(shape), *truth)])
@@ -376,7 +448,7 @@ class _ShardedStream:
             jax.block_until_ready(args)
         obs.count("mesh.rows", len(slots))
         obs.count("mesh.h2d_bytes", nbytes)
-        return args + [self.lengths_d, self.nc]
+        return args + [self.lengths_d, self.nc], list(bufs.values())
 
     def row_batches(self, fill_row=None):
         """A workload's steps: ``(operands on the devices, done, c0)``."""
@@ -635,6 +707,7 @@ def _count_steps(st: "_ShardedStream", config: Config, progress):
         # The step in flight is dropped, and waited for: the whole-file
         # path that takes over finds an idle mesh.
         jax.block_until_ready(unread[0])
+    st.release()
     return count, escapes, steps, dirty, whole_file
 
 
@@ -805,6 +878,7 @@ def full_check_summary_sharded(
                 progress(steps, done, st.total)
     finally:
         batches.close()
+    st.release()  # every step's totals were read as it was dispatched
 
     if dirty and not fallback:
         from spark_bam_tpu.check.flags import (
@@ -983,10 +1057,13 @@ def _truth_flats(path, records_path, metas) -> np.ndarray:
 def _truth_filler(truth_flats: np.ndarray):
     """``fill_row(row, base, n)`` of a check-bam pass: sets in ``row``, a
     row's bool a position, the truth's offsets that lie in the ``n`` bytes
-    the row holds from flat offset ``base`` on."""
+    the row holds from flat offset ``base`` on, and returns them (what the
+    row's next user clears)."""
     def fill_row(row, base, n):
         i0, i1 = np.searchsorted(truth_flats, (base, base + n))
-        row[truth_flats[i0:i1] - base] = True
+        at = truth_flats[i0:i1] - base
+        row[at] = True
+        return at
     return fill_row
 
 
@@ -1119,6 +1196,7 @@ def check_bam_sharded(
             batches.close()
             if observer is not None:
                 observer.close()
+    st.release()  # every step's lists were read as it was dispatched
     # What follows the last step: the rows of dirty steps and of overflowed
     # lists re-derived on the host, the listed positions sorted and split
     # by the truth, the matrix.

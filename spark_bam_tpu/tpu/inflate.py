@@ -205,36 +205,58 @@ def window_plan(metas: list[Metadata], window_uncompressed: int) -> list[list[Me
 
 
 class _Frames:
-    """The host buffers the count's windows are inflated into and put from,
-    kept from one window and one pass to the next.
+    """The host buffers a pass inflates into and puts from, kept from one
+    window or step, and from one pass, to the next.
 
-    A window's buffer is 36 MiB. Allocated anew each window it is fresh
-    memory as often as not (glibc hands back what it freed, or maps new
-    pages, by the state of its heap), and writing fresh memory costs page
-    faults: 26 ms a window against 4 on a v5e host, which at the head of a
-    pass is time the chip waits (``PERF.md`` section 6, PR 31). ``KEEP`` is
-    what a pass has in flight: ``depth`` being inflated, one being put, the
-    count's ring."""
+    A count window's buffer is 36 MiB, a mesh step's 32 MiB a row and as
+    much again for check-bam's truth. Allocated anew each time they are
+    fresh memory as often as not (glibc hands back what it freed, or maps
+    new pages, by the state of its heap; at 32 MiB it always maps), and
+    writing fresh memory costs page faults: 26 ms a window against 4 on a
+    v5e host, 47 ms to set a row's 70,000 bytes of truth against 2, which
+    is time the chips wait (``PERF.md`` section 6, PRs 31 and 43).
+
+    What is kept is sets of arrays, found again by their shapes and dtypes,
+    each with the note its user gave it back with; all of one kind, the
+    newest given (a pass at another width drops what the one before it
+    left), and at most ``keep`` of them, which the user gives as what one
+    pass has in flight. The count's stream keeps ``KEEP`` frames (``depth``
+    being inflated, one being put, the count's ring: 218 MiB); the mesh
+    steps three steps' blocks a device (``parallel/stream_mesh``: 771 MiB
+    for check-bam on four chips, 387 MiB for the count there)."""
 
     KEEP = 6
 
     def __init__(self):
         self._lock = threading.Lock()
-        self._free: list = []
+        self._free: list = []  # (key, arrays, note), every key the same
 
-    def take(self, size: int):
-        with self._lock:
-            for i, frame in enumerate(self._free):
-                if len(frame) == size:
-                    return self._free.pop(i)
-        return np.empty(size, dtype=np.uint8)
+    @staticmethod
+    def _key(specs) -> list:
+        return [(tuple(shape), np.dtype(dtype)) for shape, dtype in specs]
 
-    def give(self, frame) -> None:
-        """Hand back a frame nothing reads any more (its window's program
-        has run). Frames of another size than the newest go."""
+    def take(self, specs, make=None):
+        """Arrays of ``specs`` (a ``(shape, dtype)`` each): ``(arrays,
+        note)``, a kept set with the note it came back with, or arrays made
+        anew (``make(shape, dtype)``, ``np.empty`` unless given) and no
+        note."""
+        key = self._key(specs)
         with self._lock:
-            same = [f for f in self._free if len(f) == len(frame)]
-            self._free = same[: self.KEEP - 1] + [frame]
+            for i, (k, arrays, note) in enumerate(self._free):
+                if k == key:
+                    del self._free[i]
+                    return arrays, note
+        make = make or np.empty
+        return [make(shape, dtype) for shape, dtype in key], None
+
+    def give(self, arrays, keep: int = KEEP, note=None) -> None:
+        """Hand back arrays nothing reads any more (the program they were
+        put for has run, and its result is on the host). Sets of another
+        kind than this one go."""
+        key = self._key((a.shape, a.dtype) for a in arrays)
+        with self._lock:
+            same = [e for e in self._free if e[0] == key]
+            self._free = same[: keep - 1] + [(key, arrays, note)]
 
 
 FRAMES = _Frames()
@@ -314,7 +336,8 @@ class InflatePipeline:
                     raise ValueError(
                         f"a group of {total} bytes does not fit a frame of "
                         f"{size} with {lead} in front")
-                into = (FRAMES.take(size), lead)
+                (frame,), _note = FRAMES.take([((size,), np.uint8)])
+                into = (frame, lead)
             return inflate_blocks(
                 ch, group, file_total=self.total, threads=self.threads,
                 into=into,

@@ -598,7 +598,7 @@ class StreamChecker:
         def settle(escaped: int, at_eof: bool):
             """The ring's oldest window: its escapes, then its frame back."""
             escapes.settle(escaped, ring, at_eof)
-            FRAMES.give(held.pop(0))
+            FRAMES.give([held.pop(0)])
 
         def fold():
             """The device sums since the last fold, into the host's."""

@@ -70,7 +70,7 @@ def test_every_device_receives_its_own_rows_and_their_truth(
     with open_channel(path) as ch, ThreadPoolExecutor(4) as pool:
         for c0 in steps:
             slots = st.row_slots(c0)
-            args = st._assemble_rows(ch, c0, pool, fill_row)
+            args, _blocks = st._assemble_rows(ch, c0, pool, fill_row)
             assert len(args) == (8 if with_truth else 7)
             windows, ns, eofs = args[:3]
             los, owns = args[-4:-2]

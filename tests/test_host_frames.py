@@ -58,7 +58,7 @@ def test_a_dirty_frame_is_zeroed_behind_the_window(bam, frames):
     size = HALO + 2 * WINDOW + PAD
     for _ in range(3):
         dirty = np.full(size, 0xAB, dtype=np.uint8)
-        frames.give(dirty)
+        frames.give([dirty])
     views = list(InflatePipeline(bam, WINDOW, depth=1).frames(HALO, size))
     assert any(v.frame is dirty for v in views)
     for v in views:
@@ -90,12 +90,14 @@ def test_halo_windows_lays_the_carry_in_the_frame(bam, frames):
 
 def test_the_free_list_keeps_one_size_and_no_more_than_keep(frames):
     for _ in range(frames.KEEP + 3):
-        frames.give(np.empty(100, dtype=np.uint8))
+        frames.give([np.empty(100, dtype=np.uint8)])
     assert len(frames._free) == frames.KEEP
-    frames.give(np.empty(200, dtype=np.uint8))
-    assert [len(f) for f in frames._free] == [200]
-    assert len(frames.take(200)) == 200 and not frames._free
-    assert len(frames.take(300)) == 300
+    frames.give([np.empty(200, dtype=np.uint8)])
+    assert [len(f) for _key, (f,), _note in frames._free] == [200]
+    (frame,), _note = frames.take([((200,), np.uint8)])
+    assert len(frame) == 200 and not frames._free
+    (frame,), _note = frames.take([((300,), np.uint8)])
+    assert len(frame) == 300
 
 
 def test_count_passes_reuse_their_frames_and_agree(bam, frames, monkeypatch):
@@ -105,7 +107,7 @@ def test_count_passes_reuse_their_frames_and_agree(bam, frames, monkeypatch):
     real_empty = inflate.np.empty
 
     def empty(shape, *a, **k):
-        if isinstance(shape, int) and shape > WINDOW:
+        if isinstance(shape, tuple) and shape[0] > WINDOW:
             made.append(shape)
         return real_empty(shape, *a, **k)
 
@@ -115,7 +117,52 @@ def test_count_passes_reuse_their_frames_and_agree(bam, frames, monkeypatch):
     assert 1 <= first <= frames.KEEP and len(set(made)) == 1
     assert 1 <= len(frames._free) <= frames.KEEP
     # What a pass left in its frames must not show in the next.
-    for frame in frames._free:
+    for _key, (frame,), _note in frames._free:
         frame[:] = 0xAB
     assert StreamChecker(bam, Config(), **cfg).count_reads() == want
     assert len(made) == first
+
+
+def test_many_threads_take_and_give_and_no_array_is_in_two_hands(frames):
+    """The mesh takes blocks on its assembly thread and hands them back from
+    the feeding thread: more threads than cores take an array, write their
+    own mark over it, look again, and give it back; an array handed to two
+    at once would show the other's mark, and the kept set never holds more
+    than it was told to keep."""
+    import sys
+    import threading
+    import time
+
+    keep, spec = 4, [((4096,), np.uint8)]
+    stop = time.monotonic() + 1.0
+    faults: list = []
+    rounds = []
+
+    def worker(mark: int):
+        n = 0
+        while time.monotonic() < stop and not faults:
+            (a,), _note = frames.take(spec)
+            a[:] = mark
+            time.sleep(0)
+            if not (a == mark).all():
+                faults.append(("shared", mark))
+            frames.give([a], keep=keep, note=mark)
+            if len(frames._free) > keep:
+                faults.append(("over", len(frames._free)))
+            n += 1
+        rounds.append(n)
+
+    was = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=worker, args=(m,))
+                   for m in range(1, 33)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=30)
+    finally:
+        sys.setswitchinterval(was)
+    assert not any(t.is_alive() for t in threads)
+    assert not faults and len(rounds) == 32 and min(rounds) > 0
+    assert 0 < len(frames._free) <= keep

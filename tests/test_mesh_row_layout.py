@@ -64,8 +64,8 @@ def test_rows_land_round_robin_in_their_devices_buffers(file_of_rows, devices):
             assert load.max() - load.min() <= 1
             seen += live
 
-            windows, ns, eofs, los, owns, lengths, nc = st._assemble_rows(
-                ch, c0, pool)
+            (windows, ns, eofs, los, owns, lengths, nc), _blocks = (
+                st._assemble_rows(ch, c0, pool))
             assert windows.shape == (devices * per_dev * width,)
             assert windows.sharding.is_equivalent_to(st.row_sharding, 1)
             flat = np.asarray(windows).reshape(devices * per_dev, width)
