@@ -41,14 +41,14 @@ def read_records_index(path) -> list[Pos]:
     ]
 
 
-def read_records_arrays(path, chunk_bytes: int = 64 << 20):
-    """The sidecar as two int64 arrays ``(block_pos, offset)``, in file
-    order: the text is parsed by numpy in chunks of ``chunk_bytes`` cut at
-    line ends, so no Python object is made a record (a 60 GB BAM's sidecar
-    holds 170 million lines). Blank lines and a missing or present trailing
-    newline are tolerated, as ``read_records_index`` tolerates them; a line
-    that is not ``blockPos,offset`` raises ``ValueError``."""
-    blocks, offsets = [], []
+def iter_records_arrays(path, chunk_bytes: int):
+    """The sidecar in file order, a piece at a time: ``(block_pos, offset)``,
+    two int64 arrays a piece, the text parsed by numpy in chunks of
+    ``chunk_bytes`` cut at line ends, so no Python object is made a record
+    (a 60 GB BAM's sidecar holds 170 million lines). Blank lines and a
+    missing or present trailing newline are tolerated, as
+    ``read_records_index`` tolerates them; a line that is not
+    ``blockPos,offset`` raises ``ValueError`` when its piece is reached."""
     with open_channel(path) as ch:
         pos, carry = 0, b""
         while pos < ch.size or carry:
@@ -76,10 +76,16 @@ def read_records_arrays(path, chunk_bytes: int = 64 << 20):
             ):
                 raise ValueError(
                     f"{path}: not a .records sidecar (blockPos,offset a line)")
-            blocks.append(values[0::2])
-            offsets.append(values[1::2])
-    if not blocks:
+            yield values[0::2], values[1::2]
+
+
+def read_records_arrays(path, chunk_bytes: int = 64 << 20):
+    """The sidecar as two int64 arrays ``(block_pos, offset)``, in file
+    order: ``iter_records_arrays``' pieces, joined."""
+    pieces = list(iter_records_arrays(path, chunk_bytes))
+    if not pieces:
         return np.empty(0, np.int64), np.empty(0, np.int64)
+    blocks, offsets = zip(*pieces)
     return np.concatenate(blocks), np.concatenate(offsets)
 
 

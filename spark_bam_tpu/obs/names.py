@@ -57,7 +57,7 @@ NAMES = frozenset({
     # checkbam — check-bam against the .records truth on the mesh
     # (load/tpu_load.check_bam_tpu, parallel/stream_mesh.check_bam_sharded)
     "checkbam.list_overflows", "checkbam.mismatches", "checkbam.passes",
-    "checkbam.truth_load",
+    "checkbam.truth_load", "checkbam.truth_restarts", "checkbam.truth_wait",
     # cli — root spans, one per subcommand (cli/main.py)
     "cli.aggregate", "cli.check-bam", "cli.check-blocks",
     "cli.compare-splits", "cli.compute-splits", "cli.count-reads",

@@ -53,9 +53,11 @@ batch pipelines; §2.9 replaces Spark accumulators with ``psum``):
 
 from __future__ import annotations
 
+import bisect
 import collections
+import threading
 import time
-from concurrent.futures import ThreadPoolExecutor
+from concurrent.futures import ThreadPoolExecutor, wait
 from typing import Callable
 
 import numpy as np
@@ -311,6 +313,11 @@ class _ShardedStream:
         drops them, which is always correct.)"""
         self._hand_back(unread=0)
 
+    def forget(self) -> None:
+        """A pass that starts over over these rows drops the blocks of the
+        steps it had out: a step still running may read them."""
+        self._handed.clear()
+
     def row_slots(self, c0: int) -> list[tuple[int, int, int]]:
         """Where the step at local row offset ``c0`` puts its rows:
         ``(global row, local device, slot on that device)`` for every live
@@ -336,12 +343,13 @@ class _ShardedStream:
         d, s = divmod(local, per_dev)
         return p * self.per_proc + c0 + s * self.n_local + d
 
-    def _assemble_rows(self, ch, c0: int, rows_pool, fill_row=None):
+    def _assemble_rows(self, ch, c0: int, rows_pool, truth=None):
         """One step's operands, ON the devices, for every workload, and the
         host blocks they were put from: the step's rows are inflated side by
         side (``rows_pool``; one ``mesh.row_inflate`` a row), each straight
         into its slot of its device's block, check-bam's truth of the row
-        filled beside it on the same worker (``fill_row(row, base, n)``,
+        filled beside it on the same worker once it is known up to the row's
+        end (``truth``, a ``_Truth``: ``checkbam.truth_wait``, then
         ``mesh.truth_fill``), and every device's operands go straight to
         that chip (``mesh.h2d``, waited for). The count's rows lie flat in a
         device's block (``mesh.make_shard_map_count_step`` has the layout),
@@ -359,7 +367,7 @@ class _ShardedStream:
         kw = self.kernel_window
         width = kw + PAD
         per_dev = self.step_rows_local // self.n_local
-        with_truth = fill_row is not None
+        with_truth = truth is not None
         # A device's block as its step takes it.
         shape = (per_dev, width) if self.every_position else (per_dev * width,)
         specs = [((per_dev * width,), np.uint8)] + (
@@ -398,9 +406,11 @@ class _ShardedStream:
             owns[i], los[i] = self._row_span(
                 g, n, at_eof, not self.every_position)
             if with_truth:
+                base = int(self.flat_starts[g])
+                with obs.span("checkbam.truth_wait", row=g):
+                    truth.wait(base + n)
                 with obs.span("mesh.truth_fill", row=g):
-                    used[s] = fill_row(
-                        untrue(d, s), int(self.flat_starts[g]), n)
+                    used[s] = truth.fill(untrue(d, s), base, n)
             else:
                 used[s] = _NO_TRUTH
 
@@ -414,7 +424,13 @@ class _ShardedStream:
                         if with_truth:
                             untrue(d, s)
                         used[s] = None
-            list(rows_pool.map(obs.trace.carried(fill), slots))
+            rows = [rows_pool.submit(obs.trace.carried(fill), slot)
+                    for slot in slots]
+            # Every row is waited for before one's error is raised: a worker
+            # reads the file's mapping, which the error's way out closes.
+            wait(rows)
+            for row in rows:
+                row.result()
         nbytes = len(bufs) * per_dev * (width + (kw if with_truth else 0))
         with obs.span("mesh.h2d", bytes=nbytes, **attrs):
             shards = []  # a local device: its operands, on it
@@ -450,13 +466,14 @@ class _ShardedStream:
         obs.count("mesh.h2d_bytes", nbytes)
         return args + [self.lengths_d, self.nc], list(bufs.values())
 
-    def row_batches(self, fill_row=None):
-        """A workload's steps: ``(operands on the devices, done, c0)``."""
+    def row_batches(self, truth=None):
+        """A workload's steps: ``(operands on the devices, done, c0)``;
+        ``truth``: check-bam's, a ``_Truth``."""
         # Rows side by side, each on the inflater's own eight threads.
         with ThreadPoolExecutor(min(self.step_rows_local, 8)) as rows_pool:
             yield from self._steps(
                 lambda ch, c0: self._assemble_rows(
-                    ch, c0, rows_pool, fill_row)
+                    ch, c0, rows_pool, truth)
             )
 
 
@@ -1029,42 +1046,146 @@ def host_shard_plan(
     return plan
 
 
-def _truth_flats(path, records_path, metas) -> np.ndarray:
-    """The ``.records`` ground truth as sorted absolute flat offsets: the
-    sidecar's two columns as arrays (no object a record), mapped through
-    the block table."""
-    from spark_bam_tpu.bam.index_records import read_records_arrays
-    from spark_bam_tpu.bgzf.flat import metas_block_table
-
-    records_path = (
-        str(path) + ".records" if records_path is None else records_path
-    )
-    with obs.span("checkbam.truth_load", path=str(records_path)):
-        blocks, offs = read_records_arrays(records_path)
-        block_starts, block_flat = metas_block_table(metas)
-        idx = np.searchsorted(block_starts, blocks)
-        if len(idx) and (
-            idx.max() >= len(block_starts)
-            or not np.array_equal(block_starts[idx], blocks)
-        ):
-            raise ValueError(
-                f"{records_path}: block positions not in {path}'s block "
-                "table (stale sidecar?)"
-            )
-        return np.sort(block_flat[idx] + offs)
+#: Text of the sidecar the truth's loader parses at a time: one piece is a
+#: few ms of numpy beside the assembly's threads (PERF.md, PR 45: the sizes
+#: tried on the chip).
+TRUTH_PIECE_BYTES = 512 << 10
+#: ``_Truth``'s reach once its loader has ended: past every offset.
+_ALL = np.iinfo(np.int64).max
 
 
-def _truth_filler(truth_flats: np.ndarray):
-    """``fill_row(row, base, n)`` of a check-bam pass: sets in ``row``, a
-    row's bool a position, the truth's offsets that lie in the ``n`` bytes
-    the row holds from flat offset ``base`` on, and returns them (what the
-    row's next user clears)."""
-    def fill_row(row, base, n):
-        i0, i1 = np.searchsorted(truth_flats, (base, base + n))
-        at = truth_flats[i0:i1] - base
+class _TruthUnordered(Exception):
+    """The sidecar is not in file order: what a row was filled with from
+    the prefix may lack a truth, and the pass starts over (``_Truth.sort``)."""
+
+
+class _Truth:
+    """check-bam's ``.records`` ground truth as absolute flat offsets,
+    loaded BESIDE the steps: one thread (it carries the pass's trace; span
+    ``checkbam.truth_load`` around the whole load) parses the sidecar a
+    piece at a time (no object a record), maps the piece through the block
+    table and appends it, and a row is filled as soon as the truth is known
+    up to the row's end. The sidecar is written in file order (upstream's
+    ``IndexRecords``, ``bam/index_records``), which is what lets a prefix
+    of it be the whole truth of a prefix of the file; the loader checks
+    that on every piece, and a sidecar that is not in order raises
+    ``_TruthUnordered`` from every wait until ``sort`` has made it whole.
+    The loader's own error (a line that is no position, a block position
+    the table lacks) is raised by whichever wait meets it first."""
+
+    def __init__(self, path, records_path, metas,
+                 piece_bytes: int = TRUTH_PIECE_BYTES):
+        self.path = path
+        self.records_path = (
+            str(path) + ".records" if records_path is None else records_path)
+        self._metas = metas
+        self._piece_bytes = piece_bytes
+        # Appended by the loader alone, in file order: the pieces, and each
+        # piece's last offset (what a row's fill finds its pieces by).
+        self._pieces: list[np.ndarray] = []
+        self._lasts: list[int] = []
+        self._cond = threading.Condition()
+        self._covered = -1       # every offset below this one is known
+        self._unordered = False
+        self._error: Exception | None = None
+        self._stop = False
+        self._thread = threading.Thread(
+            target=obs.trace.carried(self._load), name="checkbam-truth",
+            daemon=True)
+        self._thread.start()
+
+    def _load(self) -> None:
+        error = None
+        try:
+            with obs.span("checkbam.truth_load", path=str(self.records_path)):
+                self._parse()
+        except Exception as e:  # the thread's boundary: a wait raises it
+            error = e
+        with self._cond:
+            self._error = error
+            self._covered = _ALL
+            self._cond.notify_all()
+
+    def _parse(self) -> None:
+        from spark_bam_tpu.bam.index_records import iter_records_arrays
+        from spark_bam_tpu.bgzf.flat import metas_block_table
+
+        block_starts, block_flat = metas_block_table(self._metas)
+        for blocks, offs in iter_records_arrays(
+                self.records_path, self._piece_bytes):
+            if self._stop:
+                return
+            idx = np.searchsorted(block_starts, blocks)
+            if idx.max() >= len(block_starts) or not np.array_equal(
+                    block_starts[idx], blocks):
+                raise ValueError(
+                    f"{self.records_path}: block positions not in "
+                    f"{self.path}'s block table (stale sidecar?)")
+            flats = block_flat[idx] + offs
+            ordered = flats[0] >= self._covered and bool(
+                (flats[1:] >= flats[:-1]).all())
+            with self._cond:
+                self._pieces.append(flats)
+                self._lasts.append(int(flats[-1]))
+                self._unordered |= not ordered
+                self._covered = self._lasts[-1]
+                self._cond.notify_all()
+
+    def wait(self, end: int) -> None:
+        """Until every true offset below ``end`` is known."""
+        with self._cond:
+            self._cond.wait_for(
+                lambda: self._covered >= end or self._unordered)
+        if self._error is not None:
+            raise self._error
+        if self._unordered:
+            raise _TruthUnordered(self.records_path)
+
+    def fill(self, row, base: int, n: int):
+        """Sets in ``row``, a row's bool a position, the truth's offsets that
+        lie in the ``n`` bytes the row holds from flat offset ``base`` on
+        (``wait(base + n)`` has returned), and returns them (what the row's
+        next user clears)."""
+        end = base + n
+        # The pieces that may hold such an offset: from the first whose last
+        # offset reaches ``base`` to the first whose last reaches ``end``.
+        lo = bisect.bisect_left(self._lasts, base)
+        hi = bisect.bisect_left(self._lasts, end, lo) + 1
+        found = []
+        for piece in self._pieces[lo:hi]:
+            i0, i1 = np.searchsorted(piece, (base, end))
+            found.append(piece[i0:i1] - base)
+        at = np.concatenate(found) if found else _NO_TRUTH
         row[at] = True
         return at
-    return fill_row
+
+    def whole(self) -> np.ndarray:
+        """Every true offset, sorted: what follows the last step asks for it
+        (the loader is long done by then)."""
+        self.wait(_ALL)
+        if len(self._pieces) > 1:
+            self._become(np.concatenate(self._pieces))
+        return self._pieces[0] if self._pieces else _NO_TRUTH
+
+    def sort(self) -> None:
+        """A sidecar out of order, loaded whole and sorted first (``np.sort``
+        over all of it: the load before PR 45); every wait returns at once."""
+        self._thread.join()
+        with self._cond:
+            self._unordered = False
+        self._become(np.sort(self.whole()))
+
+    def _become(self, flats: np.ndarray) -> None:
+        """One piece holds them all (and there are some)."""
+        with self._cond:
+            self._pieces = [flats]
+            self._lasts = [int(flats[-1])]
+
+    def close(self) -> None:
+        """The loader stopped and joined: no thread outlives the pass."""
+        with self._cond:
+            self._stop = True
+        self._thread.join()
 
 
 def _in_sorted(values: np.ndarray, among: np.ndarray) -> np.ndarray:
@@ -1125,16 +1246,42 @@ def check_bam_sharded(
     escaped chains are (``mesh.dirty_steps``); when that cannot be done the
     whole file goes through the single-device deferral-exact spans path
     (``check.fused_demotions``), so the returned matrix and lists are
-    always exact.
+    always exact. The sidecar is parsed beside the steps (``_Truth``); one
+    whose lines are not in file order starts the steps over with the truth
+    sorted first (``checkbam.truth_restarts``), to the same answer.
     """
-    from spark_bam_tpu.parallel.mesh import MISMATCH_LIST
-
     st = _ShardedStream(
         path, config, mesh, window_uncompressed, halo, metas,
         workload="check_bam", num_processes=num_processes,
         process_id=process_id,
     )
-    truth_flats = _truth_flats(path, records_path, st.metas)
+    # The truth is loaded beside what follows: the first rows wait for the
+    # part of the sidecar that lies before their end, nothing for all of it.
+    truth = _Truth(path, records_path, st.metas)
+    try:
+        try:
+            return _check_bam_steps(st, truth, progress)
+        except _TruthUnordered:
+            # Rows filled from a prefix of such a sidecar may lack a truth:
+            # the pass starts over with the truth whole and sorted first.
+            obs.count("checkbam.truth_restarts")
+            truth.sort()
+            st.forget()
+            return _check_bam_steps(st, truth, progress)
+    finally:
+        truth.close()
+
+
+def _check_bam_steps(st: _ShardedStream, truth: _Truth, progress) -> dict:
+    """``check_bam_sharded``'s steps over ``st``'s rows and what follows
+    the last one."""
+    from spark_bam_tpu.parallel.mesh import MISMATCH_LIST
+
+    path, config = st.path, st.config
+    if st.num_processes > 1:
+        # Every process leaves the steps' collectives at the same step: an
+        # error of the loader or a start over is met before the first one.
+        truth.whole()
     with obs.span("load.open", program="confusion_step"):
         step = mesh_steps(st.mesh, st.axis).confusion_step(
             reads_to_check=config.reads_to_check,
@@ -1152,7 +1299,7 @@ def check_bam_sharded(
     dirty: list[int] = []     # local row offsets (c0) of escaped steps
     overflowed: set = set()   # global rows with more mismatches than slots
     whole_file = False
-    batches = st.row_batches(_truth_filler(truth_flats))
+    batches = st.row_batches(truth)
     try:
         for args, done, c0 in batches:
             with obs.span("mesh.step", workload="check_bam", c0=c0):
@@ -1201,6 +1348,7 @@ def check_bam_sharded(
     # lists re-derived on the host, the listed positions sorted and split
     # by the truth, the matrix.
     with obs.span("load.drain", what="result"):
+        truth_flats = truth.whole()
         redo = {g for c0 in dirty for g in _step_global_rows(st, c0)}
         if (redo or overflowed) and not whole_file:
             obs.count("checkbam.list_overflows", len(overflowed))

@@ -12,7 +12,10 @@ four counts and the two position lists that the device path must return,
 exactly.
 """
 
+import functools
 import json
+import threading
+import time
 from pathlib import Path
 
 import jax
@@ -168,11 +171,29 @@ def test_check_bam_tpu_runs_the_same_step_on_what_the_process_sees(
     assert counters["checkbam.passes"] == hists["load.check_bam"] == 1
 
 
-def test_a_check_bam_pass_is_one_trace_with_its_own_account(bam, tmp_path):
+def small_pieces(monkeypatch, piece_bytes: int = 512):
+    """The truth's loader handed ``piece_bytes`` of text a piece (the pass
+    makes its own ``_Truth``): tens of pieces where the 20 KB sidecar is one."""
+    monkeypatch.setattr(stream_mesh, "_Truth", functools.partial(
+        stream_mesh._Truth, piece_bytes=piece_bytes))
+
+
+def no_loader_alive() -> bool:
+    return not any(t.name == "checkbam-truth" for t in threading.enumerate())
+
+
+@pytest.mark.parametrize("small", (False, True),
+                         ids=["one-piece", "small-pieces"])
+def test_a_check_bam_pass_is_one_trace_with_its_own_account(
+        small, bam, tmp_path, monkeypatch):
     """``check_bam_tpu`` twice: two traces, the assembly thread's spans in
-    them, the walk, the plan, the truth and both ends under spans."""
+    them, the walk, the plan and both ends under spans; the truth's load
+    once a pass on its own thread and a wait for it once a live row on the
+    row pool's, both in the pass's trace, whatever the pieces."""
     from spark_bam_tpu.load.tpu_load import check_bam_tpu
 
+    if small:
+        small_pieces(monkeypatch)
     path, index, verdict = bam
     truth, _, _ = perturbed(index, seed=12)
     local = tmp_path / "short.bam"
@@ -184,10 +205,18 @@ def test_a_check_bam_pass_is_one_trace_with_its_own_account(bam, tmp_path):
         assert_same(got, expected(verdict, truth), jax.local_device_count())
     assert_one_trace_a_pass(
         events, hists, "load.check_bam", 2,
-        phases={"load.open", "bgzf.read", "mesh.plan", "checkbam.truth_load",
+        phases={"load.open", "bgzf.read", "mesh.plan",
                 "mesh.stall", "mesh.step", "mesh.dispatch", "load.drain"},
-        threads={"mesh.assemble", "mesh.h2d", "inflate.window"})
+        threads={"mesh.assemble", "mesh.h2d", "inflate.window",
+                 "checkbam.truth_load", "checkbam.truth_wait"})
     assert hists["bgzf.read"] == hists["mesh.plan"] == 2
+    assert hists["checkbam.truth_load"] == 2
+    rows = -(-index["uncompressed_bytes"] // WINDOW)
+    assert hists["checkbam.truth_wait"] == hists["mesh.truth_fill"] == 2 * rows
+    waits = [e for e in events if e["name"] == "checkbam.truth_wait"]
+    assert sorted(e["attrs"]["row"] for e in waits) == sorted(
+        2 * list(range(rows)))
+    assert no_loader_alive()
     assert hists["load.drain"] == 4  # the close, then the result, a pass
 
 
@@ -243,6 +272,155 @@ def test_a_stale_sidecar_still_raises(bam, tmp_path):
     sidecar.write_text(f"{index['block_starts'][1] + 1},0\n")
     with pytest.raises(ValueError, match="stale sidecar"):
         check_bam_sharded(path, Config(), records_path=sidecar, **CFG)
+    assert no_loader_alive()
+
+
+@pytest.mark.parametrize("small", (False, True),
+                         ids=["one-piece", "small-pieces"])
+@pytest.mark.parametrize("last_line,match", [
+    ("1,2,3", "not a .records sidecar"), ("{stale},0", "stale sidecar")])
+def test_an_error_in_the_sidecars_last_line_reaches_the_caller(
+        last_line, match, small, bam, tmp_path, monkeypatch):
+    """The loader's error is the caller's ``ValueError`` as it was when the
+    truth was loaded before the first step, wherever in the sidecar it lies
+    and whichever wait meets it; no loader thread outlives the pass."""
+    path, index, _ = bam
+    if small:
+        small_pieces(monkeypatch)
+    sidecar = tmp_path / "bad.records"
+    write_sidecar(index, index["record_starts"], sidecar)
+    with open(sidecar, "a") as f:
+        f.write(last_line.format(stale=index["block_starts"][1] + 1) + "\n")
+    with pytest.raises(ValueError, match=match):
+        check_bam_sharded(
+            path, Config(), mesh=make_mesh(jax.devices("cpu")[:4]),
+            records_path=sidecar, **CFG)
+    assert no_loader_alive()
+
+
+# ------------------------------------- the truth, loaded beside the steps
+
+def unordered(text: str, kind: str) -> str:
+    """A sidecar's lines out of file order: all of them shuffled, or one
+    record of the first row moved behind the last line."""
+    lines = text.splitlines()
+    if kind == "shuffled":
+        np.random.default_rng(5).shuffle(lines)
+    elif kind == "one-late-line":
+        lines.append(lines.pop(40))
+    return "\n".join(lines) + "\n"
+
+
+@pytest.mark.parametrize("small", (False, True),
+                         ids=["one-piece", "small-pieces"])
+@pytest.mark.parametrize("kind", ["sorted", "shuffled", "one-late-line"])
+def test_a_sidecar_out_of_order_starts_the_pass_over_and_stays_exact(
+        kind, small, bam, tmp_path, monkeypatch):
+    """Rows are filled from the prefix of the sidecar that lies before
+    their end, which is their whole truth only in a sidecar in file order.
+    One that is not (today's load sorted it) is seen by the loader, the
+    pass starts over with the truth whole and sorted first, and the answer
+    is the sorted sidecar's, element for element."""
+    from bench.oracle_checkbam import sidecar_text
+
+    path, index, verdict = bam
+    truth, _, _ = perturbed(index)
+    one_row_a_chip(monkeypatch)  # two steps: rows filled before the end
+    if small:
+        small_pieces(monkeypatch)
+    sidecar = tmp_path / f"{kind}.records"
+    sidecar.write_text(unordered(sidecar_text(index, truth), kind))
+    got, counters, hists = _observed(lambda: check_bam_sharded(
+        path, Config(), mesh=make_mesh(jax.devices("cpu")[:4]),
+        records_path=sidecar, **CFG))
+    assert_same(got, expected(verdict, truth), 4)
+    assert counters.get("checkbam.truth_restarts", 0) == int(kind != "sorted")
+    assert hists["checkbam.truth_load"] == 1  # parsed once, restart or none
+    assert not counters.get("check.fused_demotions")
+    assert no_loader_alive()
+
+
+def test_no_row_is_filled_before_its_truth_is_covered(
+        bam, tmp_path, monkeypatch):
+    """A loader far slower than the assembly (it parses nothing before the
+    first row waits, then 5 ms a piece of 512 bytes): every row's fill finds
+    the truth known up to the row's end, the first row is filled while the
+    loader is still at work, and the answer is exact."""
+    from spark_bam_tpu.bam import index_records
+
+    path, index, verdict = bam
+    truth, _, _ = perturbed(index)
+    sidecar = tmp_path / "wrong.records"
+    write_sidecar(index, truth, sidecar)
+    pieces = index_records.iter_records_arrays
+    asked = threading.Event()  # a row waits for its truth
+    fills = []  # (the row's end, the truth's reach at its fill)
+
+    def slowly(*args):
+        assert asked.wait(60)
+        for piece in pieces(*args):
+            time.sleep(0.005)
+            yield piece
+
+    class Watched(stream_mesh._Truth):
+        def wait(self, end):
+            asked.set()
+            super().wait(end)
+
+        def fill(self, row, base, n):
+            fills.append((base + n, self._covered))
+            return super().fill(row, base, n)
+
+    monkeypatch.setattr(index_records, "iter_records_arrays", slowly)
+    monkeypatch.setattr(stream_mesh, "_Truth", functools.partial(
+        Watched, piece_bytes=512))
+    one_row_a_chip(monkeypatch)
+    got = check_bam_sharded(
+        path, Config(), mesh=make_mesh(jax.devices("cpu")[:1]),
+        records_path=sidecar, **CFG)
+    assert_same(got, expected(verdict, truth), 1)
+    assert len(fills) == 5
+    assert all(covered >= end for end, covered in fills)
+    assert fills[0][1] < index["uncompressed_bytes"]  # beside, not before
+    assert no_loader_alive()
+
+
+def test_rows_on_many_threads_wait_on_one_loader(bam, tmp_path):
+    """More waiting threads than cores, the interpreter switching between
+    them all the time, pieces of a few lines: every fill, made as soon as
+    its wait returns, holds exactly the truth of its span."""
+    import sys
+    from concurrent.futures import ThreadPoolExecutor
+
+    from spark_bam_tpu.bgzf.index_blocks import blocks_metadata
+
+    path, index, _ = bam
+    records, total = index["record_starts"], index["uncompressed_bytes"]
+    sidecar = tmp_path / "right.records"
+    write_sidecar(index, records, sidecar)
+    span = 20_000
+
+    def row(k: int) -> bool:
+        base = (k * 104_729) % (total - span)
+        got = np.zeros(span, dtype=bool)
+        truth.wait(base + span)
+        at = truth.fill(got, base, span)
+        want = records[(records >= base) & (records < base + span)] - base
+        return np.array_equal(np.flatnonzero(got), want) and np.array_equal(
+            at, want)
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    truth = stream_mesh._Truth(
+        path, sidecar, list(blocks_metadata(path)), piece_bytes=256)
+    try:
+        with ThreadPoolExecutor(64) as pool:
+            assert all(pool.map(row, range(512), timeout=120))
+        assert np.array_equal(truth.whole(), records)
+    finally:
+        sys.setswitchinterval(interval)
+        truth.close()
+    assert no_loader_alive()
 
 
 # ------------------------------------------------- the sidecar's parse
@@ -259,11 +437,33 @@ def test_the_sidecar_round_trips_through_the_vectorised_parse(bam, tmp_path):
     assert blocks.dtype == offsets.dtype == np.int64
     assert [(int(b), int(o)) for b, o in zip(blocks, offsets)] == [
         (p.block_pos, p.offset) for p in read_records_index(out)]
-    flats = stream_mesh._truth_flats(
-        path, out, list(stream_mesh._ShardedStream(
-            path, Config(), make_mesh(jax.devices("cpu")[:1]),
-            WINDOW, HALO, None).metas))
-    assert np.array_equal(flats, index["record_starts"])
+    truth = stream_mesh._Truth(path, out, stream_mesh._ShardedStream(
+        path, Config(), make_mesh(jax.devices("cpu")[:1]),
+        WINDOW, HALO, None).metas)
+    assert np.array_equal(truth.whole(), index["record_starts"])
+    truth.close()
+
+
+@pytest.mark.parametrize("chunk", [7, 512, 4096, 64 << 20])
+def test_the_pieces_joined_are_the_whole_parse(chunk, bam, tmp_path):
+    """``iter_records_arrays``: pieces in file order, cut at line ends,
+    none empty; ``read_records_arrays`` is their concatenation."""
+    from spark_bam_tpu.bam.index_records import (
+        iter_records_arrays, read_records_arrays,
+    )
+
+    _, index, _ = bam
+    sidecar = tmp_path / "right.records"
+    write_sidecar(index, index["record_starts"], sidecar)
+    pieces = list(iter_records_arrays(sidecar, chunk))
+    assert all(len(b) == len(o) > 0 for b, o in pieces)
+    assert (chunk > 4096) == (len(pieces) == 1)
+    whole = read_records_arrays(sidecar)
+    for joined, column in zip(map(np.concatenate, zip(*pieces)), whole):
+        assert joined.dtype == np.int64
+        assert np.array_equal(joined, column)
+    for column, again in zip(whole, read_records_arrays(sidecar, chunk)):
+        assert np.array_equal(column, again)
 
 
 @pytest.mark.parametrize("text,rows", [

@@ -21,7 +21,7 @@ from spark_bam_tpu.core.config import Config
 from spark_bam_tpu.parallel import stream_mesh
 from spark_bam_tpu.parallel.mesh import make_mesh
 from spark_bam_tpu.parallel.stream_mesh import (
-    _ShardedStream, _truth_filler, check_bam_sharded,
+    _ShardedStream, _Truth, check_bam_sharded,
     full_check_summary_sharded,
 )
 from spark_bam_tpu.tpu.checker import PAD
@@ -52,7 +52,7 @@ def _stream(path, devices: int, per_dev: int, workload: str):
 @pytest.mark.parametrize("workload", ["check_bam", "full_check"])
 @pytest.mark.parametrize("devices,per_dev", WIDTHS)
 def test_every_device_receives_its_own_rows_and_their_truth(
-        bam, devices, per_dev, workload):
+        bam, devices, per_dev, workload, tmp_path):
     path, index, _verdict = bam
     truth_flats, _, _ = perturbed(index)
     with_truth = workload == "check_bam"
@@ -64,13 +64,16 @@ def test_every_device_receives_its_own_rows_and_their_truth(
     per_dev = st.step_rows_local // devices
     steps = list(range(0, st.per_proc, st.step_rows_local))
     idle = set()  # devices that held no live row in some step
-    fill_row = _truth_filler(truth_flats) if with_truth else None
+    loaded = None
+    if with_truth:
+        write_sidecar(index, truth_flats, tmp_path / "wrong.records")
+        loaded = _Truth(path, tmp_path / "wrong.records", st.metas)
 
     seen = []
     with open_channel(path) as ch, ThreadPoolExecutor(4) as pool:
         for c0 in steps:
             slots = st.row_slots(c0)
-            args, _blocks = st._assemble_rows(ch, c0, pool, fill_row)
+            args, _blocks = st._assemble_rows(ch, c0, pool, loaded)
             assert len(args) == (8 if with_truth else 7)
             windows, ns, eofs = args[:3]
             los, owns = args[-4:-2]
@@ -117,6 +120,8 @@ def test_every_device_receives_its_own_rows_and_their_truth(
                 g = st.step_row(c0, i)
                 assert g >= ROWS or g - c0 >= st.per_proc
             idle |= set(range(devices)) - {d for _g, d, _s in slots}
+    if loaded is not None:
+        loaded.close()
     assert seen == list(range(ROWS))  # every row once, in the file's order
     short = st._row_span(ROWS - 1, 0, False, False)[0]
     assert 0 < short < int(st.sizes[0])  # the last row is the short one

@@ -21,7 +21,7 @@ from spark_bam_tpu.core.config import Config
 from spark_bam_tpu.parallel import stream_mesh
 from spark_bam_tpu.parallel.mesh import mesh_steps
 from spark_bam_tpu.parallel.stream_mesh import (
-    _ShardedStream, _truth_filler, check_bam_sharded, count_reads_sharded,
+    _ShardedStream, _Truth, check_bam_sharded, count_reads_sharded,
     full_check_summary_sharded,
 )
 from spark_bam_tpu.tpu import inflate
@@ -139,23 +139,24 @@ def test_a_padding_slot_that_was_live_is_zeros(files, kept):
     """Two devices, two rows a device a step: x's last step is its fifth row
     alone, in a block whose slots both held rows; the other slot is handed to
     the program as zeros that own nothing, row and truth."""
-    path, index, _sidecar = files["x"]
-    truth_flats = _wrong_truth(index, 7)
+    path, index, sidecar = files["x"]
+    truth_flats = _wrong_truth(index, 7)  # what the sidecar holds
     probe = _ShardedStream(path, Config(), _mesh(2), WINDOW, HALO, None)
     width = probe.kernel_window + PAD
     st = _ShardedStream(
         path, Config(), _mesh(2), WINDOW, HALO, None, workload="check_bam",
         chunk_bytes=4 * width)
     assert st.step_rows_local == 4
-    fill_row = _truth_filler(truth_flats)
+    loaded = _Truth(path, sidecar, st.metas)
     with open_channel(path) as ch, ThreadPoolExecutor(4) as pool:
-        args, blocks = st._assemble_rows(ch, 0, pool, fill_row)
+        args, blocks = st._assemble_rows(ch, 0, pool, loaded)
         assert all(np.asarray(args[3]).any(axis=1))  # truth in every slot
         assert len(blocks) == 2 and not kept._free
         for arrays, used in blocks:
             assert all(len(u) for u in used)
             kept.give(arrays, keep=6, note=used)
-        args, blocks = st._assemble_rows(ch, 4, pool, fill_row)
+        args, blocks = st._assemble_rows(ch, 4, pool, loaded)
+    loaded.close()
     assert st.row_slots(4) == [(4, 0, 0)]
     (arrays, used), = blocks
     assert used[1] is None and len(used[0])
