@@ -151,10 +151,7 @@ class Phase:
 
 def engines(config) -> dict:
     """Which engines ``auto`` resolves to in this process."""
-    return {
-        "flags": config.flags_impl,
-        "funnel": config.funnel_enabled(),
-    }
+    return {"funnel": config.funnel_enabled()}
 
 
 def generate(compiles: Compiles, sizes: dict) -> dict:

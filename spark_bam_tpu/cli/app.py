@@ -113,7 +113,7 @@ def device_engine(backend: str, uncompressed_bytes: int) -> bool:
     kernel's compile and launch (small files resolve faster in the NumPy
     engine). The one place that decides it: ``CheckerContext`` asks with
     its view's size, ``check-bam -s`` with the block table's sum."""
-    if backend in ("tpu", "pallas"):
+    if backend == "tpu":
         return True
     if backend != "auto" or uncompressed_bytes < DEVICE_FROM_BYTES:
         return False
@@ -196,7 +196,6 @@ class CheckerContext:
                 window=window,
                 halo=min(self.config.halo_size, window // 4),
                 reads_to_check=self.config.reads_to_check,
-                flags_impl=self.config.flags_impl,
             )
             res = checker.check_buffer(self.view.data, at_eof=True)
             return ChainResult(

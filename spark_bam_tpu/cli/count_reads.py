@@ -20,11 +20,10 @@ def run(
     iterations: int = 1,
     reference=None,
     sharded: bool = False,
-    resident: bool = False,
 ) -> None:
     def timed_loop(count_fn):
         """The no-competitor output shape shared by every standalone mode
-        (resident / sharded / CRAM): N timed counts, no hadoop-bam leg.
+        (sharded / CRAM): N timed counts, no hadoop-bam leg.
         The named Timer feeds the ``timer.count_reads.spark_bam``
         histogram when a registry is live; output format is unchanged."""
         for _ in range(max(iterations, 1)):
@@ -34,25 +33,6 @@ def run(
             p.echo(f"Read count: {count}", "")
 
     is_cram = str(path).endswith(".cram")
-    if resident and sharded:
-        raise UsageError("--resident and --sharded are mutually exclusive")
-    if resident and is_cram:
-        raise UsageError(
-            "--resident supports BAM only: CRAM has no BGZF block "
-            "structure to window (use the default count-reads path)"
-        )
-    if (resident or config.resident_scan) and not is_cram and not sharded:
-        # Single-device streaming count in resident-scan mode: windows
-        # packed into HBM chunks, one dispatch per chunk — the remote-
-        # device configuration. A config-level opt-in (env/dict) applies
-        # only where the mode exists, so CRAM counting is unaffected.
-        from spark_bam_tpu.cli.app import funnel_status_line
-        from spark_bam_tpu.tpu.stream_check import StreamChecker
-
-        checker = StreamChecker(path, config)
-        timed_loop(checker.count_reads_resident)
-        p.echo(funnel_status_line(config, stats=checker.funnel_stats), "")
-        return
     if sharded:
         # Mesh-scale streaming count across every device (no hadoop-bam
         # leg: this is the scale mode; the comparison mode is the default).

@@ -267,11 +267,6 @@ def build_parser() -> argparse.ArgumentParser:
         "--sharded", action="store_true",
         help="mesh-scale streaming count across all devices (no hadoop leg)",
     )
-    sub.add_argument(
-        "--resident", action="store_true",
-        help="resident-scan streaming count: one device dispatch per HBM "
-             "chunk (amortizes dispatch latency on remote devices)",
-    )
     sub.add_argument("path")
 
     sub = sp.add_parser("time-load")
@@ -683,8 +678,8 @@ def _compiles_device_programs(args, config) -> bool:
     return (
         args.command in ("serve", "aggregate", "export")
         or any(getattr(args, flag, False)
-               for flag in ("sharded", "resident", "streaming"))
-        or config.backend in ("tpu", "pallas")
+               for flag in ("sharded", "streaming"))
+        or config.backend == "tpu"
     )
 
 
@@ -896,7 +891,6 @@ def main(argv=None) -> int:
                 args.path, p, config.split_size_or(Config.LOAD_SPLIT_SIZE_DEFAULT),
                 config, args.spark_bam_first, args.num_iterations,
                 reference=args.reference, sharded=args.sharded,
-                resident=args.resident,
             )
         elif cmd == "export":
             from spark_bam_tpu.cli import export as export_cmd

@@ -68,7 +68,7 @@ class Config:
     estimated_compression_ratio: float = 3.0
     # --- backend selection: the Checker plugin surface ---
     checker: str = "eager"              # eager | full | indexed | seqdoop
-    backend: str = "auto"               # auto | tpu | pallas | numpy | python | native
+    backend: str = "auto"               # one of BACKENDS; anything else is refused
     # --- TPU execution shape ---
     # Uncompressed bytes checked per device window. The streaming path
     # rounds (window + carry) up to a power of two for the kernel shape, so
@@ -76,19 +76,6 @@ class Config:
     # fits a 16 GB-HBM chip (64 MB windows OOM at compile time).
     window_size: int = 24 << 20
     halo_size: int = 4 << 20            # extra trailing bytes so chains can complete
-    # Resident-scan counting (tpu/stream_check.count_reads_resident):
-    # windows packed into HBM-resident chunks, ONE dispatch per chunk via
-    # checker.count_scan. Amortizes per-dispatch latency where a dispatch
-    # is expensive next to the kernel. Opt-in: the streaming loop stays the default
-    # because resident chunks hold ~1 GiB of HBM and the count is the only
-    # projection the scan kernel serves.
-    resident_scan: bool = False
-    # HBM budget for one resident-scan chunk, bytes (clamped to ≤ 1 GiB —
-    # the int32-offset ceiling — and to ≥ one window row). Two 1 GiB
-    # chunks in flight plus the scan body's window intermediates crowd a
-    # 16 GiB part at 32 MiB windows; 256 MiB keeps the dispatch
-    # amortization with headroom.
-    resident_chunk_bytes: int = 256 << 20
     # --- fault tolerance (core/faults.py; docs/robustness.md) ---
     # Compact FaultPolicy spec ("retries=3,deadline=60,mode=tolerant"; "" =
     # defaults). Kept as the string form so the frozen dataclass stays
@@ -199,12 +186,16 @@ class Config:
     CHECK_SPLIT_SIZE_DEFAULT = 2 << 20  # Blocks.scala:64
     LOAD_SPLIT_SIZE_DEFAULT = 32 << 20  # hadoop FileSplits default in the load path
 
-    @property
-    def flags_impl(self) -> str:
-        """Which flag-pass kernel the device engines run ("pallas" when
-        ``backend=pallas``, else the XLA pass) — the single mapping every
-        tier consults (StreamChecker, the CLI, the mesh steps)."""
-        return "pallas" if self.backend == "pallas" else "xla"
+    BACKENDS = ("auto", "tpu", "numpy", "python", "native")
+
+    def __post_init__(self):
+        # Outside input (spark.bam.backend, SPARK_BAM_BACKEND): a name no
+        # engine answers to must not run the NumPy engine unasked.
+        if self.backend not in self.BACKENDS:
+            raise ValueError(
+                f"Bad backend: {self.backend!r} "
+                f"(expected {' | '.join(self.BACKENDS)})"
+            )
 
     @property
     def fault_policy(self):

@@ -207,8 +207,7 @@ SCOPES = frozenset({
 #: XLA module's name, which a device trace shows for each execution.
 PROGRAMS = frozenset({
     "agg_step", "agg_update", "check_step", "check_window",
-    "confusion_step", "count_scan", "count_step", "count_window",
-    "full_step",
+    "confusion_step", "count_step", "count_window", "full_step",
     "serve_step", "sharded_check_step",
 })
 

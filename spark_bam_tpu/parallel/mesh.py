@@ -106,43 +106,39 @@ class MeshSteps:
                 step = self._steps[key] = _instrument_step(key[0], maker())
             return step
 
-    def count_step(self, reads_to_check: int = 10, flags_impl: str = "xla",
-                   funnel: bool = False):
+    def count_step(self, reads_to_check: int = 10, funnel: bool = False):
         return self._get(
-            ("count", reads_to_check, flags_impl, funnel),
+            ("count", reads_to_check, funnel),
             lambda: make_shard_map_count_step(
                 self.mesh, reads_to_check=reads_to_check, axis=self.axis,
-                flags_impl=flags_impl, funnel=funnel,
+                funnel=funnel,
             ),
         )
 
-    def confusion_step(self, reads_to_check: int = 10,
-                       flags_impl: str = "xla", funnel: bool = False):
+    def confusion_step(self, reads_to_check: int = 10, funnel: bool = False):
         return self._get(
-            ("confusion", reads_to_check, flags_impl, funnel),
+            ("confusion", reads_to_check, funnel),
             lambda: make_shard_map_confusion_step(
                 self.mesh, reads_to_check=reads_to_check, axis=self.axis,
-                flags_impl=flags_impl, funnel=funnel,
+                funnel=funnel,
             ),
         )
 
-    def full_step(self, reads_to_check: int = 10, flags_impl: str = "xla",
-                  k_positions: int = 4096):
+    def full_step(self, reads_to_check: int = 10, k_positions: int = 4096):
         return self._get(
-            ("full", reads_to_check, flags_impl, k_positions),
+            ("full", reads_to_check, k_positions),
             lambda: make_shard_map_full_step(
                 self.mesh, reads_to_check=reads_to_check, axis=self.axis,
-                flags_impl=flags_impl, k_positions=k_positions,
+                k_positions=k_positions,
             ),
         )
 
-    def serve_step(self, reads_to_check: int = 10, flags_impl: str = "xla",
-                   funnel: bool = False):
+    def serve_step(self, reads_to_check: int = 10, funnel: bool = False):
         return self._get(
-            ("serve", reads_to_check, flags_impl, funnel),
+            ("serve", reads_to_check, funnel),
             lambda: make_shard_map_serve_step(
                 self.mesh, reads_to_check=reads_to_check, axis=self.axis,
-                flags_impl=flags_impl, funnel=funnel,
+                funnel=funnel,
             ),
         )
 
@@ -265,16 +261,6 @@ def shard_windows(
     return mesh_steps(mesh, axis).put(windows)
 
 
-def _mesh_pallas_interpret(mesh: Mesh, flags_impl: str) -> bool:
-    """Interpret mode by where THIS mesh's kernels run (not the process
-    default): Mosaic on a TPU, interpret on the CPU, an error elsewhere."""
-    from spark_bam_tpu.tpu.pallas_kernels import interpret_for_platform
-
-    return flags_impl == "pallas" and interpret_for_platform(
-        mesh.devices.flat[0].platform
-    )
-
-
 def make_shard_map_check_step(mesh: Mesh, reads_to_check: int = 10, axis: str = "data"):
     """Explicit-collective variant of the sharded step.
 
@@ -325,7 +311,7 @@ def make_shard_map_check_step(mesh: Mesh, reads_to_check: int = 10, axis: str = 
 
 def make_shard_map_count_step(
     mesh: Mesh, reads_to_check: int = 10, axis: str = "data",
-    flags_impl: str = "xla", funnel: bool = False,
+    funnel: bool = False,
 ):
     """Sharded count-reads step, the whole-file count's step on every
     backend: each device runs the one-chip stream's window program
@@ -347,16 +333,13 @@ def make_shard_map_count_step(
     (``(1, N)`` u8 is tiled four rows high) and a relayout in the program.
     A device with one row runs the window program on its block as it is;
     with more it splits the block and ``vmap``s. The per-row scalars are
-    ``(rows,)`` in the same device-major order. ``flags_impl="pallas"``
-    swaps the flag pass for the Pallas kernel (``spark.bam.backend=pallas``
-    reaches the mesh tier too). The compiled program is ``jit_count_step``."""
-    pallas_interpret = _mesh_pallas_interpret(mesh, flags_impl)
+    ``(rows,)`` in the same device-major order. The compiled program is
+    ``jit_count_step``."""
 
     def one(window, n, at_eof, lo, own, lengths, nc):
         r = count_window(
             window, lengths, nc, n, at_eof, lo, own,
-            reads_to_check=reads_to_check, flags_impl=flags_impl,
-            pallas_interpret=pallas_interpret, funnel=funnel,
+            reads_to_check=reads_to_check, funnel=funnel,
         )
         return jnp.stack([
             r["count"], r["esc_count"], r["survivors"], r["lanes"],
@@ -425,7 +408,7 @@ def _list_positions(mask, slots: int, block: int = 1024):
 
 def make_shard_map_confusion_step(
     mesh: Mesh, reads_to_check: int = 10, axis: str = "data",
-    flags_impl: str = "xla", funnel: bool = False,
+    funnel: bool = False,
 ):
     """Sharded check-bam step: verdicts vs indexed truth at every owned
     position, the (tp, fp, fn, escapes) counters ``psum``'d over the mesh
@@ -456,15 +439,11 @@ def make_shard_map_confusion_step(
     and true negatives are host-derived: the caller knows its owned spans
     (tn = positions - tp - fp - fn). The compiled program is
     ``jit_confusion_step``."""
-    # Interpret mode is decided by where THIS mesh's kernels actually run
-    # (not the process-default backend): Mosaic compiles only on real TPUs.
-    pallas_interpret = _mesh_pallas_interpret(mesh, flags_impl)
 
     def one(window, n, at_eof, tr, lo, own, lengths, num_contigs):
         res = check_window(
             window, lengths, num_contigs, n, at_eof,
-            reads_to_check=reads_to_check, flags_impl=flags_impl,
-            pallas_interpret=pallas_interpret, funnel=funnel,
+            reads_to_check=reads_to_check, funnel=funnel,
         )
         w = window.shape[0] - PAD
         with jax.named_scope("reduce"):
@@ -509,7 +488,7 @@ def make_shard_map_confusion_step(
 
 def make_shard_map_full_step(
     mesh: Mesh, reads_to_check: int = 10, axis: str = "data",
-    flags_impl: str = "xla", k_positions: int = 4096,
+    k_positions: int = 4096,
 ):
     """Sharded full-check step (the third mesh workload, after count-reads
     and check-bam): every owned position's 19-flag mask, reduced to the
@@ -540,13 +519,11 @@ def make_shard_map_full_step(
 
     bit0 = int(BIT["tooFewFixedBlockBytes"])
     n_flags = len(FLAG_NAMES)
-    pallas_interpret = _mesh_pallas_interpret(mesh, flags_impl)
 
     def one(window, n, at_eof, lo, own, lengths, num_contigs):
         res = check_window(
             window, lengths, num_contigs, n, at_eof,
-            reads_to_check=reads_to_check, flags_impl=flags_impl,
-            pallas_interpret=pallas_interpret,
+            reads_to_check=reads_to_check,
         )
         w = window.shape[0] - PAD
         return row_report(res, w, lo, own)
@@ -609,7 +586,7 @@ def make_shard_map_full_step(
 
 def make_shard_map_serve_step(
     mesh: Mesh, reads_to_check: int = 10, axis: str = "data",
-    flags_impl: str = "xla", funnel: bool = False,
+    funnel: bool = False,
 ):
     """Sharded serving step: PER-ROW (boundary count, owned escapes,
     stage-0 survivors, lanes run) with NO cross-device reduction —
@@ -626,13 +603,11 @@ def make_shard_map_serve_step(
     The batch shape is fixed by the caller (pad to ``batch_rows``), so
     the jit traces exactly once per step config.
     """
-    pallas_interpret = _mesh_pallas_interpret(mesh, flags_impl)
 
     def one(window, n, at_eof, lo, own, lengths, num_contigs):
         res = check_window(
             window, lengths, num_contigs, n, at_eof,
-            reads_to_check=reads_to_check, flags_impl=flags_impl,
-            pallas_interpret=pallas_interpret, funnel=funnel,
+            reads_to_check=reads_to_check, funnel=funnel,
         )
         w = window.shape[0] - PAD
         with jax.named_scope("reduce"):

@@ -658,7 +658,7 @@ def _count_steps(st: "_ShardedStream", config: Config, progress):
     with obs.span("load.open", program="count_step"):
         step = mesh_steps(st.mesh, st.axis).count_step(
             reads_to_check=config.reads_to_check,
-            flags_impl=config.flags_impl, funnel=config.funnel_enabled(),
+            funnel=config.funnel_enabled(),
         )
         observer = _StepObserver.maybe()
     batches = st.row_batches()
@@ -841,8 +841,7 @@ def full_check_summary_sharded(
         workload="full_check",
     )
     step = mesh_steps(st.mesh, st.axis).full_step(
-        reads_to_check=config.reads_to_check,
-        flags_impl=config.flags_impl, k_positions=k_positions,
+        reads_to_check=config.reads_to_check, k_positions=k_positions,
     )
     n_flags = len(FLAG_NAMES)
     agg = np.zeros(5 + n_flags, dtype=np.int64)
@@ -1285,7 +1284,7 @@ def _check_bam_steps(st: _ShardedStream, truth: _Truth, progress) -> dict:
     with obs.span("load.open", program="confusion_step"):
         step = mesh_steps(st.mesh, st.axis).confusion_step(
             reads_to_check=config.reads_to_check,
-            flags_impl=config.flags_impl, funnel=config.funnel_enabled(),
+            funnel=config.funnel_enabled(),
         )
         observer = _StepObserver.maybe()
 

@@ -67,8 +67,7 @@ class Batcher:
     """Tick loop turning queued :class:`RowTask`s into serve-step calls."""
 
     def __init__(self, steps, width: int, batch_rows: int, tick_ms: float,
-                 reads_to_check: int = 10, flags_impl: str = "xla",
-                 funnel: bool = False):
+                 reads_to_check: int = 10, funnel: bool = False):
         ndev = steps.mesh.devices.size
         self.steps = steps
         self.ndev = int(ndev)
@@ -76,8 +75,7 @@ class Batcher:
         self.batch_rows = -(-int(batch_rows) // ndev) * ndev
         self.tick_s = float(tick_ms) / 1000.0
         self._step = steps.serve_step(
-            reads_to_check=reads_to_check, flags_impl=flags_impl,
-            funnel=funnel,
+            reads_to_check=reads_to_check, funnel=funnel,
         )
         self._queue: "deque[RowTask]" = deque()
         self._cond = threading.Condition()

@@ -202,7 +202,6 @@ class SplitService:
             batch_rows=self.serve_cfg.batch_rows,
             tick_ms=self.serve_cfg.tick_ms,
             reads_to_check=config.reads_to_check,
-            flags_impl=config.flags_impl,
             funnel=config.funnel_enabled(),
         )
         self.gate = AdmissionGate({

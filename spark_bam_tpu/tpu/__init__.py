@@ -1,9 +1,8 @@
-"""TPU execution engines (JAX/XLA; Pallas kernels where profitable).
+"""TPU execution engines (JAX/XLA).
 
 - ``checker``  — the vectorized boundary checker as a jittable window kernel
 - ``parser``   — batched record-field extraction + on-device interval filter
-- ``inflate``  — host-parallel BGZF inflate feeding device windows (the
-  Pallas in-device DEFLATE design lives here too)
+- ``inflate``  — host-parallel BGZF inflate feeding device windows
 """
 
 from spark_bam_tpu.tpu.checker import TpuChecker, check_window, make_check_window

@@ -35,9 +35,8 @@ from spark_bam_tpu.check.flags import BIT
 from spark_bam_tpu.check.vectorized import DEFINITIVE_MASK, ESCAPE_MASK
 
 # Padding beyond any index the flag pass can touch (36 fixed + 255 name +
-# 4*65535 cigar + slack), rounded up to a multiple of 1024 so it can double
-# as the Pallas slab halo (Mosaic DMA slices tile at 1024 elements) and of 4
-# for the stride-4 scan. 257*1024 = 263168 ≥ 262431.
+# 4*65535 cigar + slack), rounded up to a multiple of 1024 (and so of 4,
+# for the stride-4 scan). 257*1024 = 263168 ≥ 262431.
 PAD = 257 * 1024
 
 _I32 = jnp.int32
@@ -77,8 +76,8 @@ def _ref_pos_bits(idx, pos, c, len_at, b_neg_idx, b_large_idx, b_neg_pos, b_larg
 
 def _compute_flags(p, lengths, num_contigs, n):
     """Flag pass over a (W+PAD,)-byte padded buffer; returns F (the 19-bit
-    mask per position). ``remaining``/``body_end`` live in ``_compute_misc``
-    — shared with the Pallas flag path; XLA CSEs the overlapping slices."""
+    mask per position). ``remaining``/``body_end`` live in ``_compute_misc``;
+    XLA CSEs the overlapping slices."""
     w = p.shape[0] - PAD
     u = _i32_at(p, w)
     i32 = lax.bitcast_convert_type(u, jnp.int32)
@@ -168,7 +167,7 @@ def _compute_flags(p, lengths, num_contigs, n):
 
 def _compute_misc(p, n):
     """remaining + body_end only (the non-flag outputs of the flag pass) —
-    what the chain walk still needs when the Pallas kernel supplies F."""
+    what the chain walk needs beside F."""
     w = p.shape[0] - PAD
     u = _i32_at(p, w)
     i32 = lax.bitcast_convert_type(u, jnp.int32)
@@ -522,8 +521,7 @@ ESCAPE_LIST = 64
 
 
 def _flag_stage(
-    padded, lengths, num_contigs, n, at_eof,
-    flags_impl: str, pallas_interpret: bool, funnel: bool,
+    padded, lengths, num_contigs, n, at_eof, funnel: bool,
 ):
     """Stage 0, position-wide and run once a window: the flag pass (the
     prefilter under the funnel, over the word view), the survivors it
@@ -541,24 +539,7 @@ def _flag_stage(
             # assemble its word from four byte gathers again), which the
             # prefilter reads its fields from too.
             U = lax.optimization_barrier(_words_at(padded))
-            if flags_impl == "pallas":
-                from spark_bam_tpu.tpu.pallas_kernels import (
-                    prefilter_check_flags,
-                )
-
-                F = prefilter_check_flags(
-                    padded, lengths, num_contigs.reshape(1), n.reshape(1),
-                    interpret=pallas_interpret,
-                )
-            else:
-                F = _prefilter_flags(padded, lengths, num_contigs, n, U)
-        elif flags_impl == "pallas":
-            from spark_bam_tpu.tpu.pallas_kernels import full_check_flags
-
-            F = full_check_flags(
-                padded, lengths, num_contigs.reshape(1), n.reshape(1),
-                interpret=pallas_interpret,
-            )
+            F = _prefilter_flags(padded, lengths, num_contigs, n, U)
         else:
             F = _compute_flags(padded, lengths, num_contigs, n)
     if funnel:
@@ -752,8 +733,7 @@ class _LaneBlocks(NamedTuple):
 
 
 def _deep_blocks(
-    padded, lengths, num_contigs, n, at_eof,
-    flags_impl: str, pallas_interpret: bool, block: int | None,
+    padded, lengths, num_contigs, n, at_eof, block: int | None,
 ) -> _LaneBlocks:
     """Stage 0 and pass 1 of THE lane stage of the funnel, sized by the
     window's own survivors; ``_walk_blocks`` is pass 2.
@@ -779,10 +759,7 @@ def _deep_blocks(
     of each loop's body: wide blocks followed by a remainder in narrow ones
     was swept against (PR 40) and not needed."""
     w = padded.shape[0] - PAD
-    S = _flag_stage(
-        padded, lengths, num_contigs, n, at_eof,
-        flags_impl, pallas_interpret, True,
-    )
+    S = _flag_stage(padded, lengths, num_contigs, n, at_eof, True)
     capacity = lane_capacity(w)
     block = min(block or lane_block(w), capacity)
     max_blocks = -(-capacity // block)
@@ -860,8 +837,7 @@ _LANE_KEYS = ("res", "fail_mask", "reads_before", "reads_parsed", "exact")
 @jax.named_scope("check")
 def _check_lanes(
     padded, lengths, num_contigs, n, at_eof,
-    reads_to_check: int = 10, flags_impl: str = "xla",
-    pallas_interpret: bool = False, funnel: bool = False,
+    reads_to_check: int = 10, funnel: bool = False,
     block: int | None = None,
 ):
     """Flag pass + survivor compaction + lane walk, WITHOUT the full-width
@@ -883,10 +859,7 @@ def _check_lanes(
     as it always was: no cell runs it and its masks are another contract."""
     w = padded.shape[0] - PAD
     if funnel:
-        B = _deep_blocks(
-            padded, lengths, num_contigs, n, at_eof,
-            flags_impl, pallas_interpret, block,
-        )
+        B = _deep_blocks(padded, lengths, num_contigs, n, at_eof, block)
         S, cand = B.S, B.cands
 
         def keep(carry, k, _cand, _live, lanes):
@@ -901,10 +874,7 @@ def _check_lanes(
         lanes = dict(zip(_LANE_KEYS, kept))
         overflow, n_survivors, ran = B.overflow, B.n_survivors, B.lanes
     else:
-        S = _flag_stage(
-            padded, lengths, num_contigs, n, at_eof,
-            flags_impl, pallas_interpret, False,
-        )
+        S = _flag_stage(padded, lengths, num_contigs, n, at_eof, False)
         F, survivor = S["F"], S["survivor"]
         capacity = lane_capacity(w)
         # No funnel: the survivors' compaction is the walk's own prologue.
@@ -937,8 +907,7 @@ def _check_lanes(
 @jax.named_scope("check")
 def _count_lanes(
     padded, lengths, num_contigs, n, at_eof, lo, own,
-    reads_to_check: int, flags_impl: str, pallas_interpret: bool,
-    block: int | None = None, escapes: int = 0,
+    reads_to_check: int, block: int | None = None, escapes: int = 0,
 ):
     """The funnelled check reduced to the count's scalars: the lane stage
     (``_deep_blocks`` / ``_walk_blocks``) with each block's lanes summed as
@@ -949,10 +918,7 @@ def _count_lanes(
     block holds its lanes' positions and results already, so nothing
     position-wide is added. Escapes beyond the slots are counted in ``esc``
     and not listed."""
-    B = _deep_blocks(
-        padded, lengths, num_contigs, n, at_eof,
-        flags_impl, pallas_interpret, block,
-    )
+    B = _deep_blocks(padded, lengths, num_contigs, n, at_eof, block)
 
     def tally(carry, _k, cand, live, lanes):
         count, esc, *listed = carry
@@ -993,9 +959,7 @@ def _list_escapes(esc_pos, before, escaped, cand):
 
 @functools.partial(
     jax.jit,
-    static_argnames=(
-        "reads_to_check", "window", "flags_impl", "pallas_interpret", "funnel"
-    ),
+    static_argnames=("reads_to_check", "window", "funnel"),
 )
 def check_window(
     padded: jnp.ndarray,       # (W+PAD,) uint8; zeros beyond n
@@ -1005,8 +969,6 @@ def check_window(
     at_eof: jnp.ndarray,       # () bool: buffer end == file end
     reads_to_check: int = 10,
     window: int | None = None,
-    flags_impl: str = "xla",   # "xla" | "pallas" (spark.bam.backend=pallas)
-    pallas_interpret: bool = False,
     funnel: bool = False,      # two-stage candidate funnel (Config.funnel)
 ):
     """Flag pass + chain walk over one window; verdicts for every offset.
@@ -1041,8 +1003,7 @@ def check_window(
     w = padded.shape[0] - PAD
     L = _check_lanes(
         padded, lengths, num_contigs, n, at_eof,
-        reads_to_check=reads_to_check, flags_impl=flags_impl,
-        pallas_interpret=pallas_interpret, funnel=funnel,
+        reads_to_check=reads_to_check, funnel=funnel,
     )
     return _scatter_lanes(L, w)
 
@@ -1093,8 +1054,7 @@ def _scatter_lanes(L: dict, w: int) -> dict:
 
 def _count_funnel(
     padded, lengths, num_contigs, n, at_eof, lo, own,
-    reads_to_check: int, flags_impl: str, pallas_interpret: bool,
-    block: int | None = None, escapes: int = 0,
+    reads_to_check: int, block: int | None = None, escapes: int = 0,
 ):
     """``count_window`` under the funnel. Scatter-free reduction: verdicts
     live only on survivor lanes (non-survivors never reach res==1) and
@@ -1114,7 +1074,7 @@ def _count_funnel(
     m = (i >= lo) & (i < own)
     L = _count_lanes(
         padded, lengths, num_contigs, n, at_eof, lo, own,
-        reads_to_check, flags_impl, pallas_interpret, block, escapes,
+        reads_to_check, block, escapes,
     )
     with jax.named_scope("reduce"):
         esc0 = jnp.sum(m & (L["res0"] == 2))
@@ -1134,15 +1094,11 @@ def _count_funnel(
 
 @functools.partial(
     jax.jit,
-    static_argnames=(
-        "reads_to_check", "window", "flags_impl", "pallas_interpret", "funnel",
-        "escapes",
-    ),
+    static_argnames=("reads_to_check", "window", "funnel", "escapes"),
 )
 def count_window(
     padded, lengths, num_contigs, n, at_eof, lo, own,
     reads_to_check: int = 10, window: int | None = None,
-    flags_impl: str = "xla", pallas_interpret: bool = False,
     funnel: bool = False, escapes: int = 0,
 ):
     """check_window fused with its owned-span count reduction.
@@ -1166,16 +1122,14 @@ def count_window(
     if funnel:
         return _count_funnel(
             padded, lengths, num_contigs, n, at_eof, lo, own,
-            reads_to_check, flags_impl, pallas_interpret, escapes=escapes,
+            reads_to_check, escapes=escapes,
         )
     w = padded.shape[0] - PAD
     i = jnp.arange(w, dtype=_I32)
     m = (i >= lo) & (i < own)
     res = check_window(
         padded, lengths, num_contigs, n, at_eof,
-        reads_to_check=reads_to_check, window=window,
-        flags_impl=flags_impl, pallas_interpret=pallas_interpret,
-        funnel=funnel,
+        reads_to_check=reads_to_check, window=window, funnel=funnel,
     )
     with jax.named_scope("reduce"):
         out = {
@@ -1192,138 +1146,27 @@ def count_window(
         return out
 
 
-def _pallas_interpret_for(impl: str) -> bool:
-    """Interpret mode for a Pallas engine on the process-default backend
-    (Mosaic on a TPU, interpret on the tests' CPU mesh, an error elsewhere);
-    False for the XLA engines, which never ask."""
-    from spark_bam_tpu.tpu.pallas_kernels import interpret_for_platform
-
-    return impl == "pallas" and interpret_for_platform()
-
-
 def make_count_window(
-    window: int, reads_to_check: int = 10, flags_impl: str = "xla",
-    funnel: bool = False, escapes: int = 0,
+    window: int, reads_to_check: int = 10, funnel: bool = False,
+    escapes: int = 0,
 ):
-    """A jit-compiled fused count kernel for fixed ``window`` size."""
-    pallas_interpret = _pallas_interpret_for(flags_impl)
-
-    def run(padded, lengths, num_contigs, n, at_eof, lo, own):
-        return count_window(
-            padded, lengths, num_contigs, n, at_eof, lo, own,
-            reads_to_check=reads_to_check, window=window,
-            flags_impl=flags_impl, pallas_interpret=pallas_interpret,
-            funnel=funnel, escapes=escapes,
-        )
-
-    return run
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=(
-        "window", "reads_to_check", "flags_impl", "pallas_interpret", "funnel"
-    ),
-)
-def count_scan(
-    chunk,      # (L,) uint8 resident chunk; L ≥ max(starts) + window + PAD
-    lengths,    # (Cmax,) int32
-    num_contigs,  # () int32
-    starts,     # (K,) int32: window byte offsets into ``chunk``
-    ns,         # (K,) int32: valid byte count per window (0 ⇒ dummy pad row)
-    at_eofs,    # (K,) bool
-    los,        # (K,) int32 owned-span starts (local to the window)
-    owns,       # (K,) int32 owned-span ends   (local to the window)
-    *,
-    window: int,
-    reads_to_check: int = 10,
-    flags_impl: str = "xla",
-    pallas_interpret: bool = False,
-    funnel: bool = False,
-):
-    """The fused count kernel scanned over K windows in ONE dispatch.
-
-    ``count_window`` pays one dispatch per window, which matters where a
-    dispatch is expensive next to the kernel (both costs on the chip: not
-    measured). Here the whole chunk of the
-    uncompressed stream is resident in HBM and ``lax.scan`` drives the
-    same window body K times inside one XLA program, so the round-trip is
-    paid once per *chunk*. XLA reuses the body's intermediates across
-    iterations, so device memory stays O(one window) + the chunk itself.
-
-    Per-window scalar rows (``ns``/``at_eofs``/``los``/``owns``) carry the
-    halo-carry ownership discipline of ``stream_check.halo_windows``;
-    a row with ``own == lo`` contributes nothing, which is how the caller
-    pads K to a bucket size without perturbing counts.
-
-    This is the count-reads workload of reference
-    load/.../CanLoadBam.scala:173-243 at whole-chunk granularity.
-    """
-    def body(carry, xs):
-        cnt, esc, surv = carry
-        s, n, ae, lo, own = xs
-        win = lax.dynamic_slice(chunk, (s,), (window + PAD,))
-        r = check_window(
-            win, lengths, num_contigs, n, ae,
-            reads_to_check=reads_to_check, window=window,
-            flags_impl=flags_impl, pallas_interpret=pallas_interpret,
-            funnel=funnel,
-        )
-        i = jnp.arange(window, dtype=_I32)
-        m = (i >= lo) & (i < own)
-        return (
-            cnt + jnp.sum(m & r["verdict"]),
-            esc + jnp.sum(m & r["escaped"]),
-            surv + r["survivors"],
-        ), None
-
-    (cnt, esc, surv), _ = lax.scan(
-        body, (_I32(0), _I32(0), _I32(0)),
-        (starts, ns, at_eofs, los, owns),
+    """The fused count kernel (``jit_count_window``) for a fixed ``window``."""
+    return functools.partial(
+        count_window, reads_to_check=reads_to_check, window=window,
+        funnel=funnel, escapes=escapes,
     )
-    return {"count": cnt, "esc_count": esc, "survivors": surv}
-
-
-def make_count_scan(
-    window: int, reads_to_check: int = 10, flags_impl: str = "xla",
-    funnel: bool = False,
-):
-    """A jit-compiled resident-chunk count kernel for fixed ``window``."""
-    pallas_interpret = _pallas_interpret_for(flags_impl)
-
-    def run(chunk, lengths, num_contigs, starts, ns, at_eofs, los, owns):
-        return count_scan(
-            chunk, lengths, num_contigs, starts, ns, at_eofs, los, owns,
-            window=window, reads_to_check=reads_to_check,
-            flags_impl=flags_impl, pallas_interpret=pallas_interpret,
-            funnel=funnel,
-        )
-
-    return run
 
 
 def make_check_window(
-    window: int, reads_to_check: int = 10, flags_impl: str = "xla",
-    funnel: bool = False,
+    window: int, reads_to_check: int = 10, funnel: bool = False,
 ):
-    """A jit-compiled window kernel for fixed ``window`` size.
-
-    ``flags_impl="pallas"`` swaps the flag pass for the Pallas full kernel
-    (tpu/pallas_kernels.py); on non-TPU backends it runs in interpret mode.
-    ``funnel=True`` swaps in the two-stage candidate funnel (same verdicts,
-    see ``check_window``).
-    """
-    pallas_interpret = _pallas_interpret_for(flags_impl)
-
-    def run(padded, lengths, num_contigs, n, at_eof):
-        return check_window(
-            padded, lengths, num_contigs, n, at_eof,
-            reads_to_check=reads_to_check, window=window,
-            flags_impl=flags_impl, pallas_interpret=pallas_interpret,
-            funnel=funnel,
-        )
-
-    return run
+    """The window kernel (``jit_check_window``) for a fixed ``window``;
+    ``funnel=True`` is the two-stage candidate funnel (same verdicts, see
+    ``check_window``)."""
+    return functools.partial(
+        check_window, reads_to_check=reads_to_check, window=window,
+        funnel=funnel,
+    )
 
 
 @dataclass
@@ -1351,7 +1194,6 @@ class TpuChecker:
         halo: int = 4 << 20,
         reads_to_check: int = 10,
         cmax: int = 1024,
-        flags_impl: str = "xla",
     ):
         self.window = window
         self.halo = halo
@@ -1360,7 +1202,7 @@ class TpuChecker:
         cmax = max(cmax, len(contig_lengths))
         self.lengths = np.zeros(cmax, dtype=np.int32)
         self.lengths[: len(contig_lengths)] = contig_lengths
-        self._kernel = make_check_window(window, reads_to_check, flags_impl)
+        self._kernel = make_check_window(window, reads_to_check)
 
     def check_buffer(self, buf: np.ndarray, at_eof: bool = True) -> WindowResult:
         """Check every position of ``buf``; exact everywhere except possibly

@@ -279,9 +279,6 @@ class StreamChecker:
         lens_dev = jax.device_put(jnp.asarray(lens))
         return lens_dev, jnp.int32(len(self.lengths))
 
-    def _flags_impl(self) -> str:
-        return self.config.flags_impl
-
     def _funnel_add(self, screened: int, survivors: int, lanes: int):
         """Fold one window's (or chunk's) funnel totals into the stats
         surface and the ``funnel.*`` observability counters: positions
@@ -306,7 +303,6 @@ class StreamChecker:
 
         kernel = make_check_window(
             self.kernel_window, self.config.reads_to_check,
-            flags_impl=self._flags_impl(),
             funnel=self.config.funnel_enabled(full_masks),
         )
         lens_dev, nc = self._device_inputs()
@@ -565,8 +561,7 @@ class StreamChecker:
         with obs.span("load.open", program="count_window"):
             kernel = make_count_window(
                 self.kernel_window, self.config.reads_to_check,
-                flags_impl=self._flags_impl(), funnel=funnel,
-                escapes=ESCAPE_LIST,
+                funnel=funnel, escapes=ESCAPE_LIST,
             )
             lens_dev, nc = self._device_inputs()
             observer = DeviceObserver.maybe()
@@ -685,162 +680,6 @@ class StreamChecker:
                 self.progress = saved
         assert not len(escapes.deferred), "escapes must resolve by EOF"
         return total + escapes.starts
-
-    def count_reads_resident(
-        self, chunk_windows: int | None = None,
-        first_chunk_windows: int = 4,
-    ) -> int:
-        """Record count with ONE device dispatch per resident chunk.
-
-        ``count_reads`` dispatches the fused kernel once per window; where
-        a dispatch is expensive next to the kernel that caps streaming
-        throughput below the chip's kernel rate (both costs on the chip:
-        not measured). Here windows are packed into HBM-resident chunks and
-        ``checker.count_scan`` walks all of a chunk's windows inside one
-        XLA program — the round-trip is paid once per ~``chunk_windows``
-        windows. The first chunk is small (``first_chunk_windows``) so
-        escape-prone inputs (ultra-long reads vs this halo) abort to the
-        exact path early: the scan sums its escapes and does not list them,
-        so here, unlike in ``count_reads``, one escape starts the file over.
-
-        Chunk device buffers are K·w+PAD bytes with K bucketed to a power
-        of two (dummy rows own nothing), bounding recompiles to one per
-        bucket; per-chunk positions stay < 2^31 so the on-device int32
-        sums cannot overflow. Falls back to the exact spans path on any
-        escape, and to the streaming loop if a pipeline row ever exceeds
-        the kernel window (cannot happen with the block-aligned pipeline,
-        but exactness must not depend on that).
-        """
-        if not self.use_device:
-            return self._count_via_spans()
-        from spark_bam_tpu.tpu.checker import PAD, make_count_scan
-
-        w = self.kernel_window
-        # Chunk bytes at the PACKED stride (w+PAD) are capped by
-        # ``Config.resident_chunk_bytes`` (≤ 1 GiB): the 1 GiB ceiling keeps
-        # the int32 ``starts`` offsets < 2^30 even after pow2 bucketing (the
-        # bucket can double a non-pow2 row count) and per-chunk positions
-        # < 2^31 for the on-device sums; the config default (256 MiB) also
-        # leaves HBM headroom for the scan body's intermediates (two 1 GiB
-        # chunks in flight plus one 32 MiB window's ~2.7 GiB of temporaries
-        # crowd a 16 GiB part). Floor-pow2 so the bucket never exceeds the
-        # cap.
-        cap_bytes = min(
-            1 << 30, max(self.config.resident_chunk_bytes, w + PAD)
-        )
-        max_windows = max(1, cap_bytes // (w + PAD))
-        max_windows = 1 << (max_windows.bit_length() - 1)
-        if chunk_windows is None:
-            chunk_windows = max_windows
-        else:
-            chunk_windows = min(chunk_windows, max_windows)
-        kernel = make_count_scan(
-            w, self.config.reads_to_check, flags_impl=self._flags_impl(),
-            funnel=self.config.funnel_enabled(),
-        )
-        funnel = self.config.funnel_enabled()
-        lens_dev, nc = self._device_inputs()
-
-        total = 0
-        # Per-chunk (count, esc) device scalars, folded to host ints one
-        # chunk behind (keeps ≤ 2 chunks in flight; folding per chunk also
-        # keeps every int32 sum within one chunk's < 2^31 positions — the
-        # cross-chunk accumulator lives on host).
-        pend: list = []
-        windows_done = 0
-        escaped = False
-
-        def flush(rows):
-            """Pack rows into a bucketed chunk and dispatch once.
-
-            Row stride is w+PAD, not w: each window's slice is
-            ``chunk[s : s+w+PAD]`` and ``check_window`` requires zeros
-            beyond the row's valid bytes — at stride w the PAD lookahead
-            would read the NEXT row (a halo-rewound, wrong-offset view of
-            the stream), corrupting flags near the row end for chains
-            that sample there (long-read regime). The per-row zero gap
-            costs PAD/w ≈ 0.8% extra HBM."""
-            k = len(rows)
-            kp = _next_pow2(k)
-            stride = w + PAD
-            chunk = np.zeros(kp * stride, dtype=np.uint8)
-            starts = np.arange(kp, dtype=np.int32) * stride
-            ns = np.zeros(kp, dtype=np.int32)
-            aes = np.zeros(kp, dtype=bool)
-            los = np.zeros(kp, dtype=np.int32)
-            owns = np.zeros(kp, dtype=np.int32)
-            for j, (buf, ae, lo, own) in enumerate(rows):
-                chunk[j * stride: j * stride + len(buf)] = buf
-                ns[j], aes[j], los[j], owns[j] = len(buf), ae, lo, own
-            return kernel(
-                jnp.asarray(chunk), lens_dev, nc, jnp.asarray(starts),
-                jnp.asarray(ns), jnp.asarray(aes), jnp.asarray(los),
-                jnp.asarray(owns),
-            )
-
-        def dispatch(rows):
-            """One chunk's pending ``(count, esc, survivors, positions,
-            lanes)``: the scan runs every row of the bucket, padding too,
-            at the window's whole lane capacity."""
-            out = flush(rows)
-            return (out["count"], out["esc_count"], out["survivors"],
-                    sum(len(r[0]) for r in rows),
-                    _next_pow2(len(rows)) * lane_capacity(w))
-
-        rows: list = []
-        chunks = 0
-        cap = first_chunk_windows
-        pos_flushed = 0
-        gen = halo_windows(self.pipeline, self.halo, self.header_end_abs)
-        try:
-            for buf, base, own_end, lo, at_eof in gen:
-                if len(buf) > w:  # impossible with the block-aligned pipeline
-                    return self.count_reads()
-                rows.append((buf, at_eof, lo, own_end))
-                windows_done += 1
-                pos_flushed = base + own_end
-                obs.count("check.windows")
-                if len(rows) >= cap:
-                    pend.append(dispatch(rows))
-                    rows = []
-                    chunks += 1
-                    cap = chunk_windows
-                    # Sync the first (small) chunk's scalars immediately;
-                    # after that, one chunk behind.
-                    if chunks == 1 or len(pend) > 1:
-                        cnt, esc, surv, scr, lanes = pend.pop(0)
-                        if int(esc):
-                            escaped = True
-                            break
-                        total += int(cnt)
-                        if funnel:
-                            self._funnel_add(scr, int(surv), lanes)
-                    # Progress at dispatch points only: buffered-but-unsent
-                    # windows must not inflate the forensics position.
-                    if self.progress is not None:
-                        self.progress(windows_done, pos_flushed, self.total)
-        finally:
-            gen.close()
-        if not escaped:
-            if rows:
-                pend.append(dispatch(rows))
-            for cnt, esc, surv, scr, lanes in pend:
-                if int(esc):
-                    escaped = True
-                    break
-                total += int(cnt)
-                if funnel:
-                    self._funnel_add(scr, int(surv), lanes)
-            if not escaped and self.progress is not None and windows_done:
-                self.progress(windows_done, pos_flushed, self.total)
-        if escaped:
-            obs.count("check.count_escape_retries")
-            saved, self.progress = self.progress, None
-            try:
-                return self._count_via_spans()
-            finally:
-                self.progress = saved
-        return total
 
     def _count_via_spans(self) -> int:
         he = self.header_end_abs

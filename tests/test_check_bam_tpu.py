@@ -600,9 +600,8 @@ def test_the_cli_scans_the_blocks_once_and_reports_every_step(
 
 
 @pytest.mark.parametrize("backend,mib,device", [
-    ("tpu", 48, True), ("pallas", 48, True), ("tpu", 8, False),
-    ("auto", 48, False)],
-    ids=["tpu-at-scale", "pallas-at-scale", "tpu-small", "auto-on-the-cpu"])
+    ("tpu", 48, True), ("tpu", 8, False), ("auto", 48, False)],
+    ids=["tpu-at-scale", "tpu-small", "auto-on-the-cpu"])
 def test_dash_s_goes_to_the_device_at_scale(backend, mib, device):
     """``-s`` is scored by ``check_bam_tpu`` where the file is over one
     kernel window and the context's eager engine is the device

@@ -163,7 +163,7 @@ def test_count_window_xla_funnel_compiles_at_32mib(chip):
     )
 
     kernel = jax.jit(make_count_window(
-        WINDOW, 10, "xla", funnel=True, escapes=ESCAPE_LIST))
+        WINDOW, 10, funnel=True, escapes=ESCAPE_LIST))
     compiled = kernel.lower(
         chip((WINDOW + PAD,), jnp.uint8), chip((CMAX,), jnp.int32),
         *_scalars(chip, jnp.int32, jnp.int32, jnp.bool_, jnp.int32,
@@ -209,7 +209,7 @@ def test_sharded_count_step_fits_one_chip_at_its_row_cap(topo, chip):
     assert _rows_fitting_device(none, WINDOW) >= 1 << 20
 
     mesh, shape, repl = _mesh_shapes(topo, 1)
-    step = make_shard_map_count_step(mesh, 10, "data", "xla", funnel=True)
+    step = make_shard_map_count_step(mesh, 10, "data", funnel=True)
     compiled = step.lower(*_count_step_shapes(shape, repl, 1, rows)).compile()
     assert _device_bytes(compiled) < HBM
 
@@ -225,7 +225,7 @@ def confusion_step(topo, chip):
 
     rows = 3
     mesh, shape, repl = _mesh_shapes(topo, 1)
-    step = make_shard_map_confusion_step(mesh, 10, "data", "xla", funnel=True)
+    step = make_shard_map_confusion_step(mesh, 10, "data", funnel=True)
     return rows, step.lower(
         shape((rows, WINDOW + PAD), jnp.uint8), shape((rows,), jnp.int32),
         shape((rows,), jnp.bool_), shape((rows, WINDOW), jnp.bool_),
@@ -319,7 +319,7 @@ def test_count_step_compiles_for_four_chips_at_one_row_a_chip(topo, chip):
 
     n = 4
     mesh, shape, repl = _mesh_shapes(topo, n)
-    step = make_shard_map_count_step(mesh, 10, "data", "xla", funnel=True)
+    step = make_shard_map_count_step(mesh, 10, "data", funnel=True)
     compiled = step.lower(*_count_step_shapes(shape, repl, n, 1)).compile()
     ma = compiled.memory_analysis()
     assert WINDOW < ma.argument_size_in_bytes < WINDOW + (1 << 20)
@@ -341,7 +341,7 @@ def test_serve_step_compiles_at_serve_config_defaults(topo, chip):
     cfg = ServeConfig()
     b, width = cfg.batch_rows, cfg.window + PAD
     mesh, shape, _ = _mesh_shapes(topo, 1)
-    step = make_shard_map_serve_step(mesh, 10, "data", "xla", funnel=True)
+    step = make_shard_map_serve_step(mesh, 10, "data", funnel=True)
     compiled = step.lower(
         shape((b, width), jnp.uint8), shape((b,), jnp.int32),
         shape((b,), jnp.bool_), shape((b,), jnp.int32),

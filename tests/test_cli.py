@@ -295,15 +295,6 @@ def test_count_reads_sharded(bam2, tmp_path):
     assert lines[1] == "Read count: 2500"
 
 
-def test_count_reads_resident(bam2, tmp_path):
-    """--resident (resident-scan mode: one dispatch per HBM chunk) must
-    count exactly, through the CLI surface."""
-    got = run_cli(["count-reads", "--resident", str(bam2)], tmp_path)
-    lines = got.splitlines()
-    assert re.fullmatch(r"spark-bam read-count time: \d+", lines[0])
-    assert lines[1] == "Read count: 2500"
-
-
 def test_check_bam_sharded(bam1, tmp_path):
     got = run_cli(["check-bam", "--sharded", str(bam1)], tmp_path)
     golden = (GOLDEN / "check-bam" / "1.bam").read_text()
@@ -380,20 +371,3 @@ def test_compare_splits_corpus(bam2, tmp_path):
     assert got.splitlines()[0] == (
         f"All {len(paths)} BAMs' splits (totals: 60, 60) matched!"
     )
-
-
-def test_count_reads_resident_sharded_conflict(bam2, capsys):
-    """--resident and --sharded are mutually exclusive."""
-    assert main(["count-reads", "--resident", "--sharded", str(bam2)]) != 0
-    assert "mutually exclusive" in capsys.readouterr().err
-
-
-def test_count_reads_config_resident_skips_cram(bam2, tmp_path, monkeypatch):
-    """A global resident-scan opt-in (env) must not break CRAM counting —
-    the mode simply doesn't apply there (review catch: the config-
-    triggered branch used to raise '--resident supports BAM only' for a
-    flag the user never passed)."""
-    cram = _cram_from_bam(bam2, tmp_path)
-    monkeypatch.setenv("SPARK_BAM_RESIDENT_SCAN", "1")
-    got = run_cli(["count-reads", str(cram)], tmp_path)
-    assert "Read count: 2500" in got

@@ -56,11 +56,6 @@ def test_config_surface():
     assert c2.split_size == 4 << 20
     c3 = Config.from_env({"SPARK_BAM_CHECKER": "full"})
     assert c3.checker == "full"
-    assert c.resident_scan is False
-    c4 = Config.from_dict({"spark.bam.resident.scan": "true"})
-    assert c4.resident_scan is True
-    c5 = Config.from_env({"SPARK_BAM_RESIDENT_SCAN": "1"})
-    assert c5.resident_scan is True
 
 
 _CACHE_PROBE = (
@@ -140,19 +135,6 @@ def test_count_without_native_library_inflates_with_zlib(
     finally:
         obs.shutdown()
     assert got == want > 0 and engines == {"zlib"}
-
-
-def test_pallas_interpret_only_on_cpu():
-    """Interpret mode is for the CPU; a TPU compiles; anything else is an
-    error, never a silent interpreter."""
-    import pytest
-
-    from spark_bam_tpu.tpu.pallas_kernels import interpret_for_platform
-
-    assert interpret_for_platform("cpu") is True
-    assert interpret_for_platform("tpu") is False
-    with pytest.raises(RuntimeError, match="gpu"):
-        interpret_for_platform("gpu")
 
 
 def test_config_env_skips_cloud_namespaces(monkeypatch):

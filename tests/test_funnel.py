@@ -4,8 +4,7 @@ Two claims keep the funnel honest (docs/design.md, "Candidate funnel"):
 the stage-0 prefilter is a provable superset filter (every full-pass
 survivor passes it), and every funnel projection is verdict-identical to
 the full pass — on factory corpora, on seeded decode-fuzz mutants, and on
-adversarial byte soup. Everything here runs on the virtual CPU mesh;
-Pallas coverage uses interpret mode.
+adversarial byte soup. Everything here runs on the virtual CPU mesh.
 """
 
 import functools
@@ -21,7 +20,7 @@ from spark_bam_tpu.core.config import Config
 from spark_bam_tpu.tpu import checker as ck
 from tests.bam_factories import random_bam
 
-W = 256 << 10  # multiple of the Pallas TILE (32 KiB)
+W = 256 << 10
 
 PARITY_KEYS = ("verdict", "escaped", "reads_before", "reads_parsed")
 
@@ -169,23 +168,6 @@ def test_superset_on_adversarial_windows(corpus):
         _assert_superset(pd, ld, nc, n)
 
 
-def test_pallas_prefilter_matches_xla(corpus):
-    """The fused Pallas prefilter tile kernel (interpret mode off-TPU) is
-    bit-identical to the XLA prefilter."""
-    from spark_bam_tpu.tpu.pallas_kernels import prefilter_check_flags
-
-    p = corpus[0]
-    pd, n = _window_of(flatten_file(p).data)
-    ld, nc = _lens_of(p)
-    got = np.asarray(
-        prefilter_check_flags(
-            pd, ld, nc.reshape(1), n.reshape(1), interpret=True
-        )
-    )
-    want = np.asarray(ck._prefilter_flags(pd, ld, nc, n))
-    np.testing.assert_array_equal(got, want)
-
-
 def test_stream_record_starts_parity(corpus):
     """Whole-stream projection: funnel on vs off yield byte-identical
     record-start positions, and only the funnelled run reports stats."""
@@ -276,8 +258,7 @@ def _full_capacity_check_window(padded, lengths, num_contigs, n, at_eof):
     """``check_window(funnel=True)`` with ONE lane stage of the window's
     whole capacity: no blocks, no loop."""
     w = padded.shape[0] - ck.PAD
-    S = ck._flag_stage(
-        padded, lengths, num_contigs, n, at_eof, "xla", False, True)
+    S = ck._flag_stage(padded, lengths, num_contigs, n, at_eof, True)
     capacity = ck.lane_capacity(w)
     table = ck._rank_table(S["survivor"])
     cand = ck._ranked_positions(table, jnp.arange(capacity, dtype=jnp.int32))
@@ -328,8 +309,7 @@ def blocked():
     def get(block):
         if block not in cache:
             cache[block] = jax.jit(functools.partial(
-                ck._count_funnel, reads_to_check=10, flags_impl="xla",
-                pallas_interpret=False, block=block))
+                ck._count_funnel, reads_to_check=10, block=block))
         return cache[block]
 
     return get
@@ -738,8 +718,8 @@ def test_the_escape_list_spans_lane_blocks_and_reports_its_overflow(
     assert len(want) > 8
     for slots, overflow in ((64, False), (8, True), (1, True)):
         fn = jax.jit(functools.partial(
-            ck._count_funnel, reads_to_check=10, flags_impl="xla",
-            pallas_interpret=False, block=block, escapes=slots))
+            ck._count_funnel, reads_to_check=10, block=block,
+            escapes=slots))
         out = fn(pd, ld, nc, n, jnp.bool_(False), jnp.int32(lo),
                  jnp.int32(own))
         assert int(out["esc_count"]) == len(want)

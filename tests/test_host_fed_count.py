@@ -435,7 +435,7 @@ def test_the_shares_add_up(mesh_file):
         chunk_bytes=1)  # one row a step
     assert st.step_rows_local == 1
     step = mesh_steps(st.mesh, st.axis).count_step(
-        reads_to_check=config.reads_to_check, flags_impl=config.flags_impl,
+        reads_to_check=config.reads_to_check,
         funnel=config.funnel_enabled(),
     )
     per_row = []

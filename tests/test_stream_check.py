@@ -165,83 +165,6 @@ def test_count_reads_flush_chunks(bam1):
     assert checker.count_reads() == 4917
 
 
-def test_count_reads_resident_matches_streaming(bam1):
-    """The resident-scan count (one dispatch per chunk, checker.count_scan)
-    must equal the per-window streaming count across chunk seams, pow2
-    bucketing with dummy rows, and the small first chunk."""
-    from spark_bam_tpu.core.config import Config
-    from spark_bam_tpu.tpu.stream_check import StreamChecker
-
-    checker = StreamChecker(
-        bam1, Config(), window_uncompressed=128 << 10, halo=32 << 10
-    )
-    # chunk_windows=3 is deliberately not a power of two: full chunks pad
-    # to a 4-row bucket with a dummy row that must contribute nothing.
-    assert checker.count_reads_resident(
-        chunk_windows=3, first_chunk_windows=2
-    ) == 4917
-
-
-def test_count_reads_resident_single_chunk(bam2):
-    """Default chunking puts the whole small file in one resident chunk."""
-    from spark_bam_tpu.core.config import Config
-    from spark_bam_tpu.tpu.stream_check import StreamChecker
-
-    checker = StreamChecker(
-        bam2, Config(), window_uncompressed=256 << 10, halo=64 << 10
-    )
-    assert checker.count_reads_resident(first_chunk_windows=64) == 2500
-
-
-def test_count_reads_resident_escape_falls_back_exact(tmp_path):
-    """Reads longer than the halo escape in the first (small) chunk; the
-    resident path must abort to the exact spans path and still be right."""
-    from spark_bam_tpu.bam.header import BamHeader, ContigLengths
-    from spark_bam_tpu.bam.record import BamRecord
-    from spark_bam_tpu.bam.writer import write_bam
-    from spark_bam_tpu.core.config import Config
-    from spark_bam_tpu.core.pos import Pos
-    from spark_bam_tpu.tpu.stream_check import StreamChecker
-
-    rng = np.random.default_rng(13)
-    path = tmp_path / "long_resident.bam"
-    header = BamHeader(
-        ContigLengths({0: ("chr1", 200_000_000)}), Pos(0, 0), 0,
-        "@HD\tVN:1.6\n@SQ\tSN:chr1\tLN:200000000\n",
-    )
-
-    def records():
-        pos = 1000
-        for i in range(30):
-            n = int(rng.integers(60_000, 110_000))
-            yield BamRecord(
-                ref_id=0, pos=pos, mapq=60, bin=0, flag=0,
-                next_ref_id=-1, next_pos=-1, tlen=0,
-                read_name=f"lr/{i}", cigar=[(n, 0)],
-                seq="A" * n, qual=bytes([30]) * n,
-            )
-            pos += n + 5
-
-    write_bam(path, header, records())
-
-    checker = StreamChecker(
-        path, Config(), window_uncompressed=256 << 10, halo=64 << 10
-    )
-    calls = []
-    orig = StreamChecker._count_via_spans
-
-    def spy(self):
-        calls.append(1)
-        return orig(self)
-
-    StreamChecker._count_via_spans = spy
-    try:
-        assert checker.count_reads_resident(chunk_windows=4) == 30
-    finally:
-        StreamChecker._count_via_spans = orig
-    assert calls, "escape fallback was not exercised"
-
-
 def test_full_spans_match_whole_file(bam1):
     """Streaming full-check spans must reassemble the whole-file fail_mask
     and reads_before exactly (flags for every position, O(window) memory)."""

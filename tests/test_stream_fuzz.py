@@ -205,25 +205,3 @@ def test_subrecord_window_projections_match_whole_file(tmp_path, seed):
     assert StreamChecker(path, Config(), **cfg).count_reads() == int(
         want.verdict[he:].sum()
     )
-
-
-@pytest.mark.parametrize("seed,chunk_windows", [(0, 2), (1, 3), (2, 5)])
-def test_resident_count_matches_whole_file(tmp_path, seed, chunk_windows):
-    """count_reads_resident at odd chunk sizes (non-pow2 → bucketed with
-    dummy rows) must equal the whole-file oracle on random BAMs — pins
-    the chunk pack/bucket arithmetic under irregular window counts."""
-    path = tmp_path / f"res{seed}.bam"
-    random_bam(
-        path, seed, contigs=(("chr1", 5_000_000), ("chr2", 3_000_000)),
-        dup_rate=0.1,
-    )
-    flat = flatten_file(path)
-    hdr = read_header(path)
-    lens = np.array(hdr.contig_lengths.lengths_list(), dtype=np.int32)
-    want = check_flat(flat.data, lens, at_eof=True)
-    he = hdr.uncompressed_size
-
-    got = StreamChecker(path, Config(), **CFG).count_reads_resident(
-        chunk_windows=chunk_windows, first_chunk_windows=2,
-    )
-    assert got == int(want.verdict[he:].sum())

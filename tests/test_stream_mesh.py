@@ -167,31 +167,6 @@ def test_progress_callback_fires():
     assert seen[-1][2] == seen[-1][1]  # final flush covers the whole file
 
 
-def test_sharded_count_pallas_backend():
-    """spark.bam.backend=pallas reaches the mesh tier: the sharded count
-    through the Pallas flag kernel (interpret mode on the CPU mesh) must
-    equal the XLA-flags result."""
-    got = count_reads_sharded(
-        BAM2, Config(backend="pallas"), mesh=_mesh(),
-        window_uncompressed=2 << 20, halo=128 << 10,
-    )
-    assert got == 2500
-
-
-def test_check_bam_sharded_pallas_backend():
-    """The confusion step's Pallas wiring (truth tensor + extra in_specs)
-    under backend=pallas must reproduce the XLA-flags matrix."""
-    from spark_bam_tpu.parallel.stream_mesh import check_bam_sharded
-
-    stats = check_bam_sharded(
-        BAM2, Config(backend="pallas"), mesh=_mesh(),
-        window_uncompressed=2 << 20, halo=128 << 10,
-    )
-    assert stats["true_positives"] == 2500
-    assert stats["false_positives"] == 0
-    assert stats["false_negatives"] == 0
-
-
 def test_stats_out_reports_fallback():
     stats = {}
     count_reads_sharded(

@@ -202,7 +202,7 @@ def test_no_block_is_rewritten_while_its_step_is_unread(files, monkeypatch):
         assert st.step_rows_local == 1 and len(st.groups) == 5
         step = mesh_steps(st.mesh, st.axis).count_step(
             reads_to_check=config.reads_to_check,
-            flags_impl=config.flags_impl, funnel=config.funnel_enabled())
+            funnel=config.funnel_enabled())
 
         def settle(out, c0):
             count, escapes, _survivors, _lanes = np.asarray(out).tolist()

@@ -56,69 +56,3 @@ def test_tpu_windowed_flags_match_numpy(bam1):
     np.testing.assert_array_equal(res.verdict, ref.verdict)
     np.testing.assert_array_equal(res.fail_mask, ref.fail_mask)
     np.testing.assert_array_equal(res.reads_before, ref.reads_before)
-
-
-def test_count_scan_matches_per_window_kernel(bam1):
-    """count_scan over packed rows must equal count_window per row. Rows
-    are filled to exactly n == w (the contract edge): at a packed stride
-    of w the scan's PAD lookahead would read the NEXT row's bytes instead
-    of the zeros check_window requires — the regression this pins is
-    silent verdict corruption near row tails (stride must be w+PAD)."""
-    import jax.numpy as jnp
-
-    from spark_bam_tpu.bam.header import contig_lengths
-    from spark_bam_tpu.tpu.checker import (
-        PAD,
-        make_count_scan,
-        make_count_window,
-    )
-
-    flat = flatten_file(bam1)
-    lens_arr = np.array(contig_lengths(bam1).lengths_list(), dtype=np.int32)
-    lens = np.zeros(1024, dtype=np.int32)
-    lens[: len(lens_arr)] = lens_arr
-    nc = jnp.int32(len(lens_arr))
-
-    w = 1 << 18
-    halo = 1 << 16
-    # Halo-carry rows over the real stream, every interior row exactly w
-    # bytes (n == w) so row tails abut the next slot.
-    rows = []
-    base = 0
-    while base < flat.size:
-        buf = flat.data[base: base + w]
-        at_eof = base + w >= flat.size
-        own = len(buf) if at_eof else len(buf) - halo
-        rows.append((buf, at_eof, 0 if base else 104, own))  # 104 ≈ header
-        base += own
-    # Reference: the trusted per-window kernel, each row zero-padded alone.
-    ref_kernel = make_count_window(w, 10)
-    want = 0
-    for buf, ae, lo, own in rows:
-        padded = np.zeros(w + PAD, dtype=np.uint8)
-        padded[: len(buf)] = buf
-        out = ref_kernel(
-            jnp.asarray(padded), jnp.asarray(lens), nc,
-            jnp.int32(len(buf)), jnp.bool_(ae), jnp.int32(lo), jnp.int32(own),
-        )
-        want += int(out["count"])
-
-    stride = w + PAD
-    kp = len(rows)
-    chunk = np.zeros(kp * stride, dtype=np.uint8)
-    ns = np.zeros(kp, dtype=np.int32)
-    aes = np.zeros(kp, dtype=bool)
-    los = np.zeros(kp, dtype=np.int32)
-    owns = np.zeros(kp, dtype=np.int32)
-    for j, (buf, ae, lo, own) in enumerate(rows):
-        chunk[j * stride: j * stride + len(buf)] = buf
-        ns[j], aes[j], los[j], owns[j] = len(buf), ae, lo, own
-    scan_kernel = make_count_scan(w, 10)
-    out = scan_kernel(
-        jnp.asarray(chunk), jnp.asarray(lens), nc,
-        jnp.asarray(np.arange(kp, dtype=np.int32) * stride),
-        jnp.asarray(ns), jnp.asarray(aes), jnp.asarray(los),
-        jnp.asarray(owns),
-    )
-    assert int(out["esc_count"]) == 0  # full halos; no escapes expected
-    assert int(out["count"]) == want
