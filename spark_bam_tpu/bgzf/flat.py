@@ -53,8 +53,7 @@ class FlatView:
         return pos_of_flat_tables(self.block_starts, self.block_flat, flat)
 
     def pos_of_flat_many(self, flat: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
-        idx = np.searchsorted(self.block_flat, flat, side="right") - 1
-        return self.block_starts[idx], flat - self.block_flat[idx]
+        return pos_of_flat_tables(self.block_starts, self.block_flat, flat)
 
 
 def metas_block_table(metas) -> tuple[np.ndarray, np.ndarray]:
@@ -68,13 +67,13 @@ def metas_block_table(metas) -> tuple[np.ndarray, np.ndarray]:
     return block_starts, block_flat
 
 
-def pos_of_flat_tables(
-    block_starts: np.ndarray, block_flat: np.ndarray, flat: int
-) -> tuple[int, int]:
-    """Flat offset → (block_pos, intra-block offset); the single source of
-    truth for the boundary convention (shared with FlatView.pos_of_flat)."""
-    i = int(np.searchsorted(block_flat, flat, side="right")) - 1
-    return int(block_starts[i]), int(flat - block_flat[i])
+def pos_of_flat_tables(block_starts: np.ndarray, block_flat: np.ndarray, flat):
+    """Flat offset → (block_pos, intra-block offset), an array of offsets →
+    the two arrays; the single source of truth for the boundary convention
+    (shared with FlatView.pos_of_flat and pos_of_flat_many)."""
+    i = np.searchsorted(block_flat, flat, side="right") - 1
+    blocks, offs = block_starts[i], flat - block_flat[i]
+    return (blocks, offs) if np.ndim(flat) else (int(blocks), int(offs))
 
 
 def read_block_payload(ch: ByteChannel, meta: Metadata):

@@ -160,12 +160,16 @@ NAMES = frozenset({
     "serve.batch_wait", "serve.batches",
     "serve.connections", "serve.cycle", "serve.d2h",
     "serve.device_dispatch",
-    "serve.errors", "serve.h2d",
+    "serve.errors", "serve.file_open", "serve.flat_resident_mib",
+    "serve.h2d",
     "serve.h2d_bytes", "serve.latency_ms", "serve.overloaded",
     "serve.parse", "serve.queue_depth", "serve.queue_ms", "serve.request",
-    "serve.requests", "serve.rewrite", "serve.scatter", "serve.shed",
+    "serve.requests", "serve.rewrite", "serve.scatter",
+    "serve.segment_evictions", "serve.segment_hits",
+    "serve.segment_inflate", "serve.segment_misses", "serve.segment_waits",
+    "serve.shed",
     "serve.step", "serve.stream_aborts",
-    "serve.tick", "serve.tick_lanes", "serve.tuned",
+    "serve.tick", "serve.tick_lanes", "serve.tuned", "serve.worker_wait_ms",
     # serve shm — segment lifecycle + encoded-frame cache
     # (docs/serving.md "Transport")
     "serve.frame_cache_hits", "serve.frame_cache_misses",
