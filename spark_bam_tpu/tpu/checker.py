@@ -435,39 +435,107 @@ def _deep_flags_at(p, U, lengths, num_contigs, n, tables, pos):
     return F, remaining, body_end
 
 
+#: Keys a row of a rank table's levels (``_rank_table``): the chip's lane
+#: width, so a lane's step down a level is ONE fetch of 512 B. On a v5e such
+#: a fetch costs 1.6 ns a lane where a gathered element of the same 4 MiB
+#: costs 7.2; a row of 32 keys costs the same to fetch, one of 1,024 keys
+#: 4.6, and no fan-out swept beat this one (``PERF.md`` §6, PR 48:
+#: ``tools/lane_sweep.py``).
+RANK_ROW = 128
+
+#: Most keys the level at the top, which every lane compares itself with
+#: whole and fetches nothing for: a served row's 2**15 words are one level
+#: of 256 rows under a top of 256 keys.
+RANK_TOP = 1024
+
+_NO_RANK = jnp.iinfo(jnp.int32).max     # a key no rank reaches: the padding
+
+
 class _RankTable(NamedTuple):
     """The word-level rank table of a mask: its bits packed to u32 words,
-    each word's popcount, their inclusive prefix (a tiny cumsum) and the
-    population. ``_ranked_positions`` finds the k-th set bit from it without
-    any full-width cumsum/sort/scatter."""
+    the population, and the words' inclusive popcount prefix (a tiny cumsum)
+    as the LEVELS of a search tree, top first, each ``(rows, keys a row)``.
+    The last level is the prefix itself in rows of ``RANK_ROW`` keys (padded
+    with a key no rank reaches); a level above holds the last key of every
+    row below, in such rows too; the top is one row of at most ``RANK_TOP``
+    keys. A table of no more words than that is its top alone. The words lie
+    in the last level's rows, a word where its key is.
+    ``_ranked_positions`` finds the k-th set bit from it without any
+    full-width cumsum/sort/scatter."""
     words: jnp.ndarray
-    wpc: jnp.ndarray
-    wcnt: jnp.ndarray
+    levels: tuple
     n_set: jnp.ndarray
 
 
 def _rank_table(mask) -> _RankTable:
     words = _pack_bits(mask)
-    wpc = lax.population_count(words).astype(_I32)
-    wcnt = jnp.cumsum(wpc)
-    return _RankTable(words, wpc, wcnt, wcnt[-1])
+    keys = jnp.cumsum(lax.population_count(words).astype(_I32))
+    n_set = keys[-1]
+    levels = []
+    while keys.shape[0] > RANK_TOP:
+        rows = -(-keys.shape[0] // RANK_ROW)
+        keys = jnp.pad(
+            keys, (0, rows * RANK_ROW - keys.shape[0]),
+            constant_values=_NO_RANK).reshape(rows, RANK_ROW)
+        levels.append(keys)
+        keys = keys[:, -1]
+    levels.append(keys[None, :])
+    last = levels[0]
+    words = jnp.pad(words, (0, last.size - words.shape[0])).reshape(last.shape)
+    return _RankTable(words, tuple(reversed(levels)), n_set)
 
 
-def _ranked_positions(table, k):
-    """Positions of the set bits of ranks ``k`` ((K,) int32, 0-based), -1
-    beyond the population: binary-search the word holding the k-th
-    survivor, then locate the in-word bit with masked popcounts."""
-    words, wpc, wcnt, n_set = table
-    wi = jnp.searchsorted(wcnt, k + 1, side="left").astype(_I32)
-    excl = jnp.take(wcnt - wpc, jnp.clip(wi, 0, wcnt.shape[0] - 1), mode="clip")
-    r = k + 1 - excl                              # target rank within word: 1..32
-    word = jnp.take(words, wi, mode="clip")
+def _bit_of_rank(word, r):
+    """The bit (0..31) that is the ``r``-th set one of its ``word``, ``r``
+    from 1 ((K,) each; 0 where the word has no such bit): masked popcounts."""
     lanes = jnp.arange(32, dtype=_U32)
     incl = (_U32(2) << lanes) - _U32(1)           # inclusive masks (lane 31 wraps to ~0)
     pcnt = lax.population_count(word[:, None] & incl[None, :])
     hit = (pcnt == r[:, None]) & (((word[:, None] >> lanes[None, :]) & 1) == 1)
-    lane = jnp.argmax(hit, axis=1).astype(_I32)
-    return jnp.where(k < n_set, wi * 32 + lane, _I32(-1))
+    return jnp.argmax(hit, axis=1).astype(_I32)
+
+
+def _rows_at(level, at):
+    """Each lane's row of a level (``at``: (K,) rows): one fetch a lane. A
+    level of one row, the top, is every lane's and is fetched by none."""
+    if level.shape[0] == 1:
+        return level
+    return jnp.take(level, at, axis=0, mode="clip")
+
+
+def _ranked_positions(table, k):
+    """Positions of the set bits of ranks ``k`` ((K,) int32, 0-based), -1
+    outside the population (a rank below zero too): find the word holding
+    the k-th survivor, then locate the in-word bit with masked popcounts.
+
+    The word is the first whose inclusive prefix reaches ``k + 1``
+    (``searchsorted``'s ``side="left"``), found down the table's levels: a
+    lane counts the keys below its target in ONE row a level, and the count
+    is its row in the next. The largest key below the target, kept on the
+    way down, is the prefix before the word, and the word comes out of its
+    row as its key did. So a 32 MiB window's 2**20 words cost a lane three
+    row fetches (two of keys, one of words) and a served row's 2**15 two,
+    where a binary search gathered an element for every halving and two
+    more (23 and 18): on a v5e 8 ns a lane where it was 165 (``PERF.md``
+    §6, PR 48)."""
+    words, levels, n_set = table
+    target = (k + 1)[:, None]
+    at = wi = excl = _I32(0)
+    for level in levels:
+        # Beyond the population the count runs off the level's end.
+        at = jnp.minimum(wi, level.shape[0] - 1)
+        row = _rows_at(level, at)
+        below = row < target
+        wi = at * level.shape[1] + jnp.sum(below, axis=1, dtype=_I32)
+        excl = jnp.maximum(
+            excl, jnp.max(jnp.where(below, row, _I32(0)), axis=1))
+    r = k + 1 - excl                              # target rank within word: 1..32
+    column = jnp.arange(words.shape[1], dtype=_I32)[None, :]
+    mine = column == (wi - at * words.shape[1])[:, None]
+    word = jnp.sum(
+        jnp.where(mine, _rows_at(words, at), _U32(0)), axis=1, dtype=_U32)
+    return jnp.where(
+        (k >= 0) & (k < n_set), wi * 32 + _bit_of_rank(word, r), _I32(-1))
 
 
 def lane_capacity(w: int) -> int:
@@ -485,11 +553,14 @@ def lane_capacity(w: int) -> int:
 #: alone, ms at 16,384 / 8,192 / 4,096 / 2,048 / 1,024): a long-read window
 #: of 965 survivors 29.4 / 21.2 / 17.0 / 14.8 / 13.7, a short-read window of
 #: 87,178 98.9 / 90.8 / 91.1 / 89.9 / 93.0, check-bam's step of three rows
-#: 363.3 / 342.7 / 340.2 / 335.1 / 343.6. A lane run costs ≈ 0.9 µs live and
-#: 1.05 dead, and a block ≈ 0.07 ms besides (its hundred-odd operations'
+#: 363.3 / 342.7 / 340.2 / 335.1 / 343.6. A lane run cost ≈ 0.9 µs live and
+#: 1.05 dead then, and a block ≈ 0.07 ms besides (its hundred-odd operations'
 #: fixed cost), which is what 1,024 loses to 2,048 on a window of 87,000
 #: survivors (86 blocks against 43) and wins on one of 965 (a millisecond of
-#: dead lanes).
+#: dead lanes). Since PR 48 a lane, live or dead, costs 0.16 µs less (the
+#: compaction's search, ``_ranked_positions``: the short-read window above
+#: 89.8 → 75.6 ms for the same 88,064 lanes, so ≈ 0.74 µs live); the
+#: widths were not swept again.
 LANE_BLOCK = 2048
 
 #: Fewest lanes a block: a served row's (1 MiB). Swept at that width alone

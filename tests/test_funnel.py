@@ -456,6 +456,83 @@ def _planted_lens():
     return jnp.asarray(lens), jnp.int32(64)
 
 
+# The compaction's search (PR 48): ``_ranked_positions`` finds the word of
+# the k-th set bit down the levels of ``_rank_table``, whole rows at a time.
+# The oracle is ``np.flatnonzero``. Lengths: a block's own table in
+# ``_list_escapes`` (64 words: its top alone), one that is no multiple of a
+# row and has levels, a served row's 2**15 and a 32 MiB window's 2**20.
+RANK_WORDS = (64, 8 * ck.RANK_ROW + 6, 1 << 15, 1 << 20)
+RANK_MASKS = ("empty", "full", "dense_8pct", "last_word_one_bit",
+              "first_word_every_bit")
+RANKS = ("negative", "inside", "straddling_the_end")
+RANK_LANES = 2048
+
+
+@functools.lru_cache(maxsize=1)      # a mask's three cases run in a row
+def _rank_mask(words: int, mask: str) -> np.ndarray:
+    n = words * 32
+    at = np.arange(n)
+    return {
+        "empty": lambda: np.zeros(n, dtype=bool),
+        "full": lambda: np.ones(n, dtype=bool),
+        "dense_8pct": lambda: np.random.default_rng(words).random(n) < 0.08,
+        "last_word_one_bit": lambda: at == n - 5,
+        "first_word_every_bit": lambda: at < 32,
+    }[mask]()
+
+
+@functools.lru_cache(maxsize=None)
+def _ranked():
+    import jax
+
+    return jax.jit(lambda mask, k: ck._ranked_positions(
+        ck._rank_table(mask), k))
+
+
+@pytest.mark.parametrize("ranks", RANKS)
+@pytest.mark.parametrize("mask", RANK_MASKS)
+@pytest.mark.parametrize("words", RANK_WORDS)
+def test_ranked_positions_are_flatnonzeros(words, mask, ranks):
+    bits = _rank_mask(words, mask)
+    at = np.flatnonzero(bits)
+    # Below zero as ``_list_escapes`` asks (``arange - before``), a whole
+    # block inside the population, and one that runs off its end.
+    first = {
+        "negative": -70,
+        "inside": max(len(at) // 2 - RANK_LANES, 0),
+        "straddling_the_end": len(at) - RANK_LANES // 2,
+    }[ranks]
+    k = np.arange(first, first + RANK_LANES, dtype=np.int32)
+    got = np.asarray(_ranked()(jnp.asarray(bits), jnp.asarray(k)))
+    mine = (k >= 0) & (k < len(at))
+    want = np.full(RANK_LANES, -1, dtype=np.int32)
+    want[mine] = at[k[mine]]
+    np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("words, levels", [
+    (64, 0), (ck.RANK_TOP, 0), (8 * ck.RANK_ROW + 6, 1), (1 << 15, 1),
+    (1 << 20, 2)])
+def test_the_search_fetches_a_row_a_level_and_loops_nowhere(words, levels):
+    """One row of ``RANK_ROW`` keys a lane a level under the top, then the
+    row the word lies in, and no loop: a binary search gathered an element a
+    halving (21 at 2**20 words), then the word. A table that is its top
+    alone gathers nothing."""
+    import jax
+
+    S = jax.ShapeDtypeStruct
+    text = _ranked().lower(
+        S((words * 32,), jnp.bool_), S((RANK_LANES,), jnp.int32)).as_text()
+    _own, _calls, reached = _gathers_by_function(text)
+    run = reached("main")
+    assert all(size == RANK_LANES * ck.RANK_ROW for _operand, size in run)
+    rows = [operand.split("x") for operand, _size in run]
+    assert [r[1:] for r in rows] == (
+        [[str(ck.RANK_ROW), "i32"]] * levels
+        + [[str(ck.RANK_ROW), "ui32"]] * bool(levels))
+    assert "stablehlo.while" not in text
+
+
 EDGE_BLOCK = 4096
 #: survivors → stride. The block's edges, none, the capacity's edges, and
 #: one window over it (stride 24 fits 43,690 blocks in a MiB).
