@@ -53,11 +53,80 @@ def _i32_at(p: jnp.ndarray, w: int) -> jnp.ndarray:
     return u
 
 
+#: Words a row of the window's word view as the lane stage fetches it
+#: (``_lane_words``): the chip's lane width, 512 B, what a row of a rank
+#: table's levels is (``RANK_ROW``) and for the same reason.
+WORD_ROW = 128
+
+#: Words behind a position that ONE row of the word view holds whatever the
+#: position's place in its row: the view is followed by itself from this
+#: word on (``_words_at``), so a position in a row's second half has a row
+#: that starts half a row later. A site's offsets stay below it.
+WORD_REACH = WORD_ROW // 2
+
+
 def _words_at(p: jnp.ndarray) -> jnp.ndarray:
     """The window's word view: the little-endian int32 at every byte offset
-    of the padded buffer, ``(w + PAD - 3,)``, which the funnel's lane stage
-    gathers its fields from (one dtype, so it is materialized once)."""
-    return lax.bitcast_convert_type(_i32_at(p, p.shape[0] - PAD), jnp.int32)
+    of the padded buffer, ``N = w + PAD`` of them (whole rows of
+    ``WORD_ROW`` at every width the package runs), and behind it the same
+    view again from its word ``WORD_REACH`` on: ``(2 N,)``, one dtype, ONE
+    materialized array a window. Stage 0 slices its fields from the first
+    half; the funnel's lane stage fetches rows of the whole
+    (``_lane_words``): a position in the second half of its row reads the
+    row of the second half that starts ``WORD_REACH`` words into its own,
+    so ONE row holds a lane's words whatever the residue (129 MiB more at
+    32 MiB for one fetch a site where the view alone wants two:
+    ``PERF.md`` §6, PR 49). A half's last three entries have no four bytes
+    of their own behind them (the first half's run on into the second's
+    bytes, the second's into zeros); no lane asks for them."""
+    total = p.shape[0]
+    whole = -(-total // WORD_ROW) * WORD_ROW
+    p = jnp.concatenate(
+        [p, jnp.zeros(whole + WORD_REACH - total, dtype=p.dtype)])
+    # The halves are laid end to end as BYTES, a quarter of the words'
+    # size, and the words made of them in one pass (halves of words laid
+    # end to end are written twice over).
+    both = jnp.concatenate(
+        [p[:whole], p[WORD_REACH:], jnp.zeros(3, dtype=p.dtype)])
+    return lax.bitcast_convert_type(_i32_at(both, total - PAD), jnp.int32)
+
+
+def _lane_words(U, pos, offsets: tuple) -> tuple:
+    """The words of the word view ``U`` (``_words_at``) at ``pos + off`` for
+    every ``off`` of the static ``offsets`` ((K,) int32 each): each the
+    element ``jnp.take(view, pos + off, mode="clip")`` gathers from the
+    view itself, the ``N - 3`` words of ``U``'s first half that have four
+    bytes of their own.
+
+    A gather costs per index and not per byte behind it, so a lane does not
+    gather its words: it fetches ONE row of ``WORD_ROW`` words for all its
+    offsets (``jnp.take(rows, at, axis=0)``, which the chip's compiler
+    lowers to a fetch of 512 B into fast memory), the row its position lies
+    in, or, from the second half of that row, the row of ``U``'s second half
+    that starts ``WORD_REACH`` words later, and picks each word out of it by
+    comparing the columns with the word's own, as ``_ranked_positions``
+    takes its word. On a v5e a row of the 32 MiB window's view costs a lane
+    8.5 ns where ONE gathered element of it costs 12.9, and a served row's
+    1.7 where 7.3 (``PERF.md`` §6, PR 49: ``tools/lane_sweep.py --sweep
+    words``): a site's eight words or three ≈ 11 ns where 103 and 38."""
+    assert 0 <= min(offsets) and max(offsets) < WORD_REACH, offsets
+    rows = U.reshape(-1, WORD_ROW)
+    half = rows.shape[0] // 2
+    last = half * WORD_ROW - 4      # the last word with four bytes of its own
+    at = jnp.clip(pos, 0, last)
+    within = at % WORD_ROW
+    late = within >= WORD_REACH
+    held = jnp.take(
+        rows, at // WORD_ROW + jnp.where(late, _I32(half), _I32(0)), axis=0,
+        mode="clip")
+    start = at - within + jnp.where(late, _I32(WORD_REACH), _I32(0))
+    column = jnp.arange(WORD_ROW, dtype=_I32)[None, :]
+
+    def word(off):
+        mine = column == (jnp.clip(pos + off, 0, last) - start)[:, None]
+        return jnp.sum(jnp.where(mine, held, _I32(0)), axis=1, dtype=_I32)
+
+    return tuple(word(off) for off in offsets)
 
 
 def _ref_pos_bits(idx, pos, c, len_at, b_neg_idx, b_large_idx, b_neg_pos, b_large_pos):
@@ -192,19 +261,17 @@ def _misc_at(U, n, pos):
     """``_compute_misc`` evaluated at arbitrary positions (K,) int32.
 
     The funnel walk needs remaining/body_end only at lane positions, so it
-    gathers there instead of materializing two full-width arrays: THREE
+    reads them there instead of materializing two full-width arrays: THREE
     words of the window's word view ``U`` (``_words_at``: the int32 at every
     byte offset), ``remaining = U[pos]``, ``name_len = U[pos + 12] & 0xFF``,
-    ``n_cigar = U[pos + 16] & 0xFFFF``. A gather costs per index, and a word
-    is one index where its bytes were up to four (seven byte gathers until
-    PR 36). Value-identical to indexing ``_compute_misc``'s outputs at ``pos``
+    ``n_cigar = U[pos + 16] & 0xFFFF``, out of the ONE row of the view that
+    ``_lane_words`` fetches the lane (three gathered words until PR 49,
+    seven gathered bytes until PR 36: a gather costs per index).
+    Value-identical to indexing ``_compute_misc``'s outputs at ``pos``
     (``pos`` pre-clipped to [0, w), PAD covers the +16)."""
-    def word(off):
-        return jnp.take(U, pos + off, mode="clip")
-
-    remaining = word(0)
-    name_len = word(12) & 0xFF
-    n_cigar = word(16) & 0xFFFF
+    remaining, name_len, n_cigar = _lane_words(U, pos, (0, 12, 16))
+    name_len = name_len & 0xFF
+    n_cigar = n_cigar & 0xFFFF
     has_name = name_len >= 2
     name_eof = has_name & (pos + 36 + name_len > n)
     name_in = has_name & (~name_eof)
@@ -342,33 +409,27 @@ def _badops_before(cwords, cwpre4, q, c):
 
 def _deep_flags_at(p, U, lengths, num_contigs, n, tables, pos):
     """The full 19-bit mask of ``_compute_flags`` at arbitrary positions
-    (K,), via K-sized gathers + the hierarchical tables. The fixed block's
+    (K,), via K-sized reads + the hierarchical tables. The fixed block's
     eight fields are EIGHT words of the window's word view ``U``
-    (``_words_at``), one index each (a 36-byte slab of byte indices until
-    PR 36; bytes 32-35 were never read); the name's last byte is the one
-    gather left that reads the bytes ``p``. Field-for-field identical to
-    the full pass (same overwrite, same reference quirks).
+    (``_words_at``), all out of the ONE row of it that ``_lane_words``
+    fetches the lane (eight gathered words until PR 49; a 36-byte slab of
+    byte indices until PR 36; bytes 32-35 were never read); the name's last
+    byte is the one gather left that reads the bytes ``p``.
+    Field-for-field identical to the full pass (same overwrite, same
+    reference quirks).
 
     Returns ``(F, remaining, body_end)``: the last two are ``_misc_at``'s
     at ``pos``, from fields read here already, so the walk's first step
-    (which stands on the lane's own position) gathers nothing."""
+    (which stands on the lane's own position) fetches nothing."""
     nwords, nwpre, cwords, cwpre4 = tables
     total = p.shape[0]
     pc = jnp.clip(pos, 0, total - 36)
 
-    def word(off):
-        return jnp.take(U, pc + off, mode="clip")
-
-    remaining = word(0)
-    ref_idx = word(4)
-    ref_pos = word(8)
-    name_len = word(12) & 0xFF
-    fnc = word(16)
+    (remaining, ref_idx, ref_pos, name_len, fnc, seq_len, next_ref_idx,
+     next_ref_pos) = _lane_words(U, pc, (0, 4, 8, 12, 16, 20, 24, 28))
+    name_len = name_len & 0xFF
     n_cigar = fnc & 0xFFFF
     mapped = ((fnc >> 18) & 1) == 0
-    seq_len = word(20)
-    next_ref_idx = word(24)
-    next_ref_pos = word(28)
 
     c = num_contigs
     cmax = lengths.shape[0]
@@ -559,8 +620,9 @@ def lane_capacity(w: int) -> int:
 #: survivors (86 blocks against 43) and wins on one of 965 (a millisecond of
 #: dead lanes). Since PR 48 a lane, live or dead, costs 0.16 µs less (the
 #: compaction's search, ``_ranked_positions``: the short-read window above
-#: 89.8 → 75.6 ms for the same 88,064 lanes, so ≈ 0.74 µs live); the
-#: widths were not swept again.
+#: 89.8 → 75.6 ms for the same 88,064 lanes, so ≈ 0.74 µs live), and since
+#: PR 49 0.30 µs less again (its words by the row, ``_lane_words``: 75.9 →
+#: 49.3 ms, ≈ 0.44 µs live); the widths were not swept again.
 LANE_BLOCK = 2048
 
 #: Fewest lanes a block: a served row's (1 MiB). Swept at that width alone
@@ -598,17 +660,19 @@ def _flag_stage(
     prefilter under the funnel, over the word view), the survivors it
     leaves and every non-survivor's verdict straight from F. Also
     ``misc_at``, which reads remaining/body_end at lane positions for the
-    walk, and under the funnel ``U``, the window's word view that it and
-    the deep flags gather from."""
+    walk, and under the funnel ``U``, the window's word view whose rows it
+    and the deep flags fetch (``_lane_words``)."""
     w = padded.shape[0] - PAD
     U = None
     with jax.named_scope("flags"):
         if funnel:
-            # The lane stage pays per gather index, so it gathers 32-bit
-            # words: the int32 at every byte offset, ONE materialized array
-            # a window (the barrier: left to fuse, each lane gather would
-            # assemble its word from four byte gathers again), which the
-            # prefilter reads its fields from too.
+            # The lane stage pays per gather index, so it reads 32-bit
+            # words, by the row: the int32 at every byte offset, ONE
+            # materialized array a window (the barrier: left to fuse, each
+            # lane's fetch would assemble its words from byte gathers
+            # again), which the prefilter reads its fields from too (its
+            # first half: ``_words_at``). The lanes see it in rows of
+            # ``WORD_ROW``: the same array, no copy.
             U = lax.optimization_barrier(_words_at(padded))
             F = _prefilter_flags(padded, lengths, num_contigs, n, U)
         else:
@@ -813,12 +877,12 @@ def _deep_blocks(
     flags → their scatter → the walk → what the consumer keeps) runs in
     blocks of ``lane_block(w)`` lanes, ``ceil(n_survivors / block)`` of
     them: a trip count the device reads from the window. Pass 1 compacts
-    block k (ranks ``k·block …``), deep-checks it (eight words a lane of
-    stage 0's word view ``U``) and scatters its masks onto the carried
-    position-wide ``F_lane``, which starts as the prefilter's ``F``
-    (``_lane_flags``), keeping what the walk's first step needs lane-wide
-    beside the positions; only then can pass 2 walk,
-    since a lane's chain visits survivors of later blocks. Lanes are
+    block k (ranks ``k·block …``), deep-checks it (eight words a lane out
+    of one fetched row of stage 0's word view ``U``) and scatters its masks
+    onto the carried position-wide ``F_lane``, which starts as the
+    prefilter's ``F`` (``_lane_flags``), keeping what the walk's first step
+    needs lane-wide beside the positions; only then can pass 2 walk, since
+    a lane's chain visits survivors of later blocks. Lanes are
     independent, so every verdict is what ONE stage of ``lane_capacity``
     lanes gives; a window over that capacity runs every block and reports
     ``overflow`` as that stage does. Under ``vmap`` the trip count is the

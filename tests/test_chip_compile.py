@@ -91,16 +91,47 @@ def _position_wide_lookups(text: str, positions: int) -> list:
 
 def _word_views(text: str, window: int) -> list:
     """The fusions of a compiled program that make the window's word view
-    (``checker._words_at``: the int32 at every byte offset, ``window + PAD
-    - 3`` of them): what the lane stage gathers its fields from since PR
-    36. One a row's program: the view is materialized once, behind its
-    barrier, and neither assembled again by stage 0 nor kept a second time
-    as uint32."""
+    (``checker._words_at``: the int32 at every byte offset, ``window + PAD``
+    of them, followed since PR 49 by the same from word ``WORD_REACH`` on:
+    ``2 (window + PAD)`` in whole rows of ``WORD_ROW``; ``window + PAD - 3``
+    before): what the lane stage reads its fields from since PR 36. One a
+    row's program: the view is materialized once, behind its barrier,
+    neither assembled again by stage 0 nor kept a second time as uint32 or
+    laid out again in rows (the lanes' ``(rows, WORD_ROW)`` is a bitcast of
+    it)."""
     import re
 
-    words = window + PAD - 3
+    from spark_bam_tpu.tpu.checker import WORD_ROW
+
+    words = 2 * (window + PAD)
+    rows = f"{words // WORD_ROW},{WORD_ROW}"
+    # In rows whatever makes it counts: a copy would be the view laid out
+    # a second time.
     return [line[:100] for line in text.splitlines()
-            if re.match(rf"%\S+ \(.*\) -> [su]32\[{words}\]", line)]
+            if re.search(rf"= [su]32\[{words}\]\S* fusion\(", line)
+            and "/check/flags/" in line
+            or re.search(rf"= [su]32\[{rows}\]\S* (fusion|copy)\(", line)]
+
+
+def _word_fetches(text: str, window: int) -> tuple:
+    """``(rows fetched, elements gathered)`` from the window's word view by
+    a compiled program: the result types of the fusions that fetch whole
+    rows of it (``_lane_words``: one a site since PR 49) and of the gathers
+    that take single words (eight a lane for the deep flags and three a
+    step of the walk until then: a gather costs per index)."""
+    import re
+
+    from spark_bam_tpu.tpu.checker import WORD_ROW
+
+    words = 2 * (window + PAD)
+    view = rf"s32\[{words // WORD_ROW},{WORD_ROW}\]"
+    rows = re.findall(
+        rf"^%\S+ \(\S+ {view}, \S+ s32\[\d+\]\) -> (s32\[\d+,{WORD_ROW}\])",
+        text, flags=re.M)
+    elements = re.findall(
+        rf"^%\S+ \(\S+ s32\[{words}\], \S+ s32\[\d+\]\) -> (s32\[\d+\])",
+        text, flags=re.M)
+    return rows, elements
 
 
 def _byte_gathers(text: str) -> list:
@@ -157,9 +188,12 @@ def test_count_window_xla_funnel_compiles_at_32mib(chip):
     scheduled before the peak, the survivors' word packing; **1.0192 since
     PR 36**: the window's word view, 0.126 GiB, is alive from stage 0 to the
     last block of the walk; 1.0174 at PR 40's block of 2,048 lanes: the one
-    byte gather left is a block wide, so its shape says the block)."""
+    byte gather left is a block wide, so its shape says the block; **1.1473
+    since PR 49**: the view is followed by itself from its 64th word on,
+    0.126 GiB more, so that a site fetches one row of it where it gathered
+    eight words or three)."""
     from spark_bam_tpu.tpu.checker import (
-        ESCAPE_LIST, LANE_BLOCK, make_count_window,
+        ESCAPE_LIST, LANE_BLOCK, WORD_ROW, make_count_window,
     )
 
     kernel = jax.jit(make_count_window(
@@ -169,8 +203,8 @@ def test_count_window_xla_funnel_compiles_at_32mib(chip):
         *_scalars(chip, jnp.int32, jnp.int32, jnp.bool_, jnp.int32,
                   jnp.int32),
     ).compile()
-    # 1.0174 GiB of temporaries + the 32.25 MiB operand = 1.049 GiB read.
-    assert 1 << 30 < _device_bytes(compiled) < 9 << 27
+    # 1.1473 GiB of temporaries + the 32.25 MiB operand = 1.179 GiB read.
+    assert 9 << 27 < _device_bytes(compiled) < 5 << 28
     text = compiled.as_text()
     assert "gather" in text  # the lane walk: a real program
     assert text.count(" while(") >= 2  # deep-check blocks, then walk blocks
@@ -178,6 +212,10 @@ def test_count_window_xla_funnel_compiles_at_32mib(chip):
     assert not _position_wide_lookups(text, WINDOW)
     assert len(_word_views(text, WINDOW)) == 1
     assert _byte_gathers(text) == [f"u8[{LANE_BLOCK}]"]
+    # No word of the view is gathered by its own index: the deep flags and
+    # the eight steps of the walk whose words are used fetch a row each.
+    assert _word_fetches(text, WINDOW) == (
+        [f"s32[{LANE_BLOCK},{WORD_ROW}]"] * 9, [])
 
 
 def _count_step_shapes(shape, repl, devices: int, rows: int):
@@ -235,7 +273,7 @@ def confusion_step(topo, chip):
 
 
 def test_confusion_step_fits_one_chip_at_three_rows(confusion_step):
-    """2.27 GiB of temporaries: ONE row's, since the rows run in turn and
+    """2.39 GiB of temporaries: ONE row's, since the rows run in turn and
     the lane stage in blocks behind a materialized survivor mask (8.23 GiB
     until PR 34: three rows batched, each a full-capacity stage whose word
     packing re-derived the flags at four times their bytes; 3.47 with the
@@ -243,12 +281,12 @@ def test_confusion_step_fits_one_chip_at_three_rows(confusion_step):
     is 0.126 GiB of the 0.2509 more: the most bytes alive at once are
     1,913,398,011 before and after, at the row's reduce, the rest is how the
     compiler packs its heap around a buffer that lives through both loops;
-    2.2628 at PR 40's block of 2,048).
+    2.2628 at PR 40's block of 2,048; 2.3851 since PR 49 doubled the view).
     The mismatch list (``MISMATCH_LIST`` slots a row, two levels of 1,024
     positions) adds nothing to speak of (1.33 GiB when it packed the mask
     into 32-bit words)."""
     from spark_bam_tpu.parallel.mesh import MISMATCH_LIST
-    from spark_bam_tpu.tpu.checker import LANE_BLOCK
+    from spark_bam_tpu.tpu.checker import LANE_BLOCK, WORD_ROW
 
     rows, compiled = confusion_step
     ma = compiled.memory_analysis()
@@ -264,6 +302,8 @@ def test_confusion_step_fits_one_chip_at_three_rows(confusion_step):
     # loop over the rows), and no lane gather reads bytes but the name's.
     assert len(_word_views(text, WINDOW)) == 1
     assert _byte_gathers(text) == [f"u8[{LANE_BLOCK}]"]
+    assert _word_fetches(text, WINDOW) == (
+        [f"s32[{LANE_BLOCK},{WORD_ROW}]"] * 9, [])
 
 
 def test_the_nameless_int8_operations_are_the_verdict_scatter(confusion_step):
@@ -311,11 +351,12 @@ def test_count_step_compiles_for_four_chips_at_one_row_a_chip(topo, chip):
     host-inflated 32 MiB row a chip, flat, the count pair ``psum``'d. A
     chip holds its own row's bytes once (a ``(1, N)`` u8 block of a
     row-major operand would be tiled four rows high). The compiler sets
-    1.05 GiB aside for the one row (1.0174 GiB of temporaries and the row;
-    0.94 until PR 36's word view), as on a mesh of one chip (5.9 against
-    2.7 GiB before PR 30: ``PERF.md`` §6)."""
+    1.18 GiB aside for the one row (1.1473 GiB of temporaries and the row;
+    1.05 until PR 49 doubled the word view, 0.94 until PR 36 made it), as on
+    a mesh of one chip (5.9 against 2.7 GiB before PR 30: ``PERF.md``
+    §6)."""
     from spark_bam_tpu.parallel.mesh import make_shard_map_count_step
-    from spark_bam_tpu.tpu.checker import LANE_BLOCK
+    from spark_bam_tpu.tpu.checker import LANE_BLOCK, WORD_ROW
 
     n = 4
     mesh, shape, repl = _mesh_shapes(topo, n)
@@ -323,20 +364,22 @@ def test_count_step_compiles_for_four_chips_at_one_row_a_chip(topo, chip):
     compiled = step.lower(*_count_step_shapes(shape, repl, n, 1)).compile()
     ma = compiled.memory_analysis()
     assert WINDOW < ma.argument_size_in_bytes < WINDOW + (1 << 20)
-    assert 1 << 30 < _device_bytes(compiled) < 9 << 27
+    assert 9 << 27 < _device_bytes(compiled) < 5 << 28
     text = compiled.as_text()
     assert "all-reduce" in text  # the psum, and nothing gathers the rows
     assert "all-gather" not in text and "all-to-all" not in text
     assert not _position_wide_lookups(text, WINDOW)
     assert len(_word_views(text, WINDOW)) == 1
     assert _byte_gathers(text) == [f"u8[{LANE_BLOCK}]"]
+    assert _word_fetches(text, WINDOW) == (
+        [f"s32[{LANE_BLOCK},{WORD_ROW}]"] * 9, [])
 
 
 # ----------------------------------------------------------------- small
 def test_serve_step_compiles_at_serve_config_defaults(topo, chip):
     from spark_bam_tpu.parallel.mesh import make_shard_map_serve_step
     from spark_bam_tpu.serve.config import MAX_CONTIGS, ServeConfig
-    from spark_bam_tpu.tpu.checker import lane_block
+    from spark_bam_tpu.tpu.checker import WORD_ROW, lane_block
 
     cfg = ServeConfig()
     b, width = cfg.batch_rows, cfg.window + PAD
@@ -353,6 +396,8 @@ def test_serve_step_compiles_at_serve_config_defaults(topo, chip):
     assert not _position_wide_lookups(text, b * cfg.window)
     assert len(_word_views(text, cfg.window)) == 1
     assert _byte_gathers(text) == [f"u8[{lane_block(cfg.window)}]"]
+    assert _word_fetches(text, cfg.window) == (
+        [f"s32[{lane_block(cfg.window)},{WORD_ROW}]"] * 9, [])
     # The tick pays for its rows' survivors: both passes of the lane stage
     # are loops whose trip count the device reads from the row.
     assert len(_whiles_of_no_constant_trip_count(text)) >= 2

@@ -533,6 +533,119 @@ def test_the_search_fetches_a_row_a_level_and_loops_nowhere(words, levels):
     assert "stablehlo.while" not in text
 
 
+# The words by the row (PR 49): a lane fetches ONE row of the word view,
+# once a site, and picks its words out of it (``_lane_words``), where it
+# gathered an element a word. The view is followed by itself from its word
+# ``WORD_REACH`` on, so a position has a row that holds the words behind it
+# whole wherever it lies in its own (28 of 128 residues put the deep flags'
+# eight words across two rows of the view alone, 16 the walk's three). The
+# oracle is the gather from the view itself, as long as it was until PR 49:
+# ``jnp.take(view[:N - 3], pos + off, mode="clip")``, at every residue of the position in its row, at the
+# view's first row, at its last (the last fixed block a buffer holds, the
+# window's last position, and past both ends, where the gather clips) and
+# for dead lanes, which are parked on position 0. Two views, a window's and
+# a served row's scaled to the tests' widths: rows of ``WORD_ROW`` at both.
+WORD_OFFSETS = {"deep_flags": (0, 4, 8, 12, 16, 20, 24, 28),
+                "walk": (0, 12, 16)}
+WORD_WINDOWS = {"window": W, "served_row": 8 << 10}
+WORD_LANES = 512
+
+
+def _word_positions(where: str, w: int) -> np.ndarray:
+    total = w + ck.PAD
+    at = np.arange(WORD_LANES, dtype=np.int32)
+    return {
+        # Every residue four times over, rows apart.
+        "every_residue": 5 * ck.WORD_ROW + at * 129 % (w - 640),
+        "first_row": at % ck.WORD_ROW,
+        "last_block": total - 36 - at % 256,
+        "window_end": w - 1 - at % 256,
+        "past_the_ends": np.concatenate([
+            total - 48 + at[:96], -at[:WORD_LANES - 96]]).astype(np.int32),
+        "dead_lanes": at * 0,
+    }[where]
+
+
+@functools.lru_cache(maxsize=None)
+def _word_view(w: int):
+    data = np.random.default_rng(w).integers(
+        0, 256, w + ck.PAD, dtype=np.uint8)
+    return ck._words_at(jnp.asarray(data))
+
+
+@pytest.mark.parametrize(
+    "where", ["every_residue", "first_row", "last_block", "window_end",
+              "past_the_ends", "dead_lanes"])
+@pytest.mark.parametrize("view", sorted(WORD_WINDOWS))
+@pytest.mark.parametrize("site", sorted(WORD_OFFSETS))
+def test_lane_words_are_the_elements_a_gather_takes(site, view, where):
+    import jax
+
+    w, offsets = WORD_WINDOWS[view], WORD_OFFSETS[site]
+    U = _word_view(w)
+    assert U.shape == (2 * (w + ck.PAD),) and (w + ck.PAD) % ck.WORD_ROW == 0
+    view = U[: w + ck.PAD - 3]          # the words with four bytes behind
+    pos = _word_positions(where, w)
+    if where == "every_residue":
+        assert len(set(pos % ck.WORD_ROW)) == ck.WORD_ROW
+    got = jax.jit(functools.partial(ck._lane_words, offsets=offsets))(
+        U, jnp.asarray(pos))
+    assert len(got) == len(offsets)
+    for off, words in zip(offsets, got):
+        np.testing.assert_array_equal(
+            np.asarray(words),
+            np.asarray(jnp.take(view, jnp.asarray(pos) + off, mode="clip")),
+            err_msg=f"offset {off}")
+
+
+@pytest.mark.parametrize("site", sorted(WORD_OFFSETS))
+def test_a_site_fetches_one_row_and_gathers_no_element(site):
+    """ONE fetch of a row a lane for all of a site's words, whatever their
+    number, and no loop: eight element gathers and three until PR 49."""
+    import jax
+
+    S = jax.ShapeDtypeStruct
+    text = jax.jit(
+        functools.partial(ck._lane_words, offsets=WORD_OFFSETS[site])).lower(
+        S((2 * (W + ck.PAD),), jnp.int32),
+        S((WORD_LANES,), jnp.int32)).as_text()
+    _own, _calls, reached = _gathers_by_function(text)
+    rows = f"{2 * (W + ck.PAD) // ck.WORD_ROW}x{ck.WORD_ROW}xi32"
+    assert reached("main") == [(rows, WORD_LANES * ck.WORD_ROW)]
+    assert "stablehlo.while" not in text
+
+
+@pytest.mark.parametrize(
+    "where", ["records", "every_residue", "first_row", "window_end",
+              "dead_lanes"])
+@pytest.mark.parametrize("reader", ["deep_flags_at", "misc_at"])
+def test_lane_fields_are_the_position_wide_passes_at_the_lanes(
+        corpus, reader, where):
+    """``_deep_flags_at`` and ``_misc_at`` read their fields out of fetched
+    rows; the funnel-less passes slice theirs position-wide and never fetch
+    a row: the same flags, ``remaining`` and ``body_end`` at every lane."""
+    pd, n = _window_of(flatten_file(corpus[0]).data)
+    assert int(n) == W                        # the window is full of records
+    ld, nc = _lens_of(corpus[0])
+    F = np.asarray(ck._compute_flags(pd, ld, nc, n))
+    if where == "records":
+        pos = np.flatnonzero(F == 0).astype(np.int32)
+        assert len(pos) > 100
+    else:
+        pos = _word_positions(where, W)
+    remaining, body_end = (np.asarray(x) for x in ck._compute_misc(pd, n))
+    U = ck._words_at(pd)
+    if reader == "misc_at":
+        got = ck._misc_at(U, n, jnp.asarray(pos))
+        want = remaining[pos], body_end[pos]
+    else:
+        got = ck._deep_flags_at(
+            pd, U, ld, nc, n, ck._funnel_tables(pd, n), jnp.asarray(pos))
+        want = F[pos], remaining[pos], body_end[pos]
+    for mine, theirs in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(mine), theirs)
+
+
 EDGE_BLOCK = 4096
 #: survivors → stride. The block's edges, none, the capacity's edges, and
 #: one window over it (stride 24 fits 43,690 blocks in a MiB).
@@ -1051,13 +1164,15 @@ def test_no_window_wide_gather_from_the_contig_table_under_the_funnel(
     assert wide_off
 
 
-# The lane stage pays per gather index (PR 32 measured it; PR 36 acts on it),
-# so under the funnel it reads 32-bit words of ONE materialized view of the
-# window and looks a flag up in ONE array. Counted in the lowered text, a
-# call each time it is written: the one lane-wide gather left that reads the
-# window's bytes is the name's last byte; the deep flags read eight words a
-# lane; a step of the walk one flag and three words, and its first step
-# nothing at all.
+# The lane stage pays per gather index (PR 32 measured it; PR 36 acts on it;
+# PR 49 takes the words' indices away), so under the funnel it reads the
+# 32-bit words of ONE materialized view of the window by the row and looks a
+# flag up in ONE array. Counted in the lowered text, a call each time it is
+# written: the one lane-wide gather left that reads the window's bytes is
+# the name's last byte; no gather takes an element of the word view; the
+# deep flags fetch one of its rows a lane for their eight words; a step of
+# the walk looks up one flag and fetches one row for its three words, and
+# its first step nothing at all.
 _READS = 10
 
 
@@ -1126,7 +1241,8 @@ def _lower_lane_program(program: str, funnel: bool, w: int) -> str:
 def test_the_lane_stage_gathers_words_and_looks_a_flag_up_once(program):
     w = 64 << 10
     lanes = ck.lane_block(w)
-    window, words = f"{w + ck.PAD}xui8", f"{w + ck.PAD - 3}xi32"
+    window, words = f"{w + ck.PAD}xui8", f"{2 * (w + ck.PAD)}xi32"
+    rows = f"{2 * (w + ck.PAD) // ck.WORD_ROW}x{ck.WORD_ROW}xi32"
     merged = f"{w + 1}xi32"
 
     own, calls, reached = _gathers_by_function(
@@ -1134,21 +1250,23 @@ def test_the_lane_stage_gathers_words_and_looks_a_flag_up_once(program):
     run = reached("main")
     # No gather at lane width reads the bytes but the name's last byte.
     assert [g for g in run if g[0] == window] == [(window, lanes)]
-    # Eight words a lane for the deep flags, three a step for the walk,
-    # whose first step gathers nothing (pass 1 read its position already).
+    # No word is gathered by element: a site fetches one row of the view,
+    # the deep flags once for their eight words, the walk once a step for
+    # its three, and its first step nothing (pass 1 read its position).
     steps_looked_up = _READS - 1
-    assert [g for g in run if g[0] == words] == [(words, lanes)] * (
-        8 + 3 * steps_looked_up)
+    assert not [g for g in run if g[0] == words]
+    assert [g for g in run if g[0] == rows] == [
+        (rows, lanes * ck.WORD_ROW)] * (1 + steps_looked_up)
     # One flag lookup a step, in the merged array.
     assert run.count((merged, lanes)) == steps_looked_up
     assert not [g for g in run if g[0] == f"{w}xi32"]
     # The walk's step, the function that calls the flag's ``take``, holds
-    # four gathers.
+    # two gathers (four until PR 49: the flag and three words).
     looks_up = {f for f in own if (merged, lanes) in own[f]}
     steps = [f for f in own if looks_up & set(calls[f])]
     assert steps
     for f in steps:
-        assert len(reached(f)) <= 4, (f, reached(f))
+        assert len(reached(f)) <= 2, (f, reached(f))
 
     # Without the funnel the program reads what it read: no word view, the
     # name's last byte at every position, and a step of the rolled walk
@@ -1157,7 +1275,7 @@ def test_the_lane_stage_gathers_words_and_looks_a_flag_up_once(program):
         _lower_lane_program(program, False, w))
     run = reached("main")
     capacity = ck.lane_capacity(w)
-    assert not [g for g in run if g[0] in (words, merged)]
+    assert not [g for g in run if g[0] in (words, rows, merged)]
     assert [g for g in run if g[0] == window] == [(window, w)]
     assert run.count((f"{w}xi32", capacity)) == 3
 

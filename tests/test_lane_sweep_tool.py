@@ -35,3 +35,35 @@ def test_a_swept_search_finds_what_flatnonzero_finds(name, words):
         want[mine] = at[k[mine]]
         got = np.asarray(find(jnp.asarray(bits), jnp.asarray(k)))
         np.testing.assert_array_equal(got, want)
+
+
+# The word family (PR 49): each column's view and ``lane_words`` read the
+# words the parent's element gathers read, at every residue of a position
+# in its row, the residues that put a site's words across two rows among
+# them, and at the last fixed block a buffer holds.
+WORD_SITES = {"deep_flags": (0, 4, 8, 12, 16, 20, 24, 28), "walk": (0, 12, 16)}
+
+
+@pytest.mark.parametrize("site", sorted(WORD_SITES))
+@pytest.mark.parametrize(
+    "name", [n for n in lane_sweep.WORD_VARIANTS if n != "parent"])
+def test_a_swept_word_layout_reads_what_the_gathers_read(name, site):
+    from spark_bam_tpu.tpu import checker as ck
+
+    w, offsets = 64 << 10, WORD_SITES[site]
+    padded = jnp.asarray(np.random.default_rng(7).integers(
+        0, 256, w + ck.PAD, dtype=np.uint8))
+    at = np.arange(4 * ck.WORD_ROW, dtype=np.int32)
+    pos = jnp.asarray(np.concatenate([
+        at, 9 * ck.WORD_ROW + at * 129 % w, w + ck.PAD - 36 - at]))
+    parent = lane_sweep.WORD_VARIANTS["parent"]
+    want = parent["lane_words"](parent["words_at"](padded), pos, offsets)
+    variant = lane_sweep.WORD_VARIANTS[name] or {
+        "words_at": ck._words_at, "lane_words": ck._lane_words}
+    V = variant["words_at"](padded)
+    if "view" in variant:
+        V = variant["view"](V, padded)
+    got = jax.jit(lambda V, pos: variant["lane_words"](V, pos, offsets))(
+        V, pos)
+    for mine, theirs in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(mine), np.asarray(theirs))

@@ -1,35 +1,54 @@
 #!/usr/bin/env python3
-"""The programs alone, every variant of the lane stage's search beside the
-others: the sweep that decides a change to ``tpu/checker._ranked_positions``
-before any cell is run (``ROADMAP.md`` D14; ``PERF.md`` §6, PR 48).
+"""The programs alone, every variant of a piece of the lane stage beside the
+others: the sweep that decides a change to ``tpu/checker``'s lane stage
+before any cell is run (``ROADMAP.md`` D14; ``PERF.md`` §6, PRs 48 and 49).
 
 One process. The operands are captured as the package puts them: a
 ``wgs-short`` file of the benchmark's own generator, counted once by
 ``count_reads_tpu`` (the stream's 32 MiB windows, kept on the device) and
 once through a ``SplitService`` (the first served step of eight 1 MiB rows).
-Every variant replaces ``checker._rank_table`` / ``checker._ranked_positions``
-while its two programs, ``jit_count_window`` and the served step, are lowered
-and compiled; nothing else of a program differs. Then a warm run, ``--rounds``
-rounds over the variants in turn, the median a program, and every output of
-every variant compared with the parent's. ``--profile`` runs each variant's
-programs once more under the profiler and prints its operations by self time,
-each divided by the lanes the program ran besides (what a gather index or a
-row fetch costs is an operation's self time a lane).
+Every variant replaces functions of ``checker`` while its two programs,
+``jit_count_window`` and the served step, are lowered and compiled; nothing
+else of a program differs. Then a warm run, ``--rounds`` rounds over the
+variants in turn, the median a program, and every output of every variant
+compared with the first column's. ``--profile`` runs each variant's programs
+once more under the profiler and prints its operations by self time, each
+divided by the lanes the program ran besides (what a gather index or a row
+fetch costs is an operation's self time a lane).
 
-    chiprun -- python tools/lane_sweep.py --profile
-    JAX_PLATFORMS=cpu python tools/lane_sweep.py --rehearse   # small, CPU
+    chiprun -- python tools/lane_sweep.py --profile               # the words
+    chiprun -- python tools/lane_sweep.py --sweep search --profile
+    JAX_PLATFORMS=cpu python tools/lane_sweep.py --rehearse   # both, small, CPU
 
-``tree`` is the package as it stands (rows of ``checker.RANK_ROW`` keys under
-a top of at most ``checker.RANK_TOP``, the word out of a fetched row too),
-``parent`` the binary search it had until PR 48; the other columns are kept
-as the record of what was tried, and every one of them gathers the word by
-element as the parent does. It is a builder's instrument: no cell runs it,
-and no module imports it.
+Two families of columns, ``--sweep``:
+
+``words`` (PR 49, the default): how a lane reads its words of the window's
+word view, ``checker._words_at`` / ``checker._lane_words`` (and, where a
+layout keeps an array beside the view, what ``checker._flag_stage`` hands
+the lanes). ``tree`` is the package as it stands (the view followed by
+itself from its 64th word on, ONE array; a lane fetches the one row that
+holds its words whole and picks a word by a compare with the column),
+``parent`` the element gathers it had until PR 49, one index a word, from
+the view alone; the other columns are the layouts that were tried: two
+rows of the view alone a site (``rows2``, the first sweep's ``tree``), one
+fetch of two rows, the package's rows out of a copy beside the view, the
+bytes as aligned words. They run with the tree's search.
+
+``search`` (PR 48): the compaction's search, ``checker._rank_table`` /
+``checker._ranked_positions``. ``tree`` is the package as it stands (rows of
+``checker.RANK_ROW`` keys under a top of at most ``checker.RANK_TOP``, the
+word out of a fetched row too), ``parent`` the binary search it had until
+PR 48; the other columns are kept as the record of what was tried, and
+every one of them gathers the word by element as the parent does. They run
+with the tree's words.
+
+It is a builder's instrument: no cell runs it, and no module imports it.
 """
 
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import shutil
 import statistics
@@ -56,7 +75,7 @@ DATA = REPO / ".smoke_data" / "lane_sweep"
 PROFILE_TOP = 24
 
 
-# --------------------------------------------------------------- variants
+# ------------------------------------------------------ variants: search
 
 class Table(NamedTuple):
     """What a variant's search may read; XLA drops what it does not."""
@@ -178,21 +197,215 @@ VARIANTS = {
 }
 
 
-class patched:
-    """The package's two functions replaced while a program is traced."""
+# ------------------------------------------------------- variants: words
 
-    def __init__(self, variant):
-        self.variant = variant
+_ROW = ck.WORD_ROW
+
+
+def _flat_view(p):
+    """The word view alone, in whole rows: what the layouts that fetch two
+    rows a site read, and what ``checker._words_at`` returned before it was
+    followed by itself (``checker.WORD_REACH``)."""
+    total = p.shape[0]
+    whole = -(-total // _ROW) * _ROW
+    p = jnp.concatenate([p, jnp.zeros(whole + 3 - total, dtype=p.dtype)])
+    return lax.bitcast_convert_type(ck._i32_at(p, total - ck.PAD), jnp.int32)
+
+
+def _on_flat_view(lane_words, view=None) -> dict:
+    """A column that reads the view alone (``_flat_view``), or ``view(U,
+    padded)`` made of it once a window."""
+    column = {"words_at": _flat_view, "lane_words": lane_words}
+    if view is not None:
+        column["view"] = view
+    return column
+
+
+def _columns():
+    return jnp.arange(_ROW, dtype=_I32)[None, :]
+
+
+def _fetch(rows, at):
+    return jnp.take(rows, at, axis=0, mode="clip")
+
+
+def _pick(held, column):
+    """The word of each lane's ``held`` row at its ``column``."""
+    mine = _columns() == column[:, None]
+    return jnp.sum(jnp.where(mine, held, jnp.zeros((), held.dtype)), axis=1,
+                   dtype=held.dtype)
+
+
+def _wrapped(rows, at, second):
+    """The ``_ROW`` words from ``at`` on, each in its own column, out of the
+    row of ``at`` and the row ``second``, folded by one select."""
+    return jnp.where(_columns() >= (at % _ROW)[:, None],
+                     _fetch(rows, at // _ROW), _fetch(rows, second))
+
+
+def elements():
+    """One gathered element a word: the tree until PR 49."""
+    def lane_words(U, pos, offsets):
+        return tuple(jnp.take(U, pos + off, mode="clip") for off in offsets)
+
+    return _on_flat_view(lane_words)
+
+
+def rows2(second: str = "next", wrap: bool = True):
+    """Two row fetches a site, no array besides the view: the position's
+    row and the ``next``, or the row of the site's ``last`` word (most
+    lanes fetch their own row twice). ``wrap``: the two folded into one
+    row's width before the picks, or every word picked out of both."""
+    def lane_words(U, pos, offsets):
+        rows = U.reshape(-1, _ROW)
+        row = pos // _ROW
+        then = row + 1 if second == "next" else (pos + max(offsets)) // _ROW
+        if wrap:
+            held = _wrapped(rows, pos, then)
+            return tuple(_pick(held, (pos + off) % _ROW) for off in offsets)
+        first, after = _fetch(rows, row), _fetch(rows, then)
+        column = pos % _ROW
+        return tuple(
+            _pick(first, column + off) + _pick(after, column + off - _ROW)
+            for off in offsets)
+
+    return _on_flat_view(lane_words)
+
+
+def slab2():
+    """ONE fetch a site of two rows, 1 KiB behind one index."""
+    def lane_words(U, pos, offsets):
+        rows = U.reshape(-1, _ROW)
+        row = jnp.minimum(pos // _ROW, rows.shape[0] - 2)
+        slab = lax.gather(
+            rows, row[:, None], lax.GatherDimensionNumbers(
+                offset_dims=(1, 2), collapsed_slice_dims=(),
+                start_index_map=(0,)),
+            slice_sizes=(2, _ROW), mode="clip")
+        column = jnp.arange(2 * _ROW, dtype=_I32).reshape(1, 2, _ROW)
+        at = pos - row * _ROW
+        return tuple(
+            jnp.sum(jnp.where(column == (at + off)[:, None, None], slab,
+                              _I32(0)), axis=(1, 2), dtype=_I32)
+            for off in offsets)
+
+    return _on_flat_view(lane_words)
+
+
+def stride64_copied():
+    """The package's one row a site, but out of a SECOND array beside the
+    view (the view, then the view from its 64th word on: a copy of 258 MiB
+    a 32 MiB window), where the package's view is that array itself."""
+    half = _ROW // 2
+
+    def view(U, _padded):
+        return jnp.concatenate([U, U[half:], jnp.zeros(half, U.dtype)])
+
+    def lane_words(V, pos, offsets):
+        assert max(offsets) < half
+        rows = V.reshape(-1, _ROW)
+        late = pos % _ROW >= half
+        row = pos // _ROW + jnp.where(late, rows.shape[0] // 2, 0)
+        held = _fetch(rows, row)
+        column = pos % _ROW - jnp.where(late, half, 0)
+        return tuple(_pick(held, column + off) for off in offsets)
+
+    return _on_flat_view(lane_words, view)
+
+
+def aligned():
+    """Rows of the window's own bytes as aligned u32 words (a quarter of the
+    word view's bytes: does the price of a row follow its operand's size?),
+    two row fetches a site, a word funnel-shifted out of two aligned ones by
+    the position's last two bits."""
+    def view(U, _padded):
+        return lax.bitcast_convert_type(U[::4], jnp.uint32)
+
+    def lane_words(A, pos, offsets):
+        assert all(off % 4 == 0 for off in offsets)
+        rows = A.reshape(-1, _ROW)
+        at = pos >> 2
+        need = sorted({off // 4 + i for off in offsets for i in (0, 1)})
+        held = _wrapped(rows, at, at // _ROW + 1)
+        got = {i: _pick(held, (at + i) % _ROW) for i in need}
+        shift = ((pos & 3) * 8).astype(jnp.uint32)
+        high = jnp.where(shift == 0, jnp.uint32(0), jnp.uint32(32) - shift)
+
+        def word(off):
+            lo, hi = got[off // 4], got[off // 4 + 1]
+            both = (lo >> shift) | jnp.where(shift == 0, jnp.uint32(0),
+                                             hi << high)
+            return lax.bitcast_convert_type(both, jnp.int32)
+
+        return tuple(word(off) for off in offsets)
+
+    return _on_flat_view(lane_words, view)
+
+
+#: name -> what a column replaces: ``words_at`` and ``lane_words`` take the
+#: place of ``checker._words_at`` / ``checker._lane_words``; ``view(U,
+#: padded)`` is what the lanes read in place of the word view ``U``, made
+#: once a window behind a barrier of its own. None leaves the package's own.
+WORD_VARIANTS = {
+    "parent": elements(),
+    "tree": None,
+    "rows2": rows2(),
+    "rows2.both": rows2(wrap=False),
+    "rows2.last": rows2(second="last"),
+    "slab2": slab2(),
+    "stride64.copied": stride64_copied(),
+    "aligned": aligned(),
+}
+
+
+def _search_patch(variant) -> dict:
+    rank_table, ranked_positions = variant
+    return {"_rank_table": rank_table, "_ranked_positions": ranked_positions}
+
+
+def _words_patch(variant) -> dict:
+    patch = {"_words_at": variant["words_at"],
+             "_lane_words": variant["lane_words"]}
+    view = variant.get("view")
+    if view is not None:
+        flag_stage = ck._flag_stage
+
+        def staged(padded, lengths, num_contigs, n, at_eof, funnel):
+            S = flag_stage(padded, lengths, num_contigs, n, at_eof, funnel)
+            if funnel:
+                V = lax.optimization_barrier(view(S["U"], padded))
+                S = {**S, "U": V,
+                     "misc_at": functools.partial(ck._misc_at, V, n)}
+            return S
+
+        patch["_flag_stage"] = staged
+    return patch
+
+
+#: family -> (its columns, what a column replaces in ``checker``).
+FAMILIES = {
+    "words": (WORD_VARIANTS, _words_patch),
+    "search": (VARIANTS, _search_patch),
+}
+
+
+class patched:
+    """Functions of the package replaced while a program is traced
+    (``{name: replacement}``; None leaves the package as it is)."""
+
+    def __init__(self, patch):
+        self.patch = patch or {}
 
     def __enter__(self):
-        self.saved = ck._rank_table, ck._ranked_positions
-        if self.variant is not None:
-            ck._rank_table, ck._ranked_positions = self.variant
+        self.saved = {name: getattr(ck, name) for name in self.patch}
+        for name, fn in self.patch.items():
+            setattr(ck, name, fn)
         # An inner jit (``check_window`` in the served step) keeps its trace.
         jax.clear_caches()
 
     def __exit__(self, *exc):
-        ck._rank_table, ck._ranked_positions = self.saved
+        for name, fn in self.saved.items():
+            setattr(ck, name, fn)
         jax.clear_caches()
 
 
@@ -323,16 +536,18 @@ def profile_ops(where: Path, prog, operands, runs: int = 3) -> list:
 
 # --------------------------------------------------------------- the sweep
 
-def sweep(lower, operand_sets: dict, names, rounds: int, lanes_of,
-          profile_to: Path | None) -> dict:
+def sweep(family: str, lower, operand_sets: dict, names, rounds: int,
+          lanes_of, profile_to: Path | None) -> dict:
     """Every variant's program compiled once, then timed on every set of
     operands (``{label: operands}``, one shape); the first set profiled
     where ``profile_to`` names a directory for the tables.
     ``lanes_of(output)`` is ``(survivors, lanes)`` of a run."""
+    variants, patch_of = FAMILIES[family]
     programs = {}
     for name in names:
         t0 = time.perf_counter()
-        with patched(VARIANTS[name]):
+        variant = variants[name]
+        with patched(variant and patch_of(variant)):
             programs[name] = lower()
         print(json.dumps({"compiled": name, "for": list(operand_sets),
                           "seconds": time.perf_counter() - t0}), flush=True)
@@ -370,13 +585,16 @@ def sweep(lower, operand_sets: dict, names, rounds: int, lanes_of,
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--sweep", choices=list(FAMILIES),
+                    help="the family of columns: words (the default) or "
+                         "search; --rehearse without it runs both")
     ap.add_argument("--seed", type=int, default=2147483684)
     ap.add_argument("--rounds", type=int, default=7)
     ap.add_argument("--windows", type=int, default=2,
                     help="windows of the stream to sweep (the first ones)")
-    ap.add_argument("--variants", default=",".join(VARIANTS),
-                    help="columns, the first the one the others are compared "
-                         "with")
+    ap.add_argument("--variants",
+                    help="columns of the family (all of them), the first the "
+                         "one the others are compared with")
     ap.add_argument("--programs", default="count,serve")
     ap.add_argument("--profile", action="store_true")
     ap.add_argument("--rehearse", action="store_true",
@@ -396,55 +614,71 @@ def main(argv=None) -> int:
               "small on this backend and times nothing worth keeping)",
               file=sys.stderr)
         return 3
-    names = args.variants.split(",")
+    families = [args.sweep] if args.sweep else (
+        list(FAMILIES) if args.rehearse else ["words"])
+    if args.variants and len(families) > 1:
+        ap.error("--variants names one family's columns: give --sweep")
     config = Config()
     if args.rehearse:
         config = Config(window_size=768 << 10, halo_size=128 << 10,
                         serve="window=128KB,halo=16KB,batch=4")
     print(json.dumps({"device": device.device_kind, "seed": args.seed,
-                      "variants": names, "rehearse": args.rehearse}),
+                      "sweep": families, "rehearse": args.rehearse}),
           flush=True)
-    out = OUT / ("lane_sweep.rehearsal" if args.rehearse else "lane_sweep")
-    out.mkdir(parents=True, exist_ok=True)
-    profile_to = out if args.profile else None
     path = make_file(args.seed, args.rehearse)
-    table = {}
     try:
+        taken = served = None
         if "count" in args.programs:
             taken = capture_count_windows(path, config, args.windows)
+        if "serve" in args.programs:
+            served = capture_serve_step(path, config)
+    finally:
+        path.unlink(missing_ok=True)
+
+    equal = True
+    for family in families:
+        names = (args.variants.split(",") if args.variants
+                 else list(FAMILIES[family][0]))
+        print(json.dumps({"sweep": family, "variants": names}), flush=True)
+        out = OUT / (f"lane_sweep.{family}"
+                     + (".rehearsal" if args.rehearse else ""))
+        out.mkdir(parents=True, exist_ok=True)
+        profile_to = out if args.profile else None
+        table = {}
+        if taken is not None:
             statics = taken[0][1]
             table.update(sweep(
-                lambda: lower_count_window(taken[0][0], statics),
+                family, lambda: lower_count_window(taken[0][0], statics),
                 {f"count_window.{i}": operands
                  for i, (operands, _) in enumerate(taken)},
                 names, args.rounds,
                 lambda got: (int(got["survivors"]), int(got["lanes"])),
                 profile_to))
-        if "serve" in args.programs:
-            mesh, operands = capture_serve_step(path, config)
+        if served is not None:
+            mesh, operands = served
 
-            def served(got):
+            def served_lanes(got):
                 per_row = np.asarray(got)
                 return int(per_row[:, 2].sum()), int(per_row[:, 3].sum())
 
             table.update(sweep(
-                lambda: lower_serve_step(mesh, config, operands),
-                {"serve_step": operands}, names, args.rounds, served,
+                family, lambda: lower_serve_step(mesh, config, operands),
+                {"serve_step": operands}, names, args.rounds, served_lanes,
                 profile_to))
-    finally:
-        path.unlink(missing_ok=True)
 
-    print("\n| program (survivors / lanes) | " + " | ".join(names) + " |")
-    print("|---|" + "---|" * len(names))
-    for label, found in table.items():
-        rows = found["ms"]
-        cells = [f"{rows[n]['ms']:.2f}" + ("" if rows[n]["equal"] else " DIFFERS")
-                 for n in names]
-        print(f"| {label} ({found['survivors']:,} / {found['lanes']:,}) | "
-              + " | ".join(cells) + " |")
-    (out / "table.json").write_text(json.dumps(table, indent=1))
-    return 0 if all(r["equal"] for found in table.values()
-                    for r in found["ms"].values()) else 1
+        print(f"\n| {family}: program (survivors / lanes) | "
+              + " | ".join(names) + " |")
+        print("|---|" + "---|" * len(names))
+        for label, found in table.items():
+            rows = found["ms"]
+            cells = [f"{rows[n]['ms']:.2f}"
+                     + ("" if rows[n]["equal"] else " DIFFERS") for n in names]
+            print(f"| {label} ({found['survivors']:,} / {found['lanes']:,}) | "
+                  + " | ".join(cells) + " |")
+        (out / "table.json").write_text(json.dumps(table, indent=1))
+        equal = equal and all(r["equal"] for found in table.values()
+                              for r in found["ms"].values())
+    return 0 if equal else 1
 
 
 if __name__ == "__main__":
