@@ -27,7 +27,7 @@ from spark_bam_tpu.bam.header import read_header
 from spark_bam_tpu.bgzf.flat import FlatView, flatten_file
 from spark_bam_tpu.core.config import Config
 from spark_bam_tpu.core.pos import Pos
-from spark_bam_tpu.load.intervals import LociSet
+from spark_bam_tpu.load.intervals import BadLociError, LociSet
 from spark_bam_tpu.tpu.checker import TpuChecker
 from spark_bam_tpu.tpu.parser import (
     ReadBatch,
@@ -142,7 +142,11 @@ def _interval_table(header, loci: LociSet | str) -> np.ndarray:
     rows = []
     for contig, ivs in loci.intervals.items():
         if contig not in name_to_idx:
-            continue
+            # ``20`` against a header of ``chr20`` would load nothing and
+            # say nothing.
+            raise BadLociError(
+                f"bad loci: the header names no contig {contig!r} "
+                f"(it has {', '.join(list(name_to_idx)[:3])}, ...)")
         ref = name_to_idx[contig]
         if not ivs:
             ivs = [(0, header.contig_lengths[ref][1])]
@@ -293,21 +297,27 @@ def stream_read_batches(
     flags_forbidden: int = 0,
 ):
     """Columnar ``ReadBatch``es per streaming window: the load path in
-    O(window) host memory (WGS scale), with interval/flag filters applied
-    on device per window. Yields ``(abs_base, batch)``; ``(-1, batch)``
-    entries carry records longer than the window lookahead, decoded exactly
-    from the seekable stream."""
+    O(window) host memory (WGS scale). Every window is put once and runs
+    one program (``jit_load_window``: the count's check, and the records it
+    accepts parsed and tested against ``loci`` and the flag masks there);
+    what comes back is the rows that passed. Yields ``(abs_base, batch)``:
+    ``batch.starts`` index ``batch.buf`` and ``abs_base + batch.starts`` are
+    flat offsets; ``(-1, batch)`` entries carry records longer than the
+    window lookahead, decoded exactly from the seekable stream and tested on
+    the host (``StreamChecker.read_batches``).
+
+    A pass is one trace (``obs.pass_span("load.reads")``), open from the
+    first batch asked for to the last one handed out."""
+    from spark_bam_tpu.tpu.parser import RowFilter
     from spark_bam_tpu.tpu.stream_check import StreamChecker
 
-    checker = StreamChecker(path, config)
-    gen = checker.read_batches()
-    if loci is None and not flags_required and not flags_forbidden:
-        yield from gen
-        return
-    for base, batch in gen:
-        yield base, _apply_filter(
-            batch, checker.header, loci, flags_required, flags_forbidden
-        )
+    with obs.pass_span("load.reads", path=str(path)):
+        checker = StreamChecker(path, config)
+        rows = RowFilter.of(
+            None if loci is None else _interval_table(checker.header, loci),
+            flags_required, flags_forbidden)
+        yield from checker.read_batches(rows)
+    obs.count("load.passes")
 
 
 def counts_across_chips() -> bool:

@@ -135,6 +135,18 @@ NAMES = frozenset({
     "load.partition",
     "load.partitions", "load.record_starts", "load.records",
     "load.split_resolutions",
+    # load — the streaming load (load/tpu_load.stream_read_batches,
+    # tpu/stream_check.read_batches): the root load.reads (a pass as the
+    # two above), load.batch (feeding thread: a window's rows read back
+    # and wrapped), load.device_ms (a window of load_window on the device,
+    # as inflate.device_ms is the count's), and a pass's account:
+    # records_parsed (every record, as the count counts them), rows_out
+    # (what passed loci and flags), d2h_bytes (all that came back),
+    # cigar_host_fixups (rows whose CIGAR outran the device's scan),
+    # spilled_records (decoded from the seekable stream)
+    "load.batch", "load.cigar_host_fixups", "load.d2h_bytes",
+    "load.device_ms", "load.passes", "load.reads", "load.records_parsed",
+    "load.rows_out", "load.spilled_records",
     # mesh — compiled-step registry + shard_map dispatch
     "mesh.assemble", "mesh.block_reuse", "mesh.dirty_steps", "mesh.dispatch",
     "mesh.escapes",
@@ -202,9 +214,14 @@ NAMES = frozenset({
 #: Under the funnel the lane stage's three (``flags``, ``funnel``,
 #: ``chain_walk``) sit inside its block loops (``check/while/body/...``),
 #: in ``check_window`` as in the count; ``scatter`` runs once, after them.
+#: The load's window program (tpu/checker.load_window) has ``check`` as the
+#: count has it and, beside it in the walk's block loop, ``parse`` (the
+#: accepted lanes' fixed blocks and CIGAR spans out of the word view) and
+#: ``filter`` (loci and flags, and the rows that passed moved into the
+#: window's table); its ``reduce`` is the seven integers it reports.
 SCOPES = frozenset({
-    "agg_reduce", "chain_walk", "check", "collect", "flags", "funnel",
-    "reduce", "scatter",
+    "agg_reduce", "chain_walk", "check", "collect", "filter", "flags",
+    "funnel", "parse", "reduce", "scatter",
 })
 
 #: Names of the jitted programs the scopes live in: ``jit_<name>`` is the
@@ -212,7 +229,7 @@ SCOPES = frozenset({
 PROGRAMS = frozenset({
     "agg_step", "agg_update", "check_step", "check_window",
     "confusion_step", "count_step", "count_window", "full_step",
-    "serve_step", "sharded_check_step",
+    "load_window", "serve_step", "sharded_check_step",
 })
 
 

@@ -21,6 +21,7 @@ item 6) is what makes the whole battery data-parallel.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 from dataclasses import dataclass
 from typing import NamedTuple
@@ -110,6 +111,21 @@ def _lane_words(U, pos, offsets: tuple) -> tuple:
     1.7 where 7.3 (``PERF.md`` §6, PR 49: ``tools/lane_sweep.py --sweep
     words``): a site's eight words or three ≈ 11 ns where 103 and 38."""
     assert 0 <= min(offsets) and max(offsets) < WORD_REACH, offsets
+    held, start, last = _lane_rows(U, pos)
+    column = jnp.arange(WORD_ROW, dtype=_I32)[None, :]
+
+    def word(off):
+        mine = column == (jnp.clip(pos + off, 0, last) - start)[:, None]
+        return jnp.sum(jnp.where(mine, held, _I32(0)), axis=1, dtype=_I32)
+
+    return tuple(word(off) for off in offsets)
+
+
+def _lane_rows(U, pos):
+    """The fetch of ``_lane_words``: ``(held, start, last)``, each lane's row
+    of the word view ((K, WORD_ROW): the words at ``start`` .. ``start +
+    WORD_ROW - 1``, which hold those at ``pos`` .. ``pos + WORD_REACH - 1``
+    at the least) and the last word with four bytes of its own."""
     rows = U.reshape(-1, WORD_ROW)
     half = rows.shape[0] // 2
     last = half * WORD_ROW - 4      # the last word with four bytes of its own
@@ -120,13 +136,7 @@ def _lane_words(U, pos, offsets: tuple) -> tuple:
         rows, at // WORD_ROW + jnp.where(late, _I32(half), _I32(0)), axis=0,
         mode="clip")
     start = at - within + jnp.where(late, _I32(WORD_REACH), _I32(0))
-    column = jnp.arange(WORD_ROW, dtype=_I32)[None, :]
-
-    def word(off):
-        mine = column == (jnp.clip(pos + off, 0, last) - start)[:, None]
-        return jnp.sum(jnp.where(mine, held, _I32(0)), axis=1, dtype=_I32)
-
-    return tuple(word(off) for off in offsets)
+    return held, start, last
 
 
 def _ref_pos_bits(idx, pos, c, len_at, b_neg_idx, b_large_idx, b_neg_pos, b_large_pos):
@@ -937,28 +947,42 @@ def _deep_blocks(
         S, F_lane, cands, first, blocks, block, overflow, n_survivors)
 
 
-def _walk_blocks(B: _LaneBlocks, n, at_eof, reads_to_check: int, fold, init):
+def _walk_blocks(
+    B: _LaneBlocks, n, at_eof, reads_to_check: int, fold, init,
+    walk_scope: str | None = None,
+):
     """Pass 2 of the lane stage: walk block k and hand its lanes to the
     stage's consumer, ``fold(carry, k, cand, live, lanes) -> carry`` with
     ``lanes`` the per-lane verdicts of ``_walk_lanes``. The count folds them
     into its scalars and its escape list (``_count_lanes``); ``check_window``
-    keeps them, lane for lane (``_check_lanes``)."""
+    keeps them, lane for lane (``_check_lanes``); the load parses and
+    filters the records among them (``_load_lanes``).
+
+    The count and ``check_window`` stand under ``check`` whole, fold and
+    all. A consumer whose fold is no part of the check (the load's) calls
+    from outside it and names the scope the walk goes under
+    (``walk_scope``): its fold then runs under its own names alone."""
     w = B.F_lane.shape[0] - 1
+    walk = (contextlib.nullcontext if walk_scope is None
+            else functools.partial(jax.named_scope, walk_scope))
 
     def flags_lookup(pi):
         # ONE gather a step: the merged array (``_lane_flags``).
         return jnp.take(B.F_lane, pi, mode="clip")
 
     def walk_block(k, carry):
-        with jax.named_scope("chain_walk"):
-            cand, *first = (
-                lax.dynamic_slice(buf, (k * B.block,), (B.block,))
-                for buf in (B.cands, *B.first))
-            live = cand >= 0
-        lanes = _walk_lanes(
-            cand, live, flags_lookup, B.S["misc_at"], n, at_eof, w,
-            reads_to_check, unroll=True, first=tuple(first),
-        )
+        with walk():
+            with jax.named_scope("chain_walk"):
+                cand, *first = (
+                    lax.dynamic_slice(buf, (k * B.block,), (B.block,))
+                    for buf in (B.cands, *B.first))
+                live = cand >= 0
+            lanes = _walk_lanes(
+                cand, live, flags_lookup, B.S["misc_at"], n, at_eof, w,
+                reads_to_check, unroll=True, first=tuple(first),
+            )
+        if walk_scope is not None:
+            return fold(carry, k, cand, live, lanes)
         with jax.named_scope("chain_walk"):
             return fold(carry, k, cand, live, lanes)
 
@@ -1302,6 +1326,137 @@ def make_check_window(
         check_window, reads_to_check=reads_to_check, window=window,
         funnel=funnel,
     )
+
+
+def _compact_lanes(keep, columns: tuple):
+    """The lanes of a block where ``keep``, moved to the front in lane
+    order: ``(picked, n)`` with ``picked`` (len(columns), block) int32, whose
+    first ``n`` columns are those lanes' entries of ``columns`` and the rest
+    whatever. Ranks over the block, as ``_list_escapes`` lists escapes, and
+    ONE fetch of a row a lane from the block's columns laid side by side:
+    no scatter, nothing wider than the block."""
+    table = _rank_table(keep)
+    lane = _ranked_positions(
+        table, jnp.arange(keep.shape[0], dtype=_I32))
+    picked = jnp.take(
+        jnp.stack(columns, axis=1), jnp.maximum(lane, 0), axis=0,
+        mode="clip")
+    return picked.T, table.n_set
+
+
+#: What ``load_window`` says of a window beside its rows, one int32 each,
+#: in the order of its ``stats``: the owned record starts, the owned
+#: escapes, the rows that passed the filter, those of them whose CIGAR the
+#: scan did not finish, stage 0's survivors, the lanes run, and whether the
+#: window's escapes could not be listed (``count_window``'s ``esc_overflow``).
+LOAD_STATS = ("count", "esc_count", "rows", "cigar_over", "survivors",
+              "lanes", "esc_overflow")
+
+
+def _load_lanes(
+    padded, lengths, num_contigs, n, at_eof, lo, own, rows,
+    reads_to_check: int, escapes: int,
+):
+    """The funnelled check with the load's fold: ``_count_lanes``' lane
+    stage, and where that sums ``own_lane & (res == 1)`` this keeps those
+    lanes: parses each record out of the word view the check made
+    (``parser.parse_lanes``), tests it (``parser.rows_pass``) and appends
+    the block's rows that passed to the window's table (``_compact_lanes``),
+    which therefore holds them in file order. A window has at most
+    ``lane_capacity(w)`` lanes and a lane at most one record, so the table
+    cannot overflow; a block is appended whole at the rows' count, the next
+    over its tail, so the table is a block longer than the lanes."""
+    from spark_bam_tpu.tpu import parser
+
+    with jax.named_scope("check"):
+        B = _deep_blocks(padded, lengths, num_contigs, n, at_eof, None)
+    U = B.S["U"]
+
+    def keep(carry, _k, cand, live, lanes):
+        count, esc, listed, table, n_rows, over = carry
+        with jax.named_scope("check"):
+            res = lanes["res"]
+            own_lane = live & (cand >= lo) & (cand < own)
+            record = own_lane & (res == 1)
+            escaped = own_lane & (res == 2)
+            listed = _list_escapes(listed, esc, escaped, cand)
+        words, cols, span, exact = parser.parse_lanes(
+            U, jnp.where(record, cand, _I32(0)), record)
+        passed = parser.rows_pass(record, cols, span, exact, rows)
+        with jax.named_scope("filter"):
+            picked, got = _compact_lanes(passed, (*words, cand, span))
+            table = lax.dynamic_update_slice(table, picked, (0, n_rows))
+            over = over + jnp.sum(passed & ~exact)
+        return (count + jnp.sum(record), esc + jnp.sum(escaped), listed,
+                table, n_rows + got, over)
+
+    lanes_wide = B.cands.shape[0]
+    count, esc, listed, table, n_rows, over = _walk_blocks(
+        B, n, at_eof, reads_to_check, keep,
+        (_I32(0), _I32(0), jnp.full(escapes, -1, dtype=_I32),
+         jnp.zeros((parser.ROW_WORDS, lanes_wide + B.block), dtype=_I32),
+         _I32(0), _I32(0)),
+        walk_scope="check")
+    return B, count, esc, listed, table, n_rows, over
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("reads_to_check", "window", "escapes"),
+)
+def load_window(
+    padded, lengths, num_contigs, n, at_eof, lo, own, rows,
+    reads_to_check: int = 10, window: int | None = None,
+    escapes: int = ESCAPE_LIST,
+):
+    """``count_window`` with the records handed back: the check of every
+    position, and at the owned lanes it accepts the record parsed, tested
+    against ``rows`` (``parser.RowFilter``: loci and flag masks) and, where
+    it passes, appended to ``table``: (``parser.ROW_WORDS``, lanes + a
+    block) int32, a column a row in file order, the nine words of the
+    fixed block, the record's position in the window and the reference span
+    of its CIGAR. The first ``stats[rows]`` columns are rows; the caller
+    reads ``stats`` (``LOAD_STATS``: seven integers) and then as many
+    columns as that says, never the table.
+
+    Always the funnel: the verdicts are the same with and without it, and
+    the parse reads the funnel's word view. Escapes as ``count_window``'s
+    with ``escapes`` slots (``esc_pos``): a record that runs past the
+    buffer is listed and not parsed. A window that escaped whole or could
+    not list its escapes says so in ``stats`` and has no rows."""
+    w = padded.shape[0] - PAD
+    B, count, esc, listed, table, n_rows, over = _load_lanes(
+        padded, lengths, num_contigs, n, at_eof, lo, own, rows,
+        reads_to_check, escapes)
+    with jax.named_scope("reduce"):
+        i = jnp.arange(w, dtype=_I32)
+        m = (i >= lo) & (i < own)
+        esc0 = jnp.sum(m & (B.S["res0"] == 2))
+        lost = B.overflow | (esc0 > 0) | (esc > escapes)
+        stats = jnp.stack([
+            jnp.where(lost, 0, count),
+            jnp.where(B.overflow, jnp.sum(m), esc0 + esc),
+            jnp.where(lost, 0, n_rows), jnp.where(lost, 0, over),
+            B.n_survivors, B.lanes, lost.astype(_I32),
+        ]).astype(_I32)
+    return {"stats": stats, "esc_pos": listed, "table": table}
+
+
+def make_load_window(
+    window: int, reads_to_check: int = 10, escapes: int = ESCAPE_LIST,
+):
+    """The fused load kernel (``jit_load_window``) for a fixed ``window``."""
+    return functools.partial(
+        load_window, reads_to_check=reads_to_check, window=window,
+        escapes=escapes,
+    )
+
+
+@functools.partial(jax.jit, static_argnames=("rows",))
+def table_head(table, rows: int):
+    """The first ``rows`` columns of ``load_window``'s table: what of it
+    crosses to the host, in power-of-two buckets."""
+    return table[:, :rows]
 
 
 @dataclass

@@ -66,8 +66,7 @@ from spark_bam_tpu import obs
 from spark_bam_tpu.bam.header import read_header
 from spark_bam_tpu.check.vectorized import check_flat
 from spark_bam_tpu.core.config import Config
-from spark_bam_tpu.tpu.checker import lane_capacity
-from spark_bam_tpu.tpu.inflate import InflatePipeline
+from spark_bam_tpu.tpu.inflate import DeviceObserver, InflatePipeline
 
 
 def _next_pow2(n: int) -> int:
@@ -147,11 +146,18 @@ class _CountEscapes:
     ``(reads_to_check + 2) * max_read_size`` (the mesh's cap,
     ``parallel/stream_mesh._RowGrowth``: adversarial size fields)."""
 
-    def __init__(self, lengths: np.ndarray, config: Config):
+    def __init__(self, lengths: np.ndarray, config: Config,
+                 found: list | None = None):
         self.deferred = StreamChecker._Deferred(lengths, config.reads_to_check)
-        self.cap_bytes = (config.reads_to_check + 2) * config.max_read_size
         self.starts = 0
         self.overflowed = False
+        # Where the resolved record starts lie (absolute), for a caller
+        # that decodes them (the load); the count wants their number.
+        self.found = found
+        # A lookahead no real chain asks for is the count's cue to start
+        # over. The load has handed rows out by then: it keeps deferring.
+        self.cap_bytes = float("inf") if found is not None else (
+            (config.reads_to_check + 2) * config.max_read_size)
 
     def settle(self, escaped: int, ring: list, at_eof: bool) -> None:
         """Pop ``ring[0]``, whose window reported ``escaped`` owned escapes;
@@ -185,9 +191,20 @@ class _CountEscapes:
             self.overflowed = len(deferred.buf) > self.cap_bytes
 
     def _walk(self, at_eof: bool) -> None:
-        for _pos, (verdicts,) in self.deferred.resolve(at_eof, ("verdict",)):
+        for pos, (verdicts,) in self.deferred.resolve(at_eof, ("verdict",)):
             self.starts += int(verdicts.sum())
+            if self.found is not None:
+                self.found.extend((pos + np.flatnonzero(verdicts)).tolist())
             obs.count("check.escape_resolved", len(verdicts))
+
+
+class _LoadObserver(DeviceObserver):
+    """The load's windows on the device, taken off the feeding thread as the
+    count's are, under a name of their own: ``load.device_ms``."""
+
+    @staticmethod
+    def _observe(device_ms: float) -> None:
+        obs.observe("load.device_ms", device_ms, unit="ms")
 
 
 class StreamChecker:
@@ -273,6 +290,26 @@ class StreamChecker:
             prev = (buf, base, own_end, at_eof, out)
         if prev is not None:
             yield prev
+
+    def _frame_rows(self, size: int):
+        """``halo_windows`` over the pipeline's frames of ``size`` bytes
+        (``InflatePipeline.frames``: the window's padded operand in place):
+        ``(view, buf, base, own_end, lo, at_eof)`` a window, ``view.frame``
+        the frame ``buf`` lies in, the consumer's to give back. Closing it
+        shuts the pipeline's pool and channel."""
+        views: list = []
+
+        def tap():
+            for view in self.pipeline.frames(self.halo, size):
+                views.append(view)
+                yield view
+
+        rows = halo_windows(tap(), self.halo, self.header_end_abs)
+        try:
+            for row in rows:
+                yield views.pop(0), *row
+        finally:
+            rows.close()
 
     def _device_inputs(self):
         lens = pad_contig_lengths(self.lengths)
@@ -466,14 +503,11 @@ class StreamChecker:
         self,
         fields: tuple[str, ...],
         defer_inexact: bool,
-        with_buf: bool = False,
     ):
-        """The shared window loop behind ``spans``/``full_spans``/
-        ``read_batches``: project ``fields`` from each window's results,
-        defer unresolved owned lanes (escaped chains; plus inexact ones when
-        the projection includes flags), and re-emit them as contiguous-run
-        spans once exact. ``with_buf`` appends the window's byte buffer to
-        each window tuple (``None`` on deferred re-emissions)."""
+        """The shared window loop behind ``spans``/``full_spans``: project
+        ``fields`` from each window's results, defer unresolved owned lanes
+        (escaped chains; plus inexact ones when the projection includes
+        flags), and re-emit them as contiguous-run spans once exact."""
         deferred = self._Deferred(self.lengths, self.config.reads_to_check)
         windows = 0
         funnel = self.use_device and self.config.funnel_enabled(defer_inexact)
@@ -484,8 +518,7 @@ class StreamChecker:
                 res = self._materialize(buf, at_eof, out)
                 if funnel:
                     self._funnel_add(
-                        len(buf), int(res["survivors"]),
-                        lane_capacity(self.kernel_window))
+                        len(buf), int(res["survivors"]), int(res["lanes"]))
                 spans = [res[f][:own_end].copy() for f in fields]
                 bad = res["escaped"][:own_end]
                 if defer_inexact:
@@ -504,9 +537,9 @@ class StreamChecker:
                 obs.count(
                     "check.escaped", int(res["escaped"][:own_end].sum())
                 )
-            yield (base, *spans, buf) if with_buf else (base, *spans)
+            yield (base, *spans)
             for pos, row in deferred.resolve(at_eof, fields):
-                yield (pos, *row, None) if with_buf else (pos, *row)
+                yield (pos, *row)
             windows += 1
             if self.progress is not None:
                 self.progress(windows, base + own_end, self.total)
@@ -555,7 +588,7 @@ class StreamChecker:
         from spark_bam_tpu.tpu.checker import (
             ESCAPE_LIST, PAD, make_count_window,
         )
-        from spark_bam_tpu.tpu.inflate import FRAMES, DeviceObserver
+        from spark_bam_tpu.tpu.inflate import FRAMES
 
         funnel = self.config.funnel_enabled()
         with obs.span("load.open", program="count_window"):
@@ -578,17 +611,10 @@ class StreamChecker:
         ring: list = []
         escapes = _CountEscapes(self.lengths, self.config)
         # Every window is inflated into a frame that is its padded operand
-        # too (carry in front, zeros behind); ``views`` and ``held`` name
-        # the frames of the row in hand and of the ring's windows.
-        views: list = []
+        # too (carry in front, zeros behind); ``held`` names the frames of
+        # the ring's windows.
         held: list = []
-
-        def tap():
-            for view in self.pipeline.frames(self.halo, self.halo + w + PAD):
-                views.append(view)
-                yield view
-
-        rows = halo_windows(tap(), self.halo, self.header_end_abs)
+        rows = self._frame_rows(self.halo + w + PAD)
 
         def settle(escaped: int, at_eof: bool):
             """The ring's oldest window: its escapes, then its frame back."""
@@ -612,10 +638,9 @@ class StreamChecker:
                     row = next(rows, None)
                     if row is None:
                         break
-                    buf, base, own_end, lo, at_eof = row
+                    view, buf, base, own_end, lo, at_eof = row
                     n = len(buf)
                     t_put = time.perf_counter()
-                    view = views.pop(0)
                     with obs.span("inflate.h2d", bytes=w + PAD):
                         # Not written again until the window has left the
                         # ring (its program has run): safe under async
@@ -702,55 +727,243 @@ class StreamChecker:
             ("fail_mask", "reads_before"), defer_inexact=True
         )
 
-    def read_batches(self) -> Iterator[tuple[int, "object"]]:
+    def read_batches(self, rows=None) -> Iterator[tuple[int, "object"]]:
         """Columnar ``ReadBatch``es per streaming window — the load path at
         WGS scale (O(window) host memory; reference CanLoadBam.scala:173-243
-        loads per split, here per device window).
+        loads per split, here per device window), of the records that pass
+        ``rows`` (``parser.RowFilter``: loci and flag masks; every record
+        without one).
 
-        Yields ``(abs_base, batch)``; batch ``starts`` are window-relative.
+        Yields ``(abs_base, batch)``: ``batch.starts`` index ``batch.buf``,
+        a copy of the window's bytes from the first row's start to the last
+        row's end, and ``abs_base + batch.starts`` are the rows' flat
+        offsets. A window none of whose records passes yields nothing.
+
+        The stream is the count's (``count_reads``): frames inflated by the
+        pipeline's workers, one put a window, ``ring_depth`` windows ahead
+        of the device, one read of a few integers a window where the loop
+        paces anyway (``check.pace``). The program is ``load_window``: the
+        count's check, and at the lanes it accepts the record parsed, tested
+        and kept in a table on the device; the host reads the table's head,
+        as many columns as rows passed rounded up to a power of two, one
+        window later still (``load.batch``), so that read queues behind a
+        program and the chip has the next one to run meanwhile. Nothing as
+        wide as the window's positions comes back.
+
         Records that start in an owned span but extend past the window's
-        lookahead (longer than the halo), plus record starts whose verdicts
-        resolved through the deferral path, are decoded exactly from a
-        seekable stream and yielded as one final batch with ``abs_base=-1``
-        (its ``starts`` index its own buffer).
+        lookahead (longer than the halo), and the rest of the candidates the
+        program listed as escaped (``_CountEscapes``), are decoded exactly
+        from a seekable stream and yielded with ``abs_base=-1`` (their
+        ``starts`` index their own buffer), tested on the host. A window
+        whose escapes the program could not list is checked and parsed on
+        the host (``check.fused_demotions``), and so is every window
+        without a device (``use_device=False``).
         """
-        from spark_bam_tpu.tpu.parser import parse_flat_records
+        from spark_bam_tpu.tpu.parser import RowFilter
 
-        he = self.header_end_abs
+        rows = RowFilter.of() if rows is None else rows
         spill_abs: list[int] = []
-        for base, verdict, buf in self._stream(
-            ("verdict",), defer_inexact=False, with_buf=True
-        ):
-            if buf is None:  # a deferred contiguous-run re-emission
-                idx = base + np.flatnonzero(verdict)
-                spill_abs.extend(idx[idx >= he].tolist())
-            else:
-                starts = np.flatnonzero(verdict)
-                starts = starts[base + starts >= he]
-                if len(starts):
-                    # A record must fit the buffer to parse in-window;
-                    # spills (size beyond the halo lookahead) decode
-                    # exactly from the stream.
-                    sizes = (
-                        buf[starts].astype(np.int64)
-                        | (buf[starts + 1].astype(np.int64) << 8)
-                        | (buf[starts + 2].astype(np.int64) << 16)
-                        | (buf[starts + 3].astype(np.int64) << 24)
-                    )
-                    fits = starts + 4 + sizes <= len(buf)
-                    spill_abs.extend((base + starts[~fits]).tolist())
-                    starts = starts[fits]
-                    if len(starts):
-                        yield base, parse_flat_records(buf, starts)
+
+        def spilled():
+            """The spills so far, decoded and tested."""
+            obs.count("load.spilled_records", len(spill_abs))
+            for batch in self._decode_spills(sorted(spill_abs)):
+                batch.columns["valid"] = rows.passes(batch.columns)
+                yield -1, batch
+            spill_abs.clear()
+
+        windows = (self._load_windows if self.use_device
+                   else self._host_windows)
+        for item in windows(rows, spill_abs):
+            yield item
             # Bound spill memory: flush in chunks during the stream, never
             # one unbounded EOF batch (ultra-long-read files spill often).
             if len(spill_abs) >= 4096:
-                for batch in self._decode_spills(sorted(spill_abs)):
-                    yield -1, batch
-                spill_abs = []
+                yield from spilled()
         if spill_abs:
-            for batch in self._decode_spills(sorted(spill_abs)):
-                yield -1, batch
+            yield from spilled()
+
+    def _host_rows(self, buf, base, lo, own_end, at_eof, rows):
+        """One window checked and parsed without ``load_window``: ``(batch
+        or None, escaped)``, the rows of its owned span that pass and the
+        window positions of its owned escapes."""
+        from spark_bam_tpu.tpu.parser import parse_flat_records
+
+        res = check_flat(
+            buf, self.lengths, at_eof=at_eof,
+            reads_to_check=self.config.reads_to_check)
+        starts = lo + np.flatnonzero(res.verdict[lo:own_end])
+        escaped = lo + np.flatnonzero(res.escaped[lo:own_end])
+        obs.count("load.records_parsed", len(starts))
+        if not len(starts):
+            return None, escaped
+        batch = parse_flat_records(buf, starts)
+        batch.columns["valid"] = rows.passes(batch.columns)
+        obs.count("load.rows_out", len(batch))
+        return (batch if len(batch) else None), escaped
+
+    def _host_windows(self, rows, spill_abs: list):
+        """``read_batches`` on the NumPy engine: the control flow of
+        ``_load_windows`` one window at a time."""
+        escapes = _CountEscapes(self.lengths, self.config, found=spill_abs)
+        windows = 0
+        for buf, base, own_end, lo, at_eof in halo_windows(
+                self.pipeline, self.halo, self.header_end_abs):
+            batch, escaped = self._host_rows(
+                buf, base, lo, own_end, at_eof, rows)
+            # What earlier windows left pending sees this one's bytes.
+            escapes.deferred.extend(buf, base)
+            escapes.settle(len(escaped), [(
+                {"esc_overflow": False, "esc_pos": escaped}, base, buf)],
+                at_eof)
+            windows += 1
+            obs.count("check.windows")
+            if batch is not None:
+                yield base, batch
+            if self.progress is not None:
+                self.progress(windows, base + own_end, self.total)
+        assert not len(escapes.deferred), "escapes must resolve by EOF"
+
+    def _load_windows(self, rows, spill_abs: list):
+        """``read_batches`` on the device; see there. Appends to
+        ``spill_abs`` the absolute starts that resolved off the device."""
+        from spark_bam_tpu.tpu.checker import (
+            LOAD_STATS, PAD, make_load_window, table_head,
+        )
+        from spark_bam_tpu.tpu.inflate import FRAMES
+        from spark_bam_tpu.tpu.parser import ReadBatch, unpack_rows
+
+        with obs.span("load.open", program="load_window"):
+            kernel = make_load_window(
+                self.kernel_window, self.config.reads_to_check)
+            lens_dev, nc = self._device_inputs()
+            rows_dev = jax.device_put(rows)
+            observer = _LoadObserver.maybe()
+        w = self.kernel_window
+        stat = {name: i for i, name in enumerate(LOAD_STATS)}
+        escapes = _CountEscapes(self.lengths, self.config, found=spill_abs)
+        # Windows dispatched whose integers are unread, oldest first
+        # (``count_reads``' ring), and behind it the windows whose rows are
+        # on their way: the head of the table dispatched, the frame held.
+        ring: list = []
+        reading: list = []
+        windows = 0
+        stream = self._frame_rows(self.halo + w + PAD)
+
+        def settle(at_eof: bool):
+            """The ring's oldest window: its integers, its escapes, and
+            the head of its table sent for."""
+            out, base, buf = ring[0]
+            with obs.span("check.pace"):
+                stats = np.asarray(out["stats"])
+            d2h = stats.nbytes
+            batch = None
+            if stats[stat["esc_overflow"]]:
+                # More escapes than the list holds, or more survivors than
+                # lanes: nothing of this window's is the device's.
+                obs.count("check.fused_demotions")
+                lo, own_end, eof, _n = out["span"]
+                # A copy: the batch outlives the frame its bytes lie in.
+                batch, at = self._host_rows(
+                    buf.copy(), base, lo, own_end, eof, rows)
+                ring[0] = ({"esc_overflow": False, "esc_pos": at}, base, buf)
+                escaped, n_rows = len(at), 0
+            else:
+                escaped = int(stats[stat["esc_count"]])
+                n_rows = int(stats[stat["rows"]])
+                obs.count("load.records_parsed", int(stats[stat["count"]]))
+                if escaped:
+                    d2h += out["esc_pos"].nbytes
+            self._funnel_add(
+                out["span"][3], int(stats[stat["survivors"]]),
+                int(stats[stat["lanes"]]))
+            escapes.settle(escaped, ring, at_eof)
+            head = None
+            if n_rows:
+                # Queued behind the programs dispatched since: read when
+                # the next window has been dispatched behind it in turn.
+                head = table_head(out["table"], _next_pow2(max(n_rows, 256)))
+                head.copy_to_host_async()
+                d2h += head.nbytes
+            obs.count("load.d2h_bytes", d2h)
+            reading.append((head, n_rows, base, buf, batch, out["frame"]))
+
+        def rows_of():
+            """The oldest window of ``reading`` as a batch (None without
+            rows), and its frame given back."""
+            head, n_rows, base, buf, batch, frame = reading.pop(0)
+            if n_rows:
+                with obs.span("load.batch", rows=n_rows):
+                    columns, starts, fixups = unpack_rows(
+                        np.asarray(head)[:, :n_rows], buf, rows)
+                    obs.count("load.cigar_host_fixups", fixups)
+                    if len(starts):
+                        first = int(starts[0])
+                        end = int(starts[-1]) + 4 + int(
+                            columns["block_size"][-1])
+                        columns["name_offset"] = (
+                            columns["name_offset"] - first)
+                        batch = ReadBatch(
+                            columns, starts - first, buf[first:end].copy())
+                        base += first
+                        obs.count("load.rows_out", len(starts))
+            FRAMES.give([frame], keep=FRAMES.KEEP + 1)
+            return None if batch is None else (base, batch)
+
+        try:
+            while True:
+                with obs.span("check.window", window=windows):
+                    row = next(stream, None)
+                    if row is None:
+                        break
+                    view, buf, base, own_end, lo, at_eof = row
+                    n = len(buf)
+                    t_put = time.perf_counter()
+                    with obs.span("inflate.h2d", bytes=w + PAD):
+                        start = view.lead + len(view.data) - n
+                        operand = jnp.asarray(
+                            view.frame[start: start + w + PAD])
+                    obs.count("inflate.h2d_bytes", w + PAD)
+                    t_dispatch = time.perf_counter()
+                    with obs.span("inflate.device_kernel"):
+                        out = dict(kernel(
+                            operand, lens_dev, nc, jnp.int32(n),
+                            jnp.bool_(at_eof), jnp.int32(lo),
+                            jnp.int32(own_end), rows_dev,
+                        ))
+                    obs.dispatched()
+                    if observer is not None:
+                        observer.window(
+                            operand, t_put, out["stats"], t_dispatch)
+                    # Beside the program's outputs: the window's span and
+                    # frame, and that its escape list is whole
+                    # (``_CountEscapes`` asks; a window whose list is not is
+                    # replaced in ``settle``).
+                    out.update(span=(lo, own_end, at_eof, n),
+                               frame=view.frame, esc_overflow=False)
+                    ring.append((out, base, buf))
+                    ready = []
+                    if len(ring) > self.ring_depth:
+                        settle(at_eof)
+                        while len(reading) > 1:
+                            ready.append(rows_of())
+                    windows += 1
+                    obs.count("check.windows")
+                    if self.progress is not None:
+                        self.progress(windows, base + own_end, self.total)
+                yield from filter(None, ready)
+            # The stream's end: the windows still in flight, oldest first.
+            with obs.span("check.flush"):
+                while ring:
+                    settle(True)
+                ready = [rows_of() for _ in range(len(reading))]
+            yield from filter(None, ready)
+        finally:
+            with obs.span("load.drain"):
+                stream.close()
+                if observer is not None:
+                    observer.close()
+        assert not len(escapes.deferred), "escapes must resolve by EOF"
 
     def _decode_spills(self, positions: list[int], chunk_bytes: int = 64 << 20):
         """Exact single-record decode for starts whose bytes outran their

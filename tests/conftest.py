@@ -63,3 +63,35 @@ def bam5k():
     if not p.exists():
         pytest.skip("reference fixtures unavailable")
     return p
+
+
+class BamCase:
+    """A small BAM with its ``.records`` sidecar and what is known of it
+    beforehand: the reference's ``2.bam`` with its golden numbers, or a
+    seeded generated file (``tests/bam_factories.random_bam``), whose
+    numbers the tests take from the sequential codec. ``contig`` is the
+    first contig's name."""
+
+    def __init__(self, path, contig: str, records=None, on_contig=None):
+        self.path = path
+        self.contig = contig
+        self.records = records      # golden record count, or None
+        self.on_contig = on_contig  # golden rows on ``contig``:0-100000
+
+
+@pytest.fixture(scope="session", params=["reference", "generated"])
+def bam2_like(request, tmp_path_factory):
+    """The cases that were written against ``2.bam`` run on it where the
+    reference's fixtures are installed (elsewhere that parameter skips) and
+    on a generated file everywhere."""
+    if request.param == "reference":
+        if not fixture("2.bam").exists():
+            pytest.skip("reference fixtures unavailable")
+        return BamCase(fixture("2.bam"), "1", 2500, 2450)
+    from tests.bam_factories import random_bam
+
+    path = tmp_path_factory.mktemp("bam2_like") / "generated.bam"
+    random_bam(path, 2, contigs=(("chr1", 5_000_000), ("chr2", 3_000_000)),
+               n_records=(600, 700), read_len=(30, 400), dup_rate=0.05,
+               index=True)
+    return BamCase(path, "chr1")

@@ -218,6 +218,43 @@ def test_count_window_xla_funnel_compiles_at_32mib(chip):
         [f"s32[{LANE_BLOCK},{WORD_ROW}]"] * 9, [])
 
 
+def test_load_window_compiles_at_32mib(chip):
+    """``jit_load_window``: the whole device program of a window of the
+    streaming load (``StreamChecker.read_batches``), the count's check with
+    the load's fold. What it adds to the count's program: two row fetches a
+    lane of the walk's blocks (the fixed block again, a row of CIGAR inside
+    a loop of its own, whose trip count the block's longest CIGAR sets), the
+    table of rows (11 int32 a lane and a block over: 44.1 MiB, an output and
+    once more as the loop's carry) and no gather of a byte or of a single
+    word of the view."""
+    from spark_bam_tpu.tpu.checker import (
+        LANE_BLOCK, LOAD_STATS, WORD_ROW, lane_capacity, make_load_window,
+    )
+    from spark_bam_tpu.tpu.parser import ROW_WORDS, RowFilter
+
+    rows = RowFilter(
+        chip((4, 3), jnp.int32),
+        *_scalars(chip, jnp.bool_, jnp.int32, jnp.int32))
+    compiled = jax.jit(make_load_window(WINDOW, 10)).lower(
+        chip((WINDOW + PAD,), jnp.uint8), chip((CMAX,), jnp.int32),
+        *_scalars(chip, jnp.int32, jnp.int32, jnp.bool_, jnp.int32,
+                  jnp.int32), rows,
+    ).compile()
+    table = f"s32[{ROW_WORDS},{lane_capacity(WINDOW) + LANE_BLOCK}]"
+    text = compiled.as_text()
+    assert table in text and f"s32[{len(LOAD_STATS)}]" in text
+    # The count's 1.18 GiB and the table twice or three times over.
+    assert 9 << 27 < _device_bytes(compiled) < 6 << 28
+    assert len(_word_views(text, WINDOW)) == 1
+    assert not _position_wide_lookups(text, WINDOW)
+    assert _byte_gathers(text) == [f"u8[{LANE_BLOCK}]"]
+    # The count's nine fetches, the record's fixed block and a row of CIGAR.
+    assert _word_fetches(text, WINDOW) == (
+        [f"s32[{LANE_BLOCK},{WORD_ROW}]"] * 11, [])
+    # The blocks of pass 1 and of the walk, and the CIGAR's rows in them.
+    assert len(_whiles_of_no_constant_trip_count(text)) == 3
+
+
 def _count_step_shapes(shape, repl, devices: int, rows: int):
     """The count step's operands for ``rows`` rows a device, flat."""
     k = devices * rows

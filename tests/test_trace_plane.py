@@ -41,6 +41,18 @@ def _lower_count_window():
     )
 
 
+def _lower_load_window():
+    from spark_bam_tpu.tpu.checker import PAD, load_window
+    from spark_bam_tpu.tpu.parser import RowFilter
+
+    i32 = jnp.int32
+    return load_window.lower(
+        jnp.zeros(W + PAD, jnp.uint8), jnp.zeros(8, i32), i32(1), i32(1000),
+        jnp.bool_(True), i32(0), i32(1000), RowFilter.of(None, 0, 1796),
+        window=W,
+    )
+
+
 def _lower_count_step():
     from spark_bam_tpu.parallel.mesh import local_mesh, make_shard_map_count_step
     from spark_bam_tpu.tpu.checker import PAD
@@ -99,6 +111,8 @@ def _lower_confusion_step():
 CHECK = {"check", "flags", "funnel", "chain_walk"}
 PROGRAM_SCOPES = [
     ("count_window", _lower_count_window, CHECK | {"reduce"}),
+    ("load_window", _lower_load_window,
+     CHECK | {"reduce", "parse", "filter"}),
     ("count_step", _lower_count_step, CHECK | {"reduce"}),
     ("serve_step", _lower_serve_step, CHECK | {"reduce", "scatter"}),
     ("confusion_step", _lower_confusion_step,
@@ -131,6 +145,15 @@ def test_scopes_are_in_the_lowered_program(program, lower, scopes):
         assert "check/flags/" in text and "check/funnel/" in text
         for scope in ("funnel", "flags", "chain_walk"):
             assert f"check/while/body/{scope}/" in text
+    if program == "load_window":
+        # The walk under ``check`` inside its block loop; the fold beside
+        # it there, under its own names and not under ``check``'s, so that
+        # a trace tells the check's time from the parse's and the filter's.
+        assert "while/body/check/chain_walk/" in text
+        for scope in ("parse", "filter"):
+            assert f"while/body/{scope}/" in text
+            assert f"check/{scope}/" not in text
+            assert f"check/while/body/{scope}/" not in text
 
 
 def test_the_programs_cover_the_catalogue():
