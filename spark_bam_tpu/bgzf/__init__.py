@@ -11,6 +11,7 @@ from spark_bam_tpu.bgzf.stream import (
     UncompressedBytes,
     SeekableUncompressedBytes,
     pos_iterator,
+    scan_metadata,
 )
 from spark_bam_tpu.bgzf.find_block_start import find_block_start
 
@@ -28,5 +29,6 @@ __all__ = [
     "UncompressedBytes",
     "SeekableUncompressedBytes",
     "pos_iterator",
+    "scan_metadata",
     "find_block_start",
 ]

@@ -21,7 +21,7 @@ import numpy as np
 from spark_bam_tpu import obs
 from spark_bam_tpu.bgzf.block import Metadata, FOOTER_SIZE
 from spark_bam_tpu.bgzf.header import Header
-from spark_bam_tpu.bgzf.stream import MetadataStream, inflate_block_payload
+from spark_bam_tpu.bgzf.stream import inflate_block_payload, scan_metadata
 from spark_bam_tpu.core.channel import ByteChannel, MMapChannel, open_channel
 
 
@@ -262,7 +262,7 @@ def flatten_file(path, threads: int = 8) -> FlatView:
     with open_channel(path) as ch, obs.span(
         "bgzf.read", kind="metadata_scan", path=str(path)
     ):
-        metas = list(MetadataStream(ch))
+        metas = scan_metadata(ch)
     with open_channel(path) as ch:
         total = sum(m.uncompressed_size for m in metas)
         return inflate_blocks(ch, metas, file_total=total, at_eof=True, threads=threads)

@@ -14,7 +14,7 @@ import time
 from typing import Iterable
 
 from spark_bam_tpu.bgzf.block import Metadata
-from spark_bam_tpu.bgzf.stream import MetadataStream
+from spark_bam_tpu.bgzf.stream import MetadataStream, scan_metadata
 from spark_bam_tpu.core.channel import (
     is_url,
     open_channel,
@@ -150,7 +150,7 @@ def blocks_metadata(
         if blocks is not None:
             return blocks
     with open_channel(bam_path) as ch:
-        blocks = list(MetadataStream(ch))
+        blocks = scan_metadata(ch)
     try:
         store_blocks(bam_path, blocks, config)
     except Exception:  # write-through is an accelerator, never a failure

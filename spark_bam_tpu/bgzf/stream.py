@@ -6,6 +6,8 @@ Host-side equivalents of the reference's block layer
 - ``BlockStream``            — iterate decompressed ``Block``s (zlib raw-deflate)
 - ``SeekableBlockStream``    — adds ``seek`` + an LRU cache of 100 blocks
 - ``MetadataStream``         — iterate ``Metadata`` without decompressing
+- ``scan_metadata``          — the whole walk at once, in the native library
+  where the channel is the plain local mapping
 - ``UncompressedBytes``      — linear byte-channel view over the blocks
 - ``SeekableUncompressedBytes`` — virtual-position addressable variant
 - ``pos_iterator``           — all candidate ``Pos`` of a block
@@ -23,9 +25,9 @@ from typing import Iterator, Optional
 
 from spark_bam_tpu import obs
 from spark_bam_tpu.bgzf.block import Block, Metadata, FOOTER_SIZE, check_isize
-from spark_bam_tpu.bgzf.header import Header
+from spark_bam_tpu.bgzf.header import EXPECTED_HEADER_SIZE, Header
 from spark_bam_tpu.core import guard
-from spark_bam_tpu.core.channel import ByteChannel
+from spark_bam_tpu.core.channel import ByteChannel, MMapChannel
 from spark_bam_tpu.core.faults import (
     BlockCorruptionError,
     BlockGapError,
@@ -33,6 +35,12 @@ from spark_bam_tpu.core.faults import (
 )
 from spark_bam_tpu.core.guard import MalformedInputError
 from spark_bam_tpu.core.pos import Pos
+from spark_bam_tpu.native.build import (
+    WALK_FULL,
+    WALK_REJECTED,
+    load_native,
+    walk_members_native,
+)
 
 
 def inflate_block_payload(comp: bytes | memoryview, uncompressed_size: int) -> bytes:
@@ -235,6 +243,47 @@ class MetadataStream:
 
     def close(self) -> None:
         self.ch.close()
+
+
+#: Members one native walk call has room for; a longer file resumes the call.
+WALK_CHUNK = 1 << 16
+#: The least a member can take: what bounds the members of a stretch of file.
+_MIN_MEMBER = EXPECTED_HEADER_SIZE + FOOTER_SIZE
+
+
+def scan_metadata(ch: ByteChannel) -> list[Metadata]:
+    """``list(MetadataStream(ch))``: the walk over every member from the
+    channel's position, the head of every whole-file pass.
+
+    Over the plain local mapping (``MMapChannel`` itself: not a remote
+    channel, a registered scheme's, a caching or a chaos wrapper) with the
+    native library loaded, the walk is ``sbt_walk_members``, one call a
+    ``WALK_CHUNK`` members, with the interpreter lock released. It accepts
+    a member by the checks of ``Header.parse``; at one it does not accept
+    the Python walk takes over, so what is raised (or the silent stop at a
+    cut header) is ``MetadataStream``'s own. Anything else is
+    ``MetadataStream`` from the start. ``bgzf.blocks_scanned_native`` over
+    ``bgzf.blocks_scanned`` is the share of members walked natively; an
+    open ``bgzf.read`` span is told ``walk="native"|"python"``.
+    """
+    metas: list[Metadata] = []
+    lib = load_native() if type(ch) is MMapChannel else None
+    why = WALK_REJECTED if lib is None else WALK_FULL
+    while why == WALK_FULL:
+        pos = ch.position()
+        room = min(WALK_CHUNK, (ch.size - pos) // _MIN_MEMBER + 1)
+        table, pos, why = walk_members_native(
+            lib, ch.memoryview(0, ch.size), pos, room)
+        metas.extend(map(Metadata, *table.tolist()))
+        ch.seek(pos)
+    if metas:
+        obs.count("bgzf.blocks_scanned", len(metas))
+        obs.count("bgzf.blocks_scanned_native", len(metas))
+    obs.annotate(
+        "bgzf.read", walk="python" if why == WALK_REJECTED else "native")
+    if why == WALK_REJECTED:
+        metas.extend(MetadataStream(ch))
+    return metas
 
 
 def pos_iterator(meta: Metadata) -> Iterator[Pos]:

@@ -129,6 +129,11 @@ def _load_native_locked():
     lib.sbt_inflate_blocks.argtypes = [
         c_u8p, c_i64p, c_i64p, ctypes.c_int64, c_u8p, c_i64p, c_i64p,
     ]
+    lib.sbt_walk_members.restype = ctypes.c_int64
+    lib.sbt_walk_members.argtypes = [
+        c_u8p, ctypes.c_int64, ctypes.c_int64, c_i64p, c_i64p, c_i64p,
+        ctypes.c_int64, c_i64p, c_i32p,
+    ]
     lib.sbt_eager_check.restype = None
     lib.sbt_eager_check.argtypes = [
         c_u8p, ctypes.c_int64, c_i64p, ctypes.c_int64,
@@ -165,6 +170,32 @@ def _load_native_locked():
 
 def _ptr(arr: np.ndarray, ctype):
     return arr.ctypes.data_as(ctypes.POINTER(ctype))
+
+
+#: ``sbt_walk_members``' reasons for stopping.
+WALK_END, WALK_SENTINEL, WALK_FULL, WALK_REJECTED = range(4)
+
+
+def walk_members_native(
+    lib, data, start: int, capacity: int
+) -> tuple[np.ndarray, int, int]:
+    """One ``sbt_walk_members`` call over ``data`` (anything with the
+    buffer protocol: the mapped file) from byte ``start``: ``(table,
+    stop_pos, stop_why)``, ``table`` an int64 array of three rows (start,
+    compressed size, uncompressed size) and at most ``capacity`` columns.
+    ctypes releases the interpreter lock for the call. The view of ``data``
+    is dropped before returning, so the caller may close its mapping."""
+    view = np.frombuffer(data, dtype=np.uint8)
+    table = np.empty((3, capacity), dtype=np.int64)
+    stop_pos = ctypes.c_int64(start)
+    stop_why = ctypes.c_int32(WALK_END)
+    n = lib.sbt_walk_members(
+        _ptr(view, ctypes.c_uint8), len(view), start,
+        _ptr(table[0], ctypes.c_int64), _ptr(table[1], ctypes.c_int64),
+        _ptr(table[2], ctypes.c_int64), capacity,
+        ctypes.byref(stop_pos), ctypes.byref(stop_why),
+    )
+    return table[:, :n], int(stop_pos.value), int(stop_why.value)
 
 
 def eager_check_native(

@@ -6,6 +6,8 @@
 //
 //   - sbt_inflate_blocks: batched raw-DEFLATE inflate of BGZF payloads
 //     (zlib, thread-free: callers fan out with one call per thread)
+//   - sbt_walk_members:   the whole-file walk over the members' headers
+//     and footers at the head of a pass (bgzf/stream.py scan_metadata)
 //   - sbt_eager_check:    the sequential eager checker over a flat buffer —
 //     byte-exact with check/eager.py, used for escaped-candidate re-checks
 //     and split-point scans without Python-loop overhead
@@ -49,6 +51,61 @@ long sbt_inflate_blocks(
     if (rc != Z_STREAM_END || produced != out_lengths[i]) return i + 1;
   }
   return 0;
+}
+
+// ------------------------------------------------------------ member walk
+// MetadataStream's loop (bgzf/stream.py) over `size` mapped bytes from
+// offset `start`: each member's start, compressed size and footer ISIZE
+// into the caller's arrays of `capacity` entries. A member is accepted by
+// exactly the checks of Header.parse (bgzf/header.py) plus a footer inside
+// the file. Through the mapping and not by pread: a member costs a touched
+// page either way, and on the chip's host a fault is a microsecond where a
+// system call is nine (PERF.md, PR 52). Returns the members written; *stop_pos is where the walk
+// stopped and *stop_why why (native/build.py has the same four): the
+// file's end, the EOF sentinel (*stop_pos past it, where the Python walk
+// leaves its channel), arrays full, a member not accepted (*stop_pos at it:
+// the Python walk resumes there and raises or stops as it always did, so no
+// error text lives here).
+enum { WALK_END, WALK_SENTINEL, WALK_FULL, WALK_REJECTED };
+
+int64_t sbt_walk_members(
+    const uint8_t* data,
+    int64_t size,
+    int64_t start,
+    int64_t* starts,
+    int64_t* compressed_sizes,
+    int64_t* uncompressed_sizes,
+    int64_t capacity,
+    int64_t* stop_pos,
+    int32_t* stop_why) {
+  int64_t pos = start, n = 0;
+  int32_t why = WALK_END;
+  while (pos < size) {
+    if (n == capacity) { why = WALK_FULL; break; }
+    const uint8_t* h = data + pos;
+    why = WALK_REJECTED;
+    if (size - pos < 18) break;
+    if (h[0] != 31 || h[1] != 139 || h[2] != 8 || h[3] != 4) break;
+    int64_t xlen = h[10] | (h[11] << 8);
+    if (xlen < 6) break;
+    if (h[12] != 66 || h[13] != 67 || h[14] != 2) break;
+    int64_t csize = (h[16] | (h[17] << 8)) + 1;
+    int64_t header = 12 + xlen;  // 18 fixed bytes + what XLEN holds past BC
+    if (csize < header + 8 || pos + csize > size) break;
+    const uint8_t* f = h + csize - 4;
+    uint32_t isize = (uint32_t)f[0] | ((uint32_t)f[1] << 8) |
+                     ((uint32_t)f[2] << 16) | ((uint32_t)f[3] << 24);
+    if (csize - header - 8 == 2) { why = WALK_SENTINEL; pos += csize; break; }
+    starts[n] = pos;
+    compressed_sizes[n] = csize;
+    uncompressed_sizes[n] = (int32_t)isize;  // read_i32: signed
+    ++n;
+    pos += csize;
+    why = WALK_END;
+  }
+  *stop_pos = pos;
+  *stop_why = why;
+  return n;
 }
 
 // ---------------------------------------------------------------- checker

@@ -23,6 +23,7 @@ from spark_bam_tpu.obs.registry import (
     PassSpan,
     Registry,
     Span,
+    annotate,
     configure,
     count,
     counter,
@@ -49,6 +50,7 @@ __all__ = [
     "Registry",
     "Span",
     "account",
+    "annotate",
     "configure",
     "count",
     "counter",

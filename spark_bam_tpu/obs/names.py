@@ -35,8 +35,8 @@ NAMES = frozenset({
     "agg.bytes_out", "agg.encode", "agg.host_fallbacks", "agg.reduce",
     "agg.requests", "agg.rows",
     # bgzf — block streaming (docs/design.md)
-    "bgzf.blocks_read", "bgzf.blocks_scanned", "bgzf.bytes_inflated",
-    "bgzf.bytes_read", "bgzf.read",
+    "bgzf.blocks_read", "bgzf.blocks_scanned", "bgzf.blocks_scanned_native",
+    "bgzf.bytes_inflated", "bgzf.bytes_read", "bgzf.read",
     # cache — .sbi split-index sidecars (docs/caching.md)
     "cache.bytes", "cache.evictions", "cache.hits", "cache.invalidations",
     "cache.misses", "cache.read_ms", "cache.write_errors", "cache.write_ms",

@@ -628,6 +628,17 @@ def span(name: str, **attrs):
     return NOOP if r is None else Span(r, name, attrs)
 
 
+def annotate(name: str, **attrs) -> None:
+    """Attach attributes to the innermost span called ``name`` that is open
+    on this thread: for code that runs inside a span its caller opened.
+    Nothing without a live registry, or with no such span open."""
+    if _active is not None:
+        for s in reversed(_SPAN_STACK.get()):
+            if s.name == name:
+                s.set(**attrs)
+                return
+
+
 def pass_span(name: str, **attrs):
     """The root span of a whole-file pass (``PassSpan``); the shared no-op
     when disabled."""

@@ -46,7 +46,7 @@ from spark_bam_tpu.bgzf.flat import (
     metas_block_table,
     pos_of_flat_tables,
 )
-from spark_bam_tpu.bgzf.stream import MetadataStream
+from spark_bam_tpu.bgzf.stream import scan_metadata
 from spark_bam_tpu.core.channel import open_channel
 from spark_bam_tpu.core.config import Config
 from spark_bam_tpu.core.faults import LatencyTracker
@@ -153,7 +153,7 @@ class _FileState:
         with open_channel(self.path) as ch, obs.span(
             "bgzf.read", kind="metadata_scan", path=self.path
         ):
-            metas = list(MetadataStream(ch))
+            metas = scan_metadata(ch)
         self.block_starts, self.block_flat = metas_block_table(metas)
         self.block_csize = np.array(
             [m.compressed_size for m in metas], dtype=np.int64
