@@ -3,7 +3,9 @@
 Scrapes the ``telemetry`` op from a serve worker or fabric router and
 renders the operator's glance view: per-worker health, queue depth,
 per-op p50/p99, the host/H2D/device ms split the inflate attribution
-gauges carry, SLO burn rates + firing alerts, per-op/per-tenant cost
+gauges carry, the host as the registry's witness saw it (stops, the
+worst one, the host's pace, the collector's pauses), SLO burn rates +
+firing alerts, per-op/per-tenant cost
 rollups, latency exemplars (trace ids of the slowest kept traces —
 feed them to ``metrics-report`` to see the offending tree), and the
 router's autoscale move ledger with each move's cited reason. Point it
@@ -33,6 +35,30 @@ def _hd_split(snapshot) -> str:
     if not vals:
         return "-"
     return "/".join(_ms(vals.get(k)) for k in ("h2d_ms", "device_ms"))
+
+
+def _host_line(p: Printer, snapshot: "dict | None", indent: str = "") -> None:
+    """``host: stops <n> worst <ms> pace p50/p99 <µs> gc <ms>`` from the
+    witness's series (obs/witness.py): wakes 40 ms late or more and the
+    latest of all, the timed unit of work, the collector's recorded
+    pauses summed. Nothing until the daemon's first request started the
+    witness."""
+    from spark_bam_tpu.obs.timeseries import _nearest_rank
+
+    snap = snapshot or {}
+    hists = {h["name"]: h for h in snap.get("hists", []) if h.get("count")}
+    late = hists.get("host.overshoot_ms")
+    if late is None:
+        return
+    stops = sum(c["value"] for c in snap.get("counters", [])
+                if c["name"] == "host.stops")
+    pace = sorted(hists.get("host.pace_us", {}).get("values", []))
+    gc_ms = hists.get("host.gc", {}).get("sum", 0.0)
+    p.echo(
+        f"{indent}host: stops {stops} worst {_ms(late['max'])}ms "
+        f"pace p50/p99 {_ms(_nearest_rank(pace, 0.5))}/"
+        f"{_ms(_nearest_rank(pace, 0.99))}us gc {_ms(gc_ms)}ms"
+    )
 
 
 def _slo_lines(p: Printer, slo: "dict | None", indent: str = "") -> None:
@@ -98,6 +124,7 @@ def _worker_lines(p: Printer, label: str, tel: dict, indent: str = "") -> None:
             f"rows={s.get('rows', 0)} "
             f"p50={_ms(s.get('p50_ms'))}ms p99={_ms(s.get('p99_ms'))}ms"
         )
+    _host_line(p, snap, indent=indent + "  ")
     _slo_lines(p, tel.get("slo"), indent=indent + "  ")
     _accounting_lines(p, tel.get("accounting"), indent=indent + "  ")
     _exemplar_lines(p, snap, indent=indent + "  ")

@@ -175,9 +175,10 @@ def merge_snapshots(snapshots: list[dict]) -> dict:
     count/sum, merge min/max, and concatenate retained samples (capped)
     so fleet p50/p99 come from a cross-worker sample. Series identity is
     ``(name, sorted labels)`` — the registry's own key. Of the workers'
-    slowest passes the fleet's is kept, a root name.
+    slowest passes the fleet's is kept, a root name, and of their
+    ``slow_passes`` the fleet's eight, slowest first.
     """
-    from spark_bam_tpu.obs.registry import _HIST_SAMPLE_CAP
+    from spark_bam_tpu.obs.registry import _HIST_SAMPLE_CAP, _SLOW_PASSES
 
     def key(entry):
         return (entry["name"], tuple(sorted(entry.get("labels", {}).items())))
@@ -186,6 +187,7 @@ def merge_snapshots(snapshots: list[dict]) -> dict:
     gauges: dict = {}
     hists: dict = {}
     slowest: dict = {}
+    slow: dict = {}
     dropped = 0
     for snap in snapshots:
         if not snap:
@@ -195,6 +197,8 @@ def merge_snapshots(snapshots: list[dict]) -> dict:
             kept = slowest.get(p["root"])
             if kept is None or p["ms"] > kept["ms"]:
                 slowest[p["root"]] = p
+        for p in snap.get("slow_passes", []):
+            slow.setdefault(p["root"], []).append(p)
         for c in snap.get("counters", []):
             cur = counters.setdefault(
                 key(c), {"name": c["name"],
@@ -241,6 +245,9 @@ def merge_snapshots(snapshots: list[dict]) -> dict:
         "hists": list(hists.values()),
         "dropped_events": dropped,
         "slowest_passes": list(slowest.values()),
+        "slow_passes": [
+            p for kept in slow.values()
+            for p in sorted(kept, key=lambda p: -p["ms"])[:_SLOW_PASSES]],
     }
 
 

@@ -24,7 +24,7 @@ from __future__ import annotations
 LAYERS = frozenset({
     "account", "agg", "bgzf", "cache", "chaos", "check", "checkbam", "cli",
     "columnar", "compress", "deflate", "fabric", "faults", "funnel",
-    "guard", "inflate", "jobs", "load", "mesh", "progress", "remote",
+    "guard", "host", "inflate", "jobs", "load", "mesh", "progress", "remote",
     "sampler", "scrub", "serve", "slo", "timer", "transport", "ts",
 })
 
@@ -111,6 +111,17 @@ NAMES = frozenset({
     "funnel.lanes", "funnel.positions", "funnel.survivors",
     # guard — untrusted-byte decode boundary (core/guard.py)
     "guard.quarantined_blocks", "guard.quarantined_records",
+    # host — the registry's witness of the host (obs/witness.py;
+    # docs/observability.md "The host"): host.sleep (annotation: each wait
+    # of the witness thread, a line of its own in a capture), host.stop
+    # (span event: a wake 40 ms late or more, the collector's hold taken
+    # out, in the trace of every open pass; histogram once a stop), host.gc
+    # (span event: a full collection, or any of 1 ms or more; attr
+    # generation), host.overshoot_ms (every wake's lateness, less the
+    # collector's hold), host.pace_us (a CRC of 64 KiB timed at every wake:
+    # the host's speed), host.stops (counter)
+    "host.gc", "host.overshoot_ms", "host.pace_us", "host.sleep",
+    "host.stop", "host.stops",
     # inflate — host BGZF inflate feeding the device (docs/design.md)
     "inflate.block", "inflate.blocks", "inflate.bytes",
     "inflate.device_kernel", "inflate.device_ms",
@@ -129,12 +140,15 @@ NAMES = frozenset({
     # phases on the feeding thread load.open (header, contig lengths and
     # their put, the program's lookup) and load.drain (what follows the last
     # dispatch and no other span holds), and its own account at the root's
-    # exit, load.head_ms / load.drain_ms (docs/observability.md "A pass")
-    "load.check_bam", "load.count", "load.drain", "load.drain_ms",
-    "load.fleet_files", "load.head_ms", "load.open", "load.parse",
-    "load.partition",
+    # exit, load.head_ms / load.drain_ms, and of the host load.stop_ms
+    # (the machine's stops inside the pass) / load.gc_ms (the collector's
+    # pauses) / load.cpu_ms (the process's CPU time over it), one
+    # observation a pass each (docs/observability.md "A pass", "The host")
+    "load.check_bam", "load.count", "load.cpu_ms", "load.drain",
+    "load.drain_ms", "load.fleet_files", "load.gc_ms", "load.head_ms",
+    "load.open", "load.parse", "load.partition",
     "load.partitions", "load.record_starts", "load.records",
-    "load.split_resolutions",
+    "load.split_resolutions", "load.stop_ms",
     # load — the streaming load (load/tpu_load.stream_read_batches,
     # tpu/stream_check.read_batches): the root load.reads (a pass as the
     # two above), load.batch (feeding thread: a window's rows read back

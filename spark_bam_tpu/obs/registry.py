@@ -15,9 +15,16 @@ path:
   raw trace is capped.
 - **Passes**: ``with obs.pass_span("load.count", path=p):`` is the root
   span of one whole-file pass: a trace of its own (every span of the pass
-  carries its id), ``load.head_ms`` / ``load.drain_ms`` at its exit, and
-  the slowest pass of each root name kept with its spans summed by name
-  (``snapshot()["slowest_passes"]``).
+  carries its id), its account at its exit (``load.head_ms`` /
+  ``load.drain_ms``, and what the host did to it: ``load.stop_ms`` /
+  ``load.gc_ms`` / ``load.cpu_ms``), and the eight slowest passes of each
+  root name kept with their spans summed by name
+  (``snapshot()["slowest_passes"]``: the slowest; ``["slow_passes"]``).
+- **The host** (``obs.witness``): from its first root on (a pass, or the
+  daemon's ``serve.request``) a live registry keeps a witness thread and a
+  hook on Python's collector: ``host.stop`` / ``host.gc`` events in the
+  trace of every pass they fell into, ``host.overshoot_ms`` /
+  ``host.pace_us`` over the window.
 - **Exporters** (``obs.exporters``): JSONL trace file, Prometheus
   text-format snapshot, and a human summary in the reference's stats
   format (``core/stats.py``).
@@ -71,6 +78,11 @@ _HIST_SAMPLE_CAP = 4096
 # The JSONL trace buffer stops appending events past this; dropped events
 # are counted and still feed the per-name duration histograms.
 _TRACE_EVENT_CAP = 200_000
+# The slowest passes kept a root name (``Registry._keep_slowest``).
+_SLOW_PASSES = 8
+# The spans that are roots: the first one a live registry sees starts its
+# witness of the host (``obs.witness``). A pass is a root by its type.
+_ROOT_SPANS = frozenset({"serve.request"})
 
 
 _SCALARS = (int, float, str, bool)
@@ -283,24 +295,39 @@ class PassSpan(Span):
     one identifier and the JSONL reads as one tree a pass.
 
     The feeding thread marks each dispatch that has returned
-    (``dispatched``). At its exit the pass observes ``load.head_ms``, its
-    start to the return of its first dispatch (what the chip waits for at
-    the head of a pass, on the host's clock), and ``load.drain_ms``, the
-    return of its last dispatch to its end, and hands itself to the
-    registry, which keeps the slowest pass of each root name with its
-    spans summed by name (``Registry._keep_slowest``)."""
+    (``dispatched``). While it is open the registry counts it among its
+    open passes, and what the witness of the host sees falls into its trace
+    and its account (``Registry.host_event``). At its exit the pass
+    observes ``load.head_ms``, its start to the return of its first
+    dispatch (what the chip waits for at the head of a pass, on the host's
+    clock), and ``load.drain_ms``, the return of its last dispatch to its
+    end; then what the host did to it, ``load.stop_ms`` (the machine's
+    stops inside it; 0 in a clean pass), ``load.gc_ms`` (the collector's
+    pauses) and ``load.cpu_ms`` (the CPU time of every thread of the
+    process over the pass, the native inflater's too: a pass that took
+    longer on the same CPU time was kept off the cores); and hands itself
+    to the registry, which keeps the slowest passes of each root name with
+    their spans summed by name (``Registry._keep_slowest``)."""
 
-    __slots__ = ("_t_first", "_t_last", "_pass_token", "_mark")
+    __slots__ = ("_t_first", "_t_last", "_pass_token", "_mark", "_cpu0",
+                 "host_ms")
 
     def __init__(self, registry: "Registry", name: str, attrs: dict):
         super().__init__(registry, name, attrs)
         self._t_first = self._t_last = None
+        self._cpu0 = 0.0
+        # Summed milliseconds of the host's events inside the pass; the
+        # witness's thread and the collector's hook add to it.
+        self.host_ms = {"host.stop": 0.0, "host.gc": 0.0}
 
     def __enter__(self) -> "PassSpan":
         self.trace_id = _trace.new_id()
         self._pass_token = _PASS.set(self)
         self._mark = self.registry._event_mark()
-        return super().__enter__()
+        super().__enter__()
+        self._cpu0 = time.process_time()
+        self.registry._root_entered(self)
+        return self
 
     def dispatched(self) -> None:
         self._t_last = time.perf_counter()
@@ -309,14 +336,27 @@ class PassSpan(Span):
 
     def _finish(self, ms: float) -> None:
         _PASS.reset(self._pass_token)
+        r = self.registry
+        r._pass_left(self)
         if self._t_first is not None:
-            r = self.registry
             r.histogram("load.head_ms", unit="ms").observe(
                 (self._t_first - self._t0) * 1e3)
             r.histogram("load.drain_ms", unit="ms").observe(
                 ms - (self._t_last - self._t0) * 1e3)
+        account = self.account()
+        r.histogram("load.stop_ms", unit="ms").observe(account["stop_ms"])
+        r.histogram("load.gc_ms", unit="ms").observe(account["gc_ms"])
+        r.histogram("load.cpu_ms", unit="ms").observe(account["cpu_ms"])
         super()._finish(ms)
-        self.registry._keep_slowest(self, ms)
+        r._keep_slowest(self, ms, account)
+
+    def account(self) -> dict:
+        """What the host did to the pass so far, in ms."""
+        return {
+            "stop_ms": round(self.host_ms["host.stop"], 3),
+            "gc_ms": round(self.host_ms["host.gc"], 3),
+            "cpu_ms": round((time.process_time() - self._cpu0) * 1e3, 3),
+        }
 
 
 class _NoopMetric:
@@ -349,7 +389,9 @@ class Registry:
     """Process-wide metric store + span trace buffer (thread-safe)."""
 
     def __init__(self, max_events: int = _TRACE_EVENT_CAP):
-        self._lock = threading.Lock()
+        # Re-entrant: Python's collector may call the witness's hook
+        # (``host_event``) on a thread that is inside one of these blocks.
+        self._lock = threading.RLock()
         self._counters: dict[tuple, Counter] = {}
         self._gauges: dict[tuple, Gauge] = {}
         self._hists: dict[tuple, Histogram] = {}
@@ -367,8 +409,13 @@ class Registry:
         # dropped request costs one set-add, not an O(events) sweep.
         self._dropped_traces: set = set()
         self._compactions = 0
-        # The slowest pass seen of each root name (``_keep_slowest``).
-        self._slowest: dict[str, dict] = {}
+        # The slowest passes seen of each root name, slowest first
+        # (``_keep_slowest``).
+        self._slowest: dict[str, list[dict]] = {}
+        # The passes open now, and the witness of the host that the first
+        # root starts (``_root_entered``, ``host_event``, ``close``).
+        self._open: set[PassSpan] = set()
+        self._witness = None
 
     # ------------------------------------------------------------- metrics
     def _get(self, table: dict, cls, name: str, labels: dict):
@@ -408,6 +455,8 @@ class Registry:
 
     # --------------------------------------------------------------- spans
     def span(self, name: str, **attrs) -> Span:
+        if name in _ROOT_SPANS and self._witness is None:
+            self._root_entered()
         return Span(self, name, attrs)
 
     def _finish_span(self, span: Span, ms: float) -> None:
@@ -455,6 +504,11 @@ class Registry:
         Returns the (possibly minted) span_id.
         """
         self.histogram(name, unit="ms").observe(ms)
+        return self._timed_event(name, ms, trace_id, span_id,
+                                 parent_span_id, t_wall, attrs)
+
+    def _timed_event(self, name: str, ms: float, trace_id, span_id,
+                     parent_span_id, t_wall, attrs: dict) -> str | None:
         event = {
             "e": "span",
             "name": name,
@@ -478,23 +532,69 @@ class Registry:
         self._append_event(event)
         return span_id
 
+    # ---------------------------------------------------------------- host
+    def _root_entered(self, root: "PassSpan | None" = None) -> None:
+        """A root has begun: ``root`` counts among the open passes, and the
+        first root of this registry starts its witness of the host. Not
+        ``configure()``: a registry made for one counter starts no
+        thread."""
+        with self._lock:
+            if root is not None:
+                self._open.add(root)
+            if self._witness is not None:
+                return
+            from spark_bam_tpu.obs.witness import Witness
+
+            self._witness = Witness(self)
+            self._witness.start()
+
+    def _pass_left(self, root: "PassSpan") -> None:
+        with self._lock:
+            self._open.discard(root)
+
+    def host_event(self, name: str, ms: float, t_wall: float,
+                   **attrs) -> None:
+        """What the witness saw of the host (``host.stop``, ``host.gc``):
+        the histogram ``name`` observed ONCE, and a span event of ``ms``
+        from ``t_wall`` in the trace of EVERY pass open now, under its root
+        span and into its account; with no pass open, one event without a
+        trace."""
+        self.histogram(name, unit="ms").observe(ms)
+        with self._lock:
+            passes = list(self._open)
+        for p in passes:
+            p.host_ms[name] += ms
+            self._timed_event(name, ms, p.trace_id, None, p.span_id,
+                              t_wall, attrs)
+        if not passes:
+            self._timed_event(name, ms, None, None, None, t_wall, attrs)
+
+    def close(self) -> None:
+        """Stop and join the witness, if one runs (``obs.shutdown()``)."""
+        with self._lock:
+            witness = self._witness
+        if witness is not None:
+            witness.stop()  # joins: not under the lock its thread takes
+
     # -------------------------------------------------------------- passes
     def _event_mark(self) -> tuple:
         """Where the event buffer stands: a pass's events lie behind the
         mark taken at its start (unless the buffer was compacted since)."""
         return len(self._events), self._compactions
 
-    def _keep_slowest(self, root: PassSpan, ms: float) -> None:
-        """Keep ``root`` if it is the longest pass of its name so far:
-        ``{root, ms, t, at_s, trace, spans: {name: [count, summed ms, max
-        ms]}}`` (``at_s``: its start, in seconds of this registry's life),
-        the pass's span events (those that carry its trace, the
-        root's own left out) summed by name. Summed once, here, and only
-        for a pass that sets the record; the other passes leave nothing
-        but what the histograms hold. What the trace buffer dropped for
-        its cap is not in the sums."""
-        kept = self._slowest.get(root.name)
-        if kept is not None and kept["ms"] >= ms:
+    def _keep_slowest(self, root: PassSpan, ms: float, account: dict) -> None:
+        """Keep ``root`` if it is among the ``_SLOW_PASSES`` longest passes
+        of its name so far, slowest first: ``{root, ms, t, at_s, trace,
+        stop_ms, gc_ms, cpu_ms, spans: {name: [count, summed ms, max
+        ms]}}`` (``at_s``: its start, in seconds of this registry's life;
+        the three after it: the pass's account of the host), the pass's span
+        events (those that carry its trace, ``host.stop`` and ``host.gc``
+        among them, the root's own left out) summed by name. Summed once,
+        here, and only for a pass that enters the list; the other passes
+        leave nothing but what the histograms hold. What the trace buffer
+        dropped for its cap is not in the sums."""
+        kept = self._slowest.get(root.name, ())
+        if len(kept) == _SLOW_PASSES and kept[-1]["ms"] >= ms:
             return
         start, compactions = root._mark
         with self._lock:
@@ -513,24 +613,38 @@ class Registry:
             "root": root.name, "ms": round(ms, 3),
             "t": round(root.t_wall, 6),
             "at_s": round(root.t_wall - self.t_start, 3),
-            "trace": root.trace_id, "spans": spans,
+            "trace": root.trace_id, **account, "spans": spans,
         }
         with self._lock:
-            self._slowest[root.name] = record
+            kept = self._slowest.setdefault(root.name, [])
+            kept.append(record)
+            kept.sort(key=lambda p: -p["ms"])  # stable: ties keep their order
+            del kept[_SLOW_PASSES:]
 
     # ------------------------------------------------------------ snapshot
     def snapshot(self) -> dict:
-        """A point-in-time copy of every series (no trace events)."""
+        """A point-in-time copy of every series (no trace events).
+        ``slowest_passes``: the slowest pass a root name; ``slow_passes``:
+        the ``_SLOW_PASSES`` slowest a root name, slowest first."""
+        def copied(p: dict) -> dict:
+            return {**p, "spans": {k: list(v) for k, v in p["spans"].items()}}
+
         with self._lock:
+            # Lists first: the collector's hook may add a series on this
+            # thread while the dicts below are built.
+            counters = list(self._counters.values())
+            gauges = list(self._gauges.values())
+            hists = list(self._hists.values())
+            slow = [copied(p) for kept in self._slowest.values() for p in kept]
             return {
                 "counters": [
                     {"name": c.name, "labels": c.labels, "value": c.value}
-                    for c in self._counters.values()
+                    for c in counters
                 ],
                 "gauges": [
                     {"name": g.name, "labels": g.labels, "value": g.value,
                      "max": g.max}
-                    for g in self._gauges.values()
+                    for g in gauges
                 ],
                 "hists": [
                     {"name": h.name, "labels": h.labels, "count": h.count,
@@ -538,13 +652,12 @@ class Registry:
                      "values": list(h.values),
                      **({"exemplars": [list(e) for e in h.exemplars]}
                         if h.exemplars else {})}
-                    for h in self._hists.values()
+                    for h in hists
                 ],
                 "dropped_events": self._dropped,
                 "slowest_passes": [
-                    {**p, "spans": {k: list(v) for k, v in p["spans"].items()}}
-                    for p in self._slowest.values()
-                ],
+                    copied(kept[0]) for kept in self._slowest.values()],
+                "slow_passes": slow,
             }
 
     def events(self) -> list[dict]:
@@ -592,10 +705,13 @@ def configure(max_events: int = _TRACE_EVENT_CAP) -> Registry:
 
 
 def shutdown() -> None:
-    """Drop the live registry; instrumentation reverts to no-ops."""
+    """Drop the live registry; instrumentation reverts to no-ops. Its
+    witness of the host, if a root started one, is stopped and joined."""
     global _active
     with _lock:
-        _active = None
+        r, _active = _active, None
+    if r is not None:
+        r.close()
 
 
 def enabled() -> bool:
@@ -625,7 +741,7 @@ def histogram(name: str, **labels):
 def span(name: str, **attrs):
     """A nesting wall-clock span; the shared no-op when disabled."""
     r = _active
-    return NOOP if r is None else Span(r, name, attrs)
+    return NOOP if r is None else r.span(name, **attrs)
 
 
 def annotate(name: str, **attrs) -> None:
@@ -675,7 +791,8 @@ def export_jsonl(path, reg: Registry | None = None) -> str:
 
     One JSON object per line: a ``meta`` header, every span event in
     completion order, then ``counter``/``gauge``/``hist`` snapshot lines
-    and one ``slowest_pass`` line a root name that ran.
+    and, a root name that ran, one ``slowest_pass`` line and up to eight
+    ``slow_pass`` lines (the slowest first, the ``slowest_pass`` among them).
     Exports the live registry by default (safe to call with observability
     disabled — writes an empty-run file); pass ``reg`` to export an
     explicit instance (per-worker test registries).
@@ -702,6 +819,8 @@ def export_jsonl(path, reg: Registry | None = None) -> str:
             lines.append(json.dumps({"e": "hist", **h}))
         for p in snap["slowest_passes"]:
             lines.append(json.dumps({"e": "slowest_pass", **p}))
+        for p in snap["slow_passes"]:
+            lines.append(json.dumps({"e": "slow_pass", **p}))
         if snap["dropped_events"]:
             lines.append(json.dumps(
                 {"e": "dropped", "count": snap["dropped_events"]}

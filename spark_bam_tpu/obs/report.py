@@ -6,8 +6,9 @@ with the same ``core/stats.py`` formatting the golden CLI reports use.
 
 A whole-file pass (``count-reads`` / ``check-bam`` on the device) is a
 trace of its own (``obs.pass_span``), so its spans render as one tree a
-pass, the slowest pass the registry kept first, with that record's
-spans summed by name above it.
+pass, the slowest passes the registry kept first (eight a root name at
+most, each with its account of the host on the tree's first line), with
+the slowest's spans summed by name above them.
 
 Multi-process traces: when several files are given (router + N fabric
 workers, each exporting its own registry), span events carrying trace
@@ -42,6 +43,8 @@ def load_trace(path) -> dict:
             snapshot["hists"].append(ev)
         elif kind == "slowest_pass":
             snapshot.setdefault("slowest_passes", []).append(ev)
+        elif kind == "slow_pass":
+            snapshot.setdefault("slow_passes", []).append(ev)
         elif kind == "meta":
             meta = ev
         elif kind == "dropped":
@@ -114,10 +117,19 @@ def render_trace_tree(events: list[dict]) -> str:
     return "\n".join(lines)
 
 
+def _account(p: dict) -> str:
+    """A kept pass's account of the host, for its tree's first line."""
+    return " ".join(f"{key}={p[key]:.3f}"
+                    for key in ("stop_ms", "gc_ms", "cpu_ms") if key in p)
+
+
 def _trace_blocks(traces: dict, snapshot: dict, max_traces: int) -> list:
     """The report's blocks below the stats: the slowest pass of each root
-    name (its spans summed by name), then one span tree a trace, those
-    passes' first and the rest largest first, ``max_traces`` in all."""
+    name (its spans summed by name), then one span tree a trace: the slow
+    passes the registry kept first, slowest first and all of them (eight a
+    root name at most), each with its account of the host (``stop_ms``,
+    ``gc_ms``, ``cpu_ms``) on its first line; then the rest, largest first,
+    up to ``max_traces`` trees in all."""
     blocks = []
     slowest = snapshot.get("slowest_passes", [])
     for p in slowest:
@@ -127,16 +139,22 @@ def _trace_blocks(traces: dict, snapshot: dict, max_traces: int) -> list:
             f" (trace {p.get('trace')})\n" + "\n".join(
                 f"  {name}: {n} x, {total:.3f}ms, max {top:.3f}ms"
                 for name, (n, total, top) in rows))
-    first = [p.get("trace") for p in slowest]
-    ranked = sorted(traces.items(),
-                    key=lambda kv: (kv[0] not in first, -len(kv[1])))
-    for tid, events in ranked[:max_traces]:
+    # A program from before the eight kept the slowest alone.
+    kept = {p.get("trace"): p
+            for p in snapshot.get("slow_passes") or slowest}
+    first = [tid for tid in kept if tid in traces]
+    rest = sorted((tid for tid in traces if tid not in kept),
+                  key=lambda tid: -len(traces[tid]))
+    shown = first + rest[:max(0, max_traces - len(first))]
+    for tid in shown:
+        account = _account(kept[tid]) if tid in kept else ""
         blocks.append(
-            f"trace {tid} ({len(events)} spans):\n"
-            + render_trace_tree(events)
+            f"trace {tid} ({len(traces[tid])} spans):"
+            + (" " + account if account else "") + "\n"
+            + render_trace_tree(traces[tid])
         )
-    if len(traces) > max_traces:
-        blocks.append(f"... {len(traces) - max_traces} more traces omitted")
+    if len(traces) > len(shown):
+        blocks.append(f"... {len(traces) - len(shown)} more traces omitted")
     return blocks
 
 

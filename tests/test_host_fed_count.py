@@ -16,7 +16,7 @@ import jax
 
 from spark_bam_tpu import obs
 from spark_bam_tpu.core.config import Config
-from spark_bam_tpu.obs.names import NAMES
+from spark_bam_tpu.obs.names import NAMES, layer_of
 from spark_bam_tpu.parallel.mesh import make_mesh, mesh_steps
 from spark_bam_tpu.parallel.stream_mesh import (
     _ShardedStream, count_reads_sharded,
@@ -106,7 +106,10 @@ def assert_one_trace_a_pass(events: list, hists: dict, root: str,
         names = {e["name"] for e in mine}
         assert phases | threads <= names, (phases | threads) - names
         assert all(e["pspan"] in ids for e in mine if e["name"] != root)
-    assert all(e.get("trace") in traces for e in events)
+    # Every span of the PROGRAM: what the witness of the host sees between
+    # two passes (a stop, a full collection) is an event in no trace.
+    assert all(e.get("trace") in traces for e in events
+               if layer_of(e["name"]) != "host")
     assert hists["load.head_ms"] == hists["load.drain_ms"] == passes
     assert {e["name"] for e in events} <= NAMES
 
@@ -253,6 +256,41 @@ def test_a_stream_pass_is_one_trace_with_its_own_account(short48):
         threads={"inflate.window"})
     assert hists["inflate.window"] == 16 and hists["bgzf.read"] == 2
     assert hists["load.open"] == 4  # the file, then the program, a pass
+
+
+def test_the_report_prints_every_kept_pass_with_its_account_of_the_host(
+        short48, tmp_path, capsys, monkeypatch):
+    """``metrics-report`` on the JSONL of a three-pass count: a tree a
+    pass, the slowest first, ``stop_ms`` / ``gc_ms`` / ``cpu_ms`` on each
+    tree's first line; the passes computed, so their CPU time is not 0."""
+    from spark_bam_tpu.cli.main import main
+    from spark_bam_tpu.load.tpu_load import count_reads_tpu
+
+    monkeypatch.delenv("SPARK_BAM_METRICS_OUT", raising=False)
+    path, index = short48
+    config = Config(window_size=6 * MEMBER, halo_size=64 << 10)
+    trace = tmp_path / "m.jsonl"
+    obs.shutdown()
+    obs.configure()
+    try:
+        for _ in range(3):
+            assert count_reads_tpu(path, config) == len(index["record_starts"])
+        kept = obs.registry().snapshot()["slow_passes"]
+        obs.export_jsonl(trace)
+    finally:
+        obs.shutdown()
+    assert [p["root"] for p in kept] == ["load.count"] * 3
+    assert kept == sorted(kept, key=lambda p: -p["ms"])
+    assert all(p["cpu_ms"] > 0 and p["stop_ms"] >= 0 and p["gc_ms"] >= 0
+               for p in kept)
+    assert main(["metrics-report", str(trace)]) == 0
+    out = capsys.readouterr().out
+    heads = [line for line in out.splitlines() if line.startswith("trace ")]
+    assert [line.split()[1] for line in heads] == [p["trace"] for p in kept]
+    for line, p in zip(heads, kept):
+        assert line.endswith(
+            f"spans): stop_ms={p['stop_ms']:.3f} gc_ms={p['gc_ms']:.3f}"
+            f" cpu_ms={p['cpu_ms']:.3f}")
 
 
 def test_a_mesh_pass_is_one_trace_with_its_own_account(short48, monkeypatch):
