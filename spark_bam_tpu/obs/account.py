@@ -5,9 +5,10 @@ mutable vector of the resources it consumed:
 
 - ``queue_ms``  — batcher queue wait, summed over the request's rows
   (the same per-row number the ``serve.queue_ms`` histogram observes);
-- ``device_ms`` — its share of each device tick it rode (``tick_ms``
-  divided evenly across the tick's live rows, so shares sum back to the
-  ``serve.tick`` histogram exactly);
+- ``device_ms`` — its share of each device tick it rode (``tick_ms``,
+  the tick's own time and not the step it was queued behind
+  (``Batcher._deliver``), divided evenly across the tick's live rows, so
+  shares sum back to the ``serve.tick`` histogram exactly);
 - ``h2d_bytes`` — the window bytes it shipped to the device (mirrored
   by the global ``serve.h2d_bytes`` counter);
 - ``host_ms``   — everything else: handler time minus queue and device

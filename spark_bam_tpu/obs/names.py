@@ -195,7 +195,8 @@ NAMES = frozenset({
     "serve.segment_inflate", "serve.segment_misses", "serve.segment_waits",
     "serve.shed",
     "serve.step", "serve.stream_aborts",
-    "serve.tick", "serve.tick_lanes", "serve.tuned", "serve.worker_wait_ms",
+    "serve.tick", "serve.tick_lanes", "serve.ticks_overlapped",
+    "serve.tuned", "serve.worker_wait_ms",
     # serve shm — segment lifecycle + encoded-frame cache
     # (docs/serving.md "Transport")
     "serve.frame_cache_hits", "serve.frame_cache_misses",

@@ -226,7 +226,7 @@ class Span:
 
     __slots__ = ("registry", "name", "attrs", "parent", "depth", "_t0",
                  "t_wall", "trace_id", "span_id", "parent_span_id",
-                 "_ctx_token", "_stack_token", "_annotation")
+                 "_ctx_token", "_stack_token", "_annotation", "_took")
 
     def __init__(self, registry: "Registry", name: str, attrs: dict):
         self.registry = registry
@@ -234,6 +234,7 @@ class Span:
         self.attrs = attrs
         self.parent = None
         self.depth = 0
+        self._took = None
         self._t0 = 0.0
         self.t_wall = 0.0
         self.trace_id = None
@@ -246,6 +247,15 @@ class Span:
     def set(self, **attrs) -> None:
         """Attach attributes mid-span (e.g. measured device time)."""
         self.attrs.update(attrs)
+
+    def took(self, ms: float, t_wall: float) -> None:
+        """The span's length and start as its caller measured them, for
+        work that began before the span could open: a tick of the
+        batcher's pipeline is put and launched a turn before its result is
+        waited for. The histogram and the event take these; the profiler
+        annotation stays what was open on the thread."""
+        self._took = float(ms)
+        self.t_wall = t_wall
 
     def __enter__(self) -> "Span":
         stack = _SPAN_STACK.get()
@@ -275,7 +285,9 @@ class Span:
 
     def __exit__(self, *exc) -> None:
         self._annotation.__exit__(None, None, None)
-        ms = (time.perf_counter() - self._t0) * 1e3
+        ms = self._took
+        if ms is None:
+            ms = (time.perf_counter() - self._t0) * 1e3
         # reset() restores the exact entry-time stack — exits from
         # interleaved asyncio tasks can't pop each other's spans.
         _SPAN_STACK.reset(self._stack_token)
@@ -371,6 +383,9 @@ class _NoopMetric:
         pass
 
     def observe(self, v: float) -> None:
+        pass
+
+    def took(self, ms: float, t_wall: float) -> None:
         pass
 
     # Context-manager face: span() returns this same singleton when

@@ -55,8 +55,9 @@ def _instrument_step(kind: str, step):
     """Wrap a jit'd mesh step so each call emits a ``mesh.dispatch`` span
     (joining whatever trace is bound — the batcher row's request trace).
     Measures host dispatch/enqueue time, not device compute: the arrays
-    come back asynchronous, and the caller's own span (``serve.tick``)
-    covers the sync. When obs is disabled this is one enabled() check per
+    come back asynchronous, and the caller's own span (``serve.tick``,
+    a turn later: the batcher launches the next tick first) covers the
+    sync. When obs is disabled this is one enabled() check per
     dispatch."""
 
     def dispatch(*args):

@@ -1,14 +1,25 @@
-"""Request batcher: coalesce concurrent window rows into one device tick.
+"""Request batcher: coalesce concurrent window rows into one device tick,
+and keep one tick ahead of the chip.
 
 Scan-class requests (count/fleet) are expanded by the service into
 window-row tasks; the batcher gathers rows arriving within ``tick_ms``
 of the first, pads to the FIXED batch shape ``(batch_rows, window+PAD)``
 and dispatches the mesh-cached serve step exactly once per tick. Fixed
 shape + cached step ⇒ one trace at warm-up, zero re-traces in steady
-state, which is the entire perf story of the daemon (docs/serving.md).
+state (docs/serving.md).
 
 Rows from different files coalesce in one tick: the serve step takes
 per-row contig dictionaries, so batching is purely shape-keyed.
+
+One thread, two ticks deep (``Batcher._cycle``): tick k+1 is packed, put
+and launched BEFORE tick k's result is read. The step is dispatched
+asynchronously and donates no operand, so the runtime queues k+1 behind k
+and the host's part of a turn (pack, put, launch, the result's copy, the
+scatter, the threads the results wake) lies under the running step. With a
+tick in flight and no row queued the batcher waits for the first of a row
+(gathered as ever and launched under the running step) and the tick's
+result (``_Tick.ready``): an idle daemon holds no result back, a thin
+queue loses no overlap. The depth is the code's, not a setting.
 """
 
 from __future__ import annotations
@@ -63,6 +74,31 @@ class RowTask:
         self.cost = obs_account.current()
 
 
+class _Tick:
+    """A tick that is launched and not yet delivered: its rows, the step's
+    result (still on its way), and where its own time starts."""
+
+    __slots__ = ("batch", "shape", "out", "packed_ts", "t_put")
+
+    def __init__(self, batch, shape, out, packed_ts, t_put):
+        self.batch = batch
+        self.shape = shape
+        self.out = out
+        self.packed_ts = packed_ts    # monotonic: the rows left the queue
+        self.t_put = t_put            # (perf_counter, time) at its put
+
+    def ready(self) -> bool:
+        """The step's result is computed (a result without ``is_ready``,
+        a numpy array, always is)."""
+        is_ready = getattr(self.out, "is_ready", None)
+        return is_ready is None or is_ready()
+
+
+#: With a tick in flight the batcher looks at its result this often while
+#: it waits for rows (the condition's wait is cut to it).
+_RESULT_POLL_S = 0.0002
+
+
 class Batcher:
     """Tick loop turning queued :class:`RowTask`s into serve-step calls."""
 
@@ -79,9 +115,12 @@ class Batcher:
         )
         self._queue: "deque[RowTask]" = deque()
         self._cond = threading.Condition()
-        self._running = threading.Event()
-        self._running.set()
+        self._paused = False
         self._closed = False
+        # The batcher thread's own: the tick launched and not yet
+        # delivered, and (perf_counter, time) of the last result read.
+        self._flight: "_Tick | None" = None
+        self._last_result = (0.0, 0.0)
         self.batch_sizes: "Counter[int]" = Counter()
         self._thread = threading.Thread(
             target=self._loop, name="serve-batcher", daemon=True
@@ -125,40 +164,58 @@ class Batcher:
         return tick_ms
 
     def pause(self) -> None:
-        """Hold dispatch (tests use this to force a full-batch coalesce)."""
-        self._running.clear()
+        """Hold the next launch (tests use this to force a full-batch
+        coalesce). A tick already launched is still delivered."""
+        with self._cond:
+            self._paused = True
 
     def resume(self) -> None:
-        self._running.set()
         with self._cond:
+            self._paused = False
             self._cond.notify()
 
     def close(self) -> None:
+        """The tick in flight is delivered and the rows queued are run;
+        whatever the thread leaves behind fails."""
         with self._cond:
             self._closed = True
+            self._paused = False
             self._cond.notify()
-        self._running.set()
         self._thread.join(timeout=10)
-        for t in list(self._queue):
-            t.future.set_exception(RuntimeError("batcher closed"))
-        self._queue.clear()
+        with self._cond:
+            left = list(self._queue)
+            self._queue.clear()
+        self._fail(left, RuntimeError("batcher closed"))
 
     # ------------------------------------------------------------------
 
-    def _take_batch(self) -> "list[RowTask]":
-        """Block for the first row, then gather up to ``batch_rows`` rows
-        arriving within one tick. Returns [] only at close."""
+    def _wait(self, flight: "_Tick | None", left: float) -> bool:
+        """One wait on the condition; with a tick in flight, cut to the
+        poll of its result. True, and no wait, once that result is ready."""
+        if flight is not None:
+            if flight.ready():
+                return True
+            left = min(left, _RESULT_POLL_S)
+        self._cond.wait(left)
+        return False
+
+    def _take_batch(self, flight: "_Tick | None") -> "list[RowTask]":
+        """Rows for the next tick. Block for the first row, then gather up
+        to ``batch_rows`` rows arriving within one tick. With a tick in
+        flight both waits also end when its result is ready, so that the
+        turn can deliver it: [] if no row came first. While paused no row
+        is taken. [] with nothing in flight only at close."""
         with self._cond:
-            while not self._queue and not self._closed:
-                self._cond.wait(0.05)
-            if not self._queue:
-                return []
+            while not self._queue or self._paused:
+                if flight is None and self._closed:
+                    return []
+                if self._wait(flight, 0.05):
+                    return []
             deadline = time.monotonic() + self.tick_s
             while len(self._queue) < self.batch_rows:
                 left = deadline - time.monotonic()
-                if left <= 0:
+                if left <= 0 or self._wait(flight, left):
                     break
-                self._cond.wait(left)
             batch = []
             while self._queue and len(batch) < self.batch_rows:
                 batch.append(self._queue.popleft())
@@ -174,13 +231,14 @@ class Batcher:
                     return
 
     def _cycle(self) -> bool:
-        """One turn: wait for a batch, shed, dispatch. False at close."""
-        # From the end of one tick's work to a batch in hand.
+        """One turn: wait for rows or for the result of the tick in
+        flight, shed, launch the next tick, THEN read and scatter the one
+        in flight. False at close, with nothing in flight."""
+        flight = self._flight
         with obs.span("serve.batch_wait"):
-            self._running.wait()
-            batch = self._take_batch()
-        if not batch:
-            return not self._closed
+            batch = self._take_batch(flight)
+        if not batch and flight is None:
+            return False
         # Shed rows whose request deadline already passed.
         now = time.monotonic()
         live = []
@@ -192,16 +250,32 @@ class Batcher:
                 )
             else:
                 live.append(t)
+        self._flight = None
         if live:
             try:
-                self._dispatch(live)
-            except BaseException as exc:  # scatter failure to every row
-                for t in live:
-                    if not t.future.done():
-                        t.future.set_exception(exc)
+                self._flight = self._launch(live)
+            except BaseException as exc:  # this tick's rows, no other's
+                self._fail(live, exc)
+            else:
+                if flight is not None:
+                    obs.count("serve.ticks_overlapped")
+        if flight is not None:
+            try:
+                self._deliver(flight)
+            except BaseException as exc:
+                self._fail(flight.batch, exc)
         return True
 
-    def _dispatch(self, batch: "list[RowTask]") -> None:
+    @staticmethod
+    def _fail(batch: "list[RowTask]", exc: BaseException) -> None:
+        for t in batch:
+            if not t.future.done():
+                t.future.set_exception(exc)
+
+    def _launch(self, batch: "list[RowTask]") -> _Tick:
+        """Pack, put, launch: the step returns at once, queued behind the
+        tick in flight if there is one. Fresh operands a tick: the put of
+        this tick may still read them while the step before runs."""
         # Pad to the CURRENT target, or up to the next mesh multiple of the
         # gathered rows when a ``tune`` shrank batch_rows after this batch
         # was taken — the dispatch shape must always cover the batch.
@@ -227,19 +301,30 @@ class Batcher:
                 obs.observe("serve.queue_ms", (now - t.enqueued_ts) * 1000.0)
         # Padding rows keep lo == own == 0: empty owned span, zero counts.
         put = self.steps.put
-        t_wall = time.time()
-        t0 = time.perf_counter()
-        with obs.span("serve.tick", rows=len(batch), shape=B):
-            with obs.span("serve.h2d"):
-                operands = [put(a) for a in
-                            (ws, ns, eofs, los, owns, lens, ncs)]
-            with obs.span("serve.step"):
-                out = self._step(*operands)
+        t_put = (time.perf_counter(), time.time())
+        with obs.span("serve.h2d"):
+            operands = [put(a) for a in (ws, ns, eofs, los, owns, lens, ncs)]
+        with obs.span("serve.step"):
+            out = self._step(*operands)
+        return _Tick(batch, B, out, now, t_put)
+
+    def _deliver(self, tick: _Tick) -> None:
+        """Wait for the tick's result, read it, scatter it. The tick's own
+        time runs from the later of its put and the result of the tick
+        before (it was queued behind that one's step: not its time) to its
+        result in host memory; ``serve.tick`` carries it (``Span.took``)
+        and the rows share it. A lone tick: put to result."""
+        batch = tick.batch
+        with obs.span("serve.tick", rows=len(batch), shape=tick.shape) as sp:
             with obs.span("serve.d2h"):
-                res = np.asarray(out)
-        tick_ms = (time.perf_counter() - t0) * 1000.0
+                res = np.asarray(tick.out)
+            done = (time.perf_counter(), time.time())
+            t0, t_wall = max(tick.t_put, self._last_result)
+            tick_ms = (done[0] - t0) * 1000.0
+            sp.took(tick_ms, t_wall)
+        self._last_result = done
         with obs.span("serve.scatter", rows=len(batch)):
-            self._scatter(batch, res, now, tick_ms, t_wall)
+            self._scatter(batch, res, tick.packed_ts, tick_ms, t_wall)
 
     def _scatter(self, batch: "list[RowTask]", res, now: float,
                  tick_ms: float, t_wall: float) -> None:
@@ -257,9 +342,10 @@ class Batcher:
         obs.count("funnel.lanes", lanes)
         obs.observe("serve.tick_lanes", lanes)
         # Per-row cost attribution: the same queue_ms the histogram saw,
-        # an even 1/rows share of the tick's device time, and the row's
-        # own window bytes — shares sum back to serve.tick / the
-        # serve.h2d_bytes counter exactly (the bench conservation gate).
+        # an even 1/rows share of the tick's OWN time (``_deliver``: not
+        # the step it was queued behind), and the row's own window bytes —
+        # shares sum back to serve.tick / the serve.h2d_bytes counter
+        # exactly (the bench conservation gate).
         share_ms = tick_ms / len(batch)
         for t in batch:
             if t.cost is not None:

@@ -344,16 +344,20 @@ class _Steps:
         return step
 
 
+def _row(n=10):
+    from spark_bam_tpu.serve.batcher import RowTask
+
+    return RowTask(np.zeros(64, np.uint8), n, True, 0, 10,
+                   np.zeros(4, np.int32), 1)
+
+
 def test_the_batchers_spans_around_a_tick(registry):
-    from spark_bam_tpu.serve.batcher import Batcher, RowTask
+    from spark_bam_tpu.serve.batcher import Batcher
 
     batcher = Batcher(_Steps(), width=64, batch_rows=4, tick_ms=500.0)
     try:
         for tick in range(2):
-            futures = [
-                batcher.submit(RowTask(np.zeros(64, np.uint8), 10 + i, True,
-                                       0, 10, np.zeros(4, np.int32), 1))
-                for i in range(4)]
+            futures = [batcher.submit(_row(10 + i)) for i in range(4)]
             assert [f.result(timeout=10)[0] for f in futures] == [
                 10, 11, 12, 13]
     finally:
@@ -369,27 +373,296 @@ def test_the_batchers_spans_around_a_tick(registry):
     for name in ("serve.batch_pack", "serve.scatter", "serve.h2d",
                  "serve.step", "serve.d2h"):
         assert len(named(name)) == 2, name
-    # One wait a tick, and the one that met the close.
-    assert len(named("serve.batch_wait")) == 3
-    for child in ("serve.h2d", "serve.step", "serve.d2h"):
-        for e, tick in zip(named(child), ticks):
-            assert e["parent"] == "serve.tick"
-            assert tick["t"] <= e["t"] + 1e-4
-            assert e["t"] + e["ms"] / 1e3 <= tick["t"] + tick["ms"] / 1e3 + 1e-3
-    for phase in ("serve.batch_wait", "serve.batch_pack", "serve.tick",
-                  "serve.scatter"):
+    # A lone tick takes two turns, one that launches it and one that
+    # delivers it: a wait each, and the one that met the close.
+    assert len(named("serve.batch_wait")) == len(named("serve.cycle")) == 5
+    # The tick's own time: from its put (nothing ran before it) to its
+    # result. Its span opens at the wait for the result, its event starts
+    # at the put.
+    for put, step, d2h, tick in zip(named("serve.h2d"), named("serve.step"),
+                                    named("serve.d2h"), ticks):
+        assert d2h["parent"] == "serve.tick"
+        assert tick["t"] <= put["t"] + 1e-4 and put["t"] <= step["t"] + 1e-4
+        assert tick["ms"] >= put["ms"] + step["ms"] + d2h["ms"] - 1e-2
+        assert d2h["t"] + d2h["ms"] / 1e3 <= (
+            tick["t"] + tick["ms"] / 1e3 + 1e-3)
+    for phase in ("serve.batch_wait", "serve.batch_pack", "serve.h2d",
+                  "serve.step", "serve.tick", "serve.scatter"):
         assert {e["parent"] for e in named(phase)} == {"serve.cycle"}
-    assert len(named("serve.cycle")) == 3
-    # In a cycle: wait, pack, tick, scatter.
+    # In a turn: wait, then the launch of the next tick (pack, put, step),
+    # then the delivery of the one in flight (tick, scatter).
     order = [e["name"] for e in events if e["name"] in (
-        "serve.batch_wait", "serve.batch_pack", "serve.tick",
-        "serve.scatter")]
-    assert order == ["serve.batch_wait", "serve.batch_pack", "serve.tick",
-                     "serve.scatter"] * 2 + ["serve.batch_wait"]
+        "serve.batch_wait", "serve.batch_pack", "serve.h2d", "serve.step",
+        "serve.tick", "serve.scatter")]
+    lone = ["serve.batch_wait", "serve.batch_pack", "serve.h2d",
+            "serve.step", "serve.batch_wait", "serve.tick", "serve.scatter"]
+    assert order == lone * 2 + ["serve.batch_wait"]
     snap = registry.snapshot()
     queue = [h for h in snap["hists"] if h["name"] == "serve.queue_ms"]
     assert sum(h["count"] for h in queue) == 8  # one a row, as before
     assert {e["name"] for e in events} <= NAMES
+
+
+class _Result:
+    """A step's result that is computed when the test says so."""
+
+    def __init__(self, value, log, k):
+        self.value, self.log, self.k = value, log, k
+        self.computed = threading.Event()
+        self.error = None
+
+    def is_ready(self):
+        return self.computed.is_set()
+
+    def finish(self, error=None):
+        self.error = error
+        self.computed.set()
+
+    def __array__(self, dtype=None, copy=None):
+        self.log.append(("read", self.k))
+        assert self.computed.wait(10)
+        if self.error is not None:
+            raise self.error
+        return self.value
+
+
+class _GatedSteps(_Steps):
+    """``_Steps`` whose step returns at once, as a dispatch does, with a
+    ``_Result``; ``log`` has every put, launch and read in their order."""
+
+    def __init__(self, finish_after=None):
+        self.log, self.results = [], []
+        self.fail_launch = ()             # the launches that raise
+        self.finish_after = finish_after  # seconds, or None: the test does
+
+    def put(self, a):
+        if a.ndim == 2 and a.dtype == np.uint8:
+            self.log.append(("put", len(self.results)))
+        return a
+
+    def serve_step(self, **_kw):
+        def step(ws, ns, *_rest):
+            k = len(self.results)
+            self.log.append(("launch", k))
+            zero = np.zeros_like(ns)
+            out = _Result(np.stack([ns, zero, zero, zero], axis=1),
+                          self.log, k)
+            self.results.append(out)
+            if k in self.fail_launch:
+                raise RuntimeError(f"launch {k}")
+            if self.finish_after is not None:
+                threading.Timer(self.finish_after, out.finish).start()
+            return out
+        return step
+
+    def logged(self, entry, timeout=10.0):
+        end = time.monotonic() + timeout
+        while entry not in self.log and time.monotonic() < end:
+            time.sleep(0.001)
+        return entry in self.log
+
+
+def _counter(registry, name):
+    return sum(c["value"] for c in registry.snapshot()["counters"]
+               if c["name"] == name)
+
+
+def _tick_ahead(batcher, steps, registry):
+    """Rows for two ticks queued: tick 1 is put and launched before tick
+    0's result is read, and counts as overlapped."""
+    batcher.pause()
+    futures = [batcher.submit(_row(10 + i)) for i in range(4)]
+    batcher.resume()
+    assert steps.logged(("read", 0))
+    assert steps.log == [("put", 0), ("launch", 0), ("put", 1),
+                         ("launch", 1), ("read", 0)]
+    assert not any(f.done() for f in futures)
+    steps.results[0].finish()
+    assert [f.result(timeout=10)[0] for f in futures[:2]] == [10, 11]
+    # With no row left to launch the batcher looks at tick 1's result
+    # (``is_ready``) and reads it once it is computed.
+    time.sleep(0.02)
+    assert ("read", 1) not in steps.log and not futures[2].done()
+    steps.results[1].finish()
+    assert [f.result(timeout=10)[0] for f in futures[2:]] == [12, 13]
+    assert _counter(registry, "serve.batches") == 2
+    assert _counter(registry, "serve.ticks_overlapped") == 1
+
+
+def _lone_ticks(batcher, steps, registry):
+    """One client, one request of a tick's rows at a time: each tick is
+    delivered as soon as its result is computed, with no row behind it to
+    wait for, and none counts as overlapped."""
+    for k in range(3):
+        futures = [batcher.submit(_row(10 + i)) for i in range(2)]
+        assert steps.logged(("launch", k))
+        t0 = time.monotonic()
+        steps.results[k].finish()
+        assert [f.result(timeout=10)[0] for f in futures] == [10, 11]
+        # Not the 0.5 s of the gather window, nor the 50 ms of an idle wait.
+        assert time.monotonic() - t0 < 0.04
+    assert _counter(registry, "serve.batches") == 3
+    assert _counter(registry, "serve.ticks_overlapped") == 0
+
+
+def _a_failed_result(batcher, steps, registry):
+    """A result that cannot be read fails its tick's rows, not the rows of
+    the tick launched behind it."""
+    batcher.pause()
+    futures = [batcher.submit(_row(10 + i)) for i in range(4)]
+    batcher.resume()
+    assert steps.logged(("read", 0))
+    steps.results[0].finish(error=ValueError("tick 0"))
+    steps.results[1].finish()
+    for f in futures[:2]:
+        with pytest.raises(ValueError, match="tick 0"):
+            f.result(timeout=10)
+    assert [f.result(timeout=10)[0] for f in futures[2:]] == [12, 13]
+
+
+def _a_failed_launch(batcher, steps, registry):
+    """A launch that fails fails its own rows; the tick in flight is
+    delivered, and the batcher goes on."""
+    steps.fail_launch = (1,)
+    batcher.pause()
+    futures = [batcher.submit(_row(10 + i)) for i in range(4)]
+    batcher.resume()
+    for f in futures[2:]:
+        with pytest.raises(RuntimeError, match="launch 1"):
+            f.result(timeout=10)
+    steps.results[0].finish()
+    assert [f.result(timeout=10)[0] for f in futures[:2]] == [10, 11]
+    more = [batcher.submit(_row(20 + i)) for i in range(2)]
+    assert steps.logged(("launch", 2))
+    steps.results[2].finish()
+    assert [f.result(timeout=10)[0] for f in more] == [20, 21]
+    assert _counter(registry, "serve.ticks_overlapped") == 0
+
+
+def _close_delivers(batcher, steps, registry):
+    """``close()`` waits for the tick in flight and delivers it."""
+    futures = [batcher.submit(_row(10 + i)) for i in range(2)]
+    assert steps.logged(("launch", 0))
+    closing = threading.Thread(target=batcher.close)
+    closing.start()
+    time.sleep(0.02)
+    assert closing.is_alive() and not any(f.done() for f in futures)
+    steps.results[0].finish()
+    closing.join(10)
+    assert not closing.is_alive()
+    assert [f.result(timeout=10)[0] for f in futures] == [10, 11]
+    with pytest.raises(RuntimeError, match="closed"):
+        batcher.submit(_row())
+
+
+def _pause_holds_the_launch(batcher, steps, registry):
+    """``pause()`` holds the next launch, not the delivery of the tick in
+    flight."""
+    first = [batcher.submit(_row(10 + i)) for i in range(2)]
+    assert steps.logged(("launch", 0))
+    batcher.pause()
+    held = [batcher.submit(_row(20 + i)) for i in range(2)]
+    steps.results[0].finish()
+    assert [f.result(timeout=10)[0] for f in first] == [10, 11]
+    time.sleep(0.02)
+    assert ("launch", 1) not in steps.log and batcher.backlog() == 2
+    batcher.resume()
+    assert steps.logged(("launch", 1))
+    steps.results[1].finish()
+    assert [f.result(timeout=10)[0] for f in held] == [20, 21]
+
+
+def _shares_sum_to_the_histogram(batcher, steps, registry):
+    """Each row's ``device_ms`` is its share of its tick's OWN time: a tick
+    queued behind another is not billed that one's step, and the shares
+    sum to the ``serve.tick`` histogram."""
+    from spark_bam_tpu.obs import account
+
+    costs = [account.RequestCost("count") for _ in range(2)]
+    batcher.pause()
+    for cost in costs:  # a request a tick
+        token = account.bind(cost)
+        try:
+            for i in range(2):
+                batcher.submit(_row(10 + i))
+        finally:
+            account.reset(token)
+    batcher.resume()
+    assert steps.logged(("read", 0))
+    time.sleep(0.05)     # tick 0's step, and tick 1 queued behind it
+    steps.results[0].finish()
+    time.sleep(0.01)     # tick 1's own
+    steps.results[1].finish()
+    batcher.close()
+    hist = [h for h in registry.snapshot()["hists"]
+            if h["name"] == "serve.tick"]
+    assert sum(h["count"] for h in hist) == 2
+    assert sum(c.device_ms for c in costs) == pytest.approx(
+        sum(h["sum"] for h in hist), abs=1e-6)
+    assert costs[0].device_ms >= 50.0
+    assert 10.0 <= costs[1].device_ms < 45.0   # 60 or more from its put
+    # The rows' events carry the same length, and start where it starts.
+    ticks = [e for e in registry.events() if e["name"] == "serve.tick"]
+    assert [e["ms"] for e in ticks] == [
+        pytest.approx(c.device_ms, abs=1e-3) for c in costs]
+    assert ticks[1]["t"] == pytest.approx(
+        ticks[0]["t"] + ticks[0]["ms"] / 1e3, abs=2e-3)
+
+
+def test_every_row_is_answered_once_under_many_submitters(registry):
+    """More submitting threads than cores, the interpreter's switch
+    interval cut short: every row gets its own answer, every tick is
+    delivered, and the ticks after the first few ride behind another."""
+    import sys
+
+    from spark_bam_tpu.serve.batcher import Batcher
+
+    steps = _GatedSteps(finish_after=0.001)
+    batcher = Batcher(steps, width=64, batch_rows=4, tick_ms=0.2)
+    got, threads = {}, []
+
+    def client(k):
+        futures = [(n, batcher.submit(_row(n)))
+                   for n in range(k * 100, k * 100 + 40)]
+        got[k] = [(n, f.result(timeout=20)[0]) for n, f in futures]
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=client, args=(k,))
+                   for k in range(24)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(30)
+    finally:
+        sys.setswitchinterval(interval)
+        batcher.close()
+    assert not any(t.is_alive() for t in threads)
+    assert sorted(got) == list(range(24))
+    assert all(n == answer for rows in got.values() for n, answer in rows)
+    ticks = len(steps.results)
+    assert _counter(registry, "serve.batches") == ticks
+    assert sum(k * v for k, v in batcher.batch_sizes.items()) == 24 * 40
+    assert _counter(registry, "serve.ticks_overlapped") > ticks // 2
+
+
+@pytest.mark.parametrize("case", [
+    _tick_ahead, _lone_ticks, _a_failed_result, _a_failed_launch,
+    _close_delivers, _pause_holds_the_launch, _shares_sum_to_the_histogram,
+], ids=lambda case: case.__name__.lstrip("_"))
+def test_the_batcher_keeps_a_tick_ahead(registry, case):
+    from spark_bam_tpu.serve.batcher import Batcher
+
+    steps = _GatedSteps()
+    batcher = Batcher(steps, width=64, batch_rows=2, tick_ms=500.0)
+    try:
+        case(batcher, steps, registry)
+    finally:
+        for out in steps.results:
+            out.computed.set()
+        batcher.close()
+    assert {e["name"] for e in registry.events()} <= NAMES
 
 
 # ------------------------------------------------------- the --profile hook
